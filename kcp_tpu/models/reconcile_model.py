@@ -25,6 +25,7 @@ this step over a multi-device mesh.
 
 from __future__ import annotations
 
+import logging
 from typing import NamedTuple
 
 import jax
@@ -34,6 +35,8 @@ import numpy as np
 from ..ops.diff import apply_deltas, compact_patches, sync_decisions
 from ..ops.labelmatch import fanout_match
 from ..ops.placement import placement_changed, split_replicas
+
+log = logging.getLogger(__name__)
 
 
 class ReconcileState(NamedTuple):
@@ -125,6 +128,7 @@ def reconcile_step(state: ReconcileState, deltas: ReconcileDeltas,
             local_b = b // row_factor(mesh)
         else:
             local_b = 1  # fails the gate below -> XLA lanes
+    br = 0
     if use_pallas and local_b % 128 == 0:
         # 2+4 fused: one Pallas pass reads each row block into VMEM once
         # and emits the decision lanes + per-selector match counts
@@ -143,8 +147,13 @@ def reconcile_step(state: ReconcileState, deltas: ReconcileDeltas,
         br = max_block_rows(local_b, up_vals.shape[1],
                             labels=state.pair_hashes.shape[1],
                             per_row_mask=state.status_mask.ndim == 2)
-    else:
-        br = 0
+    if use_pallas and not br:
+        # the gate tells: runs at trace time, so once per compiled shape
+        log.warning(
+            "use_pallas=True but B=%d S=%d (rows per shard %d) fails the "
+            "kernel's gate (128-row multiples per shard, full slots per "
+            "row, a block inside the scoped-VMEM budget): this shape "
+            "serves the XLA lanes", b, up_vals.shape[1], local_b)
     if use_pallas and br:
         if mesh is not None:
             decision, status_upsync, match_counts = decide_and_match_sharded(
@@ -216,12 +225,12 @@ reconcile_step_jit = jax.jit(
 # ---------------------------------------------------------------------------
 # Packed wire format — one array per direction across the host<->device link.
 #
-# When the device sits behind a network tunnel (or another host, §2.3's
-# "gRPC link ships informer deltas to a JAX worker which returns patch
-# sets"), every array is its own transfer RPC; packing the tick's deltas
-# into ONE uint32 array and the patch set + stats into ONE int32 array
-# makes a tick exactly one upload and one download regardless of lane
-# count. Patch entries carry row index (20 bits), decision code (2 bits,
+# Every array that crosses the link is its own transfer (and, where the
+# device sits on another host — §2.3's "gRPC link ships informer deltas
+# to a JAX worker which returns patch sets" — its own RPC); packing the
+# tick's deltas into ONE uint32 array and the patch set + stats into ONE
+# int32 array makes a tick exactly one upload and one download regardless
+# of lane count. Patch entries carry row index (20 bits), decision code (2 bits,
 # bit 20-21) and the status-upsync flag (bit 23).
 #
 # Wire layout (int32):
@@ -445,26 +454,32 @@ def unpack_seg_counts(wire: np.ndarray, patch_capacity: int, r: int, p: int,
 
 
 class WireBuffers:
-    """Double-buffered host staging for the packed-delta wire.
+    """Rotating host staging for the packed-delta wire.
 
     The staging/donation contract of :func:`reconcile_step_packed`: the
-    resident state is donated every tick, but the packed array is NOT —
-    ``jax.device_put`` may still be reading the host buffer after it
-    returns (async dispatch). A single reused staging array would let
-    tick N+1's host-side packing scribble over tick N's in-flight
-    transfer; fresh ``np.zeros`` per tick is safe but pays an allocation
-    + page-fault cost on every tick of the hot loop. Two rotating
-    buffers make reuse safe at pipeline depth 2: ``acquire`` hands out
-    the least-recently-used (packed, acks) pair, first blocking — only
-    if the pipeline ran ahead of the transfer engine — until the device
-    arrays that last consumed that pair are ready.
+    resident state is donated every tick, but the packed array is NOT,
+    and the host buffer behind it stays in use after ``jax.device_put``
+    returns: an accelerator's transfer may still be reading it, and the
+    CPU backend's put is zero-copy — the step reads the numpy memory
+    itself, whenever the asynchronous dispatch gets to it, and the put's
+    own readiness says nothing about that (measured under load: a reused
+    buffer lost a whole tick's events with only the put gating it).
+    Fresh ``np.zeros`` per tick is safe but pays an allocation +
+    page-fault cost on every tick of the hot loop. Rotating buffers make
+    reuse safe: ``acquire`` hands out the least-recently-used (packed,
+    acks) pair, first blocking until the arrays committed for it are
+    ready — the puts AND the output of the step that consumed them, the
+    one signal every backend gives that the buffer has been read. With
+    one slot more than the pipeline's in-flight window that step has
+    always been collected by then, so the gate does not wait.
     """
 
     def __init__(self, depth: int = 2):
         self.depth = depth
         self._packed: list[np.ndarray | None] = [None] * depth
         self._acks: list[np.ndarray | None] = [None] * depth
-        # device arrays whose transfer last read each slot's host buffers
+        # the puts that last read each slot's host buffers, and the output
+        # of the step that consumed them
         self._pending: list[tuple | None] = [None] * depth
         self._i = 0
         self.reuse_waits = 0  # acquires that had to block on a transfer
@@ -497,8 +512,9 @@ class WireBuffers:
         return i, packed, acks
 
     def commit(self, slot: int, *device_arrays) -> None:
-        """Record the device arrays whose host->device transfer reads the
-        slot's buffers; the next acquire of this slot gates on them."""
+        """Record the device arrays whose readiness means the slot's
+        buffers have been read — the puts and the consuming step's output;
+        the next acquire of this slot gates on them."""
         self._pending[slot] = device_arrays
 
 

@@ -8,15 +8,18 @@ orchestration layer; anything that runs per-mutation or per-object goes
 through here when the library is available.
 
 The library is built on demand with ``make`` (toolchain is expected in
-the image); if building or loading fails, ``load()`` returns ``None``
-and every caller falls back to the pure-Python path — the native layer
-is an accelerator, never a requirement. Set ``KCP_TPU_NO_NATIVE=1`` to
-force the fallback (used by differential tests).
+the image); if building or loading fails, ``load()`` logs the error once
+at WARNING, returns ``None``, and every caller takes the pure-Python
+path — the native layer is an accelerator, never a requirement, but its
+absence is never silent (:func:`status` says which it was). Set
+``KCP_TPU_NO_NATIVE=1`` to force the Python path (used by differential
+tests).
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -27,9 +30,23 @@ from ..analysis.sanitize import make_lock
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
 _LIB_NAME = "libkcpnative.so"
 
+log = logging.getLogger(__name__)
+
 _lock = make_lock("native.load")
 _lib: ctypes.CDLL | None = None
 _load_attempted = False
+_built_now = False  # this process ran make for the library
+_load_error = ""  # why load() gave None (make's stderr or the dlopen error)
+
+
+def _make(*args: str) -> None:
+    """Run ``make`` in native/; a failure raises with make's own output."""
+    try:
+        subprocess.run(["make", "-s", "-C", _NATIVE_DIR, *args],
+                       check=True, capture_output=True, timeout=120)
+    except subprocess.CalledProcessError as e:
+        out = (e.stderr or e.stdout or b"").decode(errors="replace").strip()
+        raise RuntimeError(f"make failed (rc={e.returncode}): {out[-2000:]}") from e
 
 
 def _sources_newer_than_lib(lib_path: str) -> bool:
@@ -118,7 +135,7 @@ def _declare(lib: ctypes.CDLL) -> None:
 
 def load() -> ctypes.CDLL | None:
     """Load (building if needed) the native library, or None."""
-    global _lib, _load_attempted
+    global _lib, _load_attempted, _built_now, _load_error
     if os.environ.get("KCP_TPU_NO_NATIVE"):
         return None
     with _lock:
@@ -128,16 +145,29 @@ def load() -> ctypes.CDLL | None:
         lib_path = os.path.join(_NATIVE_DIR, _LIB_NAME)
         try:
             if not os.path.exists(lib_path) or _sources_newer_than_lib(lib_path):
-                subprocess.run(
-                    ["make", "-s", "-C", _NATIVE_DIR],
-                    check=True, capture_output=True, timeout=120,
-                )
+                _make()
+                _built_now = True
             lib = ctypes.CDLL(lib_path)
             _declare(lib)
             _lib = lib
-        except Exception:
+        except Exception as e:  # noqa: BLE001 — the Python paths serve
             _lib = None
+            _load_error = f"{type(e).__name__}: {e}"
+            log.warning("native library unavailable, serving the pure-Python "
+                        "paths: %s", _load_error)
         return _lib
+
+
+def status() -> tuple[str, str]:
+    """How :func:`load` went: ``("disabled" | "built" | "loaded" |
+    "unavailable", detail)`` — ``built`` means this process ran ``make``
+    just now, ``unavailable`` carries the build or load error."""
+    if os.environ.get("KCP_TPU_NO_NATIVE"):
+        return "disabled", "KCP_TPU_NO_NATIVE is set"
+    if load() is None:
+        return "unavailable", _load_error
+    return ("built" if _built_now else "loaded"), os.path.join(
+        os.path.abspath(_NATIVE_DIR), _LIB_NAME)
 
 
 def available() -> bool:
@@ -361,11 +391,8 @@ def load_tokenizer():
                 # compile against THIS interpreter's headers — the
                 # Makefile's PATH-python3 default could be a different
                 # Python whose ABI would segfault on dlopen
-                subprocess.run(
-                    ["make", "-s", "-C", _NATIVE_DIR, "kcptok.so",
-                     f"PYINC={sysconfig.get_paths()['include']}"],
-                    check=True, capture_output=True, timeout=120,
-                )
+                _make("kcptok.so",
+                      f"PYINC={sysconfig.get_paths()['include']}")
             import importlib.machinery
             import importlib.util
 
@@ -374,8 +401,10 @@ def load_tokenizer():
             mod = importlib.util.module_from_spec(spec)
             loader.exec_module(mod)
             _tok_mod = mod
-        except Exception:
+        except Exception as e:  # noqa: BLE001 — next tier down serves
             _tok_mod = None
+            log.warning("native tokenizer unavailable: %s: %s",
+                        type(e).__name__, e)
         return _tok_mod
 
 

@@ -50,7 +50,7 @@ def spawn_server(extra_args: list[str] | None = None,
            "--no-install-controllers", "--no-tls",
            "--syncer-mode", "none"] + list(extra_args or [])
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # a child never takes the parent's chip
     env.pop("KCP_FAULTS", None)  # engine-phase schedules stay engine-side
     env["KCP_NO_COMPILE_CACHE"] = "1"
     env.update({k: str(v) for k, v in (env_overrides or {}).items()})

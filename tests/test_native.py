@@ -482,3 +482,32 @@ class TestCrashPointFuzz:
         assert {k: v for k, v in w3.scan()} == states[-1]
         assert w3.rv == 40
         w3.close()
+
+
+def test_failed_build_is_logged_and_reported(monkeypatch, tmp_path, caplog):
+    """A library that cannot be built is never silent: load() gives None,
+    logs make's own error once at WARNING, and status() carries it."""
+    import logging
+
+    from kcp_tpu import native
+
+    (tmp_path / "Makefile").write_text("all:\n\t@echo no compiler here >&2; exit 7\n")
+    monkeypatch.setattr(native, "_NATIVE_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_load_attempted", False)
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_built_now", False)
+    monkeypatch.setattr(native, "_load_error", "")
+    with caplog.at_level(logging.WARNING, "kcp_tpu.native"):
+        assert native.load() is None
+        assert native.load() is None  # remembered: no second build, no second line
+    said = [r for r in caplog.records if "native library unavailable" in r.message]
+    assert len(said) == 1 and "no compiler here" in said[0].message
+    how, detail = native.status()
+    assert how == "unavailable" and "make failed" in detail
+
+
+def test_status_names_a_loaded_library():
+    from kcp_tpu import native
+
+    how, detail = native.status()
+    assert how in ("loaded", "built") and detail.endswith("libkcpnative.so")

@@ -381,3 +381,30 @@ def test_max_block_rows_vmem_cap():
     # divisibility: block must divide the local rows
     assert max_block_rows(1024 + 128, 64) == 128
     assert max_block_rows(100, 64) == 0  # not 128-divisible
+
+
+@pytest.mark.parametrize("b,s", [(100, 16), (128, 4096)],
+                         ids=["rows-not-128-multiple", "over-vmem-budget"])
+def test_gate_that_serves_the_xla_lanes_says_so(b, s, caplog):
+    """use_pallas=True on a shape the kernel's gate refuses still serves
+    (the XLA lanes), but never silently: one warning per traced shape."""
+    import logging
+
+    from kcp_tpu.models.reconcile_model import (
+        example_deltas,
+        example_state,
+        reconcile_step,
+    )
+
+    st = example_state(b=b, s=s, r=8, p=8, l=2, c=4, seed=1)
+    dl = example_deltas(b=b, s=s, d=8, seed=2)
+    step = jax.jit(reconcile_step, static_argnames=("use_pallas",))
+    with caplog.at_level(logging.WARNING, "kcp_tpu.models.reconcile_model"):
+        _state, plain = step(st, dl, use_pallas=False)
+        assert not caplog.records
+        _state, gated = step(st, dl, use_pallas=True)
+        step(st, dl, use_pallas=True)  # cached trace: no second warning
+    said = [r for r in caplog.records if "serves the XLA lanes" in r.message]
+    assert len(said) == 1, caplog.text
+    np.testing.assert_array_equal(np.asarray(plain.decision),
+                                  np.asarray(gated.decision))

@@ -191,3 +191,63 @@ def test_pipeline_modes_validated_and_metered():
     # blocked counters must have counted them
     assert ("fused_collect_ready_total" in exposition
             or "fused_collect_blocked_total" in exposition)
+
+
+class _Lagging:
+    """A device array stand-in that is not ready until waited on."""
+
+    def __init__(self):
+        self.waited = False
+
+    def is_ready(self) -> bool:
+        return self.waited
+
+    def block_until_ready(self) -> None:
+        self.waited = True
+
+
+def test_staging_reuse_waits_for_the_step_that_read_the_buffer():
+    """A staging buffer is handed out again only after the step that
+    consumed it has finished: its output is committed with the puts. The
+    CPU backend's device_put is zero-copy, so the put's own readiness says
+    nothing — gated on the puts alone, a reused buffer lost a whole tick's
+    events under load (found by chip_smoke.py's closed loop, PR 25)."""
+    from kcp_tpu.models.reconcile_model import WireBuffers
+
+    bufs = WireBuffers(depth=2)
+    slot, packed, _acks = bufs.acquire(64, S + 2, 8)
+    put, step_out = _Lagging(), _Lagging()
+    put.waited = True  # the transfer is long done; the step is not
+    bufs.commit(slot, put, step_out)
+    packed[:] = 7
+    bufs.acquire(64, S + 2, 8)  # the other slot: no wait
+    assert not step_out.waited and bufs.reuse_waits == 0
+    slot2, packed2, _acks = bufs.acquire(64, S + 2, 8)
+    assert slot2 == slot and packed2 is packed
+    assert step_out.waited and bufs.reuse_waits == 1
+    assert not packed.any()  # zeroed only after the wait
+
+
+@pytest.mark.parametrize("fleet", [True, False], ids=["fleet", "per-bucket"])
+def test_serving_core_commits_the_step_output_with_the_puts(fleet):
+    """Both submit paths gate staging reuse on (packed, acks, wire), over
+    one slot more than the in-flight window — so on a backend that keeps
+    pace the gate never waits."""
+    async def main():
+        core = FusedCore(batch_window=0.0005, fleet=fleet)
+        owner = RecordingOwner(core, 64)
+        await core.start()
+        owner.up_vals[:8] = 5
+        core.enqueue_many(owner.section, False, list(range(8)))
+        bucket = owner.section.bucket
+        assert await wait_until(lambda: bucket.stats["ticks"] >= 1, 10)
+        bufs = (core._fleet if fleet else bucket)._wire_bufs
+        assert bufs.depth == PIPELINE_DEPTH + 1
+        committed = [p for p in bufs._pending if p is not None]
+        assert committed and all(len(p) == 3 for p in committed)
+        packed_d, _acks_d, wire = committed[0]
+        assert wire.ndim == 1 and packed_d.ndim == 2
+        await core.stop()
+        assert bufs.reuse_waits == 0
+
+    asyncio.run(main())
