@@ -4418,10 +4418,10 @@ def trace_bench() -> int:
                                      "labels": {CLUSTER_LABEL: "bench-loc"}},
                         "data": {"v": "0"}}
                 ctx = obs.TRACER.mint(sampled=True)
-                t0 = time.time()
+                t0 = time.monotonic()  # obs.phase stamps are monotonic
                 with obs.use(ctx):
                     resp = driver.create("configmaps", body)
-                t_ack = time.time()
+                t_ack = time.monotonic()
                 rv = resp["metadata"]["resourceVersion"]
                 obs.phase("write", ctx, t0, t_ack, rv=str(rv), obj=name)
                 deadline = time.time() + 30.0
@@ -4442,7 +4442,7 @@ def trace_bench() -> int:
                     await asyncio.sleep(0.01)
                 else:
                     raise RuntimeError(f"{name} status never upsynced")
-                t_obs = time.time()
+                t_obs = time.monotonic()
                 obs.phase("e2e", ctx, t0, t_obs, rv=str(rv), obj=name)
                 # assemble: router scatter (client→router→shard→repl
                 # spans) + the engine's rv-linked convergence fragment

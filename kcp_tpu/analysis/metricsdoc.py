@@ -5,14 +5,16 @@ code but absent from docs/operations.md is invisible telemetry, and a
 documented metric nothing registers is a runbook that lies. This checker
 extracts every ``REGISTRY.counter/gauge/histogram`` registration (literal
 names exactly; f-string names as globs, e.g. ``fused_{name}_seconds`` ->
-``fused_*_seconds``) plus ``span("x")`` sites (which register
-``x_seconds``), and reconciles both directions against the backticked
+``fused_*_seconds``), and reconciles both directions against the backticked
 tokens of docs/operations.md — ``<name>``/``*`` in doc tokens match glob
 segments, so ``workqueue_depth_<name>`` documents the
 ``workqueue_depth_{queue}`` family.
 
 Trace spans get the same discipline (PR 12): every literal name at an
-``obs.span(...)`` / ``obs.record_span(...)`` call site, and every phase
+``obs.span(...)`` / ``obs.record_span(...)`` / ``obs.annotate(...)``
+call site (an annotation is a span on the profiler's timeline; an
+f-string name there is a glob, ``kcp.tick.{name}`` -> ``kcp.tick.*``,
+documented by a ``<placeholder>`` row), and every phase
 literal at an ``obs.phase(...)`` site (which records ``conv.<phase>``),
 must appear as a backticked token inside the trace-span table region of
 docs/operations.md (delimited by ``<!-- trace-spans:begin -->`` /
@@ -76,10 +78,6 @@ def collect_code_metrics(files: list[SourceFile]
                 if not recv.endswith("registry"):
                     continue
                 lits, gls = _name_args(node.args[0])
-            elif isinstance(fn, ast.Name) and fn.id == "span":
-                lits, gls = _name_args(node.args[0])
-                lits = [s + "_seconds" for s in lits]
-                gls = [g + "_seconds" for g in gls]
             else:
                 continue
             for lit in lits:
@@ -114,12 +112,13 @@ SPAN_END = "<!-- trace-spans:end -->"
 
 #: obs call sites whose first literal argument names a span (phase
 #: literals record as ``conv.<phase>``)
-SPAN_METHODS = frozenset({"span", "record_span"})
+SPAN_METHODS = frozenset({"span", "record_span", "annotate"})
 
 
 def collect_code_spans(files: list[SourceFile]) -> dict[str, tuple[str, int]]:
     """Span name -> first call site, from literal ``obs.span``/
-    ``obs.record_span``/``obs.phase`` arguments across the file set."""
+    ``obs.record_span``/``obs.annotate``/``obs.phase`` arguments across
+    the file set; an f-string name is kept as a glob (``kcp.tick.*``)."""
     spans: dict[str, tuple[str, int]] = {}
     for f in files:
         for node in ast.walk(f.tree):
@@ -133,12 +132,11 @@ def collect_code_spans(files: list[SourceFile]) -> dict[str, tuple[str, int]]:
             recv = attr_chain(fn.value)
             if not recv.endswith("obs"):
                 continue
-            arg = node.args[0]
-            if not (isinstance(arg, ast.Constant)
-                    and isinstance(arg.value, str)):
-                continue
-            name = arg.value if fn.attr != "phase" else "conv." + arg.value
-            spans.setdefault(name, (f.path, node.lineno))
+            lits, globs = _name_args(node.args[0])
+            for name in lits + [g for g in globs if g != "*"]:
+                if fn.attr == "phase":
+                    name = "conv." + name
+                spans.setdefault(name, (f.path, node.lineno))
     return spans
 
 
@@ -236,19 +234,25 @@ class MetricsDocChecker(RepoChecker):
         findings: list[Finding] = []
         code_spans = collect_code_spans(files)
         doc_spans = collect_doc_spans(docs_path)
+        concrete = {t: _doc_token_concrete(t) for t in doc_spans}
         for name, (path, line) in sorted(code_spans.items()):
-            if name not in doc_spans:
+            if name not in doc_spans and not (
+                    "*" in name and any(fnmatch.fnmatchcase(c, name)
+                                        for c in concrete.values())):
                 findings.append(Finding(
                     self.name, path, line,
                     f"trace span {name!r} is recorded here but absent "
                     f"from the trace-span table in {DOCS_REL} (between "
                     f"the trace-spans markers) — document it"))
         for tok, lineno in sorted(doc_spans.items()):
-            if tok not in code_spans:
+            if tok not in code_spans and not any(
+                    "*" in g and fnmatch.fnmatchcase(concrete[tok], g)
+                    for g in code_spans):
                 findings.append(Finding(
                     self.name, DOCS_REL, lineno,
                     f"the trace-span table documents {tok!r} but no "
-                    f"obs.span/obs.phase/obs.record_span call site "
+                    f"obs.span/obs.phase/obs.record_span/obs.annotate "
+                    f"call site "
                     f"records it — stale docs or a renamed span"))
         return findings
 

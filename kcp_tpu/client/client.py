@@ -36,6 +36,13 @@ class Client:
     def scoped(self, cluster: str) -> "Client":
         return Client(self._store, cluster, self.scheme)
 
+    @property
+    def last_commit(self) -> float:
+        """``time.monotonic()`` commit stamp of the store's newest event:
+        read right after a write, it is that write's commit — the stamp
+        its event carries to every watcher (obs/trace.py PHASES)."""
+        return self._store.last_commit
+
     # -- reads ---------------------------------------------------------
 
     def get(self, gvr: GVR | str, name: str, namespace: str = "") -> dict:

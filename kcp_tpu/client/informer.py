@@ -92,6 +92,13 @@ class Informer:
         # no resync) — so a stream dropped after a quiet period resumes
         # inside the watch window instead of relisting the world
         self._rv = 0
+        # convergence-phase stamps of the event being dispatched
+        # (obs/trace.py PHASES): (write entry, commit), time.monotonic(),
+        # as an in-process store stamped them on the shared Event; None
+        # outside a dispatch (replays, resyncs, relists) and for events
+        # that crossed a wire. Handlers keep their (type, old, new)
+        # signature and read this while they run.
+        self.event_stamps: tuple[float | None, float] | None = None
         # KEP-3157-style watch-list start (opt-in: ctor arg, or
         # KCP_WATCH_LIST=1): the initial state arrives as ADDED events
         # on the watch stream itself, ending in a sync BOOKMARK — the
@@ -356,7 +363,16 @@ class Informer:
         return rv
 
     def _dispatch(self, ev: Event) -> None:
-        self._apply(ev.type, ev.object)
+        d = ev.__dict__
+        tm = d.get("_tm")
+        if tm is None:
+            self._apply(ev.type, ev.object)
+            return
+        self.event_stamps = (d.get("_tw"), tm)
+        try:
+            self._apply(ev.type, ev.object)
+        finally:
+            self.event_stamps = None
 
     async def _resync_loop(self) -> None:
         while True:

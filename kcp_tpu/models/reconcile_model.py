@@ -103,17 +103,22 @@ def reconcile_step(state: ReconcileState, deltas: ReconcileDeltas,
                    patch_capacity: int = 8192, use_pallas: bool = False,
                    mesh=None,
                    ) -> tuple[ReconcileState, ReconcileOutputs]:
+    # Every lane runs under a jax.named_scope: metadata only (same HLO,
+    # same fusions), so that a profiler trace's device operations can be
+    # grouped by the lane they came from instead of read as fusion.13.
     # 1. scatter deltas, routed by side (ops/diff.apply_deltas owns the
     #    padding-drop and dedup-by-key contract: delta batches must carry
     #    unique indices)
-    up_vals, up_exists = apply_deltas(
-        state.up_vals, state.up_exists, deltas.idx,
-        deltas.vals, deltas.exists, deltas.valid & ~deltas.side,
-    )
-    down_vals, down_exists = apply_deltas(
-        state.down_vals, state.down_exists, deltas.idx,
-        deltas.vals, deltas.exists, deltas.valid & deltas.side,
-    )
+    with jax.named_scope("apply_deltas_up"):
+        up_vals, up_exists = apply_deltas(
+            state.up_vals, state.up_exists, deltas.idx,
+            deltas.vals, deltas.exists, deltas.valid & ~deltas.side,
+        )
+    with jax.named_scope("apply_deltas_down"):
+        down_vals, down_exists = apply_deltas(
+            state.down_vals, state.down_exists, deltas.idx,
+            deltas.vals, deltas.exists, deltas.valid & deltas.side,
+        )
 
     b = up_vals.shape[0]
     local_b = b
@@ -155,47 +160,53 @@ def reconcile_step(state: ReconcileState, deltas: ReconcileDeltas,
             "row, a block inside the scoped-VMEM budget): this shape "
             "serves the XLA lanes", b, up_vals.shape[1], local_b)
     if use_pallas and br:
-        if mesh is not None:
-            decision, status_upsync, match_counts = decide_and_match_sharded(
-                mesh, up_vals, up_exists, down_vals, down_exists,
-                state.status_mask, state.pair_hashes, state.sel_hashes,
-                block_rows=br,
-            )
-        else:
-            decision, status_upsync, match_counts = decide_and_match(
-                up_vals, up_exists, down_vals, down_exists, state.status_mask,
-                state.pair_hashes, state.sel_hashes, block_rows=br,
-            )
-        matched_total = match_counts.sum(dtype=jnp.int32)
+        with jax.named_scope("decide_and_match_pallas"):
+            if mesh is not None:
+                decision, status_upsync, match_counts = decide_and_match_sharded(
+                    mesh, up_vals, up_exists, down_vals, down_exists,
+                    state.status_mask, state.pair_hashes, state.sel_hashes,
+                    block_rows=br,
+                )
+            else:
+                decision, status_upsync, match_counts = decide_and_match(
+                    up_vals, up_exists, down_vals, down_exists,
+                    state.status_mask, state.pair_hashes, state.sel_hashes,
+                    block_rows=br,
+                )
+            matched_total = match_counts.sum(dtype=jnp.int32)
     else:
         # 2. syncer lanes
-        d = sync_decisions(up_vals, up_exists, down_vals, down_exists,
-                           state.status_mask)
-        decision, status_upsync = d.decision, d.status_upsync
+        with jax.named_scope("sync_decisions"):
+            d = sync_decisions(up_vals, up_exists, down_vals, down_exists,
+                               state.status_mask)
+            decision, status_upsync = d.decision, d.status_upsync
 
         # 4. informer fan-out lane — only resident upstream objects fan
         #    out (pair_hashes rows of deleted objects are stale, not
         #    cleared)
-        match = fanout_match(state.pair_hashes, state.sel_hashes) & up_exists[:, None]  # [B, C]
-        match_counts = match.sum(axis=0, dtype=jnp.int32)
-        matched_total = match.sum(dtype=jnp.int32)
+        with jax.named_scope("fanout_match"):
+            match = fanout_match(state.pair_hashes, state.sel_hashes) & up_exists[:, None]  # [B, C]
+            match_counts = match.sum(axis=0, dtype=jnp.int32)
+            matched_total = match.sum(dtype=jnp.int32)
 
     # 3. splitter lane
-    leaf = split_replicas(state.replicas, state.avail)
-    p_dirty = placement_changed(state.current, leaf)
+    with jax.named_scope("placement"):
+        leaf = split_replicas(state.replicas, state.avail)
+        p_dirty = placement_changed(state.current, leaf)
 
     # 5. global stats — under a sharded mesh these reductions lower to
     #    XLA collectives over the tenants/slots axes
-    stats = jnp.stack([
-        up_exists.sum(dtype=jnp.int32),
-        (decision == 1).sum(dtype=jnp.int32),
-        (decision == 2).sum(dtype=jnp.int32),
-        (decision == 3).sum(dtype=jnp.int32),
-        status_upsync.sum(dtype=jnp.int32),
-        p_dirty.sum(dtype=jnp.int32),
-        matched_total,
-        deltas.valid.sum(dtype=jnp.int32),
-    ])
+    with jax.named_scope("stats"):
+        stats = jnp.stack([
+            up_exists.sum(dtype=jnp.int32),
+            (decision == 1).sum(dtype=jnp.int32),
+            (decision == 2).sum(dtype=jnp.int32),
+            (decision == 3).sum(dtype=jnp.int32),
+            status_upsync.sum(dtype=jnp.int32),
+            p_dirty.sum(dtype=jnp.int32),
+            matched_total,
+            deltas.valid.sum(dtype=jnp.int32),
+        ])
 
     new_state = ReconcileState(
         up_vals=up_vals, up_exists=up_exists,
@@ -204,7 +215,8 @@ def reconcile_step(state: ReconcileState, deltas: ReconcileDeltas,
         replicas=state.replicas, avail=state.avail, current=leaf,
         pair_hashes=state.pair_hashes, sel_hashes=state.sel_hashes,
     )
-    patches = compact_patches(decision, status_upsync, patch_capacity)
+    with jax.named_scope("compact_patches"):
+        patches = compact_patches(decision, status_upsync, patch_capacity)
     outputs = ReconcileOutputs(
         patch_idx=patches.idx, patch_code=patches.code,
         patch_upsync=patches.upsync, patch_count=patches.count,
@@ -341,44 +353,52 @@ def reconcile_step_packed(state: ReconcileState, packed: jax.Array,
             f"shard the bucket or use the unpacked ReconcileOutputs lanes"
         )
     if acks is not None and state.up_vals.shape[0] > 0:
-        b = state.up_vals.shape[0]
-        valid = (acks >= 0) & (acks < b)
-        # padding (-1) must not scatter AT ALL: clipping it to a real row
-        # would race that row's genuine ack (duplicate-index scatter order
-        # is unspecified) — route padding out of bounds and drop it
-        idx = jnp.where(valid, acks, b)
-        gather = jnp.clip(acks, 0, b - 1)
-        down_vals = state.down_vals.at[idx].set(
-            state.up_vals[gather], mode="drop")
-        down_exists = state.down_exists.at[idx].set(
-            state.up_exists[gather], mode="drop")
-        state = state._replace(down_vals=down_vals, down_exists=down_exists)
-    state = state._replace(
-        status_mask=apply_mask_stamps(state.status_mask, packed))
-    new_state, out = reconcile_step(state, unpack_deltas(packed), patch_capacity,
-                                    use_pallas=use_pallas, mesh=mesh)
-    entries = (
-        out.patch_idx
-        | (out.patch_code.astype(jnp.int32) << PACK_CODE_SHIFT)
-        | jnp.where(out.patch_upsync, PACK_UPSYNC_BIT, 0)
-    )
-    # placement segment: dirty roots compacted first, each carrying its
-    # P leaf counts (the deployment splitter's serving lane)
-    r = state.replicas.shape[0]
-    dirty = out.placement_dirty
-    (pidx,) = jnp.nonzero(dirty, size=r, fill_value=r)
-    safe = jnp.minimum(pidx, r - 1)
-    valid = pidx < r
-    counts = jnp.where(valid[:, None], out.leaf_replicas[safe], 0)
-    pl_entries = jnp.concatenate(
-        [pidx.astype(jnp.int32)[:, None], counts.astype(jnp.int32)], axis=1
-    ).reshape(-1)
-    hdr = jnp.zeros(PACK_HDR, jnp.int32)
-    hdr = hdr.at[0].set(out.patch_count)
-    hdr = hdr.at[1].set(out.patch_overflow.astype(jnp.int32))
-    hdr = hdr.at[2:10].set(out.stats)
-    hdr = hdr.at[PACK_PLACEMENT_COUNT].set(dirty.sum(dtype=jnp.int32))
-    return new_state, jnp.concatenate([hdr, entries, pl_entries])
+        with jax.named_scope("apply_acks"):
+            b = state.up_vals.shape[0]
+            valid = (acks >= 0) & (acks < b)
+            # padding (-1) must not scatter AT ALL: clipping it to a real
+            # row would race that row's genuine ack (duplicate-index
+            # scatter order is unspecified) — route padding out of bounds
+            # and drop it
+            idx = jnp.where(valid, acks, b)
+            gather = jnp.clip(acks, 0, b - 1)
+            down_vals = state.down_vals.at[idx].set(
+                state.up_vals[gather], mode="drop")
+            down_exists = state.down_exists.at[idx].set(
+                state.up_exists[gather], mode="drop")
+            state = state._replace(down_vals=down_vals,
+                                   down_exists=down_exists)
+    with jax.named_scope("apply_mask_stamps"):
+        state = state._replace(
+            status_mask=apply_mask_stamps(state.status_mask, packed))
+    with jax.named_scope("reconcile_step"):
+        new_state, out = reconcile_step(
+            state, unpack_deltas(packed), patch_capacity,
+            use_pallas=use_pallas, mesh=mesh)
+    with jax.named_scope("pack_wire"):
+        entries = (
+            out.patch_idx
+            | (out.patch_code.astype(jnp.int32) << PACK_CODE_SHIFT)
+            | jnp.where(out.patch_upsync, PACK_UPSYNC_BIT, 0)
+        )
+        # placement segment: dirty roots compacted first, each carrying
+        # its P leaf counts (the deployment splitter's serving lane)
+        r = state.replicas.shape[0]
+        dirty = out.placement_dirty
+        (pidx,) = jnp.nonzero(dirty, size=r, fill_value=r)
+        safe = jnp.minimum(pidx, r - 1)
+        valid = pidx < r
+        counts = jnp.where(valid[:, None], out.leaf_replicas[safe], 0)
+        pl_entries = jnp.concatenate(
+            [pidx.astype(jnp.int32)[:, None], counts.astype(jnp.int32)],
+            axis=1,
+        ).reshape(-1)
+        hdr = jnp.zeros(PACK_HDR, jnp.int32)
+        hdr = hdr.at[0].set(out.patch_count)
+        hdr = hdr.at[1].set(out.patch_overflow.astype(jnp.int32))
+        hdr = hdr.at[2:10].set(out.stats)
+        hdr = hdr.at[PACK_PLACEMENT_COUNT].set(dirty.sum(dtype=jnp.int32))
+        return new_state, jnp.concatenate([hdr, entries, pl_entries])
 
 
 # ---------------------------------------------------------------------------
@@ -436,12 +456,16 @@ def reconcile_step_fleet(state: ReconcileState, seg_ids: jax.Array,
     the host routes to the admission quota ledger. Out-of-range segment
     ids (padding, unowned rows) drop out of the scatter-add.
     """
-    seg_ids = apply_seg_stamps(seg_ids, packed)
-    new_state, wire = reconcile_step_packed(
-        state, packed, acks, patch_capacity, use_pallas=use_pallas, mesh=mesh)
-    counts = jnp.zeros(seg_capacity, jnp.int32).at[seg_ids].add(
-        new_state.up_exists.astype(jnp.int32), mode="drop")
-    return new_state, seg_ids, jnp.concatenate([wire, counts])
+    with jax.named_scope("reconcile_step_fleet"):
+        with jax.named_scope("apply_seg_stamps"):
+            seg_ids = apply_seg_stamps(seg_ids, packed)
+        new_state, wire = reconcile_step_packed(
+            state, packed, acks, patch_capacity, use_pallas=use_pallas,
+            mesh=mesh)
+        with jax.named_scope("seg_counts"):
+            counts = jnp.zeros(seg_capacity, jnp.int32).at[seg_ids].add(
+                new_state.up_exists.astype(jnp.int32), mode="drop")
+        return new_state, seg_ids, jnp.concatenate([wire, counts])
 
 
 def unpack_seg_counts(wire: np.ndarray, patch_capacity: int, r: int, p: int,

@@ -19,6 +19,7 @@ tests).
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import logging
 import os
 import subprocess
@@ -39,14 +40,32 @@ _built_now = False  # this process ran make for the library
 _load_error = ""  # why load() gave None (make's stderr or the dlopen error)
 
 
-def _make(*args: str) -> None:
-    """Run ``make`` in native/; a failure raises with make's own output."""
+def _ensure_built(target: str, *make_args: str) -> bool:
+    """Build ``native/<target>`` if it is missing or older than its
+    sources; True when this process ran make. The check and the build
+    sit under an inter-process lock (``flock`` on ``native/.build.lock``):
+    several processes starting on a fresh tree — pytest-xdist workers,
+    the shards of a deployment — would otherwise run make at once over
+    the same object files, and the losers would find a half-written
+    library and report it unavailable. A process that finds a build in
+    progress waits for it, then finds the target up to date."""
+    path = os.path.join(_NATIVE_DIR, target)
     try:
-        subprocess.run(["make", "-s", "-C", _NATIVE_DIR, *args],
-                       check=True, capture_output=True, timeout=120)
-    except subprocess.CalledProcessError as e:
-        out = (e.stderr or e.stdout or b"").decode(errors="replace").strip()
-        raise RuntimeError(f"make failed (rc={e.returncode}): {out[-2000:]}") from e
+        lock_fh = open(os.path.join(_NATIVE_DIR, ".build.lock"), "w")
+    except OSError:  # a read-only tree builds nothing: no one to wait for
+        lock_fh = open(os.devnull, "w")
+    with lock_fh:
+        fcntl.flock(lock_fh, fcntl.LOCK_EX)  # released when the file closes
+        if os.path.exists(path) and not _sources_newer_than_lib(path):
+            return False
+        try:
+            subprocess.run(["make", "-s", "-C", _NATIVE_DIR, *make_args],
+                           check=True, capture_output=True, timeout=120)
+        except subprocess.CalledProcessError as e:
+            out = (e.stderr or e.stdout or b"").decode(errors="replace").strip()
+            raise RuntimeError(
+                f"make failed (rc={e.returncode}): {out[-2000:]}") from e
+        return True
 
 
 def _sources_newer_than_lib(lib_path: str) -> bool:
@@ -144,9 +163,7 @@ def load() -> ctypes.CDLL | None:
         _load_attempted = True
         lib_path = os.path.join(_NATIVE_DIR, _LIB_NAME)
         try:
-            if not os.path.exists(lib_path) or _sources_newer_than_lib(lib_path):
-                _make()
-                _built_now = True
+            _built_now = _ensure_built(_LIB_NAME)
             lib = ctypes.CDLL(lib_path)
             _declare(lib)
             _lib = lib
@@ -385,14 +402,13 @@ def load_tokenizer():
         _tok_tried = True
         path = os.path.join(_NATIVE_DIR, "kcptok.so")
         try:
-            if not os.path.exists(path) or _sources_newer_than_lib(path):
-                import sysconfig
+            import sysconfig
 
-                # compile against THIS interpreter's headers — the
-                # Makefile's PATH-python3 default could be a different
-                # Python whose ABI would segfault on dlopen
-                _make("kcptok.so",
-                      f"PYINC={sysconfig.get_paths()['include']}")
+            # compile against THIS interpreter's headers — the
+            # Makefile's PATH-python3 default could be a different
+            # Python whose ABI would segfault on dlopen
+            _ensure_built("kcptok.so", "kcptok.so",
+                          f"PYINC={sysconfig.get_paths()['include']}")
             import importlib.machinery
             import importlib.util
 

@@ -5,6 +5,7 @@ from .trace import (
     TRACEPARENT,
     TRACER,
     TraceContext,
+    annotate,
     conv_begin,
     ctx_from_wal,
     current,
@@ -20,7 +21,8 @@ from .trace import (
 )
 
 __all__ = [
-    "PHASES", "TRACEPARENT", "TRACER", "TraceContext", "conv_begin",
+    "PHASES", "TRACEPARENT", "TRACER", "TraceContext", "annotate",
+    "conv_begin",
     "ctx_from_wal", "current", "link_obj", "obj_link", "phase",
     "record_span", "reset_current", "set_current", "span", "use",
     "write_ctx",

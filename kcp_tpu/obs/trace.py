@@ -26,10 +26,16 @@ layer for the kcp-tpu fleet, Dapper-style:
   object-identity link (:func:`link_obj`) so an in-process informer's
   snapshot resolves back to the committing trace with one dict probe;
 - the convergence decomposition: :func:`phase` records one contiguous
-  segment of the spec→status timeline as both a ``conv.<phase>`` span
-  and a ``convergence_<phase>_seconds`` histogram — phases share
-  boundary timestamps, so their sum telescopes to the end-to-end wall
-  time by construction (the ``bench.py --trace`` reconciliation gate).
+  segment of the spec→status timeline as a
+  ``convergence_<phase>_seconds`` observation for EVERY write and as a
+  ``conv.<phase>`` span for a sampled one — phases share boundary
+  stamps (all ``time.monotonic()``), so their sum telescopes to the
+  end-to-end time by construction (the ``bench.py --trace``
+  reconciliation gate);
+- the same boundaries on the profiler's clock: :func:`annotate` is a
+  ``jax.profiler.TraceAnnotation`` (live exactly while a profiler
+  session is open) around the synchronous ``kcp.*`` sections of the
+  tick, the store, the applier and the watch relay.
 
 Wire neutrality is a hard contract: tracing adds a request header on
 client hops and nothing else — response bytes, watch streams, and stored
@@ -46,6 +52,7 @@ import contextlib
 import contextvars
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -58,15 +65,33 @@ from ..utils.trace import REGISTRY
 #: the W3C propagation header (lower-cased: the httpd lower-cases keys)
 TRACEPARENT = "traceparent"
 
-#: the convergence phases, in timeline order. ``write`` (client spec
-#: write round trip), ``propagate`` (ack → syncer staged; derived from
-#: span boundaries), ``stage`` (staged → tick start), ``tick`` (the
-#: device/host reconcile tick that carried the row), ``patch`` (tick end
-#: → downstream write applied), ``downstream`` (downstream status churn
-#: → re-staged), ``upstatus`` (status upsync to the upstream store),
-#: ``observe`` (status committed → the driver observed it; derived).
+#: the convergence phases, in timeline order; adjacent phases share
+#: their boundary stamp. ``write`` (entry of the serving handler — or of
+#: the store, for an in-process writer — → the commit stamp on the
+#: write's event), ``propagate`` (commit → the syncer engine staged the
+#: key: commit window, watch fan-out, informer), ``stage`` (staged →
+#: start of the tick that carried the row), ``tick`` (tick start → that
+#: tick's patches handed to the applier, the pipeline's wait included),
+#: ``patch`` (patches handed over → downstream write applied),
+#: ``downstream`` (downstream write applied → the downstream status
+#: event re-staged the row: the physical cluster's controller),
+#: ``upstatus`` (re-staged → status committed upstream), ``observe``
+#: (commit of an event → its frame handed to the transport of an HTTP
+#: watch stream; every delivered event, spec echo and status alike).
 PHASES = ("write", "propagate", "stage", "tick", "patch", "downstream",
           "upstatus", "observe")
+
+#: the phase histograms, fetched once: an observation is a dict probe,
+#: a bisect and the histogram's own leaf lock — never the registry's
+_PHASE_H = {
+    p: REGISTRY.histogram(
+        f"convergence_{p}_seconds",
+        "one phase of the spec-to-status convergence timeline")
+    for p in PHASES}
+
+# phase stamps are time.monotonic(); a span's t0 is wall-clock (spans
+# from several processes are merged by t0). One offset per process.
+_MONO_TO_WALL = time.time() - time.monotonic()
 
 _current: contextvars.ContextVar["TraceContext | None"] = \
     contextvars.ContextVar("kcp_trace_ctx", default=None)
@@ -341,18 +366,46 @@ def record_span(name: str, ctx: TraceContext, parent: str | None,
 
 def phase(name: str, ctx: TraceContext | None, t0: float, t1: float,
           **attrs: Any) -> None:
-    """One convergence phase: a ``convergence_<phase>_seconds``
-    observation always, plus a ``conv.<name>`` span when sampled.
-    Adjacent phases share boundary timestamps, so the per-phase sum
-    telescopes to the end-to-end wall time."""
+    """One convergence phase between two ``time.monotonic()`` stamps: a
+    ``convergence_<phase>_seconds`` observation always (every write,
+    whatever the sampling coin said), plus a ``conv.<name>`` span when
+    ``ctx`` is sampled. Adjacent phases share boundary stamps, so the
+    per-phase sum telescopes to the end-to-end time."""
     dur = max(0.0, t1 - t0)
-    REGISTRY.histogram(
-        f"convergence_{name}_seconds",
-        "one phase of the spec-to-status convergence timeline").observe(dur)
+    h = _PHASE_H.get(name)
+    if h is None:  # a driver's own root ("e2e"): rare, off the hot path
+        h = _PHASE_H[name] = REGISTRY.histogram(
+            f"convergence_{name}_seconds",
+            "one phase of the spec-to-status convergence timeline")
+    h.observe(dur)
     if ctx is not None and ctx.sampled and TRACER.enabled:
         sub = TRACER.child(ctx)
-        TRACER.record("conv." + name, sub, ctx.span_id, t0, dur,
-                      attrs or None)
+        TRACER.record("conv." + name, sub, ctx.span_id,
+                      t0 + _MONO_TO_WALL, dur, attrs or None)
+
+
+_trace_annotation = None  # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def annotate(name: str, **stats: Any):
+    """A host annotation on the JAX profiler's clock around a
+    SYNCHRONOUS section (it is a thread-scoped begin/end: no ``await``
+    inside): a ``jax.profiler.TraceAnnotation`` while a profiler session
+    is open, the no-op singleton otherwise — so "tracing off" costs one
+    flag read per section (these sections run thousands of times a
+    second on a loop whose queueing multiplies every microsecond). A
+    process that never imported jax (router, load generator) never
+    imports it here. ``stats`` ride the event (``kcp.tick`` carries
+    ``tick`` and ``mono``, the ``time.monotonic()`` of its start, so a
+    reader can place monotonic stamps on the profiler's timeline)."""
+    global _trace_annotation
+    ta = _trace_annotation
+    if ta is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return _NOOP
+        ta = _trace_annotation = jax.profiler.TraceAnnotation
+    return ta(name, **stats) if ta.is_enabled() else _NOOP
 
 
 def write_ctx() -> TraceContext | None:

@@ -27,10 +27,12 @@ from __future__ import annotations
 
 import copy
 import logging
+import time
 from typing import Sequence
 
 import numpy as np
 
+from ... import obs
 from ...apis.cluster import CLUSTERS
 from ...apis.scheme import GVR
 from ...client import Client, Informer
@@ -55,6 +57,17 @@ _COUNTERS = ("replicas", "updatedReplicas", "readyReplicas",
 # before its leaf deployments drain — a Ready->NotReady->Ready flap
 # inside the window causes ZERO placement churn (hysteresis)
 DEFAULT_EVAC_HYSTERESIS = 5.0
+
+
+# fetched once: a root event -> its leaves written, and a leaf status
+# event -> the root's status written, one observation per root per pass
+_SPLIT_SECONDS = REGISTRY.histogram(
+    "splitter_split_seconds",
+    "a root's event to its leaves written (queue, placement lane, "
+    "applier, the leaf writes)")
+_AGGREGATE_SECONDS = REGISTRY.histogram(
+    "splitter_aggregate_seconds",
+    "a leaf's status event to the root's aggregated status written")
 
 
 def _labels(obj: dict) -> dict:
@@ -123,6 +136,11 @@ class DeploymentSplitter:
         self._apply_tasks: list = []
         self.stats = {"ticks": 0, "splits": 0, "aggregations": 0,
                       "fused_placements": 0}
+        # root key -> time.monotonic() of the first event not yet
+        # answered: a root's own (split) and its leaves' (aggregate).
+        # Popped by the pass that answers the key or finds nothing to do
+        self._split_t0: dict[tuple[str, str, str], float] = {}
+        self._agg_t0: dict[tuple[str, str, str], float] = {}
 
     @staticmethod
     def _owned_by_index(obj: dict) -> list[str]:
@@ -153,10 +171,12 @@ class DeploymentSplitter:
         m = obj["metadata"]
         key = (m.get("clusterName", ""), m.get("namespace", ""), m["name"])
         if is_root(obj):
+            self._split_t0.setdefault(key, time.monotonic())
             self.controller.enqueue(("root", key))
         else:
             owner = _labels(obj).get(OWNED_BY_LABEL)
             root_key = (m.get("clusterName", ""), m.get("namespace", ""), owner)
+            self._agg_t0.setdefault(root_key, time.monotonic())
             self.controller.enqueue(("leaf", root_key))
 
     def _on_cluster_event(self, etype: str, old: dict | None, new: dict | None) -> None:
@@ -248,9 +268,11 @@ class DeploymentSplitter:
                     self._pbucket.free_pl_row(key)
                     self._staged_n.pop(key, None)
                     self._retry_counts.pop(key, None)
+                self._split_t0.pop(key, None)
                 continue
             leafs = self.informer.index("owned_by", "/".join(key))
             if leafs and not self.rebalance and key not in self._force_replan:
+                self._split_t0.pop(key, None)
                 continue  # reference behavior: only split once
             clusters = self._clusters_for(key[0])
             plan_rows.append((key, root, clusters, leafs))
@@ -322,11 +344,12 @@ class DeploymentSplitter:
         agg_rows = []
         for key in aggregates:
             root = self.informer.cache.get(key)
-            if root is None:
-                continue
-            leafs = self.informer.index("owned_by", "/".join(key))
+            leafs = (self.informer.index("owned_by", "/".join(key))
+                     if root is not None else None)
             if leafs:
                 agg_rows.append((key, root, leafs))
+            else:
+                self._agg_t0.pop(key, None)
         if agg_rows:
             width = pad_pow2(
                 max((len(r[2]) for r in agg_rows), default=1), floor=self.max_pclusters
@@ -444,6 +467,20 @@ class DeploymentSplitter:
         existing_leafs: list[dict],
         counts: np.ndarray,
     ) -> None:
+        with obs.annotate("kcp.split"):
+            self._write_placement(key, root, clusters, existing_leafs, counts)
+        t0 = self._split_t0.pop(key, None)
+        if t0 is not None:
+            _SPLIT_SECONDS.observe(time.monotonic() - t0)
+
+    def _write_placement(
+        self,
+        key: tuple[str, str, str],
+        root: dict,
+        clusters: list[dict],
+        existing_leafs: list[dict],
+        counts: np.ndarray,
+    ) -> None:
         lc, ns, name = key
         # forced replans (evacuation drain / readmission) move replicas
         # between existing leafs even when `rebalance` is off
@@ -543,6 +580,16 @@ class DeploymentSplitter:
     def _apply_aggregation(
         self, key: tuple[str, str, str], root: dict, leafs: list[dict], sums: np.ndarray
     ) -> None:
+        with obs.annotate("kcp.aggregate"):
+            written = self._write_aggregation(key, root, leafs, sums)
+        t0 = self._agg_t0.pop(key, None)
+        if written and t0 is not None:
+            _AGGREGATE_SECONDS.observe(time.monotonic() - t0)
+
+    def _write_aggregation(
+        self, key: tuple[str, str, str], root: dict, leafs: list[dict], sums: np.ndarray
+    ) -> bool:
+        """True when the root's status changed and was written."""
         lc, ns, name = key
         scoped = self.client.scoped(lc)
         fresh = scoped.get(DEPLOYMENTS, name, ns)
@@ -560,6 +607,7 @@ class DeploymentSplitter:
         if changed:
             scoped.update_status(DEPLOYMENTS, fresh, namespace=ns)
             self.stats["aggregations"] += 1
+        return changed
 
     # ---------------------------------------------------------- lifecycle
 

@@ -198,7 +198,7 @@ def run_writer(base_url: str, tenant: str, ops: list[Op], stats: WriterStats,
                 tctx = None
                 if obs.TRACER.enabled and obs.TRACER.head_sampled():
                     tctx = obs.TRACER.mint(sampled=True)
-                tw0 = time.time()
+                tw0 = time.monotonic()
                 try:
                     with obs.use(tctx):
                         if op.kind == "create":
@@ -216,7 +216,7 @@ def run_writer(base_url: str, tenant: str, ops: list[Op], stats: WriterStats,
                         rv = int(resp.get("metadata", {})
                                  .get("resourceVersion", "0"))
                     if tctx is not None and tctx.sampled:
-                        obs.phase("write", tctx, tw0, time.time(),
+                        obs.phase("write", tctx, tw0, time.monotonic(),
                                   rv=str(rv), obj=op.name)
                     stats.ack(op.tenant, op.name, rv, op.kind)
                     break

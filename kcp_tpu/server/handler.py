@@ -125,6 +125,22 @@ class RestHandler:
         self._enc_seconds = REGISTRY.histogram(
             "response_encode_seconds",
             "time serializing one list/get/watch-batch response body")
+        # where a write request waits, every request (the `write` phase
+        # of a convergence from the inside): admission with flow-control
+        # parking; the store call (thread hop for a remote store,
+        # mutation, joining the commit window); the durability barrier,
+        # the standby wait and the response encode
+        self._adm_seconds = REGISTRY.histogram(
+            "request_admission_seconds",
+            "admission of one write request, flow-control parking included")
+        self._commit_seconds = REGISTRY.histogram(
+            "request_commit_seconds",
+            "the store call of one write request (mutation + commit-window "
+            "join; the thread hop on a storage frontend)")
+        self._finish_seconds = REGISTRY.histogram(
+            "request_finish_seconds",
+            "one write request from its store call's return to its "
+            "response: WAL commit window + sync, standby wait, encode")
         # RV-keyed list-body cache: the store RV increments on every
         # mutation, so (query shape, rv) fully determines a list
         # response's bytes — informer relists and polling dashboards
@@ -254,6 +270,7 @@ class RestHandler:
         SLO-breaching requests (> KCP_TRACE_SLO_MS), which force-record
         so a latency regression always comes with its own explanation.
         Under ``KCP_TRACE=0`` this wrapper is one attribute read."""
+        req.t0 = time.monotonic()
         tracer = obs.TRACER
         if not tracer.enabled:
             return await self._handle(req)
@@ -424,11 +441,17 @@ class RestHandler:
                 seconds = 2.0
             log_dir = req.param("dir") or tempfile.mkdtemp(
                 prefix="kcp-device-trace-")
-            with device_trace(log_dir) as started:
-                await asyncio.sleep(seconds)
+            try:
+                with device_trace(log_dir):
+                    await asyncio.sleep(seconds)
+            except Exception as e:  # noqa: BLE001 — reported, not swallowed
+                # the profiler could not start (a session is already
+                # open, no profiler on this backend): say so
+                return Response.of_json({
+                    "dir": log_dir, "seconds": seconds, "started": False,
+                    "error": f"{type(e).__name__}: {e}"}, 409)
             return Response.of_json({
-                "dir": log_dir, "seconds": seconds,
-                "started": bool(started),
+                "dir": log_dir, "seconds": seconds, "started": True,
                 "hint": "view with xprof/tensorboard --logdir",
             })
         if head == "replication":
@@ -678,27 +701,12 @@ class RestHandler:
         if req.method == "POST" and name is None:
             obj = self._body_object(req)
             target = resolve_write_cluster(cluster, obj, errors.BadRequestError)
-            # admission inline (reads never touch it): admit_nowait only
-            # hands back a coroutine when flow control parks the request,
-            # so the uncontended write path stays synchronous
-            adm = self.admission
-            if adm is None:
-                ticket = NOOP_TICKET
-            else:
-                with obs.span("admission.admit", verb="create"):
-                    got = adm.admit_nowait("create", res, target, namespace,
-                                           obj)
-                    ticket = got if hasattr(got, "ok") else await got
-            try:
-                created = await self._st(
-                    self.store.create, res, target, obj, namespace)
-            except BaseException:
-                ticket.fail()
-                raise
-            await self._finish_write(ticket)
-            return self._rv_stamped(
+            created, t_done = await self._write(
+                req, "create", res, target, namespace, obj,
+                self.store.create, res, target, obj, namespace)
+            return self._acked(t_done, self._rv_stamped(
                 Response.of_json(self._stamp(created, info, gv), 201),
-                (created.get("metadata") or {}).get("resourceVersion"))
+                (created.get("metadata") or {}).get("resourceVersion")))
 
         if req.method == "PUT" and name is not None:
             obj = self._body_object(req)
@@ -707,55 +715,70 @@ class RestHandler:
                 raise errors.BadRequestError(
                     f"name in URL ({name}) does not match name in object ({body_name})")
             target = resolve_write_cluster(cluster, obj, errors.BadRequestError)
-            adm = self.admission
-            if adm is None:
-                ticket = NOOP_TICKET
-            else:
-                with obs.span("admission.admit", verb="update"):
-                    got = adm.admit_nowait("update", res, target, namespace,
-                                           obj)
-                    ticket = got if hasattr(got, "ok") else await got
-            try:
-                if subresource == "status":
-                    updated = await self._st(
-                        self.store.update_status, res, target, obj, namespace)
-                else:
-                    updated = await self._st(
-                        self.store.update, res, target, obj, namespace)
-            except BaseException:
-                ticket.fail()
-                raise
-            await self._finish_write(ticket)
-            return self._rv_stamped(
+            updated, t_done = await self._write(
+                req, "update", res, target, namespace, obj,
+                (self.store.update_status if subresource == "status"
+                 else self.store.update), res, target, obj, namespace)
+            return self._acked(t_done, self._rv_stamped(
                 Response.of_json(self._stamp(updated, info, gv)),
-                (updated.get("metadata") or {}).get("resourceVersion"))
+                (updated.get("metadata") or {}).get("resourceVersion")))
 
         if req.method == "DELETE" and name is not None:
             target = await self._read_cluster(cluster, res, name, namespace)
-            adm = self.admission
-            if adm is None:
-                ticket = NOOP_TICKET
-            else:
-                with obs.span("admission.admit", verb="delete"):
-                    got = adm.admit_nowait("delete", res, target, namespace,
-                                           None)
-                    ticket = got if hasattr(got, "ok") else await got
-            try:
-                await self._st(self.store.delete, res, target, name, namespace)
-            except BaseException:
-                ticket.fail()
-                raise
-            await self._finish_write(ticket)
+            _none, t_done = await self._write(
+                req, "delete", res, target, namespace, None,
+                self.store.delete, res, target, name, namespace)
             # a delete's Status body carries no RV, but session
             # read-your-writes needs a floor covering it: stamp the
             # store RV (>= the delete's own RV) as a response header
             rv = (0 if self._remote
                   else getattr(self.store, "resource_version", 0))
-            return self._rv_stamped(
+            return self._acked(t_done, self._rv_stamped(
                 Response.of_json(_status_body(
-                    200, "Deleted", f"{res} {name} deleted")), rv)
+                    200, "Deleted", f"{res} {name} deleted")), rv))
 
         raise errors.BadRequestError(f"unsupported method {req.method} for {req.path}")
+
+    async def _write(self, req: Request, verb: str, res: str, target: str,
+                     namespace: str, obj: dict | None, fn, *args):
+        """The shared body of the write verbs: admission, the store call,
+        the ack barrier — each timed (``request_*_seconds``). Returns the
+        store call's result and the ``time.monotonic()`` of its return,
+        from which :meth:`_acked` closes ``request_finish_seconds`` once
+        the caller has encoded the response."""
+        t0 = time.monotonic()
+        # admission inline (reads never touch it): admit_nowait only
+        # hands back a coroutine when flow control parks the request,
+        # so the uncontended write path stays synchronous
+        adm = self.admission
+        if adm is None:
+            ticket = NOOP_TICKET
+        else:
+            with obs.span("admission.admit", verb=verb):
+                got = adm.admit_nowait(verb, res, target, namespace, obj)
+                ticket = got if hasattr(got, "ok") else await got
+        t1 = time.monotonic()
+        self._adm_seconds.observe(t1 - t0)
+        try:
+            if self._store_pool is None:
+                # in-process store: the call runs here, on the loop; the
+                # request's entry stamp rides in as the write's start
+                self.store.write_t0 = req.t0 or None
+                with obs.annotate("kcp.store.commit"):
+                    out = fn(*args)
+            else:
+                out = await self._st(fn, *args)
+        except BaseException:
+            ticket.fail()
+            raise
+        t2 = time.monotonic()
+        self._commit_seconds.observe(t2 - t1)
+        await self._finish_write(ticket)
+        return out, t2
+
+    def _acked(self, t_done: float, resp: Response) -> Response:
+        self._finish_seconds.observe(time.monotonic() - t_done)
+        return resp
 
     @staticmethod
     def _rv_stamped(resp: Response, rv) -> Response:
@@ -1438,7 +1461,8 @@ class RestHandler:
                     # splices the same cached event-line bytes — a
                     # 64-watcher fan-out encodes each event once
                     t0 = loop.time()
-                    lines = self.store.encode_events(batch)
+                    with obs.annotate("kcp.watch.encode", events=len(batch)):
+                        lines = self.store.encode_events(batch)
                     self._enc_seconds.observe(loop.time() - t0)
                     if (self._coalescer is not None
                             and getattr(stream, "write_raw_many", None)
@@ -1460,6 +1484,14 @@ class RestHandler:
                     for e in batch:
                         await stream.send_json({"type": e.type,
                                                 "object": e.object})
+                # `observe`: commit of each event -> its frame handed to
+                # this stream's transport (fan-out flush, relay wake-up,
+                # encode, write), for every delivered event
+                now = time.monotonic()
+                for e in batch:
+                    tm = e.__dict__.get("_tm")
+                    if tm is not None:
+                        obs.phase("observe", None, tm, now)
 
             async def flush_and_terminate() -> None:
                 # graceful drain: every event the fan-out already queued

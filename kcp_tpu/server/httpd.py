@@ -132,6 +132,9 @@ class Request:
     # (still percent-encoded, query included) — what a proxy (the shard
     # router) forwards so relayed requests stay byte-identical
     target: str = ""
+    # time.monotonic() at the serving handler's entry: where the `write`
+    # phase of a convergence starts (obs/trace.py PHASES)
+    t0: float = 0.0
 
     def param(self, name: str, default: str | None = None) -> str | None:
         vals = self.query.get(name)

@@ -370,6 +370,11 @@ class Server:
         # logged with the offending stacks
         self._watchdog = LoopWatchdog(asyncio.get_running_loop(),
                                       threshold=1.0).start()
+        # the runtime measured from inside, always on: loop lag and the
+        # collector's pauses (obs/runtime.py)
+        from ..obs.runtime import RuntimeProbes
+
+        self._probes = RuntimeProbes(asyncio.get_running_loop()).start()
         await self.http.start()
         if self.config.durable:
             render_kubeconfig(self.address,
@@ -564,6 +569,9 @@ class Server:
         if getattr(self, "_watchdog", None) is not None:
             self._watchdog.stop()
             self._watchdog = None
+        if getattr(self, "_probes", None) is not None:
+            self._probes.stop()
+            self._probes = None
         if getattr(self, "_set_pallas_env", False):
             os.environ.pop("KCP_PALLAS", None)
             self._set_pallas_env = False

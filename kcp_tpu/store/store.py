@@ -278,10 +278,27 @@ class Event:
     object: dict
     rv: int
     old_object: dict | None = None  # prior state on MODIFIED/DELETED
+    # out-of-band, never on the wire, set by LogicalStore._emit in the
+    # instance dict (the frozen fields above are the event): ``_tm`` the
+    # commit stamp and ``_tw`` the write's entry stamp, both
+    # time.monotonic() — the boundaries of the convergence phases
+    # (obs/trace.py PHASES); ``_tc`` a sampled write's trace context
 
     @property
     def key(self) -> Key:
         return (self.resource, self.cluster, self.namespace, self.name)
+
+
+def _rewritten(ev: Event, etype: str) -> Event:
+    """``ev`` as a selector-bound watch must see it (a label transition
+    surfaces as ADDED or DELETED): the same commit under another type,
+    so the commit's out-of-band stamps ride along (never ``_enc_line``:
+    the wire line names the type)."""
+    out = Event(etype, ev.resource, ev.cluster, ev.namespace, ev.name,
+                ev.object, ev.rv, ev.old_object)
+    d = ev.__dict__
+    out.__dict__.update((k, d[k]) for k in ("_tm", "_tw", "_tc") if k in d)
+    return out
 
 
 class Watch:
@@ -354,11 +371,9 @@ class Watch:
         if new_match and old_match:
             return ev
         if new_match:
-            return Event(ADDED, ev.resource, ev.cluster, ev.namespace, ev.name,
-                         ev.object, ev.rv, ev.old_object)
+            return _rewritten(ev, ADDED)
         if old_match:
-            return Event(DELETED, ev.resource, ev.cluster, ev.namespace, ev.name,
-                         ev.object, ev.rv, ev.old_object)
+            return _rewritten(ev, DELETED)
         return None
 
     def _push(self, ev: Event) -> None:
@@ -674,6 +689,19 @@ class LogicalStore:
             "watch_queue_depth",
             "per-watcher buffered events, sampled at powers of two >= 64",
             buckets=SIZE_BUCKETS)
+        self._fanout_size = REGISTRY.histogram(
+            "watch_fanout_batch_size",
+            "events coalesced per watch fan-out pass", buckets=SIZE_BUCKETS)
+        self._emit_seconds = REGISTRY.histogram(
+            "store_emit_seconds", "time delivering one fan-out batch")
+        # convergence-phase stamps (obs/trace.py PHASES), all
+        # time.monotonic(): the serving handler hands a request's entry
+        # stamp to the write verb it is about to call through
+        # ``write_t0`` (consumed by that verb's first statement; an
+        # in-process writer leaves it None and starts at store entry);
+        # ``last_commit`` is the commit stamp of the newest event
+        self.write_t0: float | None = None
+        self.last_commit = 0.0
         # global cluster/namespace interning for the fan-out scope
         # matrices: ids are stable across batches, so the per-watch
         # scope arrays can be cached per watch-set version instead of
@@ -902,6 +930,7 @@ class LogicalStore:
             obs.link_obj(obj, sub)
 
     def create(self, resource: str, cluster: str, obj: dict, namespace: str = "") -> dict:
+        tw, self.write_t0 = self.write_t0 or time.monotonic(), None
         self._race_guard.check()
         self._check_writable()
         self._check_cluster_writable(cluster)
@@ -937,7 +966,7 @@ class LogicalStore:
         rv = self._next_rv()
         meta["resourceVersion"] = str(rv)
         obj = self._put_obj(key, obj)
-        self._emit(ADDED, key, obj, rv, tc=tctx)
+        self._emit(ADDED, key, obj, rv, tc=tctx, tw=tw)
         rec = {"op": "put", "key": list(key), "obj": obj, "rv": rv}
         if tctx is not None:
             self._commit_trace(tctx, t0, key, rv, rec, obj)
@@ -973,6 +1002,7 @@ class LogicalStore:
         namespace: str = "",
         subresource: str | None = None,
     ) -> dict:
+        tw, self.write_t0 = self.write_t0 or time.monotonic(), None
         self._race_guard.check()
         self._check_writable()
         self._check_cluster_writable(cluster)
@@ -1032,13 +1062,13 @@ class LogicalStore:
         # finalizer-driven deletion completion
         if new_meta.get("deletionTimestamp") and not new_meta.get("finalizers"):
             self._del_obj(key)
-            self._emit(DELETED, key, new_obj, rv, old=existing, tc=tctx)
+            self._emit(DELETED, key, new_obj, rv, old=existing, tc=tctx, tw=tw)
             rec = {"op": "del", "key": list(key), "rv": rv}
             if tctx is not None:
                 self._commit_trace(tctx, t0, key, rv, rec, None)
             self._log_wal(rec)
         else:
-            self._emit(MODIFIED, key, new_obj, rv, old=existing, tc=tctx)
+            self._emit(MODIFIED, key, new_obj, rv, old=existing, tc=tctx, tw=tw)
             rec = {"op": "put", "key": list(key), "obj": new_obj, "rv": rv}
             if tctx is not None:
                 self._commit_trace(tctx, t0, key, rv, rec, new_obj)
@@ -1049,6 +1079,7 @@ class LogicalStore:
         return self.update(resource, cluster, obj, namespace, subresource="status")
 
     def delete(self, resource: str, cluster: str, name: str, namespace: str = "") -> None:
+        tw, self.write_t0 = self.write_t0 or time.monotonic(), None
         self._race_guard.check()
         self._check_writable()
         self._check_cluster_writable(cluster)
@@ -1067,7 +1098,7 @@ class LogicalStore:
                 rv = self._next_rv()
                 obj["metadata"]["resourceVersion"] = str(rv)
                 obj = self._put_obj(key, obj)
-                self._emit(MODIFIED, key, obj, rv, old=existing, tc=tctx)
+                self._emit(MODIFIED, key, obj, rv, old=existing, tc=tctx, tw=tw)
                 rec = {"op": "put", "key": list(key), "obj": obj, "rv": rv}
                 if tctx is not None:
                     self._commit_trace(tctx, t0, key, rv, rec, obj)
@@ -1075,7 +1106,7 @@ class LogicalStore:
             return
         self._del_obj(key)
         rv = self._next_rv()
-        self._emit(DELETED, key, existing, rv, old=existing, tc=tctx)
+        self._emit(DELETED, key, existing, rv, old=existing, tc=tctx, tw=tw)
         rec = {"op": "del", "key": list(key), "rv": rv}
         if tctx is not None:
             self._commit_trace(tctx, t0, key, rv, rec, None)
@@ -1744,18 +1775,25 @@ class LogicalStore:
                 self._hist_start = 0
 
     def _emit(self, etype: str, key: Key, obj: dict, rv: int, old: dict | None = None,
-              tc=None) -> None:
+              tc=None, tw: float | None = None) -> None:
+        # the commit stamp: where `write` ends and `propagate` and
+        # `observe` begin, one clock read for every watcher of the event.
+        # It, the write's entry stamp and a sampled write's trace context
+        # ride the shared Event out-of-band (one stamp for every watcher
+        # — the encode-once discipline applied to causality), like
+        # _enc_line never on the wire
+        oob = {"_tm": time.monotonic()}
+        self.last_commit = oob["_tm"]
+        if tw is not None:
+            oob["_tw"] = tw
+        if tc is not None:
+            oob["_tc"] = tc
         if not self._indexed:
             ev = Event(
                 etype, key[0], key[1], key[2], key[3], copy.deepcopy(obj), rv,
                 copy.deepcopy(old) if old is not None else None,
             )
-            if tc is not None:
-                # the committing write's trace context rides the shared
-                # Event (one stamp for every watcher — the encode-once
-                # discipline applied to causality); out-of-band like
-                # _enc_line, never on the wire
-                object.__setattr__(ev, "_tc", tc)
+            ev.__dict__.update(oob)
             self._history.append(ev)
             self._note_history(ev)
             # snapshot: an injected watch drop closes (and unsubscribes)
@@ -1769,8 +1807,7 @@ class LogicalStore:
         # replaces the whole dict), so the event shares them — the
         # per-event double deepcopy of the legacy path is gone
         ev = Event(etype, key[0], key[1], key[2], key[3], obj, rv, old)
-        if tc is not None:
-            object.__setattr__(ev, "_tc", tc)
+        ev.__dict__.update(oob)
         self._history.append(ev)
         self._note_history(ev)
         self._pending.append(ev)
@@ -1806,15 +1843,13 @@ class LogicalStore:
         self._flushing = True
         t0 = time.perf_counter()
         try:
-            self._fanout(batch)
+            with obs.annotate("kcp.store.fanout", events=len(batch)):
+                self._fanout(batch)
         finally:
             self._flushing = False
             dt = time.perf_counter() - t0
-            REGISTRY.histogram("watch_fanout_batch_size",
-                               "events coalesced per watch fan-out pass",
-                               buckets=SIZE_BUCKETS).observe(len(batch))
-            REGISTRY.histogram("store_emit_seconds",
-                               "time delivering one fan-out batch").observe(dt)
+            self._fanout_size.observe(len(batch))
+            self._emit_seconds.observe(dt)
             if obs.TRACER.enabled:
                 # attribute the flush to the first sampled event's trace
                 # (the batch shares one delivery pass; one span suffices)
@@ -1948,16 +1983,12 @@ class LogicalStore:
                 elif to_add[ni, ci]:
                     out = rw_add.get(ni)
                     if out is None:
-                        out = rw_add[ni] = Event(
-                            ADDED, ev.resource, ev.cluster, ev.namespace,
-                            ev.name, ev.object, ev.rv, ev.old_object)
+                        out = rw_add[ni] = _rewritten(ev, ADDED)
                     w._push(out)
                 else:
                     out = rw_del.get(ni)
                     if out is None:
-                        out = rw_del[ni] = Event(
-                            DELETED, ev.resource, ev.cluster, ev.namespace,
-                            ev.name, ev.object, ev.rv, ev.old_object)
+                        out = rw_del[ni] = _rewritten(ev, DELETED)
                     w._push(out)
         for w in fb_ws:
             # oversized selector: exact per-event fallback
@@ -2149,15 +2180,16 @@ class LogicalStore:
             return
         try:
             _inject("store.commit_window")
-            if self._engine is not None:
-                self._append_engine_batch(recs)
-            elif self._wal is not None and self._wal.fh is not None:
-                t0 = time.perf_counter()
-                self._wal.fh.write("".join(
-                    json.dumps(rec, separators=(",", ":")) + "\n"
-                    for rec in recs))
-                self._wal_fh_sync(t0)
-                self._wal.mutations_since_snapshot += len(recs)
+            with obs.annotate("kcp.wal.sync", records=len(recs)):
+                if self._engine is not None:
+                    self._append_engine_batch(recs)
+                elif self._wal is not None and self._wal.fh is not None:
+                    t0 = time.perf_counter()
+                    self._wal.fh.write("".join(
+                        json.dumps(rec, separators=(",", ":")) + "\n"
+                        for rec in recs))
+                    self._wal_fh_sync(t0)
+                    self._wal.mutations_since_snapshot += len(recs)
         except BaseException as e:  # noqa: BLE001 — becomes every writer's 5xx
             err = e if isinstance(e, UnavailableError) else UnavailableError(
                 f"commit window sync failed ({len(recs)} writes "

@@ -58,7 +58,7 @@ from typing import Callable, NamedTuple, Protocol, Sequence
 import jax
 import numpy as np
 
-from .. import faults
+from .. import faults, obs
 from ..models.reconcile_model import (
     MASK_STAMP_BIT,
     PACK_HDR,
@@ -87,20 +87,60 @@ def _grown(a: np.ndarray, shape, dtype) -> np.ndarray:
     return out
 
 
-def _phase(name: str, dt: float) -> None:
-    """Record one tick-phase timing (histogram ``fused_<name>_seconds``).
+#: the phases of a tick, host time each (``fused_<phase>_seconds``): the
+#: 'where does tick time go' answer that /debug/profile and bench.py
+#: report. ``encode`` is observed only on ticks that touched keys and
+#: ``full_upload`` replaces ``pack`` on a tick that re-uploads the whole
+#: mirror, so the means stay meaningful. Histograms fetched once.
+TICK_PHASES = ("encode", "pack", "full_upload", "put", "step_dispatch",
+               "collect_wait", "dispatch")
+_PHASE_H = {p: REGISTRY.histogram(f"fused_{p}_seconds",
+                                  "host time of one tick phase")
+            for p in TICK_PHASES}
+_TICK_H = REGISTRY.histogram(
+    "fused_tick_seconds",
+    "one fused tick, whole: tick start -> its patches dispatched to the "
+    "owners (every phase, pack included, and the pipeline's wait for "
+    "the wire)")
+_FLEET_TICKS = REGISTRY.counter(
+    "fused_fleet_ticks_total", "fleet-wide ragged batch steps dispatched")
 
-    The point-sample form of :func:`kcp_tpu.utils.trace.span` — used here
-    because the tick segments (pack/put/step) share perf_counter points
-    across branches and a with-block per segment cannot express that, and
-    because some phases must record only on qualifying ticks (encode only
-    when keys were touched) to keep the means meaningful. Same registry,
-    same naming convention as span.
 
-    The per-phase breakdown is the 'where does tick time go' answer the
-    /debug/profile surface and bench.py report; keep observations cheap —
-    one perf_counter pair per phase per tick, never per row."""
-    REGISTRY.histogram(f"fused_{name}_seconds").observe(dt)
+class _Phases:
+    """A cursor over the consecutive phases of one synchronous section
+    of a tick: ``enter(name)`` closes the phase before it at the same
+    clock read, so adjacent phases share their boundary. Each phase is a
+    ``fused_<name>_seconds`` observation and, while a profiler session
+    is open, a ``kcp.tick.<name>`` annotation on the profiler's clock.
+    One perf_counter read per boundary, never per row; ``close()``
+    belongs in a ``finally`` (an annotation is a begin/end pair)."""
+
+    __slots__ = ("_name", "_t", "_ann")
+
+    def __init__(self) -> None:
+        self._name = None
+
+    def enter(self, name: str) -> None:
+        now = time.perf_counter()
+        self._end(now)
+        self._name, self._t = name, now
+        self._ann = obs.annotate(f"kcp.tick.{name}")
+        self._ann.__enter__()
+
+    def close(self) -> None:
+        self._end(time.perf_counter())
+
+    def discard(self) -> None:
+        """End the open phase without an observation."""
+        if self._name is not None:
+            self._ann.__exit__(None, None, None)
+            self._name = None
+
+    def _end(self, now: float) -> None:
+        if self._name is not None:
+            self._ann.__exit__(None, None, None)
+            _PHASE_H[self._name].observe(now - self._t)
+            self._name = None
 
 MIN_ROWS = 64
 MIN_EVENTS = 64
@@ -598,9 +638,17 @@ class FusedBucket:
         needed to unpack it. None if nothing to do."""
         if not self.dirty:
             return None
-        t0 = time.perf_counter()
+        ph = _Phases()
+        try:
+            return self._submit(ph)
+        finally:
+            ph.close()
+
+    def _submit(self, ph: _Phases) -> tuple[jax.Array, tuple[int, int]]:
         s = self.S
-        was_stale = self._stale
+        # a stale tick re-uploads the whole mirror to the device, which
+        # is not the steady-state pack — the histograms stay separable
+        ph.enter("full_upload" if self._stale else "pack")
         if self._stale:
             self._state = self._device_state()
             self._stale = False
@@ -673,7 +721,7 @@ class FusedBucket:
             rows_touched.update(self._staged_masks)
             self._last_rows = sorted(rows_touched)
             self._clear_staged()
-        t1 = time.perf_counter()
+        ph.enter("put")
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
@@ -683,8 +731,7 @@ class FusedBucket:
         else:
             packed = jax.device_put(packed)
             acks = jax.device_put(acks)
-        t2 = time.perf_counter()
-        _phase("put", t2 - t1)
+        ph.enter("step_dispatch")
         k = min(self.patch_capacity, self.B)
         # KCP_FAULTS `device.step` injection point (raise@tick / error /
         # poison_row): fires HERE, where a real XLA dispatch failure
@@ -699,11 +746,7 @@ class FusedBucket:
         self._wire_bufs.commit(buf_slot, packed, acks, wire)
         self._step_failures = 0
         wire.copy_to_host_async()
-        t3 = time.perf_counter()
-        # a stale tick's t1-t0 is the whole-mirror device upload, not the
-        # steady-state pack — keep the histograms separable
-        _phase("full_upload" if was_stale else "pack", t1 - t0)
-        _phase("step_dispatch", t3 - t2)
+        ph.close()
         self.stats["ticks"] += 1
         return wire, (k, int(self._state.avail.shape[1]))
 
@@ -1073,10 +1116,17 @@ class FleetBatch:
         self._refresh_layout()
         if not self._members:
             return None
-        t0 = time.perf_counter()
+        ph = _Phases()
+        try:
+            return self._submit(ph)
+        finally:
+            ph.close()
+
+    def _submit(self, ph: _Phases) -> tuple[jax.Array, FleetMeta]:
         s = self.S
         self._seg_capacity = pad_pow2(max(self.core._next_seg, 1), floor=8)
         was_stale = self._stale or any(b._stale for b in self._members)
+        ph.enter("full_upload" if was_stale else "pack")
         local_rows: list[int] = []  # bucket-local ids for KCP_FAULTS
         if was_stale:
             self._state, self._seg_ids = self._device_state()
@@ -1178,7 +1228,7 @@ class FleetBatch:
                 local_rows.extend(touched)
                 self._last_rows.extend(base + r for r in sorted(touched))
                 b._clear_staged()
-        t1 = time.perf_counter()
+        ph.enter("put")
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
@@ -1188,8 +1238,7 @@ class FleetBatch:
         else:
             packed_d = jax.device_put(packed)
             acks_d = jax.device_put(acks)
-        t2 = time.perf_counter()
-        _phase("put", t2 - t1)
+        ph.enter("step_dispatch")
         k = self._patch_capacity()
         # KCP_FAULTS `device.step`: rows are BUCKET-LOCAL ids (the union
         # across members), so a poison_row spec targets the same logical
@@ -1204,17 +1253,13 @@ class FleetBatch:
         self._wire_bufs.commit(buf_slot, packed_d, acks_d, wire)
         self._step_failures = 0
         wire.copy_to_host_async()
-        t3 = time.perf_counter()
-        _phase("full_upload" if was_stale else "pack", t1 - t0)
-        _phase("step_dispatch", t3 - t2)
+        ph.close()
         self.stats["ticks"] += 1
         # member tick counters advance too: the fleet step covers every
         # bucket's rows, and engines/benches read their bucket's counter
         for b in self._members:
             b.stats["ticks"] += 1
-        REGISTRY.counter(
-            "fused_fleet_ticks_total",
-            "fleet-wide ragged batch steps dispatched").inc()
+        _FLEET_TICKS.inc()
         REGISTRY.gauge(
             "fused_fleet_rows", "rows in the fleet batch").set(self.B)
         REGISTRY.gauge(
@@ -1400,9 +1445,22 @@ class FusedCore:
             "fused-core", self._process_batch, batch_window=batch_window,
             overlap_drain=(pipeline == "double"),
         )
+        # (bucket, wire, layout meta, start stamp of the submitting tick)
         self._inflight: list[
-            tuple[FusedBucket, jax.Array, tuple[int, int]]
+            tuple[FusedBucket, jax.Array, tuple[int, int], float]
         ] = []
+        self._ticks = 0
+        # start stamp (time.monotonic()) of the tick whose wire is being
+        # collected, while its patches are handed to the owners
+        self.collecting_tick_start: float | None = None
+        self._depth_h = REGISTRY.histogram(
+            "fused_pipeline_depth",
+            "in-flight steps per bucket at submit time",
+            buckets=DEPTH_BUCKETS)
+        self._overlap_ticks = REGISTRY.counter(
+            "fused_pipeline_overlap_ticks_total",
+            "submits issued while a previous step was still in flight "
+            "(overlapped ticks)")
         self._flush_task: asyncio.Task | None = None
         self._eager_collect: bool | None = None  # resolved on first flush
         # quarantined keys awaiting their bounded-backoff requeue
@@ -1575,6 +1633,15 @@ class FusedCore:
     # ---------------------------------------------------------------- tick
 
     async def _process_batch(self, items: Sequence) -> list:
+        # one tick: a synchronous section of the loop, so one kcp.tick
+        # annotation spans it (``mono`` places the generator's and the
+        # collector's monotonic stamps on the profiler's timeline)
+        t_tick = time.monotonic()
+        self._ticks += 1
+        with obs.annotate("kcp.tick", tick=self._ticks, mono=t_tick):
+            return self._tick(items, t_tick)
+
+    def _tick(self, items: Sequence, t_tick: float) -> list:
         # 1. encode touched keys (engines re-read their informer caches);
         #    section=None items are retick markers — their bucket is
         #    already marked stale and will re-run on this tick. Items
@@ -1582,11 +1649,8 @@ class FusedCore:
         #    migration) are stale: touching them would resurrect rows in
         #    the old bucket — drop them, the replacement section was
         #    re-enqueued with the same keys.
-        t0 = time.perf_counter()
-        # wall-clock tick anchor for convergence attribution: the engine
-        # stamps which dispatch carried a traced row by pairing this with
-        # its fused_apply callback time (kcp_tpu/obs — phase "tick")
-        self.last_tick_start = time.time()
+        ph = _Phases()
+        ph.enter("encode")
         # per key, remember WHICH side(s) this batch's events touched —
         # an informer event changes exactly one mirror side (the
         # reference's two controllers each watch one apiserver,
@@ -1598,22 +1662,24 @@ class FusedCore:
             if section is not None and not section.released:
                 km = touched.setdefault(section, {})
                 km[key] = km.get(key, 0) | (2 if side else 1)
-        for section, keymasks in touched.items():
-            self._encode_section(section, keymasks)
-        if touched:
-            _phase("encode", time.perf_counter() - t0)
+        try:
+            for section, keymasks in touched.items():
+                self._encode_section(section, keymasks)
+        finally:
+            if touched:
+                ph.close()
+            else:  # nothing encoded: the mean stays that of real encodes
+                ph.discard()
 
         # 2. one fused step per dirty bucket; collection is pipelined.
         #    Occupancy telemetry per submit: how deep the in-flight window
         #    already was (depth histogram) and whether this dispatch
         #    overlapped an executing step (the pipeline's whole point)
         inflight_by_bucket: dict[int, int] = {}
-        for b, _w, _m in self._inflight:
+        for entry in self._inflight:
+            b = entry[0]
             inflight_by_bucket[id(b)] = inflight_by_bucket.get(id(b), 0) + 1
-        depth_h = REGISTRY.histogram(
-            "fused_pipeline_depth",
-            "in-flight steps per bucket at submit time",
-            buckets=DEPTH_BUCKETS)
+        depth_h = self._depth_h
         # fleet mode: ONE ragged batch covers every dirty bucket — the
         # same pipelined window applies, with the fleet as the unit
         submitters = ((self._fleet,) if self._fleet is not None
@@ -1637,11 +1703,9 @@ class FusedCore:
                 depth = inflight_by_bucket.get(id(bucket), 0)
                 depth_h.observe(depth)
                 if depth:
-                    REGISTRY.counter(
-                        "fused_pipeline_overlap_ticks_total",
-                        "submits issued while a previous step was still "
-                        "in flight (overlapped ticks)").inc()
-                self._inflight.append((bucket, wire, meta))
+                    self._overlap_ticks.inc()
+                # the wire carries its tick's start stamp to its collect
+                self._inflight.append((bucket, wire, meta, t_tick))
 
         # 3. collect: per BUCKET, oldest in-flight wires beyond the
         #    pipeline window (blocking is fine by then — their data has
@@ -1654,15 +1718,14 @@ class FusedCore:
         #    instantly "ready", which serializes dispatch into the tick
         #    and cost ~15% throughput at bench scale.)
         counts: dict[int, int] = {}
-        for b, _w, _m in self._inflight:
-            counts[id(b)] = counts.get(id(b), 0) + 1
+        for entry in self._inflight:
+            counts[id(entry[0])] = counts.get(id(entry[0]), 0) + 1
         i = 0
         while i < len(self._inflight):
-            b, w, m = self._inflight[i]
+            b = self._inflight[i][0]
             if counts[id(b)] > self.fetch_depth:
-                self._inflight.pop(i)
                 counts[id(b)] -= 1
-                self._collect(b, w, m)
+                self._collect(*self._inflight.pop(i))
             else:
                 i += 1
         if self._inflight:
@@ -1794,8 +1857,30 @@ class FusedCore:
         section.refresh_mask()
 
     def _collect(self, bucket: FusedBucket, wire: jax.Array,
-                 meta: tuple[int, int]) -> None:
-        t0 = time.perf_counter()
+                 meta: tuple[int, int], tick_start: float | None = None
+                 ) -> None:
+        """Fetch one in-flight wire and route its patches to the owners.
+        ``tick_start`` is the ``time.monotonic()`` start of the tick that
+        submitted it: the owners read it (``collecting_tick_start``)
+        while their patches are handed over, as the start of the `tick`
+        convergence phase, and ``fused_tick_seconds`` closes on it."""
+        ph = _Phases()
+        ph.enter("collect_wait")
+        try:
+            overflow = self._fetch_and_dispatch(bucket, wire, meta,
+                                                tick_start, ph)
+        finally:
+            ph.close()
+            self.collecting_tick_start = None
+        if tick_start is not None:
+            _TICK_H.observe(time.monotonic() - tick_start)
+        if overflow:
+            # level-triggered: re-run the bucket with doubled capacity
+            bucket.mark_stale()
+            self.controller.queue.add(("__retick__", False, id(bucket), None))
+
+    def _fetch_and_dispatch(self, bucket, wire, meta, tick_start,
+                            ph: _Phases) -> bool:
         # fetch blocks ONLY on the compact wire (copy_to_host_async was
         # issued at dispatch) — never on the donated resident state. The
         # ready split is the pipeline-occupancy answer: a blocked fetch
@@ -1810,14 +1895,9 @@ class FusedCore:
             "fetches that found the wire already on host (ready) vs had "
             "to wait for the device (blocked)").inc()
         host_wire = np.asarray(wire)
-        t1 = time.perf_counter()
-        overflow = bucket.dispatch(host_wire, meta)
-        _phase("collect_wait", t1 - t0)
-        _phase("dispatch", time.perf_counter() - t1)
-        if overflow:
-            # level-triggered: re-run the bucket with doubled capacity
-            bucket.mark_stale()
-            self.controller.queue.add(("__retick__", False, id(bucket), None))
+        ph.enter("dispatch")
+        self.collecting_tick_start = tick_start
+        return bucket.dispatch(host_wire, meta)
 
     def _schedule_flush(self) -> None:
         if self._flush_task is not None:
@@ -1845,7 +1925,7 @@ class FusedCore:
             if not self._eager_collect:
                 await asyncio.sleep(IDLE_FLUSH_S)
             while self._inflight:
-                bucket, wire, meta = self._inflight[0]
+                wire = self._inflight[0][1]
                 # exponential poll backoff: a step over a large fleet
                 # runs for milliseconds, so a flat 1 ms poll would wake
                 # the loop many times per wire for no data; cap at 8 ms
@@ -1860,11 +1940,16 @@ class FusedCore:
                 # the wire this iteration actually inspected
                 if not self._inflight or self._inflight[0][1] is not wire:
                     continue
-                self._inflight.pop(0)
-                self._collect(bucket, wire, meta)
+                self._collect_late(self._inflight.pop(0))
         except asyncio.CancelledError:
             pass
 
+    def _collect_late(self, entry: tuple) -> None:
+        """A collect between ticks (the idle flush, the shutdown drain):
+        its own ``kcp.tick`` on the profiler's timeline."""
+        with obs.annotate("kcp.tick", mono=time.monotonic()):
+            self._collect(*entry)
+
     async def _drain_inflight(self) -> None:
         while self._inflight:
-            self._collect(*self._inflight.pop(0))
+            self._collect_late(self._inflight.pop(0))

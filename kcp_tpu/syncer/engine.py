@@ -58,8 +58,60 @@ from ..ops.encode import BucketEncoder, BucketOverflow, pad_pow2
 from ..reconciler.controller import BatchController
 from ..store.selectors import LabelSelector, parse_selector
 from ..utils import errors
+from ..utils.trace import REGISTRY
 
 log = logging.getLogger(__name__)
+
+# fetched once: observed on every closed convergence / every host tick
+_CONVERGENCE = REGISTRY.histogram(
+    "kcp_sync_convergence_seconds",
+    "one key from the write that dirtied it to converged: status "
+    "upsynced, or the two sides observed equal")
+_HOST_TICK = REGISTRY.histogram(
+    "kcp_sync_tick_seconds", "one host-backend reconcile tick")
+_TICKS = REGISTRY.counter(
+    "kcp_sync_ticks_total", "reconcile ticks across all sync sessions")
+_EVENTS = REGISTRY.counter(
+    "kcp_sync_events_total", "informer events drained into tick batches")
+
+# states of a key's convergence entry, in timeline order
+_STAGED, _TICKED, _PATCHED, _DOWNSTAGED, _DONE = range(5)
+
+
+def _rv_of(obj: dict | None) -> str:
+    return str(((obj or {}).get("metadata") or {}).get("resourceVersion", ""))
+
+
+def _rv_le(a: str, b: str) -> bool:
+    """``a`` is ``b`` or an older resourceVersion (store RVs are
+    integers; anything else compares by equality)."""
+    try:
+        return int(a) <= int(b)
+    except ValueError:
+        return a == b
+
+
+class _Convergence:
+    """One dirty key's timeline (obs/trace.py PHASES), every stamp
+    ``time.monotonic()``. The entry is made by the up event that dirtied
+    the key and carries each boundary as the row passes it, so adjacent
+    phases share their stamp and the phase sum telescopes to
+    ``end - start``. ``ctx`` is the committing write's trace context —
+    present only for a sampled write, and the only thing the ``conv.*``
+    spans need beyond what every write keeps."""
+
+    __slots__ = ("state", "start", "t", "t_down", "rv", "name", "ctx",
+                 "counted")
+
+    def __init__(self, start: float, t: float, rv: str, name: str, ctx):
+        self.state = _STAGED
+        self.start = start  # earliest stamp known: write entry, else commit, else staged
+        self.t = t          # the newest boundary passed (staged, at first)
+        self.t_down = None  # last downstream event since the patch applied
+        self.rv = rv        # of the dirtying write; in _DONE, of our status write
+        self.name = name
+        self.ctx = ctx
+        self.counted = False  # kcp_sync_convergence_seconds fed once
 
 CLUSTER_LABEL = "kcp.dev/cluster"
 OWNED_BY_LABEL = "kcp.dev/owned-by"
@@ -204,24 +256,16 @@ class BatchSyncEngine:
         self._apply_tasks: list[asyncio.Task] = []
         self._retry_tasks: set[asyncio.Task] = set()
 
-        # convergence bookkeeping for the p99 metric: key -> first-dirty
-        # time; samples are bounded (a long-running server must not grow
-        # them forever — the histogram in utils/trace keeps the totals)
-        from collections import deque
-
-        self.dirty_since: dict[tuple[str, str], float] = {}
-        self.convergence_samples: "deque[float]" = deque(maxlen=10_000)
         self.stats = {"ticks": 0, "decisions_applied": 0, "rows": 0, "full_uploads": 0}
-        # convergence trace attribution (kcp_tpu/obs): key -> the traced
-        # spec write's context + the phase-boundary timestamps gathered
-        # as the row moves stage → tick → patch → downstream → upstatus.
-        # Entries exist only for sampled writes (identity-linked
-        # snapshots, or engine-minted fragments under always-on
-        # sampling), bounded FIFO — the steady-state cost when tracing
-        # is on but nothing is sampled is one dict-emptiness check.
-        self._conv: dict[tuple[str, str], dict] = {}
-        self._conv_max = 1024
-        self._tick_bounds: tuple[float, float] | None = None
+        # the ONE per-key convergence entry: made by the up event that
+        # dirtied the key, stamped at every phase boundary the row
+        # passes, closed when its status is upsynced (or the two sides
+        # are observed equal). Every write has one — the phase
+        # histograms do not depend on the sampling coin; only the
+        # conv.* spans (entry.ctx) do. Bounded FIFO: an entry whose
+        # downstream never answers is evicted, not kept forever.
+        self._dirty: dict[tuple[str, str], _Convergence] = {}
+        self._dirty_max = 8192
 
     def tick_count(self) -> int:
         """Reconcile ticks that covered this engine's rows (fused mode
@@ -239,40 +283,102 @@ class BatchSyncEngine:
 
     def _on_up_event(self, etype: str, old: dict | None, new: dict | None) -> None:
         key = self._obj_key(new or old)
-        self.dirty_since.setdefault(key, time.monotonic())
         self._apply_failures.pop(key, None)  # new data resets the budget
-        if new is not None and obs.TRACER.enabled and key not in self._conv:
-            ctx = obs.conv_begin(new)
-            if ctx is not None:
-                while len(self._conv) >= self._conv_max:
-                    self._conv.pop(next(iter(self._conv)))
-                meta = new.get("metadata") or {}
-                self._conv[key] = {
-                    "ctx": ctx, "state": "staged", "t0": time.time(),
-                    "rv": str(meta.get("resourceVersion", "")),
-                    "name": meta.get("name", "")}
+        if old is not new:  # a resync replay is not a write
+            self._stage_up(key, new or old, new is not None)
         if self.fused:
             if self._section is not None:
                 self.core.enqueue(self._section, False, key)
         else:
             self.controller.enqueue(("up", key))
 
+    def _stage_up(self, key, obj: dict, live: bool) -> None:
+        """Open the key's convergence entry; `write` and `propagate` are
+        complete here (their stamps rode the event). An entry still
+        ahead of its downstream write keeps its timeline — the
+        level-triggered tick converges the newest state either way; our
+        own status write's echo retires a finished entry; a new write
+        replaces an entry whose downstream has not answered."""
+        now = time.monotonic()
+        rv = _rv_of(obj)
+        ent = self._dirty.get(key)
+        if ent is not None:
+            if ent.state == _DONE:
+                # the echo of our own status write(s): the level-triggered
+                # tick can re-emit the upsync before the first echo lands,
+                # so every rv up to the newest one we wrote is ours
+                if _rv_le(rv, ent.rv):
+                    if rv == ent.rv:
+                        del self._dirty[key]
+                    return
+                del self._dirty[key]
+            elif ent.state < _PATCHED:
+                return
+        stamps = self.up_informer.event_stamps
+        tw, tm = stamps if stamps is not None else (None, None)
+        ctx = obs.conv_begin(obj) if live and obs.TRACER.enabled else None
+        name = key[1]
+        if tm is not None:
+            if tw is not None:
+                obs.phase("write", ctx, tw, tm, rv=rv, obj=name)
+            obs.phase("propagate", ctx, tm, now, rv=rv)
+        while len(self._dirty) >= self._dirty_max:
+            del self._dirty[next(iter(self._dirty))]
+        self._dirty[key] = _Convergence(tw or tm or now, now, rv, name, ctx)
+
     def _on_down_event(self, etype: str, old: dict | None, new: dict | None) -> None:
         key = self._obj_key(new or old)
         self._apply_failures.pop(key, None)
-        if self._conv:
-            # downstream churn (our own create echo, then the status
-            # write) re-stages the row: remember the LAST arrival as the
-            # downstream→upsync boundary (phases recorded at upsync)
-            ent = self._conv.get(key)
-            if ent is not None and ent["state"] in ("patched", "downstaged"):
-                ent["t_down"] = time.time()
-                ent["state"] = "downstaged"
+        # downstream churn (our own write's echo, then the controller's
+        # status write) re-stages the row: the LAST arrival before the
+        # upsync is where `downstream` ends and `upstatus` begins
+        ent = self._dirty.get(key)
+        if ent is not None and _PATCHED <= ent.state <= _DOWNSTAGED:
+            ent.t_down = time.monotonic()
+            ent.state = _DOWNSTAGED
         if self.fused:
             if self._section is not None:
                 self.core.enqueue(self._section, True, key)
         else:
             self.controller.enqueue(("down", key))
+
+    def _ticked(self, ent: _Convergence, tick_start: float, collected: float,
+                tick_n) -> None:
+        """`stage` and `tick` of a staged row whose first patch just came
+        home: staged -> start of the tick that carried it -> that tick's
+        patches handed to the applier (for the fused core, the pipeline's
+        wait for the wire included)."""
+        t0 = max(ent.t, min(tick_start, collected))
+        obs.phase("stage", ent.ctx, ent.t, t0, rv=ent.rv, obj=ent.name)
+        obs.phase("tick", ent.ctx, t0, collected, rv=ent.rv, tick=tick_n)
+        ent.t = max(t0, collected)
+        ent.state = _TICKED
+
+    def _converged(self, ent: _Convergence, end: float) -> None:
+        if not ent.counted:
+            ent.counted = True
+            _CONVERGENCE.observe(max(0.0, end - ent.start))
+
+    def _observed_equal(self, key, gone: bool) -> None:
+        """Both sides of a touched key are equal (or both absent): the
+        key's churn has landed without (further) action. A staged entry
+        ends here; a deleted one ends where its downstream delete was
+        confirmed; an applied create/update is counted converged once
+        but keeps its entry for the status its downstream may yet write."""
+        ent = self._dirty.get(key)
+        if ent is None:
+            return
+        if ent.state == _STAGED:
+            now = time.monotonic()
+            obs.phase("stage", ent.ctx, ent.t, now, rv=ent.rv, obj=ent.name)
+            self._converged(ent, now)
+            del self._dirty[key]
+        elif _PATCHED <= ent.state <= _DOWNSTAGED:
+            end = ent.t_down or time.monotonic()
+            self._converged(ent, end)
+            if gone:
+                obs.phase("downstream", ent.ctx, ent.t, end, rv=ent.rv)
+                del self._dirty[key]
 
     # ----------------------------------------------- fused-core interface
 
@@ -319,25 +425,25 @@ class BatchSyncEngine:
         # this key's churn has landed — close its convergence sample here
         # (actioned keys close theirs in the applier)
         if (up_obj is None) == (down_obj is None) and bool((up_v == down_v).all()):
-            self._sample_convergence(key)
+            self._observed_equal(key, up_obj is None)
         return up_v, up_obj is not None, down_v, down_obj is not None
 
     def fused_apply(self, patches: list[tuple[tuple[str, str], int, bool]]) -> None:
         """Patch rows from a collected tick: feed the applier pool
         (dedup per key; the pool re-verifies against live caches)."""
-        if self._conv and patches:
-            # stamp which fused dispatch carried each traced row: the
-            # core's wall-clock tick anchor + this collect time bound
-            # the "tick" phase, and the bucket tick counter names it
-            t1 = time.time()
-            t0 = getattr(self.core, "last_tick_start", None) or t1
+        if self._dirty and patches:
+            # which fused dispatch carried each staged row: the start
+            # stamp of the tick whose wire the core is collecting, and
+            # now (its patches are handed over here)
+            t1 = time.monotonic()
+            t0 = getattr(self.core, "collecting_tick_start", None) or t1
             tick_n = (self._section.bucket.stats.get("ticks")
                       if self._section is not None else None)
+            dirty = self._dirty
             for key, _code, _upsync in patches:
-                ent = self._conv.get(key)
-                if ent is not None and "tb" not in ent:
-                    ent["tb"] = (t0, t1)
-                    ent["tick"] = tick_n
+                ent = dirty.get(key)
+                if ent is not None and ent.state == _STAGED:
+                    self._ticked(ent, t0, t1, tick_n)
         for key, code, upsync in patches:
             if key in self._apply_pending:
                 continue
@@ -364,16 +470,6 @@ class BatchSyncEngine:
         keys = {(k[1], k[2]) for k in self.up_informer.cache}
         keys |= {(k[1], k[2]) for k in self.down_informer.cache}
         return keys
-
-    def _sample_convergence(self, key) -> None:
-        started = self.dirty_since.pop(key, None)
-        if started is not None:
-            from ..utils.trace import REGISTRY
-
-            dt = time.monotonic() - started
-            self.convergence_samples.append(dt)
-            REGISTRY.histogram("kcp_sync_convergence_seconds",
-                               "spec churn to observed convergence").observe(dt)
 
     # ----------------------------------------------------- applier pool
 
@@ -410,7 +506,8 @@ class BatchSyncEngine:
         delay = faults.maybe_fail("syncer.apply")
         if delay:
             await asyncio.sleep(delay)
-        return self._apply_decision(key, code, upsync)
+        with obs.annotate("kcp.apply"):
+            return self._apply_decision(key, code, upsync)
 
     def _apply_failed(self, key, code: int, upsync: bool, err: Exception) -> None:
         n = self._apply_failures.get(key, 0) + 1
@@ -502,20 +599,16 @@ class BatchSyncEngine:
     # -------------------------------------------------------------- tick
 
     async def _process_batch(self, items: Sequence) -> list[tuple[object, Exception]]:
-        from ..utils.trace import span
+        t0 = time.monotonic()
+        try:
+            return self._host_tick(items, t0)
+        finally:
+            _HOST_TICK.observe(time.monotonic() - t0)
 
-        with span("kcp_sync_tick"):
-            return await self._process_batch_timed(items)
-
-    async def _process_batch_timed(self, items: Sequence) -> list[tuple[object, Exception]]:
-        from ..utils.trace import REGISTRY
-
+    def _host_tick(self, items: Sequence, t_tick0: float) -> list[tuple[object, Exception]]:
         self.stats["ticks"] += 1
-        t_tick0 = time.time()
-        REGISTRY.counter("kcp_sync_ticks_total",
-                         "reconcile ticks across all sync sessions").inc()
-        REGISTRY.counter("kcp_sync_events_total",
-                         "informer events drained into tick batches").inc(len(items))
+        _TICKS.inc()
+        _EVENTS.inc(len(items))
         # 1. dedup keys touched this tick (last event wins — we re-read
         #    caches), remembering which queue items map to each key so
         #    failures are charged to the right items' retry budgets
@@ -537,9 +630,7 @@ class BatchSyncEngine:
         if n == 0:
             return []
         decision, upsync = self._host_decisions()
-        # wall-clock tick bounds for convergence attribution (the host
-        # backend's analog of the fused core's last_tick_start)
-        self._tick_bounds = (t_tick0, time.time())
+        t_decided = time.monotonic()
 
         # 4. apply non-NOOP rows with host verification
         failed_keys: dict[tuple[str, str], Exception] = {}
@@ -548,6 +639,10 @@ class BatchSyncEngine:
             if r >= n:
                 continue
             key = self.row_keys[r]
+            ent = self._dirty.get(key)
+            if ent is not None and ent.state == _STAGED:
+                # the host backend's tick: batch start -> decisions made
+                self._ticked(ent, t_tick0, t_decided, self.stats["ticks"])
             try:
                 applied = self._apply_decision(key, int(decision[r]), bool(upsync[r]))
                 if applied:
@@ -557,15 +652,9 @@ class BatchSyncEngine:
 
         # touched keys that needed no action converged by observation
         act_set = {self.row_keys[r] for r in act_rows if r < n}
-        now = time.monotonic()
-        conv_h = REGISTRY.histogram("kcp_sync_convergence_seconds",
-                                    "spec churn to observed convergence")
         for key in key_items:
             if key not in act_set:
-                started = self.dirty_since.pop(key, None)
-                if started is not None:
-                    self.convergence_samples.append(now - started)
-                    conv_h.observe(now - started)
+                self._observed_equal(key, not self.up_exists[self.rows[key]])
         self.stats["rows"] = n
 
         # failures on rows whose items are in this batch charge those
@@ -642,29 +731,12 @@ class BatchSyncEngine:
 
     # ------------------------------------------------------------- apply
 
-    def _conv_phases_pre(self, ent: dict) -> None:
-        """Record the stage + tick phases of a traced row the first time
-        an actionable decision reaches the applier: staged→tick-start is
-        queue wait, tick-start→tick-end is the dispatch that carried the
-        row (fused: the core's wall anchor; host: the batch bounds)."""
-        tb = ent.get("tb") or self._tick_bounds or (ent["t0"], ent["t0"])
-        t0 = max(ent["t0"], min(tb[0], tb[1]))
-        ctx = ent["ctx"]
-        obs.phase("stage", ctx, ent["t0"], t0, rv=ent["rv"],
-                  obj=ent["name"])
-        obs.phase("tick", ctx, t0, max(t0, tb[1]), rv=ent["rv"],
-                  tick=ent.get("tick"))
-        ent["state"] = "ticked"
-        ent["t_tick1"] = max(t0, tb[1])
-
     def _apply_decision(self, key: tuple[str, str], decision: int, upsync: bool) -> bool:
         ns, name = key
         up_obj = self.up_informer.get(self._up_cluster(), name, ns)
         down_obj = self.down_informer.get(self._down_cluster(), name, ns)
         applied = False
-        ent = self._conv.get(key) if self._conv else None
-        if ent is not None and ent["state"] == "staged" and decision:
-            self._conv_phases_pre(ent)
+        ent = self._dirty.get(key)
 
         if decision == DECISION_CREATE and up_obj is not None:
             self._ensure_namespace(ns)
@@ -697,43 +769,49 @@ class BatchSyncEngine:
             except errors.NotFoundError:
                 pass
 
-        if ent is not None and ent["state"] == "ticked":
-            # the downstream write (or delete) for this traced row just
-            # applied: tick-end → now is the patch phase
-            now = time.time()
-            obs.phase("patch", ent["ctx"], ent["t_tick1"], now,
-                      rv=ent["rv"], applied=applied)
-            ent["state"] = "patched"
-            ent["t_patch"] = now
+        if ent is not None and ent.state == _TICKED:
+            # the downstream write (or delete) of this row just applied:
+            # patches handed over -> now is `patch` (applier queue + write)
+            now = time.monotonic()
+            obs.phase("patch", ent.ctx, ent.t, now, rv=ent.rv,
+                      applied=applied)
+            ent.t = now
+            ent.state = _PATCHED
 
         if upsync and up_obj is not None and down_obj is not None:
             new_status = down_obj.get("status")
             if new_status != up_obj.get("status"):
                 fresh = self.upstream.get(self.gvr, name, ns)
                 fresh["status"] = copy.deepcopy(new_status)
-                with obs.use(ent["ctx"] if ent is not None else None):
+                if ent is not None and ent.ctx is not None:
                     # upstream status write runs under the row's trace
                     # context: an in-process upstream records its
                     # store.commit as a child; a REST upstream carries
                     # the traceparent to the owning shard
-                    self.upstream.update_status(self.gvr, fresh,
-                                                namespace=ns)
+                    with obs.use(ent.ctx):
+                        written = self.upstream.update_status(
+                            self.gvr, fresh, namespace=ns)
+                else:
+                    written = self.upstream.update_status(
+                        self.gvr, fresh, namespace=ns)
                 applied = True
-                if ent is not None and ent["state"] in ("patched",
-                                                        "downstaged"):
-                    now = time.time()
-                    t_patch = ent.get("t_patch", ent["t0"])
-                    t_down = ent.get("t_down", t_patch)
-                    obs.phase("downstream", ent["ctx"], t_patch, t_down,
-                              rv=ent["rv"])
-                    obs.phase("upstatus", ent["ctx"], t_down, now,
-                              rv=ent["rv"], obj=ent["name"])
-                    self._conv.pop(key, None)
-
-        if applied or decision or upsync:
-            started = self.dirty_since.pop(key, None)
-            if started is not None:
-                self.convergence_samples.append(time.monotonic() - started)
+                if ent is not None and _PATCHED <= ent.state <= _DOWNSTAGED:
+                    # the status is committed upstream: the timeline ends
+                    # at that commit's own stamp where the upstream can
+                    # tell it (what `observe` of the status event starts
+                    # from), else at the write's return
+                    end = (getattr(self.upstream, "last_commit", None)
+                           or time.monotonic())
+                    t_down = ent.t_down or ent.t
+                    obs.phase("downstream", ent.ctx, ent.t, t_down, rv=ent.rv)
+                    obs.phase("upstatus", ent.ctx, t_down, end, rv=ent.rv,
+                              obj=ent.name)
+                    self._converged(ent, end)
+                    # kept until the write's own echo comes up the informer
+                    ent.state = _DONE
+                    ent.rv = _rv_of(written)
+                elif ent is not None and ent.state == _DONE:
+                    ent.rv = _rv_of(written)  # a repeat before the echo
         return applied
 
     def _ensure_namespace(self, ns: str) -> None:

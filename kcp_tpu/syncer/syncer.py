@@ -92,9 +92,11 @@ class Syncer:
             else:
                 ticks += e.stats["ticks"]
         applied = sum(e.stats["decisions_applied"] for e in self.engines)
-        samples = [s for e in self.engines for s in e.convergence_samples]
-        samples.sort()
-        p99 = samples[int(len(samples) * 0.99)] if samples else None
+        # the process-wide histogram every engine's closed convergences
+        # feed (upper bucket edge; None until one has closed)
+        from .engine import _CONVERGENCE
+
+        p99 = _CONVERGENCE.quantile(0.99) if _CONVERGENCE.n else None
         return {
             "cluster": self.cluster_id,
             "ticks": ticks,

@@ -10,14 +10,15 @@ kernel time), so this module provides
 - a process-global :class:`Registry` of counters / gauges / histograms
   with Prometheus-style text exposition (served at ``/metrics`` by the
   API server),
-- :func:`span` — a context manager timing a named section into a
-  histogram (host-side structured timing),
-- :func:`device_trace` — a context manager around
-  ``jax.profiler.trace`` emitting an XLA trace directory for
-  TensorBoard/xprof when deeper device attribution is needed.
+- :func:`device_trace` — a context manager around a JAX profiler
+  session emitting an XLA trace directory for TensorBoard/xprof when
+  deeper device attribution is needed (while it is open, the
+  ``kcp.*`` host annotations of :func:`kcp_tpu.obs.annotate` land in
+  the same trace).
 
-Everything is dependency-free and safe to call on hot paths: a span is
-two ``perf_counter`` calls and a dict update.
+Hot paths fetch their histogram ONCE (a module or instance attribute)
+and call ``observe`` on it: one bisect and one uncontended leaf lock,
+never a registry lookup per observation.
 """
 
 from __future__ import annotations
@@ -185,6 +186,11 @@ class Registry:
                 else:
                     out[name] = {"count": m.n, "mean": m.mean,
                                  "p50": m.quantile(0.5), "p99": m.quantile(0.99)}
+                    # Prometheus's own ``_count`` as a plain number, so
+                    # a reader that keeps only numbers (and each
+                    # histogram's sum) can take a mean over a window:
+                    # rise(sum) / rise(count)
+                    out[name + "_count"] = m.n
             return out
 
 
@@ -192,39 +198,25 @@ REGISTRY = Registry()
 
 
 @contextlib.contextmanager
-def span(name: str, registry: Registry = REGISTRY):
-    """Time a section into histogram ``<name>_seconds``."""
-    h = registry.histogram(f"{name}_seconds")
-    t0 = time.perf_counter()
+def device_trace(log_dir: str):
+    """XLA/TPU profiler session around a block (view with
+    xprof/TensorBoard), with the options benchmarks/run.py's slice uses:
+    the Python tracer off (it would slow every call of the serving loop
+    for the whole session), host tracer level 1 (TraceMe annotations,
+    which is what ``kcp.*`` are), and a profiler that cannot start
+    RAISES — a session that is already open, or a backend that has no
+    profiler, is the caller's to report, not to swallow."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    opts.raise_error_on_start_failure = True
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
     try:
         yield
     finally:
-        h.observe(time.perf_counter() - t0)
-
-
-@contextlib.contextmanager
-def device_trace(log_dir: str):
-    """XLA/TPU profiler trace around a block (view with xprof/TensorBoard).
-
-    No-ops cleanly if the profiler cannot start (e.g. another trace is
-    active or the backend does not support it).
-    """
-    import jax
-
-    started = False
-    try:
-        jax.profiler.start_trace(log_dir)
-        started = True
-    except Exception:
-        pass
-    try:
-        yield started
-    finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+        jax.profiler.stop_trace()
 
 
 # ---------------------------------------------------------------------------

@@ -392,16 +392,29 @@ def f(ctx, t0, t1):
     assert MetricsDocChecker().check_repo(files, root) == []
 
 
-def test_metrics_doc_span_sites_count(tmp_path):
+def test_metrics_doc_annotate_sites_count(tmp_path):
+    # obs.annotate("...") names a host annotation on the profiler's
+    # timeline: held to the trace-span table like obs.span, an f-string
+    # name as a glob that a `<placeholder>` row documents
     files, root = _metrics_fixture(tmp_path, """\
-from .trace import span
+from kcp_tpu import obs
 
-def f():
-    with span("my_phase"):
+def f(name):
+    with obs.annotate("kcp.undocumented"):
         pass
-""", "nothing documented\n")
+    with obs.annotate(f"kcp.tick.{name}"):
+        pass
+    with obs.annotate("kcp.tick", tick=1):
+        pass
+""", "<!-- trace-spans:begin -->\n"
+         "| `kcp.tick` | one tick |\n"
+         "| `kcp.tick.<phase>` | its phases |\n"
+         "| `kcp.ghost` | stale row |\n"
+         "<!-- trace-spans:end -->\n")
     msgs = [f.message for f in MetricsDocChecker().check_repo(files, root)]
-    assert any("'my_phase_seconds'" in m for m in msgs)
+    assert any("'kcp.undocumented' is recorded here" in m for m in msgs)
+    assert any("'kcp.ghost' but no" in m for m in msgs)
+    assert not any("'kcp.tick" in m for m in msgs), msgs
 
 
 # ---------------------------------------------------------------------------

@@ -53,7 +53,7 @@ def test_soak_sustained_churn_converges_and_stays_bounded():
                 assert len(eng.core._inflight) <= 4, len(eng.core._inflight)
                 assert len(eng._apply_pending) <= ROWS
                 assert len(eng._retry_tasks) <= ROWS
-                assert len(eng.convergence_samples) <= 10_000
+                assert len(eng._dirty) <= ROWS
             await asyncio.sleep(0.004)
         # quiesce: everything converges
         await asyncio.sleep(2)

@@ -420,7 +420,7 @@ def test_convergence_phases_sum_reconcile_in_process(trace_env):
         await engine.start()
         try:
             ctx = obs.TRACER.mint(sampled=True)
-            t0 = time.time()
+            t0 = time.monotonic()  # phase stamps are monotonic
             obj = {"apiVersion": "v1", "kind": "ConfigMap",
                    "metadata": {"name": "phased", "namespace": "default",
                                 "labels": {CLUSTER_LABEL: "loc-1"}},
@@ -428,7 +428,7 @@ def test_convergence_phases_sum_reconcile_in_process(trace_env):
             with obs.use(ctx):
                 created = up.create("configmaps", obj)
             rv = created["metadata"]["resourceVersion"]
-            obs.phase("write", ctx, t0, time.time(), rv=str(rv))
+            obs.phase("write", ctx, t0, time.monotonic(), rv=str(rv))
             deadline = time.time() + 10
             while time.time() < deadline:
                 try:
@@ -447,7 +447,7 @@ def test_convergence_phases_sum_reconcile_in_process(trace_env):
                 await asyncio.sleep(0.01)
             else:
                 raise AssertionError("status never upsynced")
-            obs.phase("e2e", ctx, t0, time.time(), rv=str(rv))
+            obs.phase("e2e", ctx, t0, time.monotonic(), rv=str(rv))
             spans = obs.TRACER.get(ctx.trace_id)
             names = {s["name"] for s in spans}
             # the identity link keeps the engine's phases on THIS trace
