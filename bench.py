@@ -3639,6 +3639,15 @@ def watchers_serve() -> int:
     n_objects = int(os.environ.get("KCP_WB_OBJECTS", "100000"))
     n_clusters = int(os.environ.get("KCP_WB_CLUSTERS", "100"))
 
+    if "--relay" in sys.argv:
+        # the A/B lane's relay references: a watch without the push half
+        # (what a storage frontend or a router holds) is served by the
+        # pull relay — the product has no knob for this, so the bench's
+        # own child strips the half from ITS copy of the class
+        from kcp_tpu.store.store import Watch
+
+        del Watch.set_sink
+
     async def run() -> None:
         store = LogicalStore(clock=lambda: 0.0)
         per = max(1, n_objects // n_clusters)
@@ -3733,8 +3742,11 @@ async def _wb_watcher(i: int, host: str, port: int, cluster: str,
 
 
 def _wb_spawn_child(objects: int, clusters: int, coalesce: bool,
-                    flush_ms: str, extra_env: dict | None = None):
-    """Spawn the --watchers-serve child; returns (Popen, host, port)."""
+                    flush_ms: str, extra_env: dict | None = None,
+                    relay: bool = False):
+    """Spawn the --watchers-serve child (``relay``: its watches lose the
+    push half, so the pull relay serves them); returns (Popen, host,
+    port)."""
     import subprocess
     from urllib.parse import urlsplit
 
@@ -3748,7 +3760,7 @@ def _wb_spawn_child(objects: int, clusters: int, coalesce: bool,
     env["KCP_WATCH_FLUSH_MS"] = flush_ms
     env.update(extra_env or {})
     p = subprocess.Popen([sys.executable, os.path.abspath(__file__),
-                          "--watchers-serve"],
+                          "--watchers-serve", *(["--relay"] if relay else [])],
                          stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
                          env=env, text=True)
     line = p.stdout.readline()
@@ -3906,10 +3918,13 @@ def watchers_bench() -> int:
       each checkpoint — the gate is the RSS *slope* (per-watcher cost
       bounded, plateau under sustained load), not a magic number;
     - **flush A/B** (the headline value): the same seeded schedule at
-      reduced scale against coalesced (KCP_WATCH_COALESCE=1) and
-      per-batch (=0) children — per-watcher stream sha256 must be
-      IDENTICAL across modes while ``watch_flush_total`` drops by the
-      reported factor;
+      reduced scale against three children — the pull relay coalesced
+      (KCP_WATCH_COALESCE=1) and per-batch (=0), and the push path a
+      local store's watch takes by default — per-watcher stream sha256
+      must be IDENTICAL across all three while ``watch_flush_total``
+      drops by the reported factor from per-batch to coalesced relay
+      (the push path's flushes are reported beside them: one per socket
+      per fan-out pass);
     - **evict drill**: a watcher that never reads while writes flood a
       tiny KCP_WATCH_BUFFER_MAX child — the slow socket must be evicted
       (metric + terminal typed 410 on the wire) while a healthy watcher
@@ -4022,9 +4037,11 @@ def watchers_bench() -> int:
         ab_clusters = 2  # all pressure on few clusters: every event
         # fans out to ~half the A/B watchers, the shape coalescing serves
         results: dict[str, dict] = {}
-        for label, coalesce in (("per_batch", False), ("coalesced", True)):
-            p, host, port = _wb_spawn_child(ab_objects, ab_clusters,
-                                            coalesce, ab_flush_ms)
+        for label, coalesce, relay in (("per_batch", False, True),
+                                       ("coalesced", True, True),
+                                       ("push", True, False)):
+            p, host, port = _wb_spawn_child(
+                ab_objects, ab_clusters, coalesce, ab_flush_ms, relay=relay)
             stats = _WatcherStats()
             tasks: list[asyncio.Task] = []
             try:
@@ -4061,8 +4078,9 @@ def watchers_bench() -> int:
                 "elapsed_s": round(elapsed, 3),
                 "hashes": dict(stats.hashes),
             }
-        a, b = results["per_batch"], results["coalesced"]
-        bytes_equal = (a["hashes"] == b["hashes"]
+        a, b, c = (results["per_batch"], results["coalesced"],
+                   results["push"])
+        bytes_equal = (a["hashes"] == b["hashes"] == c["hashes"]
                        and len(a["hashes"]) == ab_watchers)
         reduction = a["flushes"] / max(b["flushes"], 1.0)
         return {
@@ -4070,9 +4088,10 @@ def watchers_bench() -> int:
             "clusters": ab_clusters, "flush_ms": ab_flush_ms,
             "pace_ms": ab_pace_ms,
             "bytes_equal": bytes_equal,
-            "lines_equal": a["lines"] == b["lines"],
+            "lines_equal": a["lines"] == b["lines"] == c["lines"],
             "flushes_per_batch": a["flushes"],
             "flushes_coalesced": b["flushes"],
+            "flushes_push": c["flushes"], "push_s": c["elapsed_s"],
             "flush_reduction": round(reduction, 2),
             "per_batch_s": a["elapsed_s"], "coalesced_s": b["elapsed_s"],
         }

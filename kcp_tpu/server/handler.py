@@ -44,6 +44,34 @@ class _SlowWatcher(Exception):
     — bounded memory beats an unbounded goodbye)."""
 
 
+_QUEUE_EVICTED = ("watch queue overflowed (KCP_WATCH_QUEUE): slow watcher "
+                  "evicted; re-list and resume")
+_SOCKET_EVICTED = ("watch socket backlog exceeded KCP_WATCH_BUFFER_MAX: "
+                   "slow watcher evicted; re-list and resume")
+
+
+def _bookmark(rv: int) -> dict:
+    return {"type": "BOOKMARK",
+            "object": {"kind": "Bookmark",
+                       "metadata": {"resourceVersion": str(rv)}}}
+
+
+class _StreamSignal(asyncio.Event):
+    """``draining`` / ``watch_fence``: an Event whose ``set`` also wakes
+    every push-served watch stream. Such a stream sleeps on ONE wake-up
+    of its own (its watch closing, drain, fence), not on a helper task
+    per signal."""
+
+    def __init__(self, wakes: "set[asyncio.Event]"):
+        super().__init__()
+        self._wakes = wakes
+
+    def set(self) -> None:
+        super().set()
+        for wake in list(self._wakes):
+            wake.set()
+
+
 def _status_body(code: int, reason: str, message: str) -> dict:
     return {
         "kind": "Status",
@@ -186,7 +214,8 @@ class RestHandler:
         # producer flushes its buffered events, sends a terminal
         # in-stream Status, and returns — the half of "no watcher is
         # abandoned mid-stream" that the HTTP layer cannot do alone
-        self.draining = asyncio.Event()
+        self._stream_wakes: set[asyncio.Event] = set()
+        self.draining = _StreamSignal(self._stream_wakes)
         # epoch fence (POST /replication/fence): a fenced store can never
         # deliver another watch event, so live watch producers end with
         # the SAME terminal Status as drain (resumable from last_rv) and
@@ -195,21 +224,36 @@ class RestHandler:
         # because a fenced server keeps serving: /replication/status must
         # answer probes/audits and writes must reach the store's own
         # fenced refusal (repl_fenced_writes_total)
-        self.watch_fence = asyncio.Event()
+        self.watch_fence = _StreamSignal(self._stream_wakes)
         # watcher-scale serving (KCP_WATCH_COALESCE, default on): one
         # shared flush coalescer gathers every watch stream's encode-once
         # lines and writes each socket once per coalescing tick —
         # O(sockets) buffered writes of shared bytes per tick instead of
         # a write+drain round trip per watcher per event batch. =0 keeps
         # the per-batch send_raw_many path for A/B (bench.py --watchers).
+        # Both govern the PULL relay only (a remote store's watch, a
+        # duck-typed stream): a watch on a local store is pushed to its
+        # socket by the store's fan-out pass itself (_watch), once per
+        # commit window, and pays no coalescing tick.
+        self._buffer_max = int(os.environ.get(
+            "KCP_WATCH_BUFFER_MAX", str(2 * 1024 * 1024)))
         self._coalescer = None
         if os.environ.get("KCP_WATCH_COALESCE", "1").lower() not in (
                 "0", "false", "off"):
             self._coalescer = FlushCoalescer(
                 tick_s=float(os.environ.get("KCP_WATCH_FLUSH_MS", "2"))
                 / 1000.0,
-                buffer_max=int(os.environ.get(
-                    "KCP_WATCH_BUFFER_MAX", str(2 * 1024 * 1024))))
+                buffer_max=self._buffer_max)
+        # which of the two delivered a batch: one increment per batch
+        # written to a watch stream
+        self._push_batches = REGISTRY.counter(
+            "watch_push_batches_total",
+            "watch event batches written to their stream by the store's "
+            "fan-out pass (push: a local store's watch)")
+        self._relay_batches = REGISTRY.counter(
+            "watch_relay_batches_total",
+            "watch event batches written to their stream by the pull "
+            "relay (a remote store's watch, a duck-typed stream)")
         # per-server bookmark cadence (KCP_WATCH_BOOKMARK_S): how often
         # an idle stream that asked for bookmarks gets a progress marker
         # at the store RV — what keeps a quiet informer's resume point
@@ -1443,11 +1487,47 @@ class RestHandler:
                     "watch streams opened with sendInitialEvents").inc()
             loop = asyncio.get_event_loop()
             deadline = loop.time() + timeout if timeout else None
-            drain_task: asyncio.Task | None = None
-            fence_task: asyncio.Task | None = None
+            # a watch that offers the push half (a local store.Watch),
+            # on a stream with the buffered write half, is written by
+            # the store's fan-out pass itself; everything else — the
+            # REST client's watch of a storage frontend, duck-typed test
+            # streams, a store without the encode cache — is relayed
+            pushed = (self._encode
+                      and getattr(watch, "set_sink", None) is not None
+                      and getattr(stream, "write_raw_many", None) is not None)
+
+            def stamp_observed(batch) -> None:
+                # `observe`: commit of each event -> its frame handed to
+                # this stream's transport, for every delivered event
+                now = time.monotonic()
+                for e in batch:
+                    tm = e.__dict__.get("_tm")
+                    if tm is not None:
+                        obs.phase("observe", None, tm, now)
+
+            def encode_lines(batch) -> list[bytes]:
+                # encode-once: every stream serving this store splices
+                # the same cached event-line bytes — a 64-watcher
+                # fan-out encodes each event once
+                t0 = loop.time()
+                with obs.annotate("kcp.watch.encode", events=len(batch)):
+                    lines = self.store.encode_events(batch)
+                self._enc_seconds.observe(loop.time() - t0)
+                return lines
+
+            def write_batch(batch) -> None:
+                # the push path's whole delivery, nothing awaited:
+                # encode-once lines, one chunk on the transport, the
+                # `observe` stamp where the frame is handed over. One
+                # write per watch per fan-out pass is the coalescer's
+                # promise with the commit window as the tick.
+                stream.write_raw_many(encode_lines(batch))
+                stamp_observed(batch)
+                self._push_batches.inc()
 
             async def send_batch(batch) -> None:
-                # coalesce whatever else the watch already buffered
+                # the pull relay's delivery: coalesce whatever else the
+                # watch already buffered
                 # (the store's batched fan-out delivers in bursts)
                 # into one chunk/one drain instead of a write per
                 # event; drain() never raises, so error mapping below
@@ -1457,13 +1537,7 @@ class RestHandler:
                             if self._encode else None)
                 send_many = getattr(stream, "send_json_many", None)
                 if send_raw is not None:
-                    # encode-once: every relay serving this store
-                    # splices the same cached event-line bytes — a
-                    # 64-watcher fan-out encodes each event once
-                    t0 = loop.time()
-                    with obs.annotate("kcp.watch.encode", events=len(batch)):
-                        lines = self.store.encode_events(batch)
-                    self._enc_seconds.observe(loop.time() - t0)
+                    lines = encode_lines(batch)
                     if (self._coalescer is not None
                             and getattr(stream, "write_raw_many", None)
                             is not None):
@@ -1484,14 +1558,8 @@ class RestHandler:
                     for e in batch:
                         await stream.send_json({"type": e.type,
                                                 "object": e.object})
-                # `observe`: commit of each event -> its frame handed to
-                # this stream's transport (fan-out flush, relay wake-up,
-                # encode, write), for every delivered event
-                now = time.monotonic()
-                for e in batch:
-                    tm = e.__dict__.get("_tm")
-                    if tm is not None:
-                        obs.phase("observe", None, tm, now)
+                stamp_observed(batch)
+                self._relay_batches.inc()
 
             async def flush_and_terminate() -> None:
                 # graceful drain: every event the fan-out already queued
@@ -1510,11 +1578,7 @@ class RestHandler:
                 rv_now = (getattr(watch, "last_rv", 0) if self._remote
                           else self.store.resource_version)
                 if rv_now:
-                    await stream.send_json({
-                        "type": "BOOKMARK",
-                        "object": {"kind": "Bookmark", "metadata": {
-                            "resourceVersion": str(rv_now)}},
-                    })
+                    await stream.send_json(_bookmark(rv_now))
                 await stream.send_json({
                     "type": "ERROR",
                     "object": _status_body(
@@ -1522,6 +1586,95 @@ class RestHandler:
                         "server is draining; resume from your last "
                         "resourceVersion")})
 
+            async def serve_pushed() -> None:
+                # The store's fan-out pass writes this stream (`push`,
+                # the watch's sink); this coroutine keeps only what
+                # needs a clock or a signal — bookmark cadence,
+                # timeoutSeconds, drain, fence, the closed/evicted watch
+                # — and sleeps for those on ONE wake-up. Sink and
+                # coroutine write to the same transport synchronously,
+                # so order on the wire is the order of the calls.
+                wake = asyncio.Event()
+                idle_since = loop.time()
+                slow = False
+
+                def push(batch) -> None:
+                    nonlocal idle_since, slow
+                    write_batch(batch)
+                    idle_since = loop.time()
+                    if stream.write_buffer_size() > self._buffer_max:
+                        # the socket sat past KCP_WATCH_BUFFER_MAX: a
+                        # slow socket is evicted, never awaited — the
+                        # terminal typed 410 is buffered without a drain
+                        slow = True
+                        REGISTRY.counter("watch_evicted_total").inc()
+                        self._send_evicted(stream, _SOCKET_EVICTED)
+                        watch.close()
+
+                def write_rest() -> None:
+                    batch = watch.detach()
+                    if batch:
+                        write_batch(batch)
+
+                self._stream_wakes.add(wake)
+                try:
+                    # attach only now, after the initial part of the
+                    # stream is out: what the watch buffered meanwhile
+                    # (a since_rv replay, writes racing the snapshot) is
+                    # handed over first, in order, by the attach itself
+                    watch.set_sink(push, wake.set)
+                    while True:
+                        if self.draining.is_set() or self.watch_fence.is_set():
+                            write_rest()
+                            await flush_and_terminate()
+                            return
+                        if watch.closed:
+                            if slow:
+                                return
+                            # what was buffered before the close is still
+                            # delivered, as the pull relay's iteration does
+                            write_rest()
+                            if watch.evicted:
+                                self._send_evicted(stream, _QUEUE_EVICTED)
+                            return
+                        now = loop.time()
+                        if deadline is not None and now >= deadline:
+                            return  # server-side watch timeout: clean close
+                        step = 3600.0
+                        if bookmarks:
+                            step = idle_since + bookmark_every - now
+                            if step <= 0:
+                                # idle for a whole cadence. pending()
+                                # flushes the store's pending events —
+                                # through the sink — so a bookmark never
+                                # carries an RV ahead of an event not
+                                # yet written to this stream (one still
+                                # buffered behind an unsynced commit
+                                # window reads as pending: no bookmark)
+                                if not watch.pending():
+                                    await stream.send_json(_bookmark(
+                                        self.store.resource_version))
+                                idle_since = loop.time()
+                                continue
+                        if deadline is not None:
+                            step = min(step, deadline - now)
+                        try:
+                            await asyncio.wait_for(wake.wait(), step)
+                        except asyncio.TimeoutError:
+                            pass
+                        wake.clear()
+                finally:
+                    self._stream_wakes.discard(wake)
+                    watch.clear_sink()
+
+            if pushed:
+                try:
+                    await serve_pushed()
+                finally:
+                    watch.close()
+                return
+            drain_task: asyncio.Task | None = None
+            fence_task: asyncio.Task | None = None
             nxt: asyncio.Task | None = None
             try:
                 it = watch.__aiter__()
@@ -1590,11 +1743,7 @@ class RestHandler:
                                 # typed in-stream 410 — the informer
                                 # relists NOW and resumes; the metric
                                 # was counted at the eviction site
-                                self._send_evicted(
-                                    stream,
-                                    "watch queue overflowed "
-                                    "(KCP_WATCH_QUEUE): slow watcher "
-                                    "evicted; re-list and resume")
+                                self._send_evicted(stream, _QUEUE_EVICTED)
                             return
                         raise err
                     if ev is not None:
@@ -1625,20 +1774,13 @@ class RestHandler:
                                 continue  # nothing delivered yet
                         else:
                             rv_now = self.store.resource_version
-                        await stream.send_json({
-                            "type": "BOOKMARK",
-                            "object": {"kind": "Bookmark", "metadata": {
-                                "resourceVersion": str(rv_now)}},
-                        })
+                        await stream.send_json(_bookmark(rv_now))
             except _SlowWatcher:
                 # the socket sat past KCP_WATCH_BUFFER_MAX: terminal
                 # typed 410 buffered without a drain (draining a full
                 # slow socket is exactly what eviction exists to avoid)
                 REGISTRY.counter("watch_evicted_total").inc()
-                self._send_evicted(
-                    stream,
-                    "watch socket backlog exceeded KCP_WATCH_BUFFER_MAX: "
-                    "slow watcher evicted; re-list and resume")
+                self._send_evicted(stream, _SOCKET_EVICTED)
                 return
             finally:
                 # reap outstanding helper tasks without awaiting (this

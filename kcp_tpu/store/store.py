@@ -331,6 +331,12 @@ class Watch:
         self._max_queue = store._watch_queue
         self.evicted = False
         self._wakeup: asyncio.Event | None = None
+        # push half (set_sink): while a sink is attached the store's
+        # fan-out hands it this watch's buffered events itself, in the
+        # loop pass that flushed them — no consumer task to wake
+        self._sink: Callable[[list[Event]], None] | None = None
+        self._on_close: Callable[[], None] | None = None
+        self._sink_marked = False
         # batched fan-out (indexed stores): a single-equality selector
         # matches via one interned pair id (the fanout_match shape), a
         # general kernel-shaped one via a CompiledSelector; both None =>
@@ -401,6 +407,42 @@ class Watch:
             self._store._queue_depth.observe(depth)
         if self._wakeup is not None:
             self._wakeup.set()
+        if self._sink is not None and not self._sink_marked:
+            self._sink_marked = True
+            self._store._sink_dirty.append(self)
+
+    def set_sink(self, sink: Callable[[list[Event]], None],
+                 on_close: Callable[[], None] | None = None) -> None:
+        """Attach the push half: from now on the store hands this
+        watch's buffered events to ``sink(batch)`` — once per fan-out
+        pass that touched it, in RV order, synchronously inside that
+        pass — instead of waking a pull consumer. Events buffered before
+        the attach are delivered first, here. Like every fan-out
+        delivery to a socket, a sink runs only once the events' commit
+        window is synced (``LogicalStore._run_sinks``). A sink that
+        raises closes the watch like a dropped stream; ``on_close`` is
+        called once when the watch closes, whatever closed it (eviction,
+        a fault drill, a failed sink)."""
+        self._sink = sink
+        self._on_close = on_close
+        if self._events and not self._sink_marked:
+            self._sink_marked = True
+            self._store._sink_dirty.append(self)
+        self._store._flush_events()
+
+    def clear_sink(self) -> None:
+        """Detach the push half: events buffer for :meth:`drain` /
+        ``async for`` again."""
+        self._sink = self._on_close = None
+
+    def detach(self) -> list[Event]:
+        """End the push half and return what the sink was not handed
+        yet, for the stream's last frames. An open commit window is
+        closed first (one WAL append + sync, as a size-bound split does),
+        so these events too are synced before a socket sees them."""
+        self.clear_sink()
+        self._store._gc_barrier()
+        return self.drain()
 
     def _evict(self) -> None:
         self.evicted = True
@@ -434,6 +476,8 @@ class Watch:
             self._store._unsubscribe(self)
             if self._wakeup is not None:
                 self._wakeup.set()
+            if self._on_close is not None:
+                self._on_close()
 
     @property
     def closed(self) -> bool:
@@ -644,6 +688,9 @@ class LogicalStore:
         self._pending: list[Event] = []
         self._flush_scheduled = False
         self._flushing = False
+        # watches with a push sink (Watch.set_sink) that hold events the
+        # sink has not been handed yet — O(touched), never O(watches)
+        self._sink_dirty: list[Watch] = []
         self._emit_batch = max(1, int(os.environ.get("KCP_STORE_EMIT_BATCH", "128")))
         # exact label interning for the vectorized matchers: distinct
         # (key, value) pairs / keys get sequential nonzero uint32 ids, so
@@ -1802,6 +1849,8 @@ class LogicalStore:
                 out = w._transform(ev)
                 if out is not None:
                     w._push(out)
+            if self._sink_dirty:
+                self._schedule_flush()
             return
         # CoW: stored snapshots are never mutated in place (every write
         # replaces the whole dict), so the event shares them — the
@@ -1812,33 +1861,83 @@ class LogicalStore:
         self._note_history(ev)
         self._pending.append(ev)
         if len(self._pending) >= self._emit_batch:
-            self._flush_events()
-        elif not self._flush_scheduled:
-            if self._gc_sink():
-                # group commit: this mutation's _log_wal joins (or
-                # opens) a commit window, whose flush delivers the
-                # fan-out once for the whole window — no per-mutation
-                # scheduling (watch()/drain() still flush lazily, and
-                # sync-context callers never scheduled here anyway)
+            # bound the pending list now, but hand nothing to a push
+            # sink from inside a mutation: its WAL record is not written
+            # yet (_log_wal follows _emit)
+            self._flush_events(deliver=False)
+            if not self._sink_dirty:
                 return
-            try:
-                loop = asyncio.get_running_loop()
-            except RuntimeError:
-                return  # sync context: consumers flush lazily on access
-            self._flush_scheduled = True
-            loop.call_soon(self._flush_events)
+        self._schedule_flush()
+
+    def _schedule_flush(self) -> None:
+        if self._flush_scheduled:
+            return
+        if self._gc_sink():
+            # group commit: this mutation's _log_wal joins (or
+            # opens) a commit window, whose flush delivers the
+            # fan-out once for the whole window — no per-mutation
+            # scheduling (watch()/drain() still flush lazily, and
+            # sync-context callers never scheduled here anyway)
+            return
+        try:
+            loop = asyncio.get_running_loop()
+        except RuntimeError:
+            return  # sync context: consumers flush lazily on access
+        self._flush_scheduled = True
+        loop.call_soon(self._flush_events)
 
     # ------------------------------------------------- batched fan-out
 
-    def _flush_events(self) -> None:
-        """Deliver pending events to all watches in one vectorized pass.
+    def _flush_events(self, deliver: bool = True) -> None:
+        """Deliver pending events to all watches in one vectorized pass,
+        then hand each touched push-served watch its batch
+        (:meth:`_run_sinks`; ``deliver=False`` leaves that to a later
+        flush).
 
         Reentrancy-safe: an injected watch drop closes a watch from
         inside delivery, and close() itself flushes first.
         """
         self._flush_scheduled = False
-        if self._flushing or not self._pending:
+        if self._flushing:
             return
+        if self._pending:
+            self._fanout_pending()
+        if deliver and self._sink_dirty:
+            self._run_sinks()
+
+    def _run_sinks(self) -> None:
+        """Hand every touched push-served watch its buffered events:
+        one ``sink(batch)`` call per watch per pass, in the loop pass of
+        the flush. Nothing reaches a sink while a commit window holds
+        unsynced records — a lazy flush (``pending()``, a new
+        subscription, another consumer's ``__anext__``) may have fanned
+        those events out early; they wait in the watch until
+        ``_gc_flush`` has synced the window and flushes again. A sink
+        that raises closes its own watch, like a dropped stream, and
+        never breaks the pass for the others."""
+        w = self._gc_window
+        if w is not None and w.recs:
+            return
+        dirty, self._sink_dirty = self._sink_dirty, []
+        for watch in dirty:
+            watch._sink_marked = False
+            sink = watch._sink
+            if sink is None or not watch._events:
+                continue
+            batch = list(watch._events)
+            watch._events.clear()
+            try:
+                sink(batch)
+            except Exception as e:  # noqa: BLE001 — one stream's fault
+                log.log(logging.DEBUG if isinstance(e, ConnectionError)
+                        else logging.WARNING,
+                        "watch %s/%s: push sink failed (%s: %s); closing "
+                        "the watch", watch.resource, watch.cluster,
+                        type(e).__name__, e)
+                watch._sink = None
+                watch.close()  # on_close tells the stream's coroutine
+
+    def _fanout_pending(self) -> None:
         batch, self._pending = self._pending, []
         self._flushing = True
         t0 = time.perf_counter()

@@ -271,14 +271,31 @@ def test_slow_socket_evicted_with_terminal_410(monkeypatch):
 
 
 def test_coalesced_stream_byte_identical_to_per_batch(monkeypatch):
-    """The same seeded mutation run served with KCP_WATCH_COALESCE on
-    and off yields the exact same reassembled line stream (chunk
-    framing may differ; the payload may not), while the coalesced run
-    uses fewer flushes."""
+    """The same seeded mutation run served three ways — the pull relay
+    with KCP_WATCH_COALESCE off, the pull relay with it on, and the push
+    path a local store's watch takes by default — yields the exact same
+    reassembled line stream (chunk framing may differ; the payload and
+    its order may not), while the coalesced relay uses fewer flushes
+    than the per-batch one."""
+    from kcp_tpu.store import store as store_mod
 
-    async def one_mode(coalesce: bool) -> tuple[list[bytes], float]:
-        monkeypatch.setenv("KCP_WATCH_COALESCE", "1" if coalesce else "0")
-        monkeypatch.setenv("KCP_WATCH_FLUSH_MS", "5")
+    async def one_mode(mode: str) -> tuple[list[bytes], float, float]:
+        with monkeypatch.context() as mp:
+            if mode != "push":
+                # no knob selects the relay: the choice is made on the
+                # watch object, so a watch without the push half (the
+                # REST client's, of a storage frontend) is relayed
+                mp.delattr(store_mod.Watch, "set_sink")
+            mp.setenv("KCP_WATCH_COALESCE",
+                      "1" if mode == "coalesced" else "0")
+            mp.setenv("KCP_WATCH_FLUSH_MS", "5")
+            push0 = REGISTRY.counter("watch_push_batches_total").value
+            lines, flushes = await serve()
+            return (lines, flushes,
+                    REGISTRY.counter("watch_push_batches_total").value
+                    - push0)
+
+    async def serve() -> tuple[list[bytes], float]:
         store = LogicalStore(clock=lambda: 0.0)
         for i in range(8):
             store.create("configmaps", "t0", {
@@ -332,11 +349,13 @@ def test_coalesced_stream_byte_identical_to_per_batch(monkeypatch):
         return lines, REGISTRY.counter("watch_flush_total").value - flush0
 
     async def run() -> None:
-        per_batch, f_pb = await one_mode(False)
-        coalesced, f_co = await one_mode(True)
-        assert per_batch == coalesced
+        per_batch, f_pb, p_pb = await one_mode("per-batch")
+        coalesced, f_co, p_co = await one_mode("coalesced")
+        pushed, _f_pu, p_pu = await one_mode("push")
+        assert per_batch == coalesced == pushed
         assert len(per_batch) == 40
         assert f_co < f_pb
+        assert p_pb == p_co == 0 and p_pu > 0
 
     asyncio.run(run())
 
@@ -549,3 +568,441 @@ def test_resume_through_router_spreads_to_replica(tmp_path):
         router.stop()
         replica.stop()
         primary.stop()
+
+
+# ---------------------------------------------------------------------------
+# push delivery: the store's fan-out pass writes a local watch's stream
+# ---------------------------------------------------------------------------
+
+
+class _PushStream:
+    """A duck-typed stream WITH the buffered write half (what
+    httpd.StreamResponse offers): every frame is recorded in the order
+    of the calls, which on one transport is the order on the wire."""
+
+    def __init__(self):
+        self.frames: list[bytes] = []
+        self.backlog = 0
+        self.fail_writes = False
+
+    async def send_json(self, obj):
+        self.frames.append(json.dumps(obj).encode() + b"\n")
+
+    async def send_raw_many(self, lines):
+        await asyncio.sleep(0)  # a drain that yields: writes may race it
+        self.frames.extend(lines)
+
+    def write_raw_many(self, lines):
+        if self.fail_writes:
+            raise RuntimeError("transport exploded")
+        self.frames.extend(lines)
+
+    def write_buffer_size(self):
+        return self.backlog
+
+    def decoded(self) -> list[dict]:
+        return [json.loads(f) for f in self.frames]
+
+
+def _watch_req(cluster: str = "t0", **params: str):
+    from kcp_tpu.server.httpd import Request
+
+    query = {"watch": ["true"]}
+    query.update({k: [v] for k, v in params.items()})
+    return Request(method="GET",
+                   path=f"/clusters/{cluster}/api/v1/configmaps",
+                   query=query, headers={}, body=b"")
+
+
+def _push_counts() -> tuple[float, float]:
+    return (REGISTRY.counter("watch_push_batches_total").value,
+            REGISTRY.counter("watch_relay_batches_total").value)
+
+
+async def _serve_watch(handler, stream, **params):
+    """Start a watch producer on ``stream``; returns its task once the
+    watch is subscribed (a stream with no initial part attaches its sink
+    in that same step)."""
+    store = handler.store
+    n0 = len(store._watches)
+    resp = await handler(_watch_req(**params))
+    task = asyncio.ensure_future(resp.producer(stream))
+    for _ in range(400):
+        if len(store._watches) > n0:
+            break
+        await asyncio.sleep(0.005)
+    return task
+
+
+def _rv(frame: dict) -> int:
+    return int(frame["object"]["metadata"]["resourceVersion"])
+
+
+def test_push_hands_frame_to_transport_inside_the_fanout_pass(monkeypatch):
+    """Delivery takes ONE loop pass: with a local store and a real
+    HttpServer stream, the frame has been handed to the stream's
+    transport by the time ``_flush_events`` returns — no ``await``
+    between the fan-out and the write — and the ``observe`` phase is
+    stamped right there."""
+    from kcp_tpu.server.httpd import StreamResponse
+
+    written: list[list[bytes]] = []
+    orig = StreamResponse.write_raw_many
+
+    def recording(self, lines):
+        written.append(list(lines))
+        return orig(self, lines)
+
+    monkeypatch.setattr(StreamResponse, "write_raw_many", recording)
+
+    async def run() -> None:
+        store = LogicalStore(clock=lambda: 0.0)
+        store.create("configmaps", "t0", _cm("seed", "t0"))
+        handler = RestHandler(store, default_scheme(), admission=None)
+        handler.ready = True
+        srv = HttpServer(handler)
+        await srv.start()
+        reader, writer = await asyncio.open_connection(srv.host, srv.port)
+        try:
+            writer.write(b"GET /clusters/t0/api/v1/configmaps?watch=true "
+                         b"HTTP/1.1\r\nHost: t\r\n\r\n")
+            await writer.drain()
+            await reader.readuntil(b"\r\n\r\n")
+            for _ in range(400):
+                if store._watches and store._watches[0]._sink is not None:
+                    break
+                await asyncio.sleep(0.005)
+            assert store._watches[0]._sink is not None
+            observed = REGISTRY.histogram("convergence_observe_seconds")
+            n0, push0 = observed.n, _push_counts()[0]
+            # ---- one synchronous stretch: commit, fan-out, written
+            for i in range(3):
+                store.update("configmaps", "t0", _cm("seed", "t0", f"v{i}"))
+            assert written == []  # nothing before the fan-out pass
+            store._flush_events()
+            assert len(written) == 1 and len(written[0]) == 3
+            assert observed.n == n0 + 3
+            assert _push_counts()[0] == push0 + 1
+            # ---- and the client's socket shows exactly those bytes
+            size = int((await reader.readline()).strip(), 16)
+            payload = await reader.readexactly(size)
+            assert payload == b"".join(written[0])
+            assert [json.loads(ln)["object"]["data"]["v"]
+                    for ln in payload.splitlines()] == ["v0", "v1", "v2"]
+        finally:
+            writer.close()
+            await srv.stop()
+            handler.close()
+            store.close()
+
+    asyncio.run(run())
+
+
+def test_push_waits_for_the_commit_windows_wal_sync(tmp_path):
+    """No event is handed to a socket before its commit window's WAL
+    append + sync: a lazy flush (another consumer's pending()) fans the
+    event out early, but the sink only runs from the window's flush."""
+
+    async def run() -> None:
+        store = LogicalStore(wal_path=str(tmp_path / "w.wal"))
+        store._gc_linger_s = 30.0  # the window stays open until flushed
+        handler = RestHandler(store, default_scheme(), admission=None)
+        stream = _PushStream()
+        task = await _serve_watch(handler, stream)
+        other = store.watch("configmaps")
+        try:
+            store.create("configmaps", "t0", _cm("a", "t0"))
+            window = store._gc_window
+            assert window is not None and window.recs
+            assert other.pending() == 1  # lazily fanned out already
+            assert stream.frames == []  # ...but not to the socket
+            synced = store._wal_sync_total.value
+            store._gc_flush(window)
+            assert store._wal_sync_total.value == synced + 1
+            assert [f["type"] for f in stream.decoded()] == ["ADDED"]
+            assert window.fut.done() and not window.fut.exception()
+        finally:
+            task.cancel()
+            await asyncio.gather(task, return_exceptions=True)
+            store.close()
+
+    asyncio.run(run())
+
+
+def test_watch_list_snapshot_then_bookmark_then_live_in_rv_order():
+    """A watch-list stream with writes racing the snapshot delivers
+    snapshot → sync BOOKMARK → live events in RV order, none lost, none
+    doubled: what commits while the snapshot streams stays buffered in
+    the watch and is flushed, in order, when the sink attaches."""
+
+    async def run() -> None:
+        store = LogicalStore()
+        for i in range(1100):  # three snapshot batches of <= 512
+            store.create("configmaps", "t0", _cm(f"s{i:04d}", "t0"))
+        handler = RestHandler(store, default_scheme(), admission=None)
+        stream = _PushStream()
+        written: list[int] = []
+        stop = False
+
+        async def writer() -> None:
+            i = 0
+            while not stop:
+                obj = store.update("configmaps", "t0",
+                                   _cm(f"s{i % 1100:04d}", "t0", f"w{i}"))
+                written.append(int(obj["metadata"]["resourceVersion"]))
+                i += 1
+                await asyncio.sleep(0)
+
+        wtask = asyncio.ensure_future(writer())
+        await asyncio.sleep(0)
+        task = await _serve_watch(handler, stream, sendInitialEvents="true",
+                                  allowWatchBookmarks="true")
+        await asyncio.sleep(0.05)
+        stop = True
+        await wtask
+        store._flush_events()
+        task.cancel()
+        await asyncio.gather(task, return_exceptions=True)
+        frames = stream.decoded()
+        marks = [i for i, f in enumerate(frames) if f["type"] == "BOOKMARK"]
+        assert marks, "no sync bookmark"
+        sync = marks[0]
+        init_rv = _rv(frames[sync])
+        assert frames[sync]["object"]["metadata"]["annotations"]
+        snapshot, live = frames[:sync], frames[sync + 1:]
+        assert len(snapshot) == 1100
+        assert all(f["type"] == "ADDED" and _rv(f) <= init_rv
+                   for f in snapshot)
+        live_rvs = [_rv(f) for f in live if f["type"] != "BOOKMARK"]
+        racing = [rv for rv in written if rv > init_rv]
+        assert any(rv <= init_rv for rv in written), "no write raced"
+        assert racing and live_rvs == racing  # in order, each exactly once
+        store.close()
+
+    asyncio.run(run())
+
+
+def test_push_slow_socket_evicted_through_the_sink():
+    """A socket past KCP_WATCH_BUFFER_MAX is evicted by the sink itself
+    — never awaited: the batch that crossed the bound is followed by the
+    terminal typed 410, watch_evicted_total rises by one, and the
+    stream's watch is closed."""
+
+    async def run() -> None:
+        store = LogicalStore()
+        handler = RestHandler(store, default_scheme(), admission=None)
+        stream = _PushStream()
+        task = await _serve_watch(handler, stream)
+        watch = store._watches[-1]
+        before = REGISTRY.counter("watch_evicted_total").value
+        store.create("configmaps", "t0", _cm("ok", "t0"))
+        store._flush_events()
+        assert len(stream.frames) == 1 and not watch.closed
+        stream.backlog = handler._buffer_max + 1
+        store.create("configmaps", "t0", _cm("over", "t0"))
+        store._flush_events()  # the eviction happens inside this pass
+        assert watch.closed
+        assert REGISTRY.counter("watch_evicted_total").value == before + 1
+        await asyncio.wait_for(task, 5)
+        frames = stream.decoded()
+        assert [f["type"] for f in frames] == ["ADDED", "ADDED", "ERROR"]
+        assert frames[-1]["object"]["code"] == 410
+        assert frames[-1]["object"]["reason"] == "Expired"
+        store.create("configmaps", "t0", _cm("later", "t0"))
+        store._flush_events()
+        assert len(stream.frames) == 3  # nothing after the terminal 410
+        store.close()
+
+    asyncio.run(run())
+
+
+def test_push_bookmark_never_ahead_of_an_unwritten_event(tmp_path,
+                                                         monkeypatch):
+    """A BOOKMARK never carries an RV ahead of an event not yet written
+    to that stream — also while commit windows hold events back from the
+    sink: on the recorded wire every event at or below a bookmark's RV
+    precedes it."""
+    monkeypatch.setenv("KCP_WATCH_BOOKMARK_S", "0.001")
+
+    async def run() -> None:
+        store = LogicalStore(wal_path=str(tmp_path / "w.wal"))
+        store._gc_linger_s = 0.004  # windows stay open across loop passes
+        handler = RestHandler(store, default_scheme(), admission=None)
+        stream = _PushStream()
+        task = await _serve_watch(handler, stream, allowWatchBookmarks="true")
+        rng = random.Random(7)
+        store.create("configmaps", "t0", _cm("c", "t0"))
+        for i in range(150):
+            store.update("configmaps", "t0", _cm("c", "t0", str(i)))
+            if rng.random() < 0.5:
+                await asyncio.sleep(rng.choice((0, 0.001, 0.003, 0.006)))
+        await asyncio.sleep(0.05)
+        task.cancel()
+        await asyncio.gather(task, return_exceptions=True)
+        frames = stream.decoded()
+        events = [_rv(f) for f in frames if f["type"] != "BOOKMARK"]
+        assert events == sorted(set(events)) and len(events) == 151
+        marks = 0
+        high = 0  # highest event RV on the wire so far
+        for f in frames:
+            if f["type"] == "BOOKMARK":
+                marks += 1
+                assert _rv(f) <= high, (
+                    f"bookmark {_rv(f)} ahead of the stream ({high})")
+            else:
+                high = _rv(f)
+        assert marks > 0
+        store.close()
+
+    asyncio.run(run())
+
+
+@pytest.mark.parametrize("signal", ["draining", "watch_fence"])
+def test_push_drain_and_fence_end_with_bookmark_and_status(signal):
+    """Drain and fence after pushed events end the stream with every
+    buffered event, the anchoring BOOKMARK at the store's RV, and the
+    terminal Status — on ONE wake-up of the stream's coroutine."""
+
+    async def run() -> None:
+        store = LogicalStore()
+        handler = RestHandler(store, default_scheme(), admission=None)
+        stream = _PushStream()
+        task = await _serve_watch(handler, stream)
+        for i in range(3):
+            store.create("configmaps", "t0", _cm(f"a{i}", "t0"))
+        store._flush_events()
+        assert len(stream.frames) == 3
+        # two more commit and are still pending in the store when the
+        # signal fires: they must precede the bookmark
+        for i in range(2):
+            store.create("configmaps", "t0", _cm(f"b{i}", "t0"))
+        getattr(handler, signal).set()
+        await asyncio.wait_for(task, 5)
+        frames = stream.decoded()
+        assert [f["type"] for f in frames] == \
+            ["ADDED"] * 5 + ["BOOKMARK", "ERROR"]
+        assert [_rv(f) for f in frames[:5]] == sorted(
+            _rv(f) for f in frames[:5])
+        assert _rv(frames[5]) == store.resource_version
+        assert frames[6]["object"]["code"] == 503
+        assert not handler._stream_wakes  # the wake-up is unregistered
+        assert store._watches == []
+        store.close()
+
+    asyncio.run(run())
+
+
+def test_push_sink_failure_closes_only_its_own_watch(tmp_path):
+    """A sink that raises closes its own watch like a dropped stream;
+    the other watches of the same fan-out pass still get the batch and
+    the commit window still resolves for its writers."""
+
+    async def run() -> None:
+        store = LogicalStore(wal_path=str(tmp_path / "w.wal"))
+        handler = RestHandler(store, default_scheme(), admission=None)
+        bad, good = _PushStream(), _PushStream()
+        bad_task = await _serve_watch(handler, bad)
+        bad_watch = store._watches[-1]
+        good_task = await _serve_watch(handler, good)
+        good_watch = store._watches[-1]
+        puller = store.watch("configmaps")
+        bad.fail_writes = True
+        store.create("configmaps", "t0", _cm("x", "t0"))
+        durable = store.commit_durable()
+        if durable is not None:
+            assert await asyncio.wait_for(durable, 5) == \
+                store.resource_version
+        await asyncio.wait_for(bad_task, 5)
+        assert bad_watch.closed and not bad_watch.evicted
+        assert bad.frames == []
+        assert not good_watch.closed and not good_task.done()
+        assert [f["type"] for f in good.decoded()] == ["ADDED"]
+        assert len(puller.drain()) == 1
+        store.create("configmaps", "t0", _cm("y", "t0"))
+        store._gc_barrier()
+        assert len(good.frames) == 2 and bad.frames == []
+        good_task.cancel()
+        await asyncio.gather(good_task, return_exceptions=True)
+        store.close()
+
+    asyncio.run(run())
+
+
+class _PullOnlyWatch:
+    """The REST client's watch surface (server/rest.py RestWatch): it
+    is fed by a network reader of its own, so it offers iteration,
+    drain/pending/close — and no push half."""
+
+    def __init__(self, inner):
+        self._inner = inner
+        self.last_rv = 0
+
+    def __aiter__(self):
+        return self
+
+    async def __anext__(self):
+        return await self._inner.__anext__()
+
+    def drain(self):
+        return self._inner.drain()
+
+    def pending(self):
+        return self._inner.pending()
+
+    def close(self):
+        self._inner.close()
+
+    @property
+    def closed(self):
+        return self._inner.closed
+
+    @property
+    def evicted(self):
+        return self._inner.evicted
+
+
+@pytest.mark.parametrize("case", ["duck-typed-stream", "pull-only-watch",
+                                  "encode-cache-off"])
+def test_streams_without_the_push_half_keep_the_pull_relay(case, monkeypatch):
+    """The choice is made on what the code can observe, not on a knob:
+    a duck-typed stream without ``write_raw_many``, a watch without
+    ``set_sink`` (the REST client's, on a storage frontend or a router
+    relay) and a store without the encode cache are all served by the
+    pull relay — watch_relay_batches_total rises, push does not."""
+    from kcp_tpu.server.rest import RestWatch
+
+    assert not hasattr(RestWatch, "set_sink")
+
+    async def run() -> None:
+        store = LogicalStore(encode_cache=case != "encode-cache-off")
+        if case == "pull-only-watch":
+            local_watch = store.watch
+            monkeypatch.setattr(
+                store, "watch",
+                lambda *a, **kw: _PullOnlyWatch(local_watch(*a, **kw)))
+        handler = RestHandler(store, default_scheme(), admission=None)
+        stream = _PushStream()
+        if case == "duck-typed-stream":
+            stream = type("_NoWriteHalf", (), {
+                "frames": [],
+                "send_json": _PushStream.send_json,
+                "send_raw_many": _PushStream.send_raw_many,
+                "decoded": _PushStream.decoded})()
+        push0, relay0 = _push_counts()
+        task = await _serve_watch(handler, stream)
+        for i in range(3):
+            store.create("configmaps", "t0", _cm(f"r{i}", "t0"))
+            await asyncio.sleep(0.02)
+        for _ in range(200):
+            if len(stream.frames) == 3:
+                break
+            await asyncio.sleep(0.005)
+        assert [f["type"] for f in stream.decoded()] == ["ADDED"] * 3
+        assert all(w._sink is None for w in store._watches)
+        push1, relay1 = _push_counts()
+        assert push1 == push0 and relay1 >= relay0 + 1
+        task.cancel()
+        await asyncio.gather(task, return_exceptions=True)
+        store.close()
+
+    asyncio.run(run())
