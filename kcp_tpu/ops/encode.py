@@ -30,7 +30,22 @@ from typing import Any, Iterable, Mapping, Sequence
 
 import numpy as np
 
+from ..utils.trace import REGISTRY
 from .hashing import hash_value
+
+# the widest vocabulary any encoder has grown: against a bucket's S this
+# says how near the next new field path is to a BucketOverflow. Touched
+# only where a vocabulary GROWS (a path seen for the first time), never
+# per encoded object
+_VOCAB_MAX = REGISTRY.gauge(
+    "encoder_slot_vocab_max",
+    "slots of the widest slot vocabulary any bucket encoder has grown "
+    "(compare with the bucket's S)")
+
+
+def _note_vocab(n: int) -> None:
+    if n > _VOCAB_MAX.value:
+        _VOCAB_MAX.set(n)
 
 VOLATILE_META = frozenset(
     {"resourceVersion", "generation", "uid", "creationTimestamp", "managedFields"}
@@ -113,6 +128,7 @@ class BucketEncoder:
             slot = len(self.slot_paths)
             self.slots[path] = slot
             self.slot_paths.append(path)
+            _note_vocab(slot + 1)
         return slot
 
     def _native_bucket(self):
@@ -135,6 +151,7 @@ class BucketEncoder:
             for path in nb.slot_paths()[len(self.slot_paths):]:
                 self.slots[path] = len(self.slot_paths)
                 self.slot_paths.append(path)
+            _note_vocab(len(self.slot_paths))
 
     def encode(self, obj: Mapping, out: np.ndarray | None = None) -> np.ndarray:
         """Encode one object into a uint32[capacity] vector."""

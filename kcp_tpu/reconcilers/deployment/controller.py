@@ -175,6 +175,10 @@ class DeploymentSplitter:
             self.controller.enqueue(("root", key))
         else:
             owner = _labels(obj).get(OWNED_BY_LABEL)
+            if not owner:
+                # labelled for a cluster by its tenant, owned by no root:
+                # the plain syncer's object, nothing here to aggregate
+                return
             root_key = (m.get("clusterName", ""), m.get("namespace", ""), owner)
             self._agg_t0.setdefault(root_key, time.monotonic())
             self.controller.enqueue(("leaf", root_key))

@@ -169,6 +169,13 @@ class RestHandler:
             "request_finish_seconds",
             "one write request from its store call's return to its "
             "response: WAL commit window + sync, standby wait, encode")
+        # how wide the objects of this traffic are: body bytes over the
+        # write requests (request_admission_seconds' count) is the mean
+        # width of what tenants write
+        self._body_bytes = REGISTRY.counter(
+            "write_request_body_bytes_total",
+            "request-body bytes of the write requests handled (a delete "
+            "carries none)")
         # RV-keyed list-body cache: the store RV increments on every
         # mutation, so (query shape, rv) fully determines a list
         # response's bytes — informer relists and polling dashboards
@@ -254,6 +261,14 @@ class RestHandler:
             "watch_relay_batches_total",
             "watch event batches written to their stream by the pull "
             "relay (a remote store's watch, a duck-typed stream)")
+        # how much a watcher is sent, either path: frame bytes over
+        # events is the mean width of a delivered event
+        self._stream_bytes = REGISTRY.counter(
+            "watch_stream_bytes_total",
+            "bytes of encoded watch frames handed to HTTP watch streams")
+        self._stream_events = REGISTRY.counter(
+            "watch_stream_events_total",
+            "watch events encoded for and handed to HTTP watch streams")
         # per-server bookmark cadence (KCP_WATCH_BOOKMARK_S): how often
         # an idle stream that asked for bookmarks gets a progress marker
         # at the store RV — what keeps a quiet informer's resume point
@@ -791,6 +806,7 @@ class RestHandler:
         from which :meth:`_acked` closes ``request_finish_seconds`` once
         the caller has encoded the response."""
         t0 = time.monotonic()
+        self._body_bytes.inc(len(req.body))
         # admission inline (reads never touch it): admit_nowait only
         # hands back a coroutine when flow control parks the request,
         # so the uncontended write path stays synchronous
@@ -1513,6 +1529,8 @@ class RestHandler:
                 with obs.annotate("kcp.watch.encode", events=len(batch)):
                     lines = self.store.encode_events(batch)
                 self._enc_seconds.observe(loop.time() - t0)
+                self._stream_bytes.inc(sum(map(len, lines)))
+                self._stream_events.inc(len(lines))
                 return lines
 
             def write_batch(batch) -> None:

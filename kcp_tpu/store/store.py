@@ -785,6 +785,10 @@ class LogicalStore:
             "wal_sync_total",
             "explicit WAL flush/fsync operations (KCP_WAL_SYNC policy); "
             "group commit amortizes these across a whole window")
+        self._wal_bytes = REGISTRY.counter(
+            "wal_appended_bytes_total",
+            "bytes appended to the write-ahead log (JSON lines, or the "
+            "native engine's keys and values), every record of the store")
         self._wal_sync_seconds = REGISTRY.histogram(
             "wal_sync_seconds",
             "time spent in one WAL durable append + flush/fsync call")
@@ -2284,9 +2288,11 @@ class LogicalStore:
                     self._append_engine_batch(recs)
                 elif self._wal is not None and self._wal.fh is not None:
                     t0 = time.perf_counter()
-                    self._wal.fh.write("".join(
+                    lines = "".join(
                         json.dumps(rec, separators=(",", ":")) + "\n"
-                        for rec in recs))
+                        for rec in recs)
+                    self._wal.fh.write(lines)
+                    self._wal_bytes.inc(len(lines))
                     self._wal_fh_sync(t0)
                     self._wal.mutations_since_snapshot += len(recs)
         except BaseException as e:  # noqa: BLE001 — becomes every writer's 5xx
@@ -2343,15 +2349,19 @@ class LogicalStore:
         fsync, per the KCP_WAL_SYNC policy."""
         t0 = time.perf_counter()
         ops = []
+        nbytes = 0
         for rec in recs:
             key = _wal_key(tuple(rec["key"]))
+            nbytes += len(key)
             if rec["op"] == "put":
-                ops.append((key, json.dumps(
-                    rec["obj"], separators=(",", ":")).encode("utf-8"),
-                    rec["rv"]))
+                val = json.dumps(
+                    rec["obj"], separators=(",", ":")).encode("utf-8")
+                nbytes += len(val)
+                ops.append((key, val, rec["rv"]))
             else:
                 ops.append((key, None, rec["rv"]))
         self._engine.append_batch(ops, fsync=self._wal_sync == "fsync")
+        self._wal_bytes.inc(nbytes)
         if self._wal_sync != "off":
             self._wal_sync_total.inc()
             self._wal_sync_seconds.observe(time.perf_counter() - t0)
@@ -2392,13 +2402,13 @@ class LogicalStore:
             key = _wal_key(tuple(rec["key"]))
             t0 = time.perf_counter()
             if rec["op"] == "put":
-                self._engine.put(
-                    key,
-                    json.dumps(rec["obj"], separators=(",", ":")).encode("utf-8"),
-                    rec["rv"],
-                )
+                val = json.dumps(
+                    rec["obj"], separators=(",", ":")).encode("utf-8")
+                self._engine.put(key, val, rec["rv"])
+                self._wal_bytes.inc(len(key) + len(val))
             else:
                 self._engine.delete(key, rec["rv"])
+                self._wal_bytes.inc(len(key))
             if self._wal_sync == "fsync":
                 # per-record durability: the serial A/B reference whose
                 # cost the commit window exists to amortize
@@ -2412,7 +2422,9 @@ class LogicalStore:
         if self._wal is None or self._wal.fh is None:
             return
         t0 = time.perf_counter()
-        self._wal.fh.write(json.dumps(rec, separators=(",", ":")) + "\n")
+        line = json.dumps(rec, separators=(",", ":")) + "\n"
+        self._wal.fh.write(line)
+        self._wal_bytes.inc(len(line))
         self._wal_fh_sync(t0)
         self._wal.mutations_since_snapshot += 1
         if self._wal.mutations_since_snapshot >= self._wal.snapshot_every:

@@ -104,6 +104,11 @@ _TICK_H = REGISTRY.histogram(
     "the wire)")
 _FLEET_TICKS = REGISTRY.counter(
     "fused_fleet_ticks_total", "fleet-wide ragged batch steps dispatched")
+_ENCODED_ROWS = REGISTRY.counter(
+    "fused_encoded_rows_total",
+    "rows (touched keys, both sides of each) re-encoded by the ticks' "
+    "encode phase: fused_encode_seconds' sum over this is the host cost "
+    "of one row")
 
 
 class _Phases:
@@ -1668,6 +1673,7 @@ class FusedCore:
         finally:
             if touched:
                 ph.close()
+                _ENCODED_ROWS.inc(sum(map(len, touched.values())))
             else:  # nothing encoded: the mean stays that of real encodes
                 ph.discard()
 

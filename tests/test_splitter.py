@@ -110,6 +110,41 @@ def test_no_clusters_sets_progressing_false():
     asyncio.run(main())
 
 
+def test_labelled_deployment_without_owner_is_nobodys_leaf():
+    """A tenant's own Deployment labelled for a cluster (the plain
+    syncer's object) has no root: its events enqueue nothing, so the
+    splitter runs no pass for them and keeps no timer."""
+    async def main():
+        store = LogicalStore()
+        mc = MultiClusterClient(store)
+        t = mc.cluster_client("t1")
+        t.create("clusters.cluster.example.dev", new_cluster("east"))
+        splitter = DeploymentSplitter(mc, backend="host")
+        await splitter.start()
+        own = deployment("own", 3)
+        own["metadata"]["labels"] = {"kcp.dev/cluster": "east"}
+        t.create(DEPLOYMENTS, own)
+        got = t.get(DEPLOYMENTS, "own", "default")
+        got["status"] = {"readyReplicas": 3}
+        t.update_status(DEPLOYMENTS, got)
+        key = ("t1", "default", "own")
+        await eventually(lambda: (splitter.informer.cache[key].get("status")
+                                  == {"readyReplicas": 3}))
+        await asyncio.sleep(0.1)  # a queued item would have run by now
+        assert splitter.stats["ticks"] == 0
+        assert not splitter._agg_t0 and not splitter._split_t0
+        # a root beside it still splits, and its leaf still aggregates
+        t.create(DEPLOYMENTS, deployment("web", 4))
+        await eventually(lambda: t.get(DEPLOYMENTS, "web--east", "default"))
+        leaf = t.get(DEPLOYMENTS, "web--east", "default")
+        leaf["status"] = {"readyReplicas": 4, "replicas": 4}
+        t.update_status(DEPLOYMENTS, leaf)
+        await eventually(lambda: t.get(DEPLOYMENTS, "web", "default")
+                         .get("status", {}).get("readyReplicas") == 4)
+        await splitter.stop()
+    asyncio.run(main())
+
+
 def test_tenancy_isolation_between_logical_clusters():
     async def main():
         store = LogicalStore()
