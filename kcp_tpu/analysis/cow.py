@@ -2,13 +2,15 @@
 
 The PR 3 read-path contract (docs/operations.md "CoW contract"): with
 ``KCP_STORE_INDEX=1`` the store shares references between storage,
-``list`` results, ``get_snapshot``, informer caches, watch ``Event``
-payloads, and ``_sync_view_ro`` views. Mutating any of them corrupts the
+``list`` results, ``get_snapshot`` (and the ``*_snapshot`` write verbs'
+results), informer caches, watch ``Event`` payloads, and
+``_sync_view_ro`` views. Mutating any of them corrupts the
 store — silently, with no event and no RV bump — and with encode-once
 serving on, also desynchronizes every cached byte string. This checker
 taints values flowing out of the snapshot-returning APIs and flags
 in-place writes to them; the fix is always the same: start from ``get``
-(a private copy) or ``copy.deepcopy``, then write through ``update``.
+(a private copy), ``tree_copy`` or ``copy.deepcopy``, then write through
+``update``.
 """
 
 from __future__ import annotations
@@ -29,6 +31,14 @@ ARG_MUTATORS = {
     "accept_names": 0,
     "_stamp": 0,
 }
+
+#: calls that hand back a stored snapshot (or a view sharing its nested
+#: values) instead of a copy: the read, and the write verbs for callers
+#: that only look at the result
+SHARING_CALLS = frozenset({
+    "get_snapshot", "create_snapshot", "update_snapshot",
+    "update_status_snapshot", "_sync_view_ro",
+})
 
 #: functions that return a private deep copy of their input
 COPYING_CALLS = SAFE_CALLS | {"transform_for_downstream"}
@@ -65,7 +75,7 @@ class CowScanner(TaintScanner):
         name, chain = _effective_method(call)
         if name in COPYING_CALLS:
             return None
-        if name == "get_snapshot" or name == "_sync_view_ro":
+        if name in SHARING_CALLS:
             return ELEM
         if "informer" in chain:
             if name == "get":

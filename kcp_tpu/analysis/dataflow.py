@@ -38,7 +38,7 @@ MUTATOR_METHODS = frozenset({
 })
 
 #: calls that return a private copy — taint does not flow through them
-SAFE_CALLS = frozenset({"deepcopy", "copy"})
+SAFE_CALLS = frozenset({"deepcopy", "copy", "tree_copy"})
 
 
 class TaintScanner:

@@ -81,6 +81,31 @@ class Client:
             _resource(gvr), self._write_cluster(obj), obj, namespace
         )
 
+    # -- the same, sharing the stored snapshot ---------------------------
+    #
+    # For callers that only READ what comes back (a resourceVersion, a
+    # comparison) or throw it away: the store's snapshot itself instead
+    # of a private copy of it. CoW contract: never mutate the result;
+    # editors start from get(). Only an in-process client can offer
+    # these (a REST client has no snapshot to share), so a caller that
+    # takes either kind asks the client object which it has.
+
+    def get_snapshot(self, gvr: GVR | str, name: str, namespace: str = "") -> dict:
+        return self._store.get_snapshot(_resource(gvr), self.cluster, name, namespace)
+
+    def create_snapshot(self, gvr: GVR | str, obj: dict, namespace: str = "") -> dict:
+        return self._store.create_snapshot(
+            _resource(gvr), self._write_cluster(obj), obj, namespace)
+
+    def update_snapshot(self, gvr: GVR | str, obj: dict, namespace: str = "") -> dict:
+        return self._store.update_snapshot(
+            _resource(gvr), self._write_cluster(obj), obj, namespace)
+
+    def update_status_snapshot(self, gvr: GVR | str, obj: dict, namespace: str = "") -> dict:
+        return self._store.update_snapshot(
+            _resource(gvr), self._write_cluster(obj), obj, namespace,
+            subresource="status")
+
     def delete(self, gvr: GVR | str, name: str, namespace: str = "", cluster: str | None = None) -> None:
         target = cluster or self.cluster
         if target == WILDCARD:

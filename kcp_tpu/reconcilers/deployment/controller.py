@@ -25,7 +25,6 @@ Behavior parity (pkg/reconciler/deployment/deployment.go):
 
 from __future__ import annotations
 
-import copy
 import logging
 import time
 from typing import Sequence
@@ -42,6 +41,7 @@ from ...ops.placement import aggregate_status_jit
 from ...reconciler.controller import BatchController
 from ...utils import errors
 from ...utils.trace import REGISTRY
+from ...utils.treecopy import tree_copy
 
 log = logging.getLogger(__name__)
 
@@ -526,7 +526,7 @@ class DeploymentSplitter:
             desired_replicas = int(counts[j])
             existing = by_name.pop(lname, None)
             if existing is None:
-                leaf = copy.deepcopy(root)
+                leaf = tree_copy(root)
                 m = leaf["metadata"]
                 m["name"] = lname
                 for f in ("resourceVersion", "uid", "creationTimestamp", "generation"):
@@ -606,7 +606,7 @@ class DeploymentSplitter:
         leaf_conds = (leafs[0].get("status") or {}).get("conditions")
         if leaf_conds and status.get("conditions") != leaf_conds:
             # reference "cheat": root conditions := first leaf's
-            status["conditions"] = copy.deepcopy(leaf_conds)
+            status["conditions"] = tree_copy(leaf_conds)
             changed = True
         if changed:
             scoped.update_status(DEPLOYMENTS, fresh, namespace=ns)

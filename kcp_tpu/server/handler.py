@@ -760,11 +760,13 @@ class RestHandler:
         if req.method == "POST" and name is None:
             obj = self._body_object(req)
             target = resolve_write_cluster(cluster, obj, errors.BadRequestError)
+            # the stored snapshot, not a private copy of it: it is only
+            # encoded here (stamped on a shallow copy of its top level)
             created, t_done = await self._write(
                 req, "create", res, target, namespace, obj,
-                self.store.create, res, target, obj, namespace)
+                self.store.create_snapshot, res, target, obj, namespace)
             return self._acked(t_done, self._rv_stamped(
-                Response.of_json(self._stamp(created, info, gv), 201),
+                Response.of_json(self._stamp(dict(created), info, gv), 201),
                 (created.get("metadata") or {}).get("resourceVersion")))
 
         if req.method == "PUT" and name is not None:
@@ -776,10 +778,10 @@ class RestHandler:
             target = resolve_write_cluster(cluster, obj, errors.BadRequestError)
             updated, t_done = await self._write(
                 req, "update", res, target, namespace, obj,
-                (self.store.update_status if subresource == "status"
-                 else self.store.update), res, target, obj, namespace)
+                self.store.update_snapshot, res, target, obj, namespace,
+                subresource)
             return self._acked(t_done, self._rv_stamped(
-                Response.of_json(self._stamp(updated, info, gv)),
+                Response.of_json(self._stamp(dict(updated), info, gv)),
                 (updated.get("metadata") or {}).get("resourceVersion")))
 
         if req.method == "DELETE" and name is not None:

@@ -211,6 +211,12 @@ class RemoteStore:
         return self.update(resource, cluster, obj, namespace,
                            subresource="status")
 
+    # the snapshot-sharing verbs of LogicalStore: a remote store has no
+    # snapshot to share, and what its verbs return is private already
+    get_snapshot = get
+    create_snapshot = create
+    update_snapshot = update
+
     def delete(self, resource: str, cluster: str, name: str,
                namespace: str = "") -> None:
         with self._pool.client(cluster) as client:

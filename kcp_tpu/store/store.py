@@ -34,8 +34,17 @@ Read path (KCP_STORE_INDEX=1, the default):
   (every write replaces the whole dict), so ``list`` results and watch
   ``Event`` objects share references with the store and the deep copy
   is deferred to the mutation boundary — callers treat listed objects
-  and event payloads as frozen and re-``get`` (or deepcopy) before
+  and event payloads as frozen and re-``get`` (or ``tree_copy``) before
   editing, exactly like client-go informer caches;
+- one cheap copy per hand-over: the copy at that boundary is
+  :func:`~kcp_tpu.utils.treecopy.tree_copy` (plain recursion over a
+  JSON tree, no memo), a write copies only what it takes of its
+  argument (a status write: the status), and successive snapshots of
+  one object share the subtrees a write leaves alone (a status write's
+  snapshot shares ``spec`` with the one it replaces, a spec write's
+  shares ``status``) — invisible, because nothing reachable from a
+  snapshot is ever mutated; ``*_snapshot`` verbs hand the stored
+  snapshot to callers that only read the result;
 - watch fan-out is batched: ``_emit`` coalesces events into
   micro-batches and matches each batch against all registered watch
   selectors in one vectorized pass (ops/labelmatch host twins over
@@ -113,7 +122,6 @@ from __future__ import annotations
 
 import asyncio
 import base64
-import copy
 import json
 import logging
 import os
@@ -137,6 +145,7 @@ from ..utils.errors import (
     UnavailableError,
 )
 from ..utils.trace import REGISTRY, SIZE_BUCKETS
+from ..utils.treecopy import tree_copy
 from .selectors import LabelSelector, everything
 
 log = logging.getLogger(__name__)
@@ -981,6 +990,15 @@ class LogicalStore:
             obs.link_obj(obj, sub)
 
     def create(self, resource: str, cluster: str, obj: dict, namespace: str = "") -> dict:
+        return tree_copy(self.create_snapshot(resource, cluster, obj, namespace))
+
+    def create_snapshot(self, resource: str, cluster: str, obj: dict,
+                        namespace: str = "") -> dict:
+        """:meth:`create`, returning the stored snapshot itself instead
+        of a private copy of it — for callers that only read the result
+        (or throw it away). The argument is copied as in :meth:`create`;
+        the result is shared with the store (CoW contract: do not
+        mutate it)."""
         tw, self.write_t0 = self.write_t0 or time.monotonic(), None
         self._race_guard.check()
         self._check_writable()
@@ -988,7 +1006,7 @@ class LogicalStore:
         tctx = obs.write_ctx()
         t0 = time.time() if tctx is not None else 0.0
         _inject("store.put")
-        obj = copy.deepcopy(obj)
+        obj = tree_copy(obj)
         meta = obj.setdefault("metadata", {})
         name = meta.get("name")
         if not name:
@@ -1022,7 +1040,7 @@ class LogicalStore:
         if tctx is not None:
             self._commit_trace(tctx, t0, key, rv, rec, obj)
         self._log_wal(rec)
-        return copy.deepcopy(obj)
+        return obj
 
     def get(self, resource: str, cluster: str, name: str, namespace: str = "") -> dict:
         _inject("store.get")
@@ -1030,7 +1048,7 @@ class LogicalStore:
         obj = self._objects.get(key)
         if obj is None:
             raise NotFoundError(f"{resource} {cluster}/{namespace}/{name} not found")
-        return copy.deepcopy(obj)
+        return tree_copy(obj)
 
     def get_snapshot(self, resource: str, cluster: str, name: str,
                      namespace: str = "") -> dict:
@@ -1053,6 +1071,28 @@ class LogicalStore:
         namespace: str = "",
         subresource: str | None = None,
     ) -> dict:
+        return tree_copy(self.update_snapshot(
+            resource, cluster, obj, namespace, subresource))
+
+    def update_snapshot(
+        self,
+        resource: str,
+        cluster: str,
+        obj: dict,
+        namespace: str = "",
+        subresource: str | None = None,
+    ) -> dict:
+        """:meth:`update`, returning the stored snapshot itself instead
+        of a private copy of it — for callers that only read the result
+        (its resourceVersion, its bytes) or throw it away. Nothing of
+        the argument is aliased into the store; the result is shared
+        with it (CoW contract: do not mutate it).
+
+        Successive snapshots of one object share what a write leaves
+        alone: a status write's snapshot shares ``spec`` (and every
+        other top-level subtree but ``metadata`` and ``status``) with
+        the one it replaces, a spec write's shares ``status``. Safe
+        because no snapshot is ever mutated in place."""
         tw, self.write_t0 = self.write_t0 or time.monotonic(), None
         self._race_guard.check()
         self._check_writable()
@@ -1060,7 +1100,6 @@ class LogicalStore:
         tctx = obs.write_ctx()
         t0 = time.time() if tctx is not None else 0.0
         _inject("store.put")
-        obj = copy.deepcopy(obj)
         meta = self._meta(obj)
         name = meta.get("name")
         if not name:
@@ -1078,17 +1117,19 @@ class LogicalStore:
                 f"{supplied_rv} (current {ex_meta['resourceVersion']})"
             )
         if subresource == "status":
-            new_obj = copy.deepcopy(existing)
-            new_obj["status"] = obj.get("status")
+            # only the status changes hands: the rest of the snapshot is
+            # the old one's, shared, under a fresh metadata dict
+            new_obj = dict(existing)
+            new_obj["status"] = tree_copy(obj.get("status"))
+            new_meta = new_obj["metadata"] = dict(ex_meta)
         else:
-            new_obj = obj
+            new_obj = tree_copy(obj)
             # status is only writable through the status subresource
             if "status" in existing:
-                new_obj["status"] = copy.deepcopy(existing["status"])
+                new_obj["status"] = existing["status"]
             elif "status" in new_obj:
                 del new_obj["status"]
-        new_meta = new_obj.setdefault("metadata", {})
-        if subresource != "status":
+            new_meta = new_obj.setdefault("metadata", {})
             # metadata edits (labels/annotations/finalizers) ride spec updates
             preserved = {
                 "uid": ex_meta.get("uid"),
@@ -1100,9 +1141,6 @@ class LogicalStore:
             new_meta.update(preserved)
             if ex_meta.get("deletionTimestamp"):
                 new_meta["deletionTimestamp"] = ex_meta["deletionTimestamp"]
-        else:
-            new_obj["metadata"] = copy.deepcopy(ex_meta)
-            new_meta = new_obj["metadata"]
 
         spec_changed = subresource != "status" and self._non_status_changed(existing, new_obj)
         new_meta["generation"] = ex_meta.get("generation", 1) + (1 if spec_changed else 0)
@@ -1124,7 +1162,7 @@ class LogicalStore:
             if tctx is not None:
                 self._commit_trace(tctx, t0, key, rv, rec, new_obj)
             self._log_wal(rec)
-        return copy.deepcopy(new_obj)
+        return new_obj
 
     def update_status(self, resource: str, cluster: str, obj: dict, namespace: str = "") -> dict:
         return self.update(resource, cluster, obj, namespace, subresource="status")
@@ -1144,10 +1182,11 @@ class LogicalStore:
         meta = existing["metadata"]
         if meta.get("finalizers"):
             if not meta.get("deletionTimestamp"):
-                obj = copy.deepcopy(existing)
-                obj["metadata"]["deletionTimestamp"] = self._now()
+                # a fresh metadata dict; the rest is the old snapshot's
+                obj = dict(existing)
                 rv = self._next_rv()
-                obj["metadata"]["resourceVersion"] = str(rv)
+                obj["metadata"] = {**meta, "deletionTimestamp": self._now(),
+                                   "resourceVersion": str(rv)}
                 obj = self._put_obj(key, obj)
                 self._emit(MODIFIED, key, obj, rv, old=existing, tc=tctx, tw=tw)
                 rec = {"op": "put", "key": list(key), "obj": obj, "rv": rv}
@@ -1176,9 +1215,9 @@ class LogicalStore:
 
         Indexed mode walks only the (resource, cluster, namespace)
         candidate buckets and returns shared references (CoW contract:
-        callers must not mutate items — re-``get`` or deepcopy before
-        editing). Legacy mode is the pre-index O(total-objects) scan
-        with a deepcopy per match.
+        callers must not mutate items — re-``get`` or ``tree_copy``
+        before editing). Legacy mode is the pre-index O(total-objects)
+        scan with a copy per match.
         """
         _inject("store.list")
         selector = selector or everything()
@@ -1194,7 +1233,7 @@ class LogicalStore:
                 labels = (obj.get("metadata") or {}).get("labels") or {}
                 if not selector.matches(labels):
                     continue
-                out.append(copy.deepcopy(obj))
+                out.append(tree_copy(obj))
             out.sort(key=lambda o: (o["metadata"].get("clusterName", ""),
                                     o["metadata"].get("namespace", ""),
                                     o["metadata"]["name"]))
@@ -1841,8 +1880,8 @@ class LogicalStore:
             oob["_tc"] = tc
         if not self._indexed:
             ev = Event(
-                etype, key[0], key[1], key[2], key[3], copy.deepcopy(obj), rv,
-                copy.deepcopy(old) if old is not None else None,
+                etype, key[0], key[1], key[2], key[3], tree_copy(obj), rv,
+                tree_copy(old) if old is not None else None,
             )
             ev.__dict__.update(oob)
             self._history.append(ev)
@@ -2599,7 +2638,7 @@ class LogicalStore:
             "migrated WAL records applied on a cluster's new owning "
             "shard").inc()
         if op == "put":
-            obj = copy.deepcopy(rec["obj"])
+            obj = tree_copy(rec["obj"])
             old = self._objects.get(key)
             rv = self._next_rv()
             obj.setdefault("metadata", {})["resourceVersion"] = str(rv)

@@ -38,7 +38,6 @@ Decision application parity with the reference:
 from __future__ import annotations
 
 import asyncio
-import copy
 import logging
 import os
 import time
@@ -59,6 +58,7 @@ from ..reconciler.controller import BatchController
 from ..store.selectors import LabelSelector, parse_selector
 from ..utils import errors
 from ..utils.trace import REGISTRY
+from ..utils.treecopy import tree_copy
 
 log = logging.getLogger(__name__)
 
@@ -121,17 +121,21 @@ DEFAULT_RESYNC_PERIOD = 600.0  # the collision/missed-event safety net
 # metadata fields that must not cross the cluster boundary
 # (reference: specsyncer.go:97-108 strips UID + ResourceVersion and drops
 # owner references pointing at the kcp-side owner)
-_STRIP_META = ("uid", "resourceVersion", "creationTimestamp", "generation",
-               "managedFields", "clusterName", "ownerReferences", "deletionTimestamp")
+_STRIP_META = frozenset({
+    "uid", "resourceVersion", "creationTimestamp", "generation",
+    "managedFields", "clusterName", "ownerReferences", "deletionTimestamp"})
 
 
 def transform_for_downstream(obj: dict) -> dict:
-    out = copy.deepcopy(obj)
-    out.pop("status", None)
-    meta = out.get("metadata") or {}
-    for f in _STRIP_META:
-        meta.pop(f, None)
-    return out
+    """A private copy of ``obj`` as its physical cluster gets it: no
+    status, no side-local metadata. What is dropped is dropped before
+    the copy, so it is never copied."""
+    out = {k: v for k, v in obj.items() if k != "status"}
+    meta = out.get("metadata")
+    if meta:
+        out["metadata"] = {k: v for k, v in meta.items()
+                           if k not in _STRIP_META}
+    return tree_copy(out)
 
 
 def _sync_view(obj: dict) -> dict:
@@ -142,16 +146,16 @@ def _sync_view(obj: dict) -> dict:
     """
     view = transform_for_downstream(obj)
     if "status" in obj:
-        view["status"] = copy.deepcopy(obj["status"])
+        view["status"] = tree_copy(obj["status"])
     return view
 
 
 def _sync_view_ro(obj: dict) -> dict:
-    """:func:`_sync_view` without the deepcopy tax, for read-only
+    """:func:`_sync_view` without the copy, for read-only
     consumers (the encoders hash it, `_spec_differs` compares it). The
     nested values stay shared with the informer caches — which the CoW
     store shares with storage — so callers must not mutate the result;
-    write paths keep using the deep-copying :func:`_sync_view` /
+    write paths keep using the copying :func:`_sync_view` /
     :func:`transform_for_downstream`."""
     out = {k: v for k, v in obj.items() if k != "status"}
     meta = out.get("metadata") or {}
@@ -159,6 +163,15 @@ def _sync_view_ro(obj: dict) -> dict:
     if "status" in obj:
         out["status"] = obj["status"]
     return out
+
+
+def _sharing(client, verb: str):
+    """``client``'s ``verb`` for a caller that only READS what comes
+    back. An in-process client offers ``<verb>_snapshot``: the same
+    call, returning the stored snapshot itself instead of a private copy
+    of it (never mutated here). A REST client has no snapshot to share
+    and gives its plain verb, whose result is private anyway."""
+    return getattr(client, verb + "_snapshot", None) or getattr(client, verb)
 
 
 class BatchSyncEngine:
@@ -197,6 +210,14 @@ class BatchSyncEngine:
     ):
         self.upstream = upstream
         self.downstream = downstream
+        # the applier reads a current object for its resourceVersion (or
+        # to compare) and a write's result for its resourceVersion, if
+        # at all: none of that needs a private copy
+        self._up_get = _sharing(upstream, "get")
+        self._up_update_status = _sharing(upstream, "update_status")
+        self._down_get = _sharing(downstream, "get")
+        self._down_create = _sharing(downstream, "create")
+        self._down_update = _sharing(downstream, "update")
         self.gvr = gvr
         self.cluster_id = cluster_id
         self.backend = backend
@@ -742,22 +763,22 @@ class BatchSyncEngine:
             self._ensure_namespace(ns)
             desired = transform_for_downstream(up_obj)
             try:
-                self.downstream.create(self.gvr, desired, namespace=ns)
+                self._down_create(self.gvr, desired, namespace=ns)
                 applied = True
             except errors.AlreadyExistsError:
                 # informer lag: fall through to update semantics
-                current = self.downstream.get(self.gvr, name, ns)
+                current = self._down_get(self.gvr, name, ns)
                 if self._spec_differs(desired, current):
                     merged = self._merged_downstream(desired, current)
-                    self.downstream.update(self.gvr, merged, namespace=ns)
+                    self._down_update(self.gvr, merged, namespace=ns)
                     applied = True
         elif decision == DECISION_UPDATE and up_obj is not None and down_obj is not None:
             desired = transform_for_downstream(up_obj)
             # host verification: never trust a hash alone before writing
             if self._spec_differs(desired, down_obj):
-                current = self.downstream.get(self.gvr, name, ns)
+                current = self._down_get(self.gvr, name, ns)
                 merged = self._merged_downstream(desired, current)
-                self.downstream.update(self.gvr, merged, namespace=ns)
+                self._down_update(self.gvr, merged, namespace=ns)
                 applied = True
         elif decision == DECISION_DELETE and down_obj is not None and up_obj is None:
             # the up_obj re-check re-derives the action at apply time: a
@@ -781,18 +802,22 @@ class BatchSyncEngine:
         if upsync and up_obj is not None and down_obj is not None:
             new_status = down_obj.get("status")
             if new_status != up_obj.get("status"):
-                fresh = self.upstream.get(self.gvr, name, ns)
-                fresh["status"] = copy.deepcopy(new_status)
+                # the upstream object as it stands (its resourceVersion
+                # guards the write) under the downstream status: both
+                # only read here, and a status write takes nothing of
+                # its argument but a copy of the status
+                fresh = {**self._up_get(self.gvr, name, ns),
+                         "status": new_status}
                 if ent is not None and ent.ctx is not None:
                     # upstream status write runs under the row's trace
                     # context: an in-process upstream records its
                     # store.commit as a child; a REST upstream carries
                     # the traceparent to the owning shard
                     with obs.use(ent.ctx):
-                        written = self.upstream.update_status(
+                        written = self._up_update_status(
                             self.gvr, fresh, namespace=ns)
                 else:
-                    written = self.upstream.update_status(
+                    written = self._up_update_status(
                         self.gvr, fresh, namespace=ns)
                 applied = True
                 if ent is not None and _PATCHED <= ent.state <= _DOWNSTAGED:
@@ -818,7 +843,7 @@ class BatchSyncEngine:
         if not ns:
             return
         try:
-            self.downstream.get(self.namespace_gvr, ns)
+            self._down_get(self.namespace_gvr, ns)
         except errors.NotFoundError:
             try:
                 self.downstream.create(
@@ -839,11 +864,13 @@ class BatchSyncEngine:
 
     @staticmethod
     def _merged_downstream(desired: dict, current: dict) -> dict:
-        merged = copy.deepcopy(desired)
-        merged.setdefault("metadata", {})["resourceVersion"] = current["metadata"][
+        """``desired`` at ``current``'s resourceVersion. ``desired`` is
+        the private copy :func:`transform_for_downstream` just made, so
+        it is stamped in place."""
+        desired.setdefault("metadata", {})["resourceVersion"] = current["metadata"][
             "resourceVersion"
         ]
-        return merged
+        return desired
 
     # ---------------------------------------------------------- lifecycle
 
