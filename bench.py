@@ -429,242 +429,6 @@ async def _measure(best: dict, pipeline: str | None = None,
     return result
 
 
-class _FleetOwner:
-    """Open-loop SectionOwner for the fleet A/B: fixed mirror arrays,
-    every patch recorded (sha256-compared across modes), no feedback —
-    so per-bucket and ragged runs see identical staging schedules and
-    the patch-stream byte-equality check is exact."""
-
-    def __init__(self, core, b: int, s: int):
-        self.core = core
-        self.B, self.S = b, s
-        mask = np.zeros(s, bool)
-        mask[-max(1, s // 8):] = True
-        self._mask = mask
-        self.up_vals = np.zeros((b, s), np.uint32)
-        self.down_vals = np.zeros((b, s), np.uint32)
-        self.patch_rows = 0
-        self._digest = None  # lazily-created hashlib stream digest
-        self.section = core.register(self, s)
-        self.section.bucket.patch_capacity = 8192
-
-    def fused_status_mask(self) -> np.ndarray:
-        return self._mask
-
-    def fused_encode(self, key: int):
-        return self.up_vals[key], True, self.down_vals[key], True
-
-    def fused_encode_many(self, keys):
-        idx = np.fromiter(keys, np.int64, len(keys))
-        ones = np.ones(idx.size, bool)
-        return self.up_vals[idx], ones, self.down_vals[idx], ones
-
-    def fused_overflow(self) -> None:  # pragma: no cover - fixed vocab
-        raise AssertionError("fleet bench vocabulary never grows")
-
-    def fused_apply(self, patches) -> None:
-        import hashlib
-
-        if self._digest is None:
-            self._digest = hashlib.sha256()
-        self.patch_rows += len(patches)
-        self._digest.update(np.asarray(
-            [(int(k), int(c), int(u)) for k, c, u in patches],
-            np.int64).tobytes())
-
-    def digest(self) -> str:
-        return self._digest.hexdigest() if self._digest else "empty"
-
-
-async def _fleet_mode_run(fleet: bool, shape, stragglers: int, steps: int,
-                          warmup: int, churn_frac: float,
-                          seed: int = 7) -> dict:
-    """One lockstep run (per-bucket or ragged): every step churns every
-    bucket, then waits for every bucket to tick once — so both modes
-    decide the identical row set per tick and the streams compare."""
-    from kcp_tpu.syncer.core import FusedCore
-
-    core = FusedCore(batch_window=0.0005, fleet=fleet,
-                     use_pallas=True if "--pallas" in sys.argv else None)
-    owners = [_FleetOwner(core, b, s) for b, s in shape]
-    srng = np.random.default_rng(seed + 1)
-    straggler_owners = [
-        _FleetOwner(core, int(srng.integers(1, 5)), 8)
-        for _ in range(stragglers)]
-    all_owners = owners + straggler_owners
-    buckets = list({id(o.section.bucket): o.section.bucket
-                    for o in all_owners}.values())
-    total_rows = sum(o.B for o in all_owners)
-    await core.start()
-
-    rng = np.random.default_rng(seed)
-    step_times: list[float] = []
-    t_start = None
-    decided = 0
-    last_progress = time.perf_counter()
-    for step in range(warmup + steps):
-        if step == warmup:
-            t_start = time.perf_counter()
-            for o in all_owners:
-                o.patch_rows = 0
-        before = {id(b): b.stats["ticks"] for b in buckets}
-        t0 = time.perf_counter()
-        for o in all_owners:
-            pool = min(o.B, 4096)
-            n = max(1, int(pool * churn_frac))
-            touched = (rng.choice(pool, size=n, replace=False)
-                       if n < pool else np.arange(pool))
-            o.up_vals[touched] = rng.integers(
-                1, 2**32, (touched.size, o.S), dtype=np.uint32)
-            core.enqueue_many(o.section, False, touched.tolist())
-        while not all(b.stats["ticks"] > before[id(b)] for b in buckets):
-            await asyncio.sleep(0.0002)
-            if time.perf_counter() - last_progress > STALL_S:
-                raise RuntimeError(f"fleet bench stalled at step {step}")
-        last_progress = time.perf_counter()
-        if step >= warmup:
-            step_times.append(time.perf_counter() - t0)
-            decided += total_rows  # every bucket decided all its rows
-    wall = time.perf_counter() - t_start
-    dispatches = (core._fleet.stats["ticks"] if fleet
-                  else sum(b.stats["ticks"] for b in buckets))
-    # dispatches since measurement start: subtract warmup's share
-    warm_disp = warmup * (1 if fleet else len(buckets))
-    dispatches -= warm_disp
-    await core.stop()
-    lat = np.asarray(step_times) * 1e3
-    return {
-        "rows": total_rows,
-        "buckets": len(buckets),
-        "sections": len(all_owners),
-        "rows_per_sec": decided / wall,
-        "dispatches": int(dispatches),
-        "rows_per_dispatch": decided / max(dispatches, 1),
-        "tick_ms_p50": float(np.percentile(lat, 50)),
-        "tick_ms_p99": float(np.percentile(lat, 99)),
-        "patch_rows": sum(o.patch_rows for o in all_owners),
-        "stream_digests": [o.digest() for o in all_owners],
-    }
-
-
-async def _fleet_quarantine_drill() -> dict:
-    """Green-path drill for the CI gate: a poison row in a 2-bucket
-    fleet must quarantine ONLY the poison (segment-scoped bisection)
-    while every co-tenant's patch still lands."""
-    from kcp_tpu import faults
-    from kcp_tpu.syncer.core import FusedCore
-
-    faults.install(faults.FaultInjector("device.step:poison_row=3", seed=0))
-    try:
-        core = FusedCore(batch_window=0.0005, fleet=True)
-        streams: dict[int, set] = {}
-
-        class DrillOwner(_FleetOwner):
-            def fused_apply(self, patches):
-                streams.setdefault(id(self), set()).update(
-                    int(k) for k, _c, _u in patches)
-
-        owners = [DrillOwner(core, 32, w) for w in (8, 16)]
-        await core.start()
-        keys = list(range(20))
-        for o in owners:
-            o.up_vals[keys, 0] = 7
-            core.enqueue_many(o.section, False, keys)
-        deadline = time.perf_counter() + 60
-        want = set(keys) - {3}
-        while time.perf_counter() < deadline:
-            if (core._fleet.stats["quarantined"] >= 2
-                    and all(streams.get(id(o), set()) >= want
-                            for o in owners)):
-                break
-            await asyncio.sleep(0.005)
-        quarantined = core._fleet.stats["quarantined"]
-        co_ok = all(streams.get(id(o), set()) >= want for o in owners)
-        only_poison = all(3 not in streams.get(id(o), set()) for o in owners)
-        faults.clear()
-        await core.stop()
-        return {"quarantined": int(quarantined), "co_tenants_ok": bool(co_ok),
-                "only_poison": bool(only_poison),
-                "ok": bool(quarantined >= 2 and co_ok and only_poison)}
-    finally:
-        faults.clear()
-
-
-async def _measure_fleet(best: dict) -> dict:
-    """``--fleet``: per-bucket vs ragged fleet dispatch A/B at 10k
-    clusters x mixed bucket sizes (a 64-slot main fleet, 32/16-slot mid
-    and small buckets, plus many 1-4-row straggler sections in an
-    8-slot bucket). Headline value = device-utilization gain (rows
-    decided per device dispatch, ragged / per-bucket: the dispatch
-    amortization ragged batching exists for); combined reconcile
-    throughput and tick latency ride along, and the per-owner patch
-    streams must hash identically across modes."""
-    rows = int(os.environ.get("KCP_BENCH_FLEET_ROWS", "131072"))
-    stragglers = int(os.environ.get("KCP_BENCH_FLEET_STRAGGLERS", "24"))
-    steps = int(os.environ.get("KCP_BENCH_FLEET_STEPS", "40"))
-    warmup = int(os.environ.get("KCP_BENCH_FLEET_WARMUP", "8"))
-    churn_frac = float(os.environ.get("KCP_BENCH_FLEET_CHURN_FRAC", "0.1"))
-    shape = [(rows, 64), (max(rows // 8, 64), 32), (max(rows // 64, 16), 16)]
-    tenants = sum(b for b, _s in shape) // 13
-
-    results: dict[str, dict] = {}
-    for mode, fleet in (("per_bucket", False), ("ragged", True)):
-        print(f"--- fleet dispatch mode: {mode} ---", file=sys.stderr)
-        r = await _fleet_mode_run(fleet, shape, stragglers, steps, warmup,
-                                  churn_frac)
-        results[mode] = r
-        print(f"{mode}: {r['rows_per_sec'] / 1e6:.2f}M rows/s | "
-              f"{r['dispatches']} dispatches | "
-              f"{r['rows_per_dispatch'] / 1e3:.0f}k rows/dispatch | "
-              f"tick p50 {r['tick_ms_p50']:.1f} ms p99 "
-              f"{r['tick_ms_p99']:.1f} ms", file=sys.stderr)
-        best["result"] = {
-            "metric": "fleet_device_utilization", "unit": "x", "value": 0,
-            "stage": f"fleet-{mode}", "provisional": True,
-            "device": dict(DEVICE),
-            "fleet_bench": {mode: {k: v for k, v in r.items()
-                                   if k != "stream_digests"}},
-        }
-        emit(best["result"])
-
-    streams_equal = (results["per_bucket"]["stream_digests"]
-                     == results["ragged"]["stream_digests"])
-    util_gain = (results["ragged"]["rows_per_dispatch"]
-                 / max(results["per_bucket"]["rows_per_dispatch"], 1))
-    speedup = (results["ragged"]["rows_per_sec"]
-               / max(results["per_bucket"]["rows_per_sec"], 1e-9))
-    drill = await _fleet_quarantine_drill()
-    headline = {
-        "metric": "fleet_device_utilization",
-        "value": round(util_gain, 2),
-        "unit": "x",
-        "stage": "fleet-ab",
-        "device": dict(DEVICE),
-        "tenants": tenants,
-        "fleet_bench": {
-            "rows": results["ragged"]["rows"],
-            "buckets": results["ragged"]["buckets"],
-            "sections": results["ragged"]["sections"],
-            "stragglers": stragglers,
-            "streams_equal": streams_equal,
-            "combined_speedup": round(speedup, 3),
-            "combined_rows_per_sec": {
-                m: round(r["rows_per_sec"]) for m, r in results.items()},
-            "rows_per_dispatch": {
-                m: round(r["rows_per_dispatch"]) for m, r in results.items()},
-            "dispatches": {m: r["dispatches"] for m, r in results.items()},
-            "tick_ms_p50": {
-                m: round(r["tick_ms_p50"], 2) for m, r in results.items()},
-            "tick_ms_p99": {
-                m: round(r["tick_ms_p99"], 2) for m, r in results.items()},
-            "quarantine_drill": drill,
-        },
-    }
-    best["result"] = headline
-    emit(headline)
-    return headline
-
-
 def main() -> int:
     best: dict = {}
     print("initializing device...", file=sys.stderr, flush=True)
@@ -675,12 +439,6 @@ def main() -> int:
 
     enable_compilation_cache()
     rc = 0 if _find_device() else RC_NO_CHIP
-
-    if "--fleet" in sys.argv:
-        # per-bucket vs ragged fleet dispatch A/B
-        asyncio.run(_measure_fleet(best))
-        sys.stdout.flush()
-        os._exit(rc)
 
     ab = pipeline_arg(sys.argv)
     if ab is None:

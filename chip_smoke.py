@@ -608,7 +608,7 @@ def phase_fused_core(b: int = 131072, s: int = 64, churn: int = 768,
 
     async def loop() -> dict:
         core = FusedCore(batch_window=0.0005)
-        check(core.fleet_mode and core.pipeline == "double",
+        check(core.pipeline == "double",
               "not the serving defaults")
         owner = _BenchOwner(core, b, s, seed=seed + 7)
         bucket = owner.bucket
@@ -804,7 +804,7 @@ def phase_mesh(n_devices: int = 4, b: int = 131072, s: int = 64,
         owner = Owner(core, b, s, seed=seed + 7)
         bucket = owner.bucket
         await core.start()
-        core.kick(bucket)  # the first full upload, before any churn
+        core.kick()  # the first full upload, before any churn
         info: dict = {}
         t0 = time.perf_counter()
         for _ in range(steps):

@@ -285,8 +285,7 @@ class DeploymentSplitter:
             # SERVED path: roots ride the FusedCore's placement lanes —
             # the same fused step that serves the sync sections computes
             # the split, and dirty rows come back via placement_apply.
-            # Under fleet dispatch (KCP_FLEET_BATCH, the default) the
-            # kick wakes the whole-fleet ragged batch: placement rows
+            # The kick wakes the whole-fleet ragged batch: placement rows
             # from every bucket concatenate into ONE device program's
             # placement lanes, and the FleetBatch scatters the dirty
             # roots back to this bucket's placement_apply on collect —
@@ -322,7 +321,7 @@ class DeploymentSplitter:
                 self._staged_n[key] = len(clusters)
                 kicked = True
             if kicked:
-                self.core.kick(self._pbucket)
+                self.core.kick()
         elif plan_rows:
             reps = np.array(
                 [r[1].get("spec", {}).get("replicas", 0) or 0 for r in plan_rows],

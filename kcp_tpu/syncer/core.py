@@ -5,19 +5,22 @@ The reference runs one goroutine pair per (cluster, GVR)
 small device program per (cluster, GVR). This module closes the gap
 between the benched program and the served one: every sync engine in the
 process registers a row *section* inside a shared schema bucket, and each
-reconcile tick runs ONE fused ``reconcile_step_packed`` per bucket —
-resident donated state, packed one-array-each-way wire format, pipelined
-collection — exactly the artifact ``bench.py`` measures.
+reconcile tick runs ONE fused ``reconcile_step_fleet`` over every
+bucket's rows — resident donated state, packed one-array-each-way wire
+format, pipelined collection.
 
-Topology:
+Topology (a bucket stages on the host, the fleet batch owns the device):
 
   FusedCore ── one per asyncio loop (the process's serving loop)
     ├── BatchController      one tick loop draining all engines' events
-    └── FusedBucket(S)       one per slot capacity (the schema bucket)
-          ├── ReconcileState device-resident [B, S] mirrors + per-row
-          │                  status masks (engines have different slot
-          │                  vocabularies, so masks are [B, S])
-          └── Section        one per engine: a set of rows + callbacks
+    ├── FusedBucket(S)       one per slot capacity (the schema bucket):
+    │     │                  host [B, S] mirrors + per-row status masks
+    │     │                  (engines have different slot vocabularies,
+    │     │                  so masks are [B, S]), staged wire entries
+    │     └── Section        one per engine: a set of rows + callbacks
+    └── FleetBatch           the one device owner: every bucket's rows
+                             in one resident ReconcileState, one jitted
+                             step, the wire buffers, probe + bisection
 
 Tick pipeline — three explicit stages with a PIPELINE_DEPTH-deep
 in-flight window (pipeline="double", the default; "serial" runs the
@@ -25,9 +28,10 @@ stages back-to-back as the A/B reference):
 
   drain/pack  — drain events (the NEXT batch drains concurrently with
                 this tick: BatchController overlap_drain), engines
-                encode touched keys, bucket stages rows into one of two
-                rotating pre-allocated wire buffers (WireBuffers — tick
-                N's device_put never races tick N+1's packing)
+                encode touched keys, buckets stage rows, the fleet packs
+                them into one of its rotating pre-allocated wire buffers
+                (WireBuffers — tick N's device_put never races tick
+                N+1's packing)
   dispatch    — device_put + fused step (donated resident state) +
                 wire.copy_to_host_async(); the host never blocks here
   fetch/apply — wires beyond the in-flight window (2 ticks old, or any
@@ -41,7 +45,7 @@ rows. Because the loop is level-triggered (every tick re-decides every
 row), overflow loses nothing — the core doubles capacity (one recompile)
 and re-ticks.
 
-Mesh serving: pass ``mesh=`` to shard every bucket's state over a
+Mesh serving: pass ``mesh=`` to shard the fleet state over a
 (tenants, slots) device mesh — same layout as ``parallel/mesh.py`` and
 ``dryrun_multichip``. Stats reductions lower to cross-device collectives;
 the packed wire batch is replicated (it is O(events), not O(fleet)).
@@ -67,7 +71,6 @@ from ..models.reconcile_model import (
     ReconcileState,
     WireBuffers,
     reconcile_step_fleet,
-    reconcile_step_packed,
     unpack_patches,
     unpack_placement,
     unpack_seg_counts,
@@ -150,7 +153,7 @@ class _Phases:
 MIN_ROWS = 64
 MIN_EVENTS = 64
 MIN_PATCH_CAPACITY = 256
-# pipelined tick window: in-flight steps per bucket before a blocking
+# pipelined tick window: in-flight steps before a blocking
 # collect. Depth 2 is the double-buffered pipeline — while the device
 # executes tick N, the host packs tick N+1 and applies tick N-1.
 # WireBuffers rotates one staging slot more than the window, so a slot
@@ -168,6 +171,10 @@ IDLE_FLUSH_S = 0.003  # collect leftovers when no new tick arrives
 QUARANTINE_BASE_BACKOFF = 0.05
 QUARANTINE_MAX_BACKOFF = 5.0
 BISECT_MAX_PROBES = 64
+# a queue item with no section: "tick again" (placement staged outside the
+# section path, a failed step's retry, a patch overflow). One constant, so
+# the work queue's dedup folds any number of requests into one tick.
+_RETICK = ("__retick__", False, None, None)
 
 
 def _group_test_poison(probe: Callable[[Sequence[int]], bool],
@@ -258,12 +265,11 @@ class Section:
             # forever (fuzz-found) — ship it as a wire entry. A stale
             # bucket needs no stamp: the pending full upload carries the
             # host mask arrays wholesale (and bulk row preallocation
-            # before the first tick would otherwise stage one per row)
-            # in fleet mode EVERY new row stamps (even an all-False mask):
-            # the stamp entry is also how the device learns the row's
-            # segment id for the per-segment counters
-            if ((self._mask.any() or self.bucket.always_stamp)
-                    and not self.bucket._stale):
+            # before the first tick would otherwise stage one per row).
+            # EVERY new row stamps (even an all-False mask): the stamp
+            # entry is also how the device learns the row's segment id
+            # for the per-segment counters
+            if not self.bucket._stale:
                 self.bucket.stage_mask(row, self.bucket.status_mask[row])
         return row
 
@@ -288,24 +294,15 @@ class Section:
 
 
 class FusedBucket:
-    """One schema bucket: host staging + device-resident fused state."""
+    """One schema bucket: host staging only. The [B, S] mirrors, row
+    allocation, sections, placement rows and the staged wire-layout
+    entries live here; the FleetBatch packs them and owns the device."""
 
-    def __init__(self, slots: int, mesh=None, use_pallas: bool = False,
-                 always_stamp: bool = False):
+    def __init__(self, slots: int, mesh=None):
         self.S = slots
         self.B = 0
         self.mesh = mesh
-        # the fused Pallas decision+fanout pass (ops/pallas_kernels.py);
-        # on a mesh it runs per device via shard_map (reconcile_model
-        # gates on local-row divisibility and falls back to XLA lanes)
-        self.use_pallas = use_pallas
-        # fleet mode: every newly-allocated row stages a mask stamp (the
-        # wire entry that also carries its segment id), mask or no mask
-        self.always_stamp = always_stamp
-        # converged-row ack compression kill switch, resolved once (the
-        # opt-out cannot change mid-process; staging is the hot path)
-        self.use_acks = os.environ.get("KCP_NO_ACKS") != "1"
-        # sharded state must device_put cleanly: row counts are padded to
+        # sharded state must split cleanly: row counts are padded to
         # a multiple of the row-axis product (see _grow), and the slots
         # axis must divide the (power-of-two) slot capacity up front
         self._row_factor = 1
@@ -342,7 +339,6 @@ class FusedBucket:
         self._pl_free: list[int] = []
         self._pl_next = 0
         self._pl_staged = False
-        self._state: ReconcileState | None = None
         self._stale = True
         self.patch_capacity = MIN_PATCH_CAPACITY
         # staged events for the next tick, accumulated directly in the
@@ -363,33 +359,12 @@ class FusedBucket:
         # mask stamps for rows allocated since the last full upload
         # (row -> bool[S]); ride the packed wire as MASK_STAMP entries
         self._staged_masks: dict[int, np.ndarray] = {}
-        # acks-lane wire capacity: sticky high-water doubling, so the
-        # (packed, acks) shape pair stays stable after warmup — per-tick
-        # pow2 padding here would multiply compiled-shape variants. The
-        # floor is generous (4 KB of -1s) because a mid-serving growth
-        # costs a recompile — seconds of p99 — while padding costs ~µs
+        # acks-lane floor this bucket asks of the fleet wire (a caller
+        # that knows its burst size pre-warms it): the fleet's sticky
+        # high-water never drops below it, so the (packed, acks) shape
+        # pair stays stable after warmup — a mid-serving growth costs a
+        # recompile, seconds of p99, while padding costs ~µs
         self.ack_capacity = 1024
-        # double-buffered packed-wire staging (models/reconcile_model.py):
-        # tick N+1 packs into the other buffer while tick N's device_put
-        # may still be reading this one — the allocation-free hot path
-        # that makes the 2-deep pipeline window safe
-        self._wire_bufs = WireBuffers(PIPELINE_DEPTH + 1)
-        # the resident state is donated on every backend: steady state
-        # lives in device memory and only deltas cross the link, and the
-        # tests' CPU backend runs the same donated program as the chip
-        self._step = jax.jit(
-            reconcile_step_packed,
-            donate_argnums=(0,),
-            static_argnames=("patch_capacity", "use_pallas", "mesh"),
-        )
-        # degraded-mode bookkeeping (poison-row quarantine): the rows the
-        # last submission covered (the bisection's suspect set), the
-        # consecutive step-failure count, and the non-donating probe step
-        # used by the bisection (donation would consume the resident
-        # state probes must leave intact)
-        self._last_rows: list[int] = []
-        self._step_failures = 0
-        self._probe_step = None
         self._dropped_logged: set[int] = set()
         self.stats = {"ticks": 0, "full_uploads": 0, "overflows": 0,
                       "acked": 0, "step_failures": 0, "quarantined": 0}
@@ -423,7 +398,7 @@ class FusedBucket:
         new_b = pad_pow2(max(needed, MIN_ROWS))
         if new_b % self._row_factor:
             # non-power-of-two row sharding (e.g. a 5-device tenants
-            # axis): round up so every row dimension device_puts cleanly
+            # axis): round up so every row dimension shards cleanly
             new_b += self._row_factor - new_b % self._row_factor
 
         self.up_vals = _grown(self.up_vals, (new_b, self.S), np.uint32)
@@ -561,19 +536,18 @@ class FusedBucket:
         (the fused_encode_many path): fancy-indexed mirror writes plus a
         single slot-map pass, no per-event python loop."""
         n, w = vals.shape
-        ack_ok = None
+        ack_ok = False
         if side:
-            if self.use_acks:
-                # ack eligibility must be proven BEFORE any buffers
-                # change: the event's value equals the host up mirror
-                # (which equals the device's resident row, because no
-                # up-side entry is staged for it this tick) — then the
-                # device can produce the row itself from a 4-byte index
-                ack_ok = (exists & self.up_exists[rows]
-                          & (self._staged_slot[rows.astype(np.int64) << 1] < 0)
-                          & (vals == self.up_vals[rows, :w]).all(axis=1))
-                if w < self.S:
-                    ack_ok &= (self.up_vals[rows, w:] == 0).all(axis=1)
+            # ack eligibility must be proven BEFORE any buffers change:
+            # the event's value equals the host up mirror (which equals
+            # the device's resident row, because no up-side entry is
+            # staged for it this tick) — then the device can produce the
+            # row itself from a 4-byte index
+            ack_ok = (exists & self.up_exists[rows]
+                      & (self._staged_slot[rows.astype(np.int64) << 1] < 0)
+                      & (vals == self.up_vals[rows, :w]).all(axis=1))
+            if w < self.S:
+                ack_ok &= (self.up_vals[rows, w:] == 0).all(axis=1)
             self.down_vals[rows, :w] = vals
             self.down_vals[rows, w:] = 0
             self.down_exists[rows] = exists
@@ -597,232 +571,14 @@ class FusedBucket:
         self._staged_vals[slots, w:] = 0
         self._staged_flags[slots] = (exists.astype(np.uint32)
                                      | (2 if side else 0) | 4)
-        self._staged_ack[slots] = ack_ok if ack_ok is not None else False
+        self._staged_ack[slots] = ack_ok
 
     @property
     def dirty(self) -> bool:
         return (bool(self._staged_n) or bool(self._staged_masks)
                 or self._stale or self._pl_staged)
 
-    # -------------------------------------------------------------- tick
-
-    def _device_state(self) -> ReconcileState:
-        # placement lanes: real when a placement owner registered (the
-        # splitter's roots), minimal placeholders otherwise — either way
-        # the program IS the flagship step, lanes and all (placement
-        # rows are row-sharded too — pad to the row factor)
-        f = self._row_factor
-        if self.R:
-            replicas, avail = self.pl_replicas, self.pl_avail
-            r, p = self.R, self.P
-        else:
-            r = ((8 + f - 1) // f) * f
-            p = 8
-            replicas = np.zeros(r, np.int32)
-            avail = np.zeros((r, p), bool)
-        l, c = 1, 8
-        state = ReconcileState(
-            up_vals=self.up_vals, up_exists=self.up_exists,
-            down_vals=self.down_vals, down_exists=self.down_exists,
-            status_mask=self.status_mask,
-            replicas=replicas,
-            avail=avail,
-            current=np.zeros((r, p), np.int32),
-            pair_hashes=np.zeros((self.B, l), np.uint32),
-            sel_hashes=np.zeros(c, np.uint32),
-        )
-        if self.mesh is not None:
-            from ..parallel.mesh import shard_state
-
-            return shard_state(state, self.mesh)
-        return jax.tree.map(jax.device_put, state)
-
-    def submit(self) -> tuple[jax.Array, tuple[int, int]] | None:
-        """Upload staged events, run one fused step, return the wire array
-        (with copy_to_host_async issued) plus the (patch_capacity, P)
-        needed to unpack it. None if nothing to do."""
-        if not self.dirty:
-            return None
-        ph = _Phases()
-        try:
-            return self._submit(ph)
-        finally:
-            ph.close()
-
-    def _submit(self, ph: _Phases) -> tuple[jax.Array, tuple[int, int]]:
-        s = self.S
-        # a stale tick re-uploads the whole mirror to the device, which
-        # is not the steady-state pack — the histograms stay separable
-        ph.enter("full_upload" if self._stale else "pack")
-        if self._stale:
-            self._state = self._device_state()
-            self._stale = False
-            self._clear_staged()
-            self._pl_staged = False
-            self.stats["full_uploads"] += 1
-            # a full upload re-submits every owned row — they are all
-            # suspects if this step fails (quarantine bisection input)
-            self._last_rows = sorted(self.row_owner)
-            # full upload replaces the mirrors wholesale; still run the
-            # step so decisions for the new state come back
-            buf_slot, packed, acks = self._wire_bufs.acquire(
-                MIN_EVENTS, s + 2, self.ack_capacity)
-        else:
-            if self._pl_staged:
-                # placement inputs changed (roots staged/retired): swap
-                # ONLY the small replicas/avail leaves — never the [B,S]
-                # mirrors (shapes are stable here; growth marks stale)
-                self._pl_staged = False
-                reps, avail = self.pl_replicas.copy(), self.pl_avail.copy()
-                if self.mesh is not None:
-                    from ..parallel.mesh import state_shardings
-
-                    sh = state_shardings(self.mesh)
-                    reps = jax.device_put(reps, sh["placement_rows"])
-                    avail = jax.device_put(avail, sh["placement"])
-                else:
-                    reps = jax.device_put(reps)
-                    avail = jax.device_put(avail)
-                self._state = self._state._replace(replicas=reps, avail=avail)
-            # the staged buffers already hold the packed-wire layout
-            # (vals / row / flags, the unpack_deltas format) — one padded
-            # block copy and a reset of the slot map finish the pack.
-            # Ack-eligible slots ship on the 4-byte acks lane instead;
-            # mask stamps for newly-allocated rows append as MASK_STAMP
-            # entries (vals columns = the bool mask row).
-            n = self._staged_n
-            ack_sel = self._staged_ack[:n]
-            na = int(ack_sel.sum())
-            nf = n - na
-            nm = len(self._staged_masks)
-            d = pad_pow2(nf + nm, floor=MIN_EVENTS)
-            # always ship the acks array, even all-padding: an acks=None
-            # fast path would be a SECOND jit trace variant, and the
-            # first ack-bearing tick would then compile it mid-serving —
-            # a seconds-long loop stall (measured) vs the ~nothing an
-            # all-dropped scatter pass costs per tick
-            while self.ack_capacity < na:
-                self.ack_capacity *= 2
-            buf_slot, packed, acks = self._wire_bufs.acquire(
-                d, s + 2, self.ack_capacity)
-            if na:
-                self.stats["acked"] += na
-                full_sel = ~ack_sel
-                packed[:nf, :s] = self._staged_vals[:n][full_sel]
-                packed[:nf, s] = self._staged_rows[:n][full_sel]
-                packed[:nf, s + 1] = self._staged_flags[:n][full_sel]
-                acks[:na] = self._staged_rows[:n][ack_sel]
-            else:
-                packed[:n, :s] = self._staged_vals[:n]
-                packed[:n, s] = self._staged_rows[:n]
-                packed[:n, s + 1] = self._staged_flags[:n]
-            if nm:
-                mrows = np.fromiter(self._staged_masks, np.uint32, nm)
-                masks = np.stack(list(self._staged_masks.values()))
-                packed[nf:nf + nm, : masks.shape[1]] = masks.astype(np.uint32)
-                packed[nf:nf + nm, s] = mrows
-                packed[nf:nf + nm, s + 1] = 4 | MASK_STAMP_BIT
-            rows_touched = set(self._staged_rows[:n].tolist())
-            rows_touched.update(self._staged_masks)
-            self._last_rows = sorted(rows_touched)
-            self._clear_staged()
-        ph.enter("put")
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            repl = NamedSharding(self.mesh, PartitionSpec())
-            packed = jax.device_put(packed, repl)
-            acks = jax.device_put(acks, repl)
-        else:
-            packed = jax.device_put(packed)
-            acks = jax.device_put(acks)
-        ph.enter("step_dispatch")
-        k = min(self.patch_capacity, self.B)
-        # KCP_FAULTS `device.step` injection point (raise@tick / error /
-        # poison_row): fires HERE, where a real XLA dispatch failure
-        # would surface — the quarantine machinery recovers either way
-        faults.maybe_fail("device.step", rows=self._last_rows)
-        self._state, wire = self._step(
-            self._state, packed, acks, patch_capacity=k,
-            use_pallas=self.use_pallas, mesh=self.mesh,
-        )
-        # the staging buffers may be re-acquired only once this step has
-        # read them (see WireBuffers)
-        self._wire_bufs.commit(buf_slot, packed, acks, wire)
-        self._step_failures = 0
-        wire.copy_to_host_async()
-        ph.close()
-        self.stats["ticks"] += 1
-        return wire, (k, int(self._state.avail.shape[1]))
-
     # ------------------------------------------------------- quarantine
-
-    def probe_rows(self, rows: Sequence[int]) -> bool:
-        """Run one trial step over a synthetic wire carrying only
-        ``rows`` (both sides, from the host mirrors), discarding the
-        result. True iff the step completed — the bisection's oracle.
-
-        The probe jit does NOT donate: the resident state must survive
-        an arbitrary number of probes. Probe wire shapes are pow2-padded,
-        so a bisection compiles at most a handful of variants (this is
-        the rare failure path; docs/operations.md covers the cost)."""
-        if self.B == 0:
-            return True
-        rows = [int(r) for r in rows]
-        try:
-            faults.maybe_fail("device.step", rows=rows)
-            if self._probe_step is None:
-                self._probe_step = jax.jit(
-                    reconcile_step_packed,
-                    static_argnames=("patch_capacity", "use_pallas", "mesh"))
-            if self._state is None:
-                self._state = self._device_state()
-                self._stale = False
-            s = self.S
-            d = pad_pow2(max(2 * len(rows), 1), floor=MIN_EVENTS)
-            packed = np.zeros((d, s + 2), np.uint32)
-            for i, row in enumerate(rows):
-                packed[2 * i, :s] = self.up_vals[row]
-                packed[2 * i, s] = row
-                packed[2 * i, s + 1] = (1 if self.up_exists[row] else 0) | 4
-                packed[2 * i + 1, :s] = self.down_vals[row]
-                packed[2 * i + 1, s] = row
-                packed[2 * i + 1, s + 1] = (
-                    (1 if self.down_exists[row] else 0) | 2 | 4)
-            acks = np.full(self.ack_capacity, -1, np.int32)
-            if self.mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec
-
-                repl = NamedSharding(self.mesh, PartitionSpec())
-                packed = jax.device_put(packed, repl)
-                acks = jax.device_put(acks, repl)
-            _state, wire = self._probe_step(
-                self._state, packed, acks,
-                patch_capacity=min(self.patch_capacity, self.B),
-                use_pallas=self.use_pallas, mesh=self.mesh)
-            np.asarray(wire)  # force execution; async backends defer errors
-            return True
-        except Exception:  # noqa: BLE001 — any failure means "poisoned"
-            return False
-
-    def bisect_poison(self, suspects: Sequence[int],
-                      max_probes: int = BISECT_MAX_PROBES) -> list[int] | None:
-        """Isolate the rows whose presence makes the step fail, by
-        group-testing probe steps (~k*log2(n) probes for k poisons).
-
-        Returns None when even an EMPTY probe fails — the failure is
-        row-independent and quarantine cannot help. If the probe budget
-        runs out, the unresolved remainder is quarantined wholesale
-        (innocents may be swept up; degraded beats dead, and their
-        requeue brings them back)."""
-        if not self.probe_rows([]):
-            return None
-        return _group_test_poison(
-            self.probe_rows, [[int(r) for r in suspects]], max_probes)
-
-    def note_step_failure(self) -> None:
-        self.stats["step_failures"] += 1
-        self._step_failures += 1
 
     def quarantine_row(self, row: int) -> tuple[object | None, Section | None]:
         """Evict one poisoned row: zero its host mirrors (the pending
@@ -852,27 +608,11 @@ class FusedBucket:
 
     # ----------------------------------------------------------- routing
 
-    def dispatch(self, wire: np.ndarray, meta: tuple[int, int]) -> bool:
-        """Route a collected wire's patches (and dirty placement rows) to
-        their owners.
-
-        Returns True if the patch set overflowed (caller re-ticks after
-        doubling capacity)."""
-        idx, code, upsync, overflow, _stats = unpack_patches(wire)
-        self.route_patches(idx, code, upsync)
-        if self.placement_owner is not None:
-            k, p = meta
-            rows, counts = unpack_placement(wire, k, p)
-            self.route_placement(rows, counts)
-        if overflow:
-            self.note_overflow()
-        return bool(overflow)
-
     def route_patches(self, idx: np.ndarray, code: np.ndarray,
                       upsync: np.ndarray) -> None:
         """Route patch rows (bucket-local indices) to their owning
-        sections — shared by the per-bucket dispatch and the fleet batch
-        (which splits a fleet wire's patches by row range first)."""
+        sections (the fleet batch splits its wire's patches by row range
+        first)."""
         per_section: dict[Section, list[tuple[object, int, bool]]] = {}
         dropped = 0
         for r, c, u in zip(idx.tolist(), code.tolist(), upsync.tolist()):
@@ -940,10 +680,9 @@ class FleetMeta(NamedTuple):
 
 
 class FleetBatch:
-    """One ragged device batch for the whole bucket fleet.
+    """One ragged device batch for the whole bucket fleet — the core's
+    one device owner (state, jit step, wire buffers, probe + bisection).
 
-    Per-bucket dispatch pays full dispatch/pipeline latency per schema
-    bucket — small and ragged buckets leave the chip idle between kicks.
     The fleet batch packs EVERY bucket's rows into one unified
     ReconcileState (rows range-partitioned by bucket, slot columns
     zero-padded to the widest member, per-row status masks — the [B, S]
@@ -951,9 +690,8 @@ class FleetBatch:
     ``reconcile_step_fleet`` no matter how many buckets exist, and the
     mesh shardings in parallel/mesh.py spread that single batch over all
     devices. Results scatter back to per-bucket patch streams on collect
-    (row ranges -> bucket.route_patches), so engines observe byte-
-    identical patch streams vs per-bucket dispatch — the differential-
-    fuzz contract.
+    (row ranges -> bucket.route_patches): each owner sees its rows'
+    decisions in row order, whatever else shares the batch.
 
     Per-row *segment ids* (the owning section) ride the batch as a
     resident int32 lane; the step returns per-segment live-row counts on
@@ -986,14 +724,29 @@ class FleetBatch:
         self._seg_ids = None  # device int32 [B]: row -> section segment
         self._seg_capacity = 8
         self._stale = True
+        # acks-lane wire capacity: sticky high-water doubling, so the
+        # (packed, acks) shape pair stays stable after warmup — per-tick
+        # pow2 padding here would multiply compiled-shape variants
         self.ack_capacity = 1024
+        # rotating packed-wire staging (models/reconcile_model.py): tick
+        # N+1 packs into another buffer while tick N's device_put may
+        # still be reading this one — the allocation-free hot path that
+        # makes the 2-deep pipeline window safe
         self._wire_bufs = WireBuffers(PIPELINE_DEPTH + 1)
+        # the resident state is donated on every backend: steady state
+        # lives in device memory and only deltas cross the link, and the
+        # tests' CPU backend runs the same donated program as the chip
         self._step = jax.jit(
             reconcile_step_fleet,
             donate_argnums=(0, 1),
             static_argnames=("patch_capacity", "seg_capacity",
                              "use_pallas", "mesh"),
         )
+        # degraded-mode bookkeeping (poison-row quarantine): the rows the
+        # last submission covered (the bisection's suspect set), the
+        # consecutive step-failure count, and the non-donating probe step
+        # used by the bisection (donation would consume the resident
+        # state probes must leave intact)
         self._probe_step = None
         self._last_rows: list[int] = []
         self._step_failures = 0
@@ -1031,7 +784,7 @@ class FleetBatch:
         self.P = p
         # any layout change invalidates the resident fleet state: row
         # bases moved, so a full re-upload rebuilds it (bucket growth is
-        # pow2 + rare, same cost class as a bucket's own growth)
+        # pow2 + rare)
         self._stale = True
 
     @property
@@ -1069,8 +822,11 @@ class FleetBatch:
 
     def _device_state(self) -> tuple[ReconcileState, jax.Array]:
         """The concatenated fleet state + the row->segment lane, sharded
-        like any bucket state (rows over tenants/hosts, slots over the
-        slots axis; the seg lane shards like the exists flags)."""
+        by parallel/mesh.py's specs (rows over tenants/hosts, slots over
+        the slots axis; the seg lane shards like the exists flags).
+        Placement lanes are real when a placement owner registered (the
+        splitter's roots), minimal placeholders otherwise — either way
+        the program IS the flagship step, lanes and all."""
         s = self.S
         up_vals = np.zeros((self.B, s), np.uint32)
         down_vals = np.zeros((self.B, s), np.uint32)
@@ -1131,12 +887,16 @@ class FleetBatch:
         s = self.S
         self._seg_capacity = pad_pow2(max(self.core._next_seg, 1), floor=8)
         was_stale = self._stale or any(b._stale for b in self._members)
+        # a stale tick re-uploads the whole mirror to the device, which
+        # is not the steady-state pack — the histograms stay separable
         ph.enter("full_upload" if was_stale else "pack")
         local_rows: list[int] = []  # bucket-local ids for KCP_FAULTS
         if was_stale:
             self._state, self._seg_ids = self._device_state()
             self._stale = False
             self._last_rows = []
+            # a full upload re-submits every owned row — they are all
+            # suspects if this step fails (quarantine bisection input)
             for b, base in zip(self._members, self._bases):
                 b._stale = False
                 b._clear_staged()
@@ -1146,10 +906,15 @@ class FleetBatch:
                 local_rows.extend(owned)
                 self._last_rows.extend(base + r for r in owned)
             self.stats["full_uploads"] += 1
+            # full upload replaces the mirrors wholesale; still run the
+            # step so decisions for the new state come back
             buf_slot, packed, acks = self._wire_bufs.acquire(
                 MIN_EVENTS, s + 2, self.ack_capacity)
         else:
             if any(b._pl_staged for b in self._members):
+                # placement inputs changed (roots staged/retired): swap
+                # ONLY the small replicas/avail leaves — never the [B,S]
+                # mirrors (shapes are stable here; growth marks stale)
                 for b in self._members:
                     b._pl_staged = False
                 replicas, avail, _r, _p = self._placement_leaves()
@@ -1179,9 +944,13 @@ class FleetBatch:
                 na_total += na
                 nm_total += nm
             d = pad_pow2(nf_total + nm_total, floor=MIN_EVENTS)
-            # fleet acks capacity honors each member's sticky high-water
-            # (bench pre-warms bucket.ack_capacity to dodge mid-serving
-            # recompiles — the fleet must not undo that)
+            # always ship the acks array, even all-padding: an acks=None
+            # fast path would be a SECOND jit trace variant, and the
+            # first ack-bearing tick would then compile it mid-serving —
+            # a seconds-long loop stall (measured) vs the ~nothing an
+            # all-dropped scatter pass costs per tick. The capacity
+            # honors each member's floor (bench pre-warms
+            # bucket.ack_capacity to dodge mid-serving recompiles)
             cap = max(self.ack_capacity,
                       max((b.ack_capacity for b in self._members),
                           default=1024))
@@ -1245,16 +1014,19 @@ class FleetBatch:
             acks_d = jax.device_put(acks)
         ph.enter("step_dispatch")
         k = self._patch_capacity()
-        # KCP_FAULTS `device.step`: rows are BUCKET-LOCAL ids (the union
-        # across members), so a poison_row spec targets the same logical
-        # rows whether dispatch is per-bucket or fleet-wide — the
-        # differential fuzz relies on it
+        # KCP_FAULTS `device.step` injection point (raise@tick / error /
+        # poison_row): fires HERE, where a real XLA dispatch failure
+        # would surface — the quarantine machinery recovers either way.
+        # Rows are BUCKET-LOCAL ids (the union across members), so a
+        # poison_row spec names an owner's row whatever the layout
         faults.maybe_fail("device.step", rows=local_rows)
         self._state, self._seg_ids, wire = self._step(
             self._state, self._seg_ids, packed_d, acks_d,
             patch_capacity=k, seg_capacity=self._seg_capacity,
             use_pallas=self.use_pallas, mesh=self.mesh,
         )
+        # the staging buffers may be re-acquired only once this step has
+        # read them (see WireBuffers)
         self._wire_bufs.commit(buf_slot, packed_d, acks_d, wire)
         self._step_failures = 0
         wire.copy_to_host_async()
@@ -1333,8 +1105,14 @@ class FleetBatch:
 
     def probe_rows(self, rows: Sequence[int]) -> bool:
         """The fleet bisection oracle: one non-donating trial step over a
-        synthetic wire carrying only ``rows`` (fleet ids), rebuilt from
-        the owning buckets' host mirrors. True iff the step completed."""
+        synthetic wire carrying only ``rows`` (fleet ids, both sides),
+        rebuilt from the owning buckets' host mirrors, discarding the
+        result. True iff the step completed.
+
+        The probe jit does NOT donate: the resident state must survive
+        an arbitrary number of probes. Probe wire shapes are pow2-padded,
+        so a bisection compiles at most a handful of variants (this is
+        the rare failure path; docs/operations.md covers the cost)."""
         if self.B == 0:
             return True
         rows = [int(r) for r in rows]
@@ -1382,7 +1160,11 @@ class FleetBatch:
         """Segment-scoped bisection over the ragged batch: the group test
         is seeded with one suspect group per member bucket, so a clean
         segment clears in one probe and poison isolates within its own
-        segment. None when even the empty probe fails (systemic)."""
+        segment (~k*log2(n) probes for k poisons). None when even the
+        empty probe fails — the failure is row-independent and quarantine
+        cannot help. If the probe budget runs out, the unresolved
+        remainder is quarantined wholesale (innocents may be swept up;
+        degraded beats dead, and their requeue brings them back)."""
         if not self.probe_rows([]):
             return None
         groups: dict[int, list[int]] = {}
@@ -1402,7 +1184,7 @@ class FleetBatch:
 
 
 class FusedCore:
-    """The per-loop serving core: one tick loop over all fused buckets."""
+    """The per-loop serving core: one tick loop, one fleet step a tick."""
 
     _instances: dict[int, "FusedCore"] = {}
     # process-default admission quota ledger (set_process_ledger): the
@@ -1411,31 +1193,26 @@ class FusedCore:
 
     def __init__(self, mesh=None, batch_window: float = 0.002,
                  use_pallas: bool | None = None,
-                 pipeline: str | None = None,
-                 fleet: bool | None = None):
+                 pipeline: str | None = None):
         self.mesh = mesh
+        # the fused Pallas decision+fanout pass (ops/pallas_kernels.py);
+        # on a mesh it runs per device via shard_map (reconcile_model
+        # gates on local-row divisibility and falls back to XLA lanes)
         if use_pallas is None:
             use_pallas = os.environ.get("KCP_PALLAS", "") == "1"
         self.use_pallas = use_pallas
-        # fleet-wide ragged batching (default on): every tick packs all
-        # dirty buckets into ONE pipelined device program. KCP_FLEET_BATCH=0
-        # is the fallback knob — per-bucket dispatch, the A/B reference
-        # for bench.py --fleet and the ragged differential fuzz
-        if fleet is None:
-            fleet = os.environ.get("KCP_FLEET_BATCH", "1").lower() not in (
-                "0", "false", "off")
-        self.fleet_mode = fleet
-        self._fleet = FleetBatch(self) if fleet else None
+        # every tick packs all dirty buckets into ONE pipelined device
+        # program: the fleet batch is the core's one device owner
+        self._fleet = FleetBatch(self)
         self._segments: dict[int, Section] = {}  # seg id -> section
         self._next_seg = 0
         self.ledger = FusedCore._process_ledger
         # tick pipelining mode: "double" (default) keeps up to
-        # PIPELINE_DEPTH steps in flight per bucket — pack N+1 and apply
-        # N-1 while the device runs N; "serial" collects every wire in
-        # the tick that submitted it (the A/B reference for bench.py
-        # --pipeline and the equivalence fuzz)
-        if pipeline is None:
-            pipeline = os.environ.get("KCP_PIPELINE", "") or "double"
+        # PIPELINE_DEPTH steps in flight — pack N+1 and apply N-1 while
+        # the device runs N; "serial" collects every wire in the tick
+        # that submitted it (the A/B reference for bench.py --pipeline
+        # and the equivalence fuzz)
+        pipeline = pipeline or "double"
         if pipeline not in PIPELINE_MODES:
             raise ValueError(f"pipeline must be one of {PIPELINE_MODES}, "
                              f"got {pipeline!r}")
@@ -1450,17 +1227,15 @@ class FusedCore:
             "fused-core", self._process_batch, batch_window=batch_window,
             overlap_drain=(pipeline == "double"),
         )
-        # (bucket, wire, layout meta, start stamp of the submitting tick)
-        self._inflight: list[
-            tuple[FusedBucket, jax.Array, tuple[int, int], float]
-        ] = []
+        # (wire, layout meta, start stamp of the submitting tick)
+        self._inflight: list[tuple[jax.Array, FleetMeta, float]] = []
         self._ticks = 0
         # start stamp (time.monotonic()) of the tick whose wire is being
         # collected, while its patches are handed to the owners
         self.collecting_tick_start: float | None = None
         self._depth_h = REGISTRY.histogram(
             "fused_pipeline_depth",
-            "in-flight steps per bucket at submit time",
+            "in-flight steps at submit time",
             buckets=DEPTH_BUCKETS)
         self._overlap_ticks = REGISTRY.counter(
             "fused_pipeline_overlap_ticks_total",
@@ -1479,16 +1254,14 @@ class FusedCore:
     # ---------------------------------------------------------- lifecycle
 
     @classmethod
-    def for_current_loop(cls, mesh=None,
-                         pipeline: str | None = None) -> "FusedCore":
+    def for_current_loop(cls, mesh=None) -> "FusedCore":
         """The process-wide core for the running asyncio loop (tests run
         many loops sequentially; each gets a fresh core).
 
         ``mesh=None`` falls back to the process serving mesh
         (parallel.mesh.set_serving_mesh — the server's Config.mesh /
         --mesh flag), so a configured process serves sharded without
-        every engine re-plumbing the mesh. ``pipeline=None`` falls back
-        to ``KCP_PIPELINE`` (default "double")."""
+        every engine re-plumbing the mesh."""
         if mesh is None:
             from ..parallel.mesh import get_serving_mesh
 
@@ -1501,16 +1274,12 @@ class FusedCore:
         # the identity check guards against id() reuse after a dead loop
         # is garbage-collected: a stale core's tick task died with its loop
         if core is None or core._closed() or core._loop is not loop:
-            core = cls(mesh=mesh, pipeline=pipeline)
+            core = cls(mesh=mesh)
             core._loop = loop
             cls._instances[id(loop)] = core
-        else:
-            if mesh is not None and core.mesh != mesh:
-                log.warning("FusedCore for this loop already exists with a "
-                            "different mesh; keeping the existing core's mesh")
-            if pipeline is not None and core.pipeline != pipeline:
-                log.warning("FusedCore for this loop already exists with "
-                            "pipeline=%s; keeping it", core.pipeline)
+        elif mesh is not None and core.mesh != mesh:
+            log.warning("FusedCore for this loop already exists with a "
+                        "different mesh; keeping the existing core's mesh")
         return core
 
     @classmethod
@@ -1586,7 +1355,7 @@ class FusedCore:
                 self._flush_task = None
             await self._drain_inflight()
             # drop the registry entry so closed cores (and their device-
-            # resident bucket state) do not accumulate across loops
+            # resident fleet state) do not accumulate across loops
             for k, v in list(FusedCore._instances.items()):
                 if v is self:
                     del FusedCore._instances[k]
@@ -1598,8 +1367,7 @@ class FusedCore:
     def bucket(self, slots: int) -> FusedBucket:
         b = self.buckets.get(slots)
         if b is None:
-            b = FusedBucket(slots, mesh=self.mesh, use_pallas=self.use_pallas,
-                            always_stamp=self.fleet_mode)
+            b = FusedBucket(slots, mesh=self.mesh)
             self.buckets[slots] = b
         return b
 
@@ -1621,10 +1389,10 @@ class FusedCore:
         b.register_placement(owner, p)
         return b
 
-    def kick(self, bucket: FusedBucket) -> None:
+    def kick(self) -> None:
         """Request a tick for a bucket dirtied outside the section path
         (placement staging)."""
-        self.controller.queue.add(("__kick__", False, id(bucket), None))
+        self.controller.queue.add(_RETICK)
 
     def enqueue(self, section: Section, side: bool, key) -> None:
         self.controller.enqueue((id(section.owner), side, key, section))
@@ -1648,8 +1416,8 @@ class FusedCore:
 
     def _tick(self, items: Sequence, t_tick: float) -> list:
         # 1. encode touched keys (engines re-read their informer caches);
-        #    section=None items are retick markers — their bucket is
-        #    already marked stale and will re-run on this tick. Items
+        #    section=None items are retick markers — whatever they
+        #    dirtied is submitted by this tick's fleet step. Items
         #    whose section was released (engine stop or vocabulary
         #    migration) are stale: touching them would resurrect rows in
         #    the old bucket — drop them, the replacement section was
@@ -1677,109 +1445,91 @@ class FusedCore:
             else:  # nothing encoded: the mean stays that of real encodes
                 ph.discard()
 
-        # 2. one fused step per dirty bucket; collection is pipelined.
-        #    Occupancy telemetry per submit: how deep the in-flight window
-        #    already was (depth histogram) and whether this dispatch
-        #    overlapped an executing step (the pipeline's whole point)
-        inflight_by_bucket: dict[int, int] = {}
-        for entry in self._inflight:
-            b = entry[0]
-            inflight_by_bucket[id(b)] = inflight_by_bucket.get(id(b), 0) + 1
-        depth_h = self._depth_h
-        # fleet mode: ONE ragged batch covers every dirty bucket — the
-        # same pipelined window applies, with the fleet as the unit
-        submitters = ((self._fleet,) if self._fleet is not None
-                      else tuple(self.buckets.values()))
-        for bucket in submitters:
-            try:
-                submitted = bucket.submit()
-            except Exception as err:  # noqa: BLE001 — degraded-mode gate
-                if self._recover_step_failure(bucket, err):
-                    continue
+        # 2. ONE fused step over every dirty bucket; collection is
+        #    pipelined. Occupancy telemetry per submit: how deep the
+        #    in-flight window already was (depth histogram) and whether
+        #    this dispatch overlapped an executing step (the pipeline's
+        #    whole point)
+        fleet = self._fleet
+        try:
+            submitted = fleet.submit()
+        except Exception as err:  # noqa: BLE001 — degraded-mode gate
+            if not self._recover_step_failure(err):
                 # surface loudly: a row-independent submit failure (bad
                 # sharding, systemic device error) otherwise dies as 5
                 # silent INFO-level retries
-                log.exception("fused-core: %s submit failed "
-                              "(B=%d S=%d mesh=%s)",
-                              type(bucket).__name__, bucket.B, bucket.S,
-                              bucket.mesh is not None)
+                log.exception("fused-core: fleet submit failed "
+                              "(B=%d S=%d mesh=%s)", fleet.B, fleet.S,
+                              fleet.mesh is not None)
                 raise
-            if submitted is not None:
-                wire, meta = submitted
-                depth = inflight_by_bucket.get(id(bucket), 0)
-                depth_h.observe(depth)
-                if depth:
-                    self._overlap_ticks.inc()
-                # the wire carries its tick's start stamp to its collect
-                self._inflight.append((bucket, wire, meta, t_tick))
+            submitted = None
+        if submitted is not None:
+            depth = len(self._inflight)
+            self._depth_h.observe(depth)
+            if depth:
+                self._overlap_ticks.inc()
+            # the wire carries its tick's start stamp to its collect
+            self._inflight.append((*submitted, t_tick))
 
-        # 3. collect: per BUCKET, oldest in-flight wires beyond the
-        #    pipeline window (blocking is fine by then — their data has
-        #    had fetch_depth full ticks to land; serial mode, depth 0,
-        #    collects everything including this tick's own wire). Depth
-        #    is per bucket so one bucket's fresh wire never forces a
-        #    zero-depth blocking collect of another's.
+        # 3. collect the oldest in-flight wires beyond the pipeline
+        #    window (blocking is fine by then — their data has had
+        #    fetch_depth full ticks to land; serial mode, depth 0,
+        #    collects everything including this tick's own wire).
         #    (Measured and rejected: collecting already-ready wires
         #    opportunistically — on a synchronous backend every wire is
         #    instantly "ready", which serializes dispatch into the tick
         #    and cost ~15% throughput at bench scale.)
-        counts: dict[int, int] = {}
-        for entry in self._inflight:
-            counts[id(entry[0])] = counts.get(id(entry[0]), 0) + 1
-        i = 0
-        while i < len(self._inflight):
-            b = self._inflight[i][0]
-            if counts[id(b)] > self.fetch_depth:
-                counts[id(b)] -= 1
-                self._collect(*self._inflight.pop(i))
-            else:
-                i += 1
+        while len(self._inflight) > self.fetch_depth:
+            self._collect(*self._inflight.pop(0))
         if self._inflight:
             self._schedule_flush()
         return []
 
     # ------------------------------------------------ degraded-mode path
 
-    def _recover_step_failure(self, bucket, err: Exception) -> bool:
-        """Survive a failed device step without stalling the bucket's
-        co-tenants: retry once wholesale (full re-upload rebuilds the
-        resident state from the host mirrors — the source of truth), and
-        on a second consecutive failure bisect the submitted rows to
-        quarantine the poison. ``bucket`` is a FusedBucket or the
-        FleetBatch (whose bisection is segment-scoped and whose
-        quarantine routes through the owning member bucket). Returns
-        False when the failure is row-independent (the caller then
-        propagates it)."""
-        bucket.note_step_failure()
+    def _recover_step_failure(self, err: Exception) -> bool:
+        """Survive a failed device step without stalling the poison
+        row's co-tenants: retry once wholesale (full re-upload rebuilds
+        the resident state from the host mirrors — the source of truth),
+        and on a second consecutive failure bisect the submitted rows to
+        quarantine the poison (the fleet's bisection is segment-scoped
+        and its quarantine routes through the owning member bucket).
+        Returns False when the failure is row-independent (the caller
+        then propagates it)."""
+        fleet = self._fleet
+        fleet.note_step_failure()
         REGISTRY.counter(
             "fused_step_failures_total",
             "fused device-step submissions that raised").inc()
-        if bucket._step_failures == 1:
+        if fleet._step_failures == 1:
             log.warning("fused-core: device step failed (%s: %s); retrying "
                         "once with a full re-upload", type(err).__name__, err)
-            bucket.mark_stale()
-            self.controller.queue.add(("__retick__", False, id(bucket), None))
+            self._retick()
             return True
-        suspects = list(bucket._last_rows)
-        bad = bucket.bisect_poison(suspects)
+        bad = fleet.bisect_poison(list(fleet._last_rows))
         if bad is None:
             # even the empty probe fails: systemic. Propagate — but keep
-            # the bucket dirty: the failed submit already consumed the
+            # the fleet dirty: the failed submit already consumed the
             # staged events and cleared _stale, so without this the
             # controller's retried items would find nothing to submit
-            # and the bucket would wedge converged-looking forever
-            bucket.mark_stale()
+            # and the fleet would wedge converged-looking forever
+            fleet.mark_stale()
             return False
         for row in bad:
-            key, section = bucket.quarantine_row(row)
+            key, section = fleet.quarantine_row(row)
             log.warning("fused-core: quarantined row %d (key=%r) after "
                         "repeated device-step failures", row, key)
             if key is not None and section is not None:
                 self._requeue_quarantined(section, key)
-        bucket._step_failures = 0
-        bucket.mark_stale()
-        self.controller.queue.add(("__retick__", False, id(bucket), None))
+        fleet._step_failures = 0
+        self._retick()
         return True
+
+    def _retick(self) -> None:
+        """Level-triggered re-run: rebuild the resident state from the
+        host mirrors on a tick of its own."""
+        self._fleet.mark_stale()
+        self.controller.queue.add(_RETICK)
 
     def _requeue_quarantined(self, section: Section, key) -> None:
         """Hand a quarantined key back to its owner after a bounded
@@ -1862,9 +1612,8 @@ class FusedCore:
                               down_e[down_sel])
         section.refresh_mask()
 
-    def _collect(self, bucket: FusedBucket, wire: jax.Array,
-                 meta: tuple[int, int], tick_start: float | None = None
-                 ) -> None:
+    def _collect(self, wire: jax.Array, meta: FleetMeta,
+                 tick_start: float | None = None) -> None:
         """Fetch one in-flight wire and route its patches to the owners.
         ``tick_start`` is the ``time.monotonic()`` start of the tick that
         submitted it: the owners read it (``collecting_tick_start``)
@@ -1873,19 +1622,17 @@ class FusedCore:
         ph = _Phases()
         ph.enter("collect_wait")
         try:
-            overflow = self._fetch_and_dispatch(bucket, wire, meta,
-                                                tick_start, ph)
+            overflow = self._fetch_and_dispatch(wire, meta, tick_start, ph)
         finally:
             ph.close()
             self.collecting_tick_start = None
         if tick_start is not None:
             _TICK_H.observe(time.monotonic() - tick_start)
         if overflow:
-            # level-triggered: re-run the bucket with doubled capacity
-            bucket.mark_stale()
-            self.controller.queue.add(("__retick__", False, id(bucket), None))
+            # level-triggered: re-run with doubled capacity
+            self._retick()
 
-    def _fetch_and_dispatch(self, bucket, wire, meta, tick_start,
+    def _fetch_and_dispatch(self, wire, meta, tick_start,
                             ph: _Phases) -> bool:
         # fetch blocks ONLY on the compact wire (copy_to_host_async was
         # issued at dispatch) — never on the donated resident state. The
@@ -1903,7 +1650,7 @@ class FusedCore:
         host_wire = np.asarray(wire)
         ph.enter("dispatch")
         self.collecting_tick_start = tick_start
-        return bucket.dispatch(host_wire, meta)
+        return self._fleet.dispatch(host_wire, meta)
 
     def _schedule_flush(self) -> None:
         if self._flush_task is not None:
@@ -1931,7 +1678,7 @@ class FusedCore:
             if not self._eager_collect:
                 await asyncio.sleep(IDLE_FLUSH_S)
             while self._inflight:
-                wire = self._inflight[0][1]
+                wire = self._inflight[0][0]
                 # exponential poll backoff: a step over a large fleet
                 # runs for milliseconds, so a flat 1 ms poll would wake
                 # the loop many times per wire for no data; cap at 8 ms
@@ -1944,7 +1691,7 @@ class FusedCore:
                 # based collect pops it, and a collect failure means
                 # _schedule_flush never cancelled this task) — pop only
                 # the wire this iteration actually inspected
-                if not self._inflight or self._inflight[0][1] is not wire:
+                if not self._inflight or self._inflight[0][0] is not wire:
                     continue
                 self._collect_late(self._inflight.pop(0))
         except asyncio.CancelledError:

@@ -206,7 +206,6 @@ class BatchSyncEngine:
         mesh=None,
         apply_workers: int = 4,
         max_apply_retries: int = 5,
-        pipeline: str | None = None,
     ):
         self.upstream = upstream
         self.downstream = downstream
@@ -224,9 +223,6 @@ class BatchSyncEngine:
         self.fused = backend == "tpu"
         self.core = core
         self.mesh = mesh  # sharding for the fused core (None = serving default)
-        # tick pipelining for the fused core (None = KCP_PIPELINE env /
-        # "double"); only consulted when this engine creates the core
-        self.pipeline = pipeline
         self.namespace_gvr = namespace_gvr
         self.selector: LabelSelector = parse_selector(f"{CLUSTER_LABEL}={cluster_id}")
 
@@ -882,8 +878,7 @@ class BatchSyncEngine:
             if self.core is None:
                 from .core import FusedCore
 
-                self.core = FusedCore.for_current_loop(
-                    mesh=self.mesh, pipeline=self.pipeline)
+                self.core = FusedCore.for_current_loop(mesh=self.mesh)
             self._section = self.core.register(self, self.enc.capacity)
             await self.core.start()
         # informers after the section exists: their initial list replays

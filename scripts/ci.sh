@@ -86,31 +86,6 @@ print("pipeline A/B smoke ok:",
       "| speedup:", r.get("pipeline_speedup"))
 '
 
-echo "== fleet: ragged-vs-per-bucket dispatch smoke (mixed buckets + stragglers)"
-# small mixed-bucket fleet: the ragged batch must (1) emit byte-identical
-# per-owner patch streams, (2) beat per-bucket dispatch >=1.5x combined
-# throughput on this host, (3) amortize >=2x rows per device dispatch,
-# and (4) pass the poison-row quarantine drill (segment-scoped bisection)
-fleet_line=$({ JAX_PLATFORMS=cpu KCP_BENCH_FLEET_ROWS=2048 \
-    KCP_BENCH_FLEET_STEPS=16 KCP_BENCH_FLEET_WARMUP=6 \
-    KCP_BENCH_FLEET_STRAGGLERS=8 python bench.py --fleet \
-    || [ $? -eq 3 ]; } | tail -1)
-printf '%s\n' "$fleet_line" | python -c '
-import json, sys
-r = json.loads(sys.stdin.readline())
-fb = r["fleet_bench"]
-assert fb["streams_equal"], "ragged and per-bucket patch streams diverged"
-assert r["value"] >= 2.0, "device-utilization gain %sx < 2x floor" % r["value"]
-assert fb["combined_speedup"] >= 1.5, (
-    "ragged combined throughput %sx < 1.5x floor" % fb["combined_speedup"])
-drill = fb["quarantine_drill"]
-assert drill["ok"], "quarantine drill failed: %s" % drill
-print("fleet smoke ok: %sx rows/dispatch | %sx combined | %d buckets"
-      " -> 1 program | drill: %d quarantined, co-tenants ok"
-      % (r["value"], fb["combined_speedup"], fb["buckets"],
-         drill["quarantined"]))
-'
-
 echo "== placement: fleet bin-pack smoke (batched-vs-per-workspace floor + assignment byte-equality)"
 # reduced-scale --placement lane (2k workspaces x 8 pclusters, 400-row
 # loop sample): the batched device solve must beat the pre-fleet

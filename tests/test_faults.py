@@ -21,7 +21,6 @@ import kcp_tpu.syncer.core as core_mod
 from kcp_tpu import faults
 from kcp_tpu.apis.cluster import new_cluster, set_not_ready, set_ready
 from kcp_tpu.client import Client, Informer, MultiClusterClient
-from kcp_tpu.models.reconcile_model import PACK_HDR
 from kcp_tpu.reconcilers.deployment import DeploymentSplitter
 from kcp_tpu.reconcilers.deployment.controller import DEPLOYMENTS
 from kcp_tpu.server.rest import RestClient
@@ -501,13 +500,12 @@ def test_ci_chaos_smoke():
 
 def test_dispatch_counts_and_logs_dropped_patch_rows(caplog):
     bucket = FusedBucket(8)
-    wire = np.zeros(PACK_HDR + 4, np.int32)
-    wire[0] = 1
-    wire[PACK_HDR] = 7  # row 7: never allocated, no owner
+    # one patch for row 7: never allocated, no owner
+    idx, code, upsync = np.array([7]), np.array([2]), np.array([False])
     before = counter("fused_dropped_patch_rows")
     with caplog.at_level("WARNING", logger="kcp_tpu.syncer.core"):
-        assert bucket.dispatch(wire, (4, 8)) is False
-        assert bucket.dispatch(wire, (4, 8)) is False
+        bucket.route_patches(idx, code, upsync)
+        bucket.route_patches(idx, code, upsync)
     assert counter("fused_dropped_patch_rows") == before + 2
     hits = [r for r in caplog.records if "dropping patch for row 7" in r.message]
     assert len(hits) == 1  # logged once per row, counted every time

@@ -1,19 +1,19 @@
-"""Fleet-wide ragged batching: ragged-vs-per-bucket equivalence.
+"""Fleet-wide ragged batching: the one device path, against an oracle.
 
-The fleet batch (syncer/core.py FleetBatch, KCP_FLEET_BATCH=1 default)
-packs every schema bucket's rows into ONE pipelined device program per
-tick. It must be an OBSERVATIONALLY invisible optimization: over an
-identical seeded churn schedule spanning several buckets it must emit
-byte-identical per-owner patch streams vs per-bucket dispatch (the
-differential-fuzz contract every perf PR in this repo ships with), it
-must preserve the PR 2 poison-row semantics (segment-scoped bisection
-quarantining ONLY the poison rows), the PR 1 shutdown-drain ordering,
-and it must feed the admission quota ledger from the device-side
-per-segment counters.
+The fleet batch (syncer/core.py FleetBatch) packs every schema bucket's
+rows into ONE pipelined device program per tick; the buckets only stage
+on the host. Over a seeded churn schedule spanning several buckets it
+must emit, per owner, byte for byte the stream a few lines of numpy
+predict from the owners' mirrors (no kcp_tpu.ops / kcp_tpu.models in the
+prediction); it must preserve the PR 2 poison-row semantics
+(segment-scoped bisection quarantining ONLY the poison rows), the PR 1
+shutdown-drain ordering, and it must feed the admission quota ledger
+from the device-side per-segment counters.
 """
 
 import asyncio
 
+import jax
 import numpy as np
 import pytest
 
@@ -25,9 +25,10 @@ from helpers import wait_until
 
 class Owner:
     """Open-loop SectionOwner at a chosen slot width: fixed mirrors,
-    every patch recorded, NO feedback — so fleet and per-bucket modes
-    see identical staging schedules and streams compare byte-for-byte
-    (the test_pipeline.py RecordingOwner pattern, width-parameterized)."""
+    every patch recorded, NO feedback — so the stream is a function of
+    the staging schedule alone and compares byte-for-byte with the
+    oracle (the test_pipeline.py RecordingOwner pattern,
+    width-parameterized)."""
 
     def __init__(self, core, b: int, s: int):
         self.core = core
@@ -75,64 +76,144 @@ def _stream_bytes(stream) -> bytes:
 
 
 WIDTHS = (16, 32)  # two slot widths -> two schema buckets
+# bucket layouts of the fuzz: owner widths, the last a 3-row straggler.
+# "two-width" is the ragged case (a straggler sharing the narrow bucket);
+# "one-64" is the single 64-slot bucket every benchmark cell runs
+LAYOUTS = {"two-width": (16, 32, 16), "one-64": (64, 64, 64)}
+UPDATE = 2  # ops/diff.py: both sides exist and a non-status slot differs
 
 
-async def _run_schedule(fleet: bool, seed: int, rows: int = 256,
-                        steps: int = 15, mesh=None,
-                        straggler_rows: int = 3):
+class Oracle:
+    """What one owner's stream must be, from its mirrors alone. The step
+    is level-triggered: every tick reports, in row order (rows are
+    allocated in first-touch order and never freed here), each resident
+    row whose mirrors differ — code UPDATE when a non-status slot
+    differs, ``upsync`` when a status slot does."""
+
+    def __init__(self, owner: Owner):
+        self.o = owner
+        self.order: dict[int, None] = {}  # resident keys, row order
+        self.stream: list[tuple[int, int, bool]] = []
+
+    def touch(self, keys) -> None:
+        self.order.update(dict.fromkeys(keys))
+
+    def tick(self) -> None:
+        keys = np.fromiter(self.order, np.int64, len(self.order))
+        neq = self.o.up_vals[keys] != self.o.down_vals[keys]
+        spec = (neq & ~self.o._mask).any(axis=1)
+        status = (neq & self.o._mask).any(axis=1)
+        self.stream.extend(
+            (int(k), UPDATE if sp else 0, bool(st))
+            for k, sp, st in zip(keys, spec, status) if sp or st)
+
+
+def _churn(rng, o: Owner, pool: int) -> tuple[list[int], list[int]]:
+    """Mutate a few of one owner's keys, one op each: a new upstream
+    row, upstream status churn, downstream convergence (the acks lane:
+    the event equals the resident upstream row) or downstream spec
+    drift. Returns the keys whose (up, down) side to enqueue."""
+    hi = min(pool, o.B)
+    n = int(rng.integers(1, min(16, hi + 1)))
+    ups, downs = [], []
+    for key, op in zip(rng.choice(hi, size=n, replace=False).tolist(),
+                       rng.integers(0, 4, n).tolist()):
+        if op == 0:
+            o.up_vals[key] = rng.integers(1, 2**32, o.S, dtype=np.uint32)
+        elif op == 1:
+            o.up_vals[key, -2:] = rng.integers(1, 2**32, 2, dtype=np.uint32)
+        elif op == 2:
+            o.down_vals[key] = o.up_vals[key]
+        else:
+            o.down_vals[key, 0] = rng.integers(1, 2**32, dtype=np.uint32)
+        (ups if op < 2 else downs).append(key)
+    return ups, downs
+
+
+async def _run_schedule(seed: int, layout: str = "two-width", steps: int = 15,
+                        mesh=None):
     """Drive one deterministic multi-bucket churn schedule in lockstep
     (all owners enqueue, then wait for every bucket to tick once) and
-    return per-owner fully-drained patch streams + stats."""
-    core = FusedCore(batch_window=0.0005, pipeline="double", fleet=fleet,
-                     mesh=mesh)
-    owners = [Owner(core, rows, w) for w in WIDTHS]
-    # a 1-4-row straggler section sharing the narrow bucket: the ragged
-    # case the fleet batch exists for
-    straggler = Owner(core, straggler_rows, WIDTHS[0])
-    owners.append(straggler)
+    return the owners (streams fully drained), their oracles and the
+    stopped core."""
+    core = FusedCore(batch_window=0.0005, pipeline="double", mesh=mesh)
+    widths = LAYOUTS[layout]
+    owners = [Owner(core, 256, w) for w in widths[:-1]]
+    # a 3-row straggler section sharing a bucket: the ragged case the
+    # fleet batch exists for
+    owners.append(Owner(core, 3, widths[-1]))
+    oracles = [Oracle(o) for o in owners]
     await core.start()
-    buckets = list({id(o.section.bucket): o.section.bucket for o in owners}
-                   .values())
-    assert len(buckets) == len(WIDTHS), "widths must map to distinct buckets"
+    buckets = list(core.buckets.values())
+    assert len(buckets) == len(set(widths)), "one bucket per width"
     rng = np.random.default_rng(seed)
     pool = 100  # < patch capacity so level-triggered re-patches never overflow
     for step in range(steps):
-        before = {id(b): b.stats["ticks"] for b in buckets}
-        for o in owners:
-            hi = min(pool, o.B)
-            n = int(rng.integers(1, min(16, hi + 1)))
-            touched = rng.choice(hi, size=n, replace=False)
-            o.up_vals[touched] = rng.integers(
-                1, 2**32, (n, o.S), dtype=np.uint32)
-            core.enqueue_many(o.section, False, touched.tolist())
+        before = [b.stats["ticks"] for b in buckets]
+        for o, oracle in zip(owners, oracles):
+            ups, downs = _churn(rng, o, pool)
+            core.enqueue_many(o.section, False, ups)
+            core.enqueue_many(o.section, True, downs)
+            oracle.touch(ups + downs)
         assert await wait_until(
-            lambda: all(b.stats["ticks"] > before[id(b)] for b in buckets),
-            10), f"fleet={fleet}: tick never ran for step {step}"
+            lambda: all(b.stats["ticks"] > t for b, t in zip(buckets, before)),
+            10), f"tick never ran for step {step}"
+        for oracle in oracles:
+            oracle.tick()
     await core.stop()
     assert not core._inflight
-    return ([_stream_bytes(o.stream) for o in owners],
-            [dict(b.stats) for b in buckets],
-            dict(core._fleet.stats) if core._fleet is not None else None)
+    return owners, oracles, core
 
 
+@pytest.mark.parametrize("layout", list(LAYOUTS))
 @pytest.mark.parametrize("seed", [2, 11, 29])
-def test_ragged_vs_per_bucket_differential_fuzz(seed):
-    """Byte-identical per-owner patch streams across several buckets
-    (including a 3-row straggler section): fleet packing must not
-    reorder, duplicate, drop, or cross-route decisions."""
+def test_ragged_vs_per_bucket_differential_fuzz(seed, layout):
+    """Byte-identical per-owner patch streams, fleet vs the numpy oracle,
+    across the bucket layout (including a 3-row straggler section):
+    fleet packing must not reorder, duplicate, drop, or cross-route
+    decisions."""
 
     async def main():
-        per_bucket, pb_stats, _ = await _run_schedule(False, seed)
-        ragged, rg_stats, fleet_stats = await _run_schedule(True, seed)
-        for i, (a, b) in enumerate(zip(per_bucket, ragged)):
-            assert a == b, (
-                f"seed={seed}: owner {i} stream diverged "
-                f"({len(a)} vs {len(b)} bytes)")
-        assert any(len(s) > 0 for s in ragged), "no patches — vacuous"
-        # the lockstep drove one staged batch per tick in both modes
-        assert [s["ticks"] for s in pb_stats] == [s["ticks"] for s in rg_stats]
-        # and the whole fleet rode ONE dispatch per tick, not one per bucket
-        assert fleet_stats["ticks"] == rg_stats[0]["ticks"]
+        steps = 15
+        owners, oracles, core = await _run_schedule(seed, layout, steps)
+        # the lockstep drove one staged batch per tick, and the whole
+        # fleet rode ONE dispatch per tick, not one per bucket: the
+        # oracle's one emission per step is the right count
+        assert core._fleet.stats["ticks"] == steps
+        assert [b.stats["ticks"] for b in core.buckets.values()] == (
+            [steps] * len(core.buckets))
+        for i, (o, oracle) in enumerate(zip(owners, oracles)):
+            got, want = _stream_bytes(o.stream), _stream_bytes(oracle.stream)
+            assert got == want, (
+                f"seed={seed}: owner {i} stream diverged from the oracle "
+                f"({len(o.stream)} vs {len(oracle.stream)} patches)")
+        streams = [p for o in owners for p in o.stream]
+        # not vacuous: updates, status-only upsyncs and the acks lane ran
+        assert any(c == UPDATE for _k, c, _u in streams)
+        assert any(c == 0 and u for _k, c, u in streams)
+        assert core._fleet.stats["acked"] > 0
+
+    asyncio.run(main())
+
+
+def test_buckets_hold_no_device_state():
+    """A bucket is host staging, the fleet batch the one device owner:
+    after a two-bucket core has ticked, nothing a bucket holds is a
+    device array or a compiled function, and every tick was ONE device
+    program for both buckets."""
+
+    async def main():
+        _owners, _oracles, core = await _run_schedule(7, steps=4)
+        assert len(core.buckets) == 2
+        fleet = core._fleet
+        assert isinstance(fleet._state.up_vals, jax.Array)
+        assert fleet.stats["ticks"] == 4
+        jitted = type(fleet._step)
+        for b in core.buckets.values():
+            assert b.stats["ticks"] == fleet.stats["ticks"]
+            for name, value in vars(b).items():
+                assert not any(isinstance(x, (jax.Array, jitted))
+                               for x in jax.tree.leaves(value)), name
 
     asyncio.run(main())
 
@@ -144,36 +225,18 @@ def test_fleet_on_mesh_matches_unsharded_fleet():
     from kcp_tpu.parallel.mesh import SLOTS_AXIS, TENANTS_AXIS, make_mesh
 
     async def main():
-        single, _, _ = await _run_schedule(True, seed=5)
+        single, _, _ = await _run_schedule(5)
         mesh = make_mesh(n_devices=8, tenants=8, slots=1)
-        core = FusedCore(batch_window=0.0005, pipeline="double", fleet=True,
-                         mesh=mesh)
-        owners = [Owner(core, 256, w) for w in WIDTHS]
-        straggler = Owner(core, 3, WIDTHS[0])
-        owners.append(straggler)
-        await core.start()
-        buckets = list({id(o.section.bucket): o.section.bucket
-                        for o in owners}.values())
-        rng = np.random.default_rng(5)
-        for step in range(15):
-            before = {id(b): b.stats["ticks"] for b in buckets}
-            for o in owners:
-                hi = min(100, o.B)
-                n = int(rng.integers(1, min(16, hi + 1)))
-                touched = rng.choice(hi, size=n, replace=False)
-                o.up_vals[touched] = rng.integers(
-                    1, 2**32, (n, o.S), dtype=np.uint32)
-                core.enqueue_many(o.section, False, touched.tolist())
-            assert await wait_until(
-                lambda: all(b.stats["ticks"] > before[id(b)]
-                            for b in buckets), 15)
+        meshed, oracles, core = await _run_schedule(5, mesh=mesh)
         spec = core._fleet._state.up_vals.sharding.spec
         assert tuple(spec) == (TENANTS_AXIS, SLOTS_AXIS), spec
         # fleet rows pad to the row factor: 8-way mesh -> B % 8 == 0
         assert core._fleet.B % 8 == 0 and core._fleet.B > 0
-        await core.stop()
-        meshed = [_stream_bytes(o.stream) for o in owners]
-        assert meshed == single, "mesh-sharded fleet diverged from single-device"
+        assert ([_stream_bytes(o.stream) for o in meshed]
+                == [_stream_bytes(o.stream) for o in single]), (
+            "mesh-sharded fleet diverged from single-device")
+        assert ([_stream_bytes(o.stream) for o in meshed]
+                == [_stream_bytes(o.stream) for o in oracles])
 
     asyncio.run(main())
 
@@ -184,10 +247,10 @@ def test_fleet_on_mesh_matches_unsharded_fleet():
 
 
 def test_fleet_poison_quarantine_is_segment_scoped(monkeypatch):
-    """device.step:poison_row=3 poisons bucket-LOCAL row 3 — the same
-    rows a per-bucket schedule would poison. The fleet bisection must
-    isolate within segments and quarantine ONLY those rows: every
-    co-tenant in every bucket still converges."""
+    """device.step:poison_row=3 poisons bucket-LOCAL row 3 of every
+    bucket. The fleet bisection must isolate within segments and
+    quarantine ONLY those rows: every co-tenant in every bucket still
+    converges."""
     # keep the wall-clock requeue backoff out of the run
     monkeypatch.setattr("kcp_tpu.syncer.core.QUARANTINE_BASE_BACKOFF", 0.001)
 
@@ -195,8 +258,7 @@ def test_fleet_poison_quarantine_is_segment_scoped(monkeypatch):
         faults.install(faults.FaultInjector("device.step:poison_row=3",
                                             seed=0))
         try:
-            core = FusedCore(batch_window=0.0005, pipeline="double",
-                             fleet=True)
+            core = FusedCore(batch_window=0.0005, pipeline="double")
             owners = [Owner(core, 64, w) for w in WIDTHS]
             await core.start()
             fleet = core._fleet
@@ -240,8 +302,7 @@ def test_fleet_systemic_failure_still_propagates():
     async def main():
         faults.install(faults.FaultInjector("device.step:raise", seed=0))
         try:
-            core = FusedCore(batch_window=0.0005, pipeline="serial",
-                             fleet=True)
+            core = FusedCore(batch_window=0.0005, pipeline="serial")
             owner = Owner(core, 64, 16)
             await core.start()
             owner.up_vals[0, 0] = 1
@@ -274,7 +335,7 @@ def test_fleet_shutdown_drains_inflight_window():
     final ticks first, THEN the in-flight fleet wires)."""
 
     async def main():
-        core = FusedCore(batch_window=0.0005, pipeline="double", fleet=True)
+        core = FusedCore(batch_window=0.0005, pipeline="double")
         owners = [Owner(core, 64, w) for w in WIDTHS]
         await core.start()
         touched = list(range(40))
@@ -304,7 +365,7 @@ def test_fleet_segment_counts_feed_quota_ledger():
 
     async def main():
         ledger = QuotaLedger()
-        core = FusedCore(batch_window=0.0005, fleet=True)
+        core = FusedCore(batch_window=0.0005)
         core.ledger = ledger
         o1 = LedgerOwner(core, 64, 16, ("c1", "configmaps"))
         o2 = LedgerOwner(core, 64, 32, ("c2", "widgets"))
@@ -342,7 +403,7 @@ def test_fleet_patch_overflow_doubles_member_capacity():
     member's patch capacity and the level-triggered retick converges."""
 
     async def main():
-        core = FusedCore(batch_window=0.0005, fleet=True)
+        core = FusedCore(batch_window=0.0005)
         owners = [Owner(core, 64, w) for w in WIDTHS]
         for o in owners:
             o.section.bucket.patch_capacity = 8  # force overflow

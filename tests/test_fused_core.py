@@ -294,7 +294,7 @@ def test_idle_flush_head_guard_survives_collect_failure():
 
         collected = []
 
-        class FakeBucket:
+        class FakeFleet:
             def dispatch(self, wire, meta):
                 collected.append(int(np.asarray(wire)[0]))
                 return False
@@ -310,9 +310,9 @@ def test_idle_flush_head_guard_survives_collect_failure():
             def __array__(self, dtype=None, copy=None):
                 return np.array([self.tag])
 
-        b = FakeBucket()
+        core._fleet = FakeFleet()
         wire_a, wire_b = FakeWire(1), FakeWire(2)
-        core._inflight = [(b, wire_a, (0, 8)), (b, wire_b, (0, 8))]
+        core._inflight = [(wire_a, (0, 8)), (wire_b, (0, 8))]
         # park the flusher in its not-ready poll, holding the head tuple
         core._schedule_flush()
         await asyncio.sleep(0.005)

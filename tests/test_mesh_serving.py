@@ -86,10 +86,8 @@ async def drive_scenario(mesh):
         lambda: up.get("configmaps", "cm-5", "default").get("status") == {"ready": True})
 
     bucket = eng._section.bucket
-    # fleet mode (the serving default) holds the resident state on the
-    # whole-fleet ragged batch; per-bucket fallback holds it per bucket
-    state = (eng.core._fleet._state if eng.core._fleet is not None
-             else bucket._state)
+    # the resident state lives on the whole-fleet ragged batch
+    state = eng.core._fleet._state
     down_dump = {
         o["metadata"]["name"]: (o["data"], o.get("status"))
         for o in down.list("configmaps")[0]
@@ -229,8 +227,7 @@ def test_sharded_overflow_and_growth_paths():
                          timeout=20)
         assert bucket.stats["overflows"] >= 1
         assert bucket.B >= 128
-        state = (eng.core._fleet._state if eng.core._fleet is not None
-                 else bucket._state)
+        state = eng.core._fleet._state
         assert state.up_vals.sharding.spec == (TENANTS_AXIS, SLOTS_AXIS)
         await syncer.stop()
 

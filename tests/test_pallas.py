@@ -223,7 +223,7 @@ class TestReconcileStepPallasLane:
                     for o in down.list("configmaps")[0]}
             bucket = eng._section.bucket
             assert bucket.B >= 256
-            assert bucket.use_pallas == use_pallas
+            assert eng.core._fleet.use_pallas == use_pallas
             await syncer.stop()
             return dump
 

@@ -228,20 +228,19 @@ def test_staging_reuse_waits_for_the_step_that_read_the_buffer():
     assert not packed.any()  # zeroed only after the wait
 
 
-@pytest.mark.parametrize("fleet", [True, False], ids=["fleet", "per-bucket"])
-def test_serving_core_commits_the_step_output_with_the_puts(fleet):
-    """Both submit paths gate staging reuse on (packed, acks, wire), over
+def test_serving_core_commits_the_step_output_with_the_puts():
+    """The submit path gates staging reuse on (packed, acks, wire), over
     one slot more than the in-flight window — so on a backend that keeps
     pace the gate never waits."""
     async def main():
-        core = FusedCore(batch_window=0.0005, fleet=fleet)
+        core = FusedCore(batch_window=0.0005)
         owner = RecordingOwner(core, 64)
         await core.start()
         owner.up_vals[:8] = 5
         core.enqueue_many(owner.section, False, list(range(8)))
         bucket = owner.section.bucket
         assert await wait_until(lambda: bucket.stats["ticks"] >= 1, 10)
-        bufs = (core._fleet if fleet else bucket)._wire_bufs
+        bufs = core._fleet._wire_bufs
         assert bufs.depth == PIPELINE_DEPTH + 1
         committed = [p for p in bufs._pending if p is not None]
         assert committed and all(len(p) == 3 for p in committed)
