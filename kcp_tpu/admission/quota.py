@@ -61,6 +61,8 @@ log = logging.getLogger(__name__)
 
 QUOTA_RESOURCE = "resourcequotas"
 UNLIMITED = -1
+#: the device lane's state for a key no fleet segment has reported yet
+NEVER_REPORTED = -1
 
 
 def normalize_hard(hard: dict) -> dict[str, int]:
@@ -148,11 +150,11 @@ class QuotaLedger:
         # resync has to revisit when quota objects disappear
         self._limited_clusters: set[str] = set()
         self._store = None
-        # device-side usage lane: per-key live-row counts computed by
-        # the fused fleet batch's per-segment counters (FusedCore
-        # forwards them on every collect) — admission accounting riding
-        # the device batch instead of a host-side pass
-        self._device_counts: dict[int, int] = {}
+        # device-side usage lane, indexed by slot like _usage: per-key
+        # live-row counts computed by the fused fleet batch's per-segment
+        # counters (FusedCore forwards them on every collect) — admission
+        # accounting riding the device batch instead of a host-side pass
+        self._device = np.full(cap, NEVER_REPORTED, np.int64)
         self._device_stamp = float("-inf")
 
     # ---------------------------------------------------------- interning
@@ -169,6 +171,9 @@ class QuotaLedger:
                 hard = np.full(grow, UNLIMITED, np.int64)
                 hard[:i] = self._hard[:i]
                 self._hard = hard
+                device = np.full(grow, NEVER_REPORTED, np.int64)
+                device[:i] = self._device[:i]
+                self._device = device
                 self._usage[i:] = 0
             self._usage[i] = 0
             self._reserved[i] = 0
@@ -259,7 +264,9 @@ class QuotaLedger:
 
     def set_hard(self, cluster: str, resource: str, limit: int) -> None:
         with self._lock:
-            self._hard[self._slot(cluster, resource)] = limit
+            # intern first: _slot replaces the arrays when it grows them
+            i = self._slot(cluster, resource)
+            self._hard[i] = limit
         if limit != UNLIMITED:
             self._limited_clusters.add(cluster)
 
@@ -282,7 +289,8 @@ class QuotaLedger:
                 if c == cluster:
                     self._hard[i] = desired.pop(res, UNLIMITED)
             for res, n in desired.items():
-                self._hard[self._slot(cluster, res)] = n
+                i = self._slot(cluster, res)
+                self._hard[i] = n
             limited = any(self._hard[i] != UNLIMITED
                           for (c, _r), i in self._idx.items() if c == cluster)
         if limited:
@@ -298,14 +306,29 @@ class QuotaLedger:
 
     # ----------------------------------------------- device-count lane
 
-    def ingest_device_counts(self, counts: dict[tuple[str, str], int]) -> None:
+    def device_slots(self, keys: list[tuple[str, str]]) -> np.ndarray:
+        """Interned slots (int64) for ``keys``, under one lock: what the
+        fused core caches per fleet segment so that a collect hands
+        :meth:`ingest_device_counts` arrays, not keys. Slots are stable
+        for the ledger's lifetime."""
+        with self._lock:
+            return np.fromiter((self._slot(*key) for key in keys),
+                               np.int64, len(keys))
+
+    def ingest_device_counts(self, slots: np.ndarray,
+                             counts: np.ndarray) -> None:
         """Fold the fleet batch's device-side per-segment counters into
         the ledger's device-usage lane.
 
-        ``counts`` maps (cluster, resource) to the number of live synced
-        rows the fused step counted for that key THIS tick — computed on
-        device as a segment-sum riding the same batch as the reconcile
-        decisions, so it costs the serving path nothing. The lane feeds
+        ``slots`` are distinct interned slots (:meth:`device_slots`) and
+        ``counts[j]`` the number of live synced rows the fused step
+        counted for ``slots[j]``'s (cluster, resource) THIS tick (the
+        core has already summed the segments that share a key) —
+        computed on device as a segment-sum riding the same batch as the
+        reconcile decisions. On the host it is one vector pass over the
+        reported slots per collect, whatever the number of tenants: the
+        serving loop pays a few array operations for it, not a Python
+        turn per key. The lane feeds
         (1) the ``quota_usage_device`` gauge, (2) drift detection
         (``quota_device_drift_total`` counts keys where the device lane
         and the ledger disagree — a synced-but-miscounted tenant), and
@@ -316,18 +339,14 @@ class QuotaLedger:
         count exactly when every object of the resource is labeled for
         sync, and any disagreement falls back to the host pass."""
         now = time.monotonic()
-        drift = 0
         with self._lock:
-            for key, n in counts.items():
-                i = self._slot(*key)
-                self._device_counts[i] = int(n)
-                if self._usage.item(i) != n:
-                    drift += 1
+            self._device[slots] = counts
+            drift = int(np.count_nonzero(self._usage[slots] != counts))
             self._device_stamp = now
         REGISTRY.gauge(
             "quota_usage_device",
             "live synced rows counted on-device by the fleet batch's "
-            "per-segment counters").set(sum(counts.values()))
+            "per-segment counters").set(int(counts.sum()))
         if drift:
             REGISTRY.counter(
                 "quota_device_drift_total",
@@ -338,7 +357,8 @@ class QuotaLedger:
         """The device-lane count for a key (None = never reported)."""
         with self._lock:
             i = self._idx.get((cluster, resource))
-            return self._device_counts.get(i) if i is not None else None
+            n = NEVER_REPORTED if i is None else self._device.item(i)
+        return None if n == NEVER_REPORTED else n
 
     def device_counts_agree(self, max_age: float) -> bool:
         """True when every limited key has a device-lane count no older
@@ -348,15 +368,13 @@ class QuotaLedger:
         with self._lock:
             if time.monotonic() - self._device_stamp > max_age:
                 return False
-            limited = [i for i in range(len(self._keys))
-                       if self._hard[i] != UNLIMITED]
-            if not limited:
+            n = len(self._keys)
+            limited = self._hard[:n] != UNLIMITED
+            if not limited.any():
                 return False
-            for i in limited:
-                dc = self._device_counts.get(i)
-                if dc is None or dc != self._usage.item(i):
-                    return False
-        return True
+            device = self._device[:n][limited]
+            return bool(((device != NEVER_REPORTED)
+                         & (device == self._usage[:n][limited])).all())
 
     # ----------------------------------------------------------- repair
 

@@ -248,6 +248,9 @@ class Section:
         # identity the ragged fleet batch carries on device so the
         # per-segment counters can attribute live rows to this section
         self.seg: int | None = None
+        # the registering core (FusedCore.register sets it beside seg):
+        # a release tells it that the set of live sections changed
+        self.core: "FusedCore | None" = None
         self.released = False
 
     def row_for(self, key) -> int:
@@ -287,6 +290,8 @@ class Section:
 
     def release(self) -> None:
         self.released = True
+        if self.core is not None:
+            self.core._segments_version += 1
         for row in self.rows.values():
             self.bucket.free_row(row)
         self.rows.clear()
@@ -696,7 +701,8 @@ class FleetBatch:
     Per-row *segment ids* (the owning section) ride the batch as a
     resident int32 lane; the step returns per-segment live-row counts on
     the wire tail, which the core forwards to the admission quota ledger
-    (admission accounting rides the same batch, no host-side pass).
+    (admission accounting rides the same batch: one vector hand-over a
+    collect, no host-side pass over the tenants).
 
     Degraded mode preserves the PR 2 semantics: a failed step retries
     once wholesale, then bisects *by segment* — the group test is seeded
@@ -1206,7 +1212,19 @@ class FusedCore:
         self._fleet = FleetBatch(self)
         self._segments: dict[int, Section] = {}  # seg id -> section
         self._next_seg = 0
+        # bumped by every register and every Section.release: the one
+        # signal that the segment -> ledger slot map below is out of date
+        self._segments_version = 0
         self.ledger = FusedCore._process_ledger
+        # what the map was built for: (_segments_version, the collected
+        # wire's seg_capacity, the ledger whose slots it holds)
+        self._ledger_map_key: tuple | None = None
+        # the map as a gather plan: accounting segment ids ordered by
+        # ledger slot, the start of each slot's run in that order, and
+        # the distinct slots — one take and one reduceat a collect
+        self._ledger_segs = np.zeros(0, np.int64)
+        self._ledger_starts = np.zeros(0, np.int64)
+        self._ledger_slots = np.zeros(0, np.int64)
         # tick pipelining mode: "double" (default) keeps up to
         # PIPELINE_DEPTH steps in flight — pack N+1 and apply N-1 while
         # the device runs N; "serial" collects every wire in the tick
@@ -1293,32 +1311,61 @@ class FusedCore:
 
     def _publish_fleet_counts(self, seg_counts: np.ndarray) -> None:
         """Forward a collected fleet wire's per-segment live-row counts
-        to the quota ledger, keyed by each owning section's
-        ``fused_ledger_key()`` (sections without one don't account)."""
+        to the quota ledger, summed per owning section's
+        ``fused_ledger_key()`` (sections without one don't account).
+
+        Runs on every collect, so it holds no per-section Python: the
+        segment -> ledger slot map is rebuilt only when a section was
+        registered or released, the wire's segment capacity changed
+        (registrations grow it) or another ledger was attached. An
+        owner's ``fused_ledger_key()`` is therefore read once per
+        rebuild and must not change while its section lives."""
         ledger = self.ledger
         if ledger is None:
             return
-        counts: dict[tuple, int] = {}
-        released = []
-        for seg, section in self._segments.items():
-            if section.released:
-                released.append(seg)
-                continue
-            if seg >= seg_counts.shape[0]:
-                continue
-            keyfn = getattr(section.owner, "fused_ledger_key", None)
-            key = keyfn() if keyfn is not None else None
-            if key is None:
-                continue
-            counts[key] = counts.get(key, 0) + int(seg_counts[seg])
-        for seg in released:
-            del self._segments[seg]
-        if counts:
-            ledger.ingest_device_counts(counts)
+        key = (self._segments_version, seg_counts.shape[0], ledger)
+        if key != self._ledger_map_key:
+            self._rebuild_ledger_map(key)
+        if self._ledger_segs.size:
+            ledger.ingest_device_counts(
+                self._ledger_slots,
+                np.add.reduceat(seg_counts[self._ledger_segs],
+                                self._ledger_starts, dtype=np.int64))
             REGISTRY.counter(
                 "fused_fleet_ledger_updates_total",
                 "device-side per-segment count batches forwarded to the "
                 "quota ledger").inc()
+
+    def _rebuild_ledger_map(self, key: tuple) -> None:
+        """Drop released sections and re-read every live section's ledger
+        key. A section does not account if it has no ``fused_ledger_key``
+        or a None key, or if its segment id lies beyond this wire's
+        capacity (it registered after the wire was submitted)."""
+        for seg in [s for s, sec in self._segments.items() if sec.released]:
+            del self._segments[seg]
+        _version, cap, ledger = key
+        segs, keys = [], []
+        for seg, section in self._segments.items():
+            if seg >= cap:
+                continue
+            keyfn = getattr(section.owner, "fused_ledger_key", None)
+            ledger_key = keyfn() if keyfn is not None else None
+            if ledger_key is not None:
+                segs.append(seg)
+                keys.append(ledger_key)
+        # segments that share a key share a slot and must sum: order the
+        # accounting segments by slot, so each slot is one run
+        slots = ledger.device_slots(keys)
+        order = np.argsort(slots, kind="stable")
+        self._ledger_segs = np.asarray(segs, np.int64)[order]
+        self._ledger_slots, self._ledger_starts = np.unique(
+            slots[order], return_index=True)
+        self._ledger_map_key = key
+        REGISTRY.counter(
+            "fused_fleet_ledger_map_rebuilds_total",
+            "rebuilds of the fleet segment -> quota ledger slot map (a "
+            "section registered or released, the wire's segment capacity "
+            "grew, another ledger attached); flat while serving").inc()
 
     def _closed(self) -> bool:
         return self._started and self._refs == 0
@@ -1376,8 +1423,10 @@ class FusedCore:
         # fleet segment id: stable for the section's lifetime; retired
         # ids are not reused (the capacity is pow2-padded and tiny)
         section.seg = self._next_seg
+        section.core = self
         self._segments[self._next_seg] = section
         self._next_seg += 1
+        self._segments_version += 1
         return section
 
     def register_placement(self, owner, p: int = 8,

@@ -405,8 +405,10 @@ class BatchSyncEngine:
     def fused_ledger_key(self) -> tuple[str, str]:
         """(cluster, resource) key for the fleet batch's device-side
         per-segment counters: the quota ledger's interning key
-        (admission/quota.py ``ingest_device_counts``), so this engine's
-        live synced rows are counted on-device every tick."""
+        (admission/quota.py ``device_slots``), so this engine's live
+        synced rows are counted on-device every tick. The core reads it
+        when the set of sections changes, not per tick: it is fixed for
+        the engine's lifetime."""
         return (self._up_cluster(), str(self.gvr))
 
     def _encode_view(self, obj: dict) -> np.ndarray:
