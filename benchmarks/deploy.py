@@ -5,7 +5,13 @@ file instead of arguments.
 
     Server (durable, WAL on, TLS off, controllers on, auto_publish_apis,
     syncer_mode push) + PhysicalRegistry of fake:// locations
-    + one agent (benchmarks/agents.py) per location
+    + one agent per location: the shape's ``AGENT``, a class of the module
+    the shape names in ``AGENT_MODULE`` (benchmarks.agents by default)
+
+This is the default topology. A configuration names another with a
+``deployment`` key: a module with a ``Deployment`` class that offers what
+run.py, sweep.py, compare.py and controls.py call (benchmarks/README.md
+has the list); ``load`` below finds it.
 
 Everything the harness takes from the program is imported here and in
 benchmarks/compare.py: the server, its in-process client, the registry of
@@ -14,6 +20,7 @@ fake locations, the fused core's counters.
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import shutil
@@ -21,11 +28,17 @@ import subprocess
 import sys
 import time
 
-from benchmarks import agents as agents_mod
 from benchmarks import shapes
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
+
+
+def load(config: dict):
+    """The configuration's topology: the ``Deployment`` class of the module
+    its ``deployment`` key names, this one by default."""
+    return importlib.import_module(
+        config.get("deployment", "benchmarks.deploy")).Deployment
 
 
 def wait_for(pred, timeout: float, what: str, interval: float = 0.1):
@@ -95,12 +108,14 @@ class Deployment:
 
     def bring_up(self, say=print) -> None:
         """The whole set-up, in order, one line of timing each."""
+        agent = self.agent_class()
         steps = (("server up", self.start),
                  (f"{len(self.tenants) * len(self.locations)} locations Ready",
                   self.register),
                  (f"{len(self.population)} residents populated",
                   self.populate),
-                 ("agents started", self.start_agents),
+                 (f"agents started ({agent.__module__}.{agent.__qualname__})",
+                  self.start_agents),
                  ("residents converged", self.settle),
                  (f"warm bursts {self.cfg.get('warm_bursts', [])}", self.warm))
         for what, step in steps:
@@ -138,8 +153,15 @@ class Deployment:
 
         wait_for(ready, timeout, f"{len(pairs)} Clusters Ready", 0.25)
 
+    def agent_class(self):
+        """The location's controller: the shape's ``AGENT``, found in the
+        module the shape names (``AGENT_MODULE``)."""
+        mod = importlib.import_module(
+            getattr(self.shape, "AGENT_MODULE", "benchmarks.agents"))
+        return getattr(mod, self.shape.AGENT)
+
     def start_agents(self) -> None:
-        cls = getattr(agents_mod, self.shape.AGENT)
+        cls = self.agent_class()
         made = [cls(self.registry.resolve(self.fake(t, l)))
                 for t in self.tenants for l in self.locations]
 
@@ -254,14 +276,20 @@ class Deployment:
 
     # ------------------------------------------------------------- traffic
 
-    def loadgen(self, traffic: dict, seed: int, seconds: float, tag: str):
-        """Start the load generator's process (which never imports JAX
-        and is pinned to the CPU besides) and wait until it is ready."""
-        spec = {"server": self.srv.address, "shape": self.cfg["shape"],
+    def loadgen_spec(self, traffic: dict, seed: int, seconds: float) -> dict:
+        """What a generator kind is told: seven keys it has always had,
+        and the whole configuration under ``config``."""
+        return {"server": self.srv.address, "shape": self.cfg["shape"],
                 "seed": int(seed), "seconds": float(seconds),
                 "tenants": self.cfg["logical_clusters"],
                 "per_tenant": self.cfg["resident_per_cluster"],
-                "locations": self.locations, "traffic": traffic}
+                "locations": self.locations, "traffic": traffic,
+                "config": self.cfg}
+
+    def loadgen(self, traffic: dict, seed: int, seconds: float, tag: str):
+        """Start the load generator's process (which never imports JAX
+        and is pinned to the CPU besides) and wait until it is ready."""
+        spec = self.loadgen_spec(traffic, seed, seconds)
         spec_path = os.path.join(self.out_dir, f"loadgen-{tag}.spec.json")
         out_path = os.path.join(self.out_dir, f"loadgen-{tag}.out.json")
         spec["population_file"] = os.path.join(

@@ -4,7 +4,7 @@
 # controls. Costs no chip time; says nothing about speed (every line it
 # prints carries "platform": "cpu").
 #
-#   bash benchmarks/rehearse.sh          # everything (about three minutes)
+#   bash benchmarks/rehearse.sh          # everything (about eight minutes)
 #   bash benchmarks/rehearse.sh quick    # unit tests + one run of each cell
 set -euo pipefail
 cd "$(dirname "$0")/.."

@@ -4,10 +4,12 @@
     python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell, its configuration, its traffic mix, the generator kind, the
-object shape and every metric are found by NAME: BENCHMARK.json ->
-configs/<config>.json, traffic/<traffic>.json, generators/<kind>.py,
-shapes/<shape>.py, end_to_end/<metric>.py, layer_metrics/<metric>.py.
-Nothing in this file names a cell.
+object shape, the topology and every metric are found by NAME:
+BENCHMARK.json -> configs/<config>.json, traffic/<traffic>.json,
+generators/<kind>.py, shapes/<shape>.py, the configuration's
+``deployment`` module (benchmarks/deploy.py by default),
+end_to_end/<metric>.py, layer_metrics/<metric>.py. Nothing in this file
+names a cell, a configuration or a deployment class.
 
 A run is: set-up (server, locations Ready, population, agents, warm-up of
 every shape, ``warmup_s`` of the cell's own traffic unmeasured) -> the
@@ -19,7 +21,8 @@ comparison; the profiler is open only for a slice inside the window.
 Arguments the driver's command never carries: ``--platform cpu`` with
 ``--rehearse`` (tiny sizes of the configuration's ``rehearsal`` block, for
 the CPU sandbox; the line then says ``"platform": "cpu"``), ``--control
-<name>`` (benchmarks/controls.py).
+<name>`` (benchmarks/controls.py), ``--manifest <file>`` (another
+manifest than BENCHMARK.json: benchmarks/tests rehearse toy cells).
 """
 
 from __future__ import annotations
@@ -54,13 +57,14 @@ def load_json(*parts: str) -> dict:
         return json.load(f)
 
 
-def resolve(workload: str, rehearse: bool = False) -> tuple[dict, dict, dict, dict]:
+def resolve(workload: str, rehearse: bool = False,
+            manifest_file: str = "BENCHMARK.json") -> tuple[dict, dict, dict, dict]:
     """(manifest, cell, configuration, traffic) by name; ``rehearse``
     lays each file's ``rehearsal`` block over it."""
-    manifest = load_json(REPO, "BENCHMARK.json")
+    manifest = load_json(REPO, manifest_file)
     cells = {w["name"]: w for w in manifest["workloads"]}
     if workload not in cells:
-        raise SystemExit(f"run.py: no cell {workload!r} in BENCHMARK.json "
+        raise SystemExit(f"run.py: no cell {workload!r} in {manifest_file} "
                          f"(has {sorted(cells)})")
     cell = cells[workload]
     configs = {c["name"]: c for c in manifest["configs"]}
@@ -75,6 +79,12 @@ def resolve(workload: str, rehearse: bool = False) -> tuple[dict, dict, dict, di
 def metric_names(manifest: dict, section: str, cell: str) -> list[str]:
     return [m["name"] for m in manifest[section]
             if "workloads" not in m or cell in m["workloads"]]
+
+
+def generator_extras(out: dict) -> dict:
+    """Everything the generator handed back besides its records: what it
+    measured or counted itself, for the readers (``ctx["generator"]``)."""
+    return {k: v for k, v in out.items() if k != "records"}
 
 
 def read_metrics(package: str, names: list[str], units: dict, ctx: dict) -> dict:
@@ -188,11 +198,13 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--platform", default="tpu", choices=("tpu", "cpu"))
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--control", default="")
+    ap.add_argument("--manifest", default="BENCHMARK.json")
     args = ap.parse_args(argv)
     if args.platform == "cpu" and not args.rehearse:
         raise SystemExit("run.py: --platform cpu is for --rehearse only")
 
-    manifest, cell, config, traffic = resolve(args.workload, args.rehearse)
+    manifest, cell, config, traffic = resolve(args.workload, args.rehearse,
+                                              args.manifest)
     units = {m["name"]: m["unit"]
              for m in manifest["end_to_end"] + manifest["per_layer"]}
 
@@ -215,7 +227,9 @@ def main(argv: list[str] | None = None) -> int:
         say(f"CONTROL {args.control}: a guarantee is broken underneath; "
             f"this run must come out not correct")
 
-    dep = deploy.Deployment(config, args.seed, out_dir)
+    topology = deploy.load(config)
+    dep = topology(config, args.seed, out_dir)
+    say(f"topology {topology.__module__}.{topology.__qualname__}")
     say(f"cell {cell['name']}: seed {args.seed}, window {args.seconds:g}s, "
         f"{config['logical_clusters']} logical clusters x "
         f"{config['locations_per_cluster']} locations x "
@@ -245,7 +259,7 @@ def main(argv: list[str] | None = None) -> int:
         lg = None
 
         # ---- the generator's stamps
-        records = out["records"]
+        records, extras = out["records"], generator_extras(out)
         ops = [r for r in records if w0 <= r["due"] < w1]
         main_ops = [r for r in ops if not r.get("aux")]
         timed = [r for r in main_ops if r["kind"] != "delete"]
@@ -259,8 +273,7 @@ def main(argv: list[str] | None = None) -> int:
                 f"acked={'yes' if r['acked'] else 'no'} error={r['error']}")
         say(f"stamps: {len(main_ops)} operations due in the window "
             f"({len(timed)} timed, {len(lat)} converged, {len(failed)} "
-            f"failed); generator extras "
-            f"{ {k: v for k, v in out.items() if k != 'records'} }")
+            f"failed); generator extras {extras}")
         if lat:
             say("percentiles of due->seen, ms, over "
                 f"{len(lat)} converged + {len(failed_timed)} failed "
@@ -297,7 +310,8 @@ def main(argv: list[str] | None = None) -> int:
                "seconds": args.seconds, "setup_s": setup_s,
                "registry": window_rise, "compiles": comp1 - comp0,
                "gc": gc1 - gc0, "fleet": fleet,
-               "device_kind": device["kind"], "trace": None}
+               "device_kind": device["kind"], "trace": None,
+               "generator": extras}
         device["memory_peak_bytes"] = memory_peak(jax.devices())
         line = {"correct": correct, "attempted": len(main_ops),
                 "failed": len(failed)}
@@ -320,11 +334,16 @@ def main(argv: list[str] | None = None) -> int:
                 "end_to_end",
                 metric_names(manifest, "end_to_end", cell["name"]), units, ctx)
         line["device"] = device
+        line["checks"] = {c.name: {"value": c.value, "limit": c.limit,
+                                   "ok": c.ok} for c in checks}
     finally:
         if lg is not None:
             lg.kill()
         dep.stop()
         shutil.rmtree(out_dir, ignore_errors=True)
+    for c in checks:
+        print(c.line(), file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

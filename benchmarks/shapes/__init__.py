@@ -1,7 +1,8 @@
 """Object shapes: what a deployment's tenants write, how convergence of
 one write is recognised, and what every store must hold afterwards.
 
-A shape is a module named in a configuration's ``shape`` key. Generator
+A shape is a module named in a configuration's ``shape`` key: a bare name
+is a module of this package, a dotted one a module path. Generator
 kinds and the comparison reach objects only through these functions, so
 any traffic mix runs over any shape. Nothing here imports JAX: the load
 generator's process imports shapes too.
@@ -14,7 +15,8 @@ import random
 
 
 def load(name: str):
-    return importlib.import_module(f"benchmarks.shapes.{name}")
+    return importlib.import_module(
+        name if "." in name else f"benchmarks.shapes.{name}")
 
 
 def tenant_names(n: int) -> list[str]:

@@ -53,7 +53,7 @@ def main(argv: list[str] | None = None) -> int:
     out_dir = os.path.join(HERE, ".out")
     shutil.rmtree(out_dir, ignore_errors=True)
     os.makedirs(out_dir)
-    dep = deploy.Deployment(config, args.seed, out_dir)
+    dep = deploy.load(config)(config, args.seed, out_dir)
     rows = []
     try:
         dep.bring_up()
