@@ -77,9 +77,13 @@ TRACEPARENT = "traceparent"
 #: event re-staged the row: the physical cluster's controller),
 #: ``upstatus`` (re-staged → status committed upstream), ``observe``
 #: (commit of an event → its frame handed to the transport of an HTTP
-#: watch stream; every delivered event, spec echo and status alike).
+#: watch stream; every delivered event, spec echo and status alike),
+#: ``restatus`` (outside the telescoping sum: each LATER status trip of
+#: a write whose first status is already up, as a rolling controller
+#: makes them — the downstream status event re-staged the row → that
+#: status committed upstream; no span, a histogram only).
 PHASES = ("write", "propagate", "stage", "tick", "patch", "downstream",
-          "upstatus", "observe")
+          "upstatus", "observe", "restatus")
 
 #: the phase histograms, fetched once: an observation is a dict probe,
 #: a bisect and the histogram's own leaf lock — never the registry's

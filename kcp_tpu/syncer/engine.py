@@ -73,9 +73,23 @@ _TICKS = REGISTRY.counter(
     "kcp_sync_ticks_total", "reconcile ticks across all sync sessions")
 _EVENTS = REGISTRY.counter(
     "kcp_sync_events_total", "informer events drained into tick batches")
+_STATUS_UPSYNCS = REGISTRY.counter(
+    "kcp_sync_status_upsyncs_total",
+    "upstream status writes made by the syncer engines")
+_STATUS_REPEATS = REGISTRY.counter(
+    "kcp_sync_status_upsync_repeats_total",
+    "status upsyncs of a key made before the echo of its previous one "
+    "came up the informer")
+_PATCHES_DEFERRED = REGISTRY.counter(
+    "kcp_sync_patches_deferred_total",
+    "patches of a collected tick skipped because the key's apply was "
+    "still pending")
 
 # states of a key's convergence entry, in timeline order
 _STAGED, _TICKED, _PATCHED, _DOWNSTAGED, _DONE = range(5)
+
+# in the pending-apply table: a patch of another decision was skipped
+_REARM = ("rearm",)
 
 
 def _rv_of(obj: dict | None) -> str:
@@ -98,7 +112,16 @@ class _Convergence:
     phases share their stamp and the phase sum telescopes to
     ``end - start``. ``ctx`` is the committing write's trace context —
     present only for a sampled write, and the only thing the ``conv.*``
-    spans need beyond what every write keeps."""
+    spans need beyond what every write keeps.
+
+    ``_DONE`` is the state of a key whose (first) status is up: ``rv`` is
+    the newest status write of ours whose echo is still to come up the
+    informer ("" when none is), ``t_down`` the arrival of a downstream
+    status event not yet carried up — a LATER status trip of the same
+    write (`restatus`), as a controller that steps a status through a
+    rollout makes them. Such an entry is retired by the echo of our
+    newest write once nothing waits to be carried, or where the two
+    sides are observed equal."""
 
     __slots__ = ("state", "start", "t", "t_down", "rv", "name", "ctx",
                  "counted")
@@ -112,6 +135,14 @@ class _Convergence:
         self.name = name
         self.ctx = ctx
         self.counted = False  # kcp_sync_convergence_seconds fed once
+
+    @classmethod
+    def later_trip(cls, now: float, name: str) -> "_Convergence":
+        """The ``_DONE`` entry of a key whose own entry has been retired,
+        opened by a downstream status event that arrived at ``now``."""
+        ent = cls(now, now, "", name, None)
+        ent.state, ent.counted, ent.t_down = _DONE, True, now
+        return ent
 
 CLUSTER_LABEL = "kcp.dev/cluster"
 OWNED_BY_LABEL = "kcp.dev/owned-by"
@@ -268,7 +299,10 @@ class BatchSyncEngine:
         self.apply_workers = apply_workers
         self.max_apply_retries = max_apply_retries
         self._apply_q: asyncio.Queue | None = None
-        self._apply_pending: set = set()
+        # key -> the (decision, upsync) its pending apply was handed, or
+        # _REARM once a newer patch of ANOTHER decision was skipped behind
+        # it: that key is re-enqueued when the apply ends
+        self._apply_pending: dict = {}
         self._apply_failures: dict = {}  # key -> consecutive failure count
         self._apply_tasks: list[asyncio.Task] = []
         self._retry_tasks: set[asyncio.Task] = set()
@@ -324,9 +358,12 @@ class BatchSyncEngine:
                 # the echo of our own status write(s): the level-triggered
                 # tick can re-emit the upsync before the first echo lands,
                 # so every rv up to the newest one we wrote is ours
-                if _rv_le(rv, ent.rv):
+                if live and ent.rv and _rv_le(rv, ent.rv):
                     if rv == ent.rv:
-                        del self._dirty[key]
+                        if ent.t_down is None:
+                            del self._dirty[key]
+                        else:  # a later status waits to be carried up
+                            ent.rv = ""
                     return
                 del self._dirty[key]
             elif ent.state < _PATCHED:
@@ -339,9 +376,12 @@ class BatchSyncEngine:
             if tw is not None:
                 obs.phase("write", ctx, tw, tm, rv=rv, obj=name)
             obs.phase("propagate", ctx, tm, now, rv=rv)
+        self._admit(key, _Convergence(tw or tm or now, now, rv, name, ctx))
+
+    def _admit(self, key, ent: _Convergence) -> None:
         while len(self._dirty) >= self._dirty_max:
             del self._dirty[next(iter(self._dirty))]
-        self._dirty[key] = _Convergence(tw or tm or now, now, rv, name, ctx)
+        self._dirty[key] = ent
 
     def _on_down_event(self, etype: str, old: dict | None, new: dict | None) -> None:
         key = self._obj_key(new or old)
@@ -350,9 +390,17 @@ class BatchSyncEngine:
         # status write) re-stages the row: the LAST arrival before the
         # upsync is where `downstream` ends and `upstatus` begins
         ent = self._dirty.get(key)
-        if ent is not None and _PATCHED <= ent.state <= _DOWNSTAGED:
+        if ent is None:
+            if new is not None and old is not new:
+                # no write of the tenant's is under way: a status the
+                # location wrote after the first one went up (and its
+                # echo retired the entry) opens a status trip of its own
+                self._admit(key, _Convergence.later_trip(time.monotonic(),
+                                                         key[1]))
+        elif ent.state >= _PATCHED:
             ent.t_down = time.monotonic()
-            ent.state = _DOWNSTAGED
+            if ent.state < _DONE:  # _DONE: a later status trip opens
+                ent.state = _DOWNSTAGED
         if self.fused:
             if self._section is not None:
                 self.core.enqueue(self._section, True, key)
@@ -395,6 +443,10 @@ class BatchSyncEngine:
             self._converged(ent, end)
             if gone:
                 obs.phase("downstream", ent.ctx, ent.t, end, rv=ent.rv)
+                del self._dirty[key]
+        elif ent.state == _DONE:
+            ent.t_down = None  # nothing left to carry up
+            if not ent.rv:
                 del self._dirty[key]
 
     # ----------------------------------------------- fused-core interface
@@ -463,12 +515,22 @@ class BatchSyncEngine:
                 ent = dirty.get(key)
                 if ent is not None and ent.state == _STAGED:
                     self._ticked(ent, t0, t1, tick_n)
+        pending = self._apply_pending
         for key, code, upsync in patches:
-            if key in self._apply_pending:
+            handed = pending.get(key)
+            if handed is not None:
+                # an apply reads the live caches, so a pending one of the
+                # same decision does this patch's work too; one of
+                # another decision (a status upsync pending when the
+                # spec patch arrives) does not, and ticks are
+                # event-driven: a fleet gone quiet never re-emits it
+                _PATCHES_DEFERRED.inc()
+                if handed != (code, upsync):
+                    pending[key] = _REARM
                 continue
             if self._apply_failures.get(key, 0) > self.max_apply_retries:
                 continue  # dropped until a new event resets the budget
-            self._apply_pending.add(key)
+            pending[key] = (code, upsync)
             self._apply_q.put_nowait((key, code, upsync))
 
     def fused_overflow(self) -> None:
@@ -507,10 +569,13 @@ class BatchSyncEngine:
                 # pending holds until the apply FINISHES: a slow apply
                 # must suppress the level-triggered re-patches every tick
                 # emits for its still-divergent row, or duplicates of one
-                # slow key eat the whole worker pool. Anything that
-                # changed mid-apply is recovered by the next tick — the
-                # row is still divergent, pending is clear, it re-patches
-                self._apply_pending.discard(key)
+                # slow key eat the whole worker pool. A skipped patch of
+                # ANOTHER decision is not covered by this apply: the key
+                # is re-enqueued, so a tick re-decides its row without
+                # waiting for some other key's event
+                if (self._apply_pending.pop(key, None) is _REARM
+                        and self._section is not None):
+                    self.core.enqueue(self._section, False, key)
                 self._apply_q.task_done()
 
     async def _apply_async(self, key, code: int, upsync: bool) -> bool:
@@ -555,7 +620,7 @@ class BatchSyncEngine:
     async def _retry_apply(self, key, code: int, upsync: bool, delay: float) -> None:
         await asyncio.sleep(delay)
         if key not in self._apply_pending:
-            self._apply_pending.add(key)
+            self._apply_pending[key] = (code, upsync)
             self._apply_q.put_nowait((key, code, upsync))
 
     # ------------------------------------------------------------- rows
@@ -818,13 +883,14 @@ class BatchSyncEngine:
                     written = self._up_update_status(
                         self.gvr, fresh, namespace=ns)
                 applied = True
+                _STATUS_UPSYNCS.inc()
+                # the status is committed upstream: a timeline ends at
+                # that commit's own stamp where the upstream can tell it
+                # (what `observe` of the status event starts from), else
+                # at the write's return
+                end = (getattr(self.upstream, "last_commit", None)
+                       or time.monotonic())
                 if ent is not None and _PATCHED <= ent.state <= _DOWNSTAGED:
-                    # the status is committed upstream: the timeline ends
-                    # at that commit's own stamp where the upstream can
-                    # tell it (what `observe` of the status event starts
-                    # from), else at the write's return
-                    end = (getattr(self.upstream, "last_commit", None)
-                           or time.monotonic())
                     t_down = ent.t_down or ent.t
                     obs.phase("downstream", ent.ctx, ent.t, t_down, rv=ent.rv)
                     obs.phase("upstatus", ent.ctx, t_down, end, rv=ent.rv,
@@ -832,9 +898,16 @@ class BatchSyncEngine:
                     self._converged(ent, end)
                     # kept until the write's own echo comes up the informer
                     ent.state = _DONE
+                    ent.t_down = None
                     ent.rv = _rv_of(written)
                 elif ent is not None and ent.state == _DONE:
-                    ent.rv = _rv_of(written)  # a repeat before the echo
+                    if ent.t_down is None:
+                        _STATUS_REPEATS.inc()  # a repeat before the echo
+                    else:
+                        # a later status of the same write: its own trip
+                        obs.phase("restatus", None, ent.t_down, end)
+                        ent.t_down = None
+                    ent.rv = _rv_of(written)
         return applied
 
     def _ensure_namespace(self, ns: str) -> None:
