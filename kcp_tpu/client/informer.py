@@ -8,6 +8,11 @@ design:
 - handlers receive (event_type, old, new) and are called on the event
   loop; controllers usually just enqueue keys — the heavy lifting happens
   in the batched reconcile tick
+- delivery follows the watch: an in-process store watch hands its events
+  over in the store's fan-out pass (``Watch.set_sink``: handlers run in
+  the loop pass that flushed the event, once its commit window is
+  synced), so handlers there must not assume a task of their own; a
+  watch fed over the wire (``RestWatch``) is pulled by the pump task
 - a periodic resync replays the full cache as MODIFIED events, the
   level-triggered safety net that bounds missed-event damage
   (reference resyncPeriod=10h, pkg/syncer/syncer.go:27)
@@ -29,6 +34,15 @@ from ..utils.trace import REGISTRY
 from .client import Client
 
 log = logging.getLogger(__name__)
+
+_EVENTS = REGISTRY.counter(
+    "informer_events_total",
+    "watch events informers dispatched to their caches and handlers")
+_PUSHED = REGISTRY.counter(
+    "informer_pushed_events_total",
+    "of those, events handed over by the store's fan-out pass itself "
+    "(an in-process watch's push sink); the rest were pulled by the "
+    "informer's pump task from a watch fed over the wire")
 
 Handler = Callable[[str, dict | None, dict | None], None]
 IndexFunc = Callable[[dict], Iterable[str]]
@@ -83,6 +97,12 @@ class Informer:
         self._task: asyncio.Task | None = None
         self._resync_task: asyncio.Task | None = None
         self._watch = None
+        # push delivery (_attach): the watch's fan-out pass dispatches,
+        # the pump sleeps on _closed; _delivered counts what was
+        # dispatched since the pump last looked, for its resume decision
+        self._pushing = False
+        self._closed = asyncio.Event()
+        self._delivered = 0
         self._stopping = False
         self.rewatch_backoff = 0.2  # reflector retry pacing on stream loss
         self.retry_after_cap = 30.0  # ceiling on server Retry-After hints
@@ -225,6 +245,7 @@ class Informer:
                 self.gvr, self.namespace, self.selector, since_rv=rv
             )
         self._synced.set()
+        self._attach()
         self._task = asyncio.create_task(self._pump())
         if self.resync_period:
             self._resync_task = asyncio.create_task(self._resync_loop())
@@ -265,8 +286,43 @@ class Informer:
         w.close()
         return False
 
+    def _attach(self) -> None:
+        """Take the (re)opened watch's events in the store's fan-out
+        pass where the watch offers that (``set_sink``: an in-process
+        ``store.Watch``) — no task to wake between a durable event and
+        its handlers. What the watch buffered since it was opened (a
+        ``since_rv`` replay, writes racing the list) is handed over
+        first, in RV order, by the attach itself. A watch without the
+        push half (``RestWatch``) is pulled by :meth:`_pump`."""
+        set_sink = getattr(self._watch, "set_sink", None)
+        self._pushing = set_sink is not None
+        if self._pushing:
+            self._closed.clear()
+            set_sink(self._pushed, self._closed.set)
+
+    def _pushed(self, batch: list[Event]) -> None:
+        """The watch's push sink: one fan-out pass's events, in RV
+        order. Raising closes the watch, which wakes the pump."""
+        self._deliver(batch)
+        _PUSHED.inc(len(batch))
+
+    def _deliver(self, batch: list[Event]) -> None:
+        for ev in batch:
+            self._dispatch(ev)
+            if ev.rv:
+                self._rv = max(self._rv, ev.rv)
+            self._delivered += 1
+        _EVENTS.inc(len(batch))
+
     async def _pump(self) -> None:
         """Dispatch watch events; on stream end, resume or re-list.
+
+        A watch with the push half is dispatched by the store's fan-out
+        pass (:meth:`_attach`); this task then only sleeps until the
+        watch is closed — a fault drill's drop, an eviction, a dispatch
+        that raised, the store's own close — and delivers what the watch
+        still held, as iteration would have. Every other watch is
+        iterated here.
 
         The reflector loop of client-go: an in-process store Watch only
         ends when closed, but a REST watch ends on connection drop, an
@@ -285,11 +341,17 @@ class Informer:
             delivered = 0
             err: BaseException | None = None
             try:
-                async for ev in self._watch:
-                    self._dispatch(ev)
-                    if ev.rv:
-                        self._rv = max(self._rv, ev.rv)
-                    delivered += 1
+                if self._pushing:
+                    await self._closed.wait()
+                    self._deliver(self._watch.detach())
+                    delivered, self._delivered = self._delivered, 0
+                else:
+                    async for ev in self._watch:
+                        self._dispatch(ev)
+                        if ev.rv:
+                            self._rv = max(self._rv, ev.rv)
+                        delivered += 1
+                        _EVENTS.inc()
                 delay = self.rewatch_backoff
             except Exception as e:  # noqa: BLE001 — expired window / transport error
                 err = e
@@ -329,6 +391,7 @@ class Informer:
                         self.gvr, self.namespace, self.selector,
                         since_rv=rv)
                     fast_budget = 1
+                self._attach()
                 delay = self.rewatch_backoff
             except Exception as err2:  # noqa: BLE001 — server down or shedding load
                 # an overloaded frontend's 429 hint paces the next lap;
@@ -402,6 +465,10 @@ class Informer:
                     pass
         self._task = self._resync_task = None
         if self._watch is not None:
+            if self._pushing:
+                # detach first: close() flushes the store's pending
+                # events, and a stopped informer dispatches none
+                self._watch.clear_sink()
             self._watch.close()
             self._watch = None
 

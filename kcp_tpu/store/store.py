@@ -700,6 +700,7 @@ class LogicalStore:
         # watches with a push sink (Watch.set_sink) that hold events the
         # sink has not been handed yet — O(touched), never O(watches)
         self._sink_dirty: list[Watch] = []
+        self._sinking = False  # inside _run_sinks: no nested delivery
         self._emit_batch = max(1, int(os.environ.get("KCP_STORE_EMIT_BATCH", "128")))
         # exact label interning for the vectorized matchers: distinct
         # (key, value) pairs / keys get sequential nonzero uint32 ids, so
@@ -1922,6 +1923,9 @@ class LogicalStore:
             # scheduling (watch()/drain() still flush lazily, and
             # sync-context callers never scheduled here anyway)
             return
+        self._flush_next_pass()
+
+    def _flush_next_pass(self) -> None:
         try:
             loop = asyncio.get_running_loop()
         except RuntimeError:
@@ -1945,40 +1949,57 @@ class LogicalStore:
             return
         if self._pending:
             self._fanout_pending()
-        if deliver and self._sink_dirty:
+        if deliver and self._sink_dirty and not self._sinking:
             self._run_sinks()
 
     def _run_sinks(self) -> None:
         """Hand every touched push-served watch its buffered events:
         one ``sink(batch)`` call per watch per pass, in the loop pass of
-        the flush. Nothing reaches a sink while a commit window holds
+        the flush. No sink is called while a commit window holds
         unsynced records — a lazy flush (``pending()``, a new
         subscription, another consumer's ``__anext__``) may have fanned
         those events out early; they wait in the watch until
-        ``_gc_flush`` has synced the window and flushes again. A sink
-        that raises closes its own watch, like a dropped stream, and
-        never breaks the pass for the others."""
-        w = self._gc_window
-        if w is not None and w.recs:
-            return
+        ``_gc_flush`` has synced the window and flushes again. The
+        window is looked at before EVERY sink, not once a pass: a sink
+        is program code (an informer's handlers) that may write into
+        this store and flush lazily, which puts an unsynced event into
+        the watches this pass has not reached yet — they are held back
+        for that window's flush. What a sink writes or flushes is never
+        delivered recursively (a flush from inside a sink leaves the
+        sinks to the next pass). A sink that raises closes its own
+        watch, like a dropped stream, and never breaks the pass for the
+        others."""
         dirty, self._sink_dirty = self._sink_dirty, []
-        for watch in dirty:
-            watch._sink_marked = False
-            sink = watch._sink
-            if sink is None or not watch._events:
-                continue
-            batch = list(watch._events)
-            watch._events.clear()
-            try:
-                sink(batch)
-            except Exception as e:  # noqa: BLE001 — one stream's fault
-                log.log(logging.DEBUG if isinstance(e, ConnectionError)
-                        else logging.WARNING,
-                        "watch %s/%s: push sink failed (%s: %s); closing "
-                        "the watch", watch.resource, watch.cluster,
-                        type(e).__name__, e)
-                watch._sink = None
-                watch.close()  # on_close tells the stream's coroutine
+        self._sinking = True
+        try:
+            for i, watch in enumerate(dirty):
+                w = self._gc_window
+                if w is not None and w.recs:
+                    # still marked, so no _push has queued them twice
+                    self._sink_dirty.extend(dirty[i:])
+                    return
+                watch._sink_marked = False
+                sink = watch._sink
+                if sink is None or not watch._events:
+                    continue
+                batch = list(watch._events)
+                watch._events.clear()
+                try:
+                    sink(batch)
+                except Exception as e:  # noqa: BLE001 — one stream's fault
+                    log.log(logging.DEBUG if isinstance(e, ConnectionError)
+                            else logging.WARNING,
+                            "watch %s/%s: push sink failed (%s: %s); "
+                            "closing the watch", watch.resource,
+                            watch.cluster, type(e).__name__, e)
+                    watch._sink = None
+                    watch.close()  # on_close tells the watch's consumer
+        finally:
+            self._sinking = False
+        if self._sink_dirty and not self._flush_scheduled:
+            # touched from inside a sink: the next pass comes for them
+            # (or, past it, the flush of the window that holds them back)
+            self._flush_next_pass()
 
     def _fanout_pending(self) -> None:
         batch, self._pending = self._pending, []

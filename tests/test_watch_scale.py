@@ -928,6 +928,113 @@ def test_push_sink_failure_closes_only_its_own_watch(tmp_path):
     asyncio.run(run())
 
 
+@pytest.mark.parametrize("durable", [False, True])
+def test_push_stream_behind_a_writing_informer_waits_for_the_sync(
+        durable, tmp_path):
+    """An informer is a sink whose handlers are program code: one that
+    answers inside the fan-out pass (``get`` + ``update_status`` into the
+    store it is fed from) and then flushes lazily puts an UNSYNCED event
+    into the watches the pass has not reached yet. The HTTP stream behind
+    it is handed nothing while that window is open, and the answer is
+    never delivered recursively: it arrives with the next flush."""
+    from kcp_tpu.client import Client
+
+    async def run() -> None:
+        store = LogicalStore(
+            wal_path=str(tmp_path / "w.wal") if durable else None)
+        store._gc_linger_s = 30.0  # windows stay open until flushed
+        client = Client(store, "t0")
+        puller = store.watch("configmaps")
+        in_handler = answers = 0
+
+        def answer(etype, old, new) -> None:
+            nonlocal in_handler, answers
+            assert in_handler == 0, "delivered recursively"
+            in_handler += 1
+            try:
+                if "status" not in new:
+                    obj = client.get("configmaps", new["metadata"]["name"],
+                                     "default")
+                    obj["status"] = {"seen": True}
+                    client.update_status("configmaps", obj, "default")
+                    answers += 1
+                    assert puller.pending() >= 1  # lazy flush, in the pass
+            finally:
+                in_handler -= 1
+
+        agent = Informer(client, "configmaps")
+        agent.add_handler(answer)
+        await agent.start()
+        handler = RestHandler(store, default_scheme(), admission=None)
+        stream = _PushStream()  # subscribed behind the informer
+        task = await _serve_watch(handler, stream)
+        try:
+            store.create("configmaps", "t0", _cm("a", "t0"))
+            if durable:
+                store._gc_flush(store._gc_window)  # sync, then the pass
+            else:
+                store._flush_events()
+            assert answers == 1
+            if durable:
+                window = store._gc_window
+                assert window is not None and len(window.recs) == 1
+                assert stream.frames == []  # held whole: a window is open
+                synced = store._wal_sync_total.value
+                store._gc_flush(window)
+                assert store._wal_sync_total.value == synced + 1
+            frames = stream.decoded()
+            assert [f["type"] for f in frames] == ["ADDED", "MODIFIED"]
+            assert "status" not in frames[0]["object"]
+            assert frames[1]["object"]["status"] == {"seen": True}
+            assert _rv(frames[0]) < _rv(frames[1])
+        finally:
+            task.cancel()
+            await asyncio.gather(task, return_exceptions=True)
+            await agent.stop()
+            store.close()
+
+    asyncio.run(run())
+
+
+def test_a_flush_from_inside_a_sink_leaves_the_sinks_to_the_next_pass():
+    """What a sink writes is fanned out by a lazy flush at once, but no
+    sink — its own or another's — runs inside a sink: the touched
+    watches are queued, and the pass the store schedules delivers."""
+
+    async def run() -> None:
+        store = LogicalStore()
+        first, second = store.watch("configmaps"), store.watch("configmaps")
+        got: dict[str, list[list[str]]] = {"first": [], "second": []}
+        depth = 0
+
+        def sink(name):
+            def take(batch) -> None:
+                nonlocal depth
+                assert depth == 0, "a sink ran inside a sink"
+                depth += 1
+                got[name].append([e.name for e in batch])
+                if name == "first" and len(got["first"]) == 1:
+                    store.create("configmaps", "t0", _cm("b", "t0"))
+                    store._flush_events()
+                depth -= 1
+            return take
+
+        first.set_sink(sink("first"))
+        second.set_sink(sink("second"))
+        store.create("configmaps", "t0", _cm("a", "t0"))
+        store._flush_events()
+        # the pass is over: `second`, not reached when `b` was fanned
+        # out, took both; `first` waits for the pass that is due
+        assert got == {"first": [["a"]], "second": [["a", "b"]]}
+        assert store._sink_dirty == [first] and store._flush_scheduled
+        await asyncio.sleep(0)
+        assert got == {"first": [["a"], ["b"]], "second": [["a", "b"]]}
+        assert store._sink_dirty == []
+        store.close()
+
+    asyncio.run(run())
+
+
 class _PullOnlyWatch:
     """The REST client's watch surface (server/rest.py RestWatch): it
     is fed by a network reader of its own, so it offers iteration,
