@@ -1,0 +1,10 @@
+"""Mean, over every store verb of the storage frontend in the window, of ``remote_store_call_seconds``: connection borrowed -> the backend's answer returned (the request's trip, the backend's whole handling, the answer parsed).
+Read from the frontend's own ``/metrics``, scraped at the window's
+edges (``ctx["generator"]["frontend"]``, benchmarks/child_scrape.py);
+None where the topology has no frontend or the frontend no such histogram."""
+
+from benchmarks import child_scrape
+
+
+def read(ctx):
+    return child_scrape.mean_ms(ctx, "frontend", "remote_store_call_seconds")

@@ -130,16 +130,20 @@ class RestHandler:
         # external-storage frontends: every store verb is a blocking HTTP
         # round trip to the backend, so it must not run on the serving
         # loop (one slow backend call would freeze every request, watch
-        # stream, and health probe). A small pool bounds concurrency;
-        # in-process stores stay inline (in-memory, and the race guard
-        # expects loop-thread affinity).
+        # stream, and health probe). A small pool bounds concurrency, a
+        # thread for every backend connection the store can have out at
+        # once (``io_concurrency``: a thread more would wait for a
+        # connection, a thread fewer would strand one); in-process
+        # stores stay inline (in-memory, and the race guard expects
+        # loop-thread affinity).
         self._remote = getattr(store, "is_remote", False)
         self._store_pool = None
         if self._remote:
             from concurrent.futures import ThreadPoolExecutor
 
             self._store_pool = ThreadPoolExecutor(
-                max_workers=8, thread_name_prefix="store-io")
+                max_workers=getattr(store, "io_concurrency", 8),
+                thread_name_prefix="store-io")
         # encode-once serving (KCP_ENCODE_CACHE, in-process CoW stores
         # only): list responses splice cached item bytes, single GETs
         # splice the cached body, and the watch relay threads pre-encoded
@@ -238,10 +242,12 @@ class RestHandler:
         # O(sockets) buffered writes of shared bytes per tick instead of
         # a write+drain round trip per watcher per event batch. =0 keeps
         # the per-batch send_raw_many path for A/B (bench.py --watchers).
-        # Both govern the PULL relay only (a remote store's watch, a
-        # duck-typed stream): a watch on a local store is pushed to its
-        # socket by the store's fan-out pass itself (_watch), once per
-        # commit window, and pays no coalescing tick.
+        # Both govern only a PULL relay of encode-once lines (a local
+        # store's watch that offers no push half): a watch on a local
+        # store is pushed to its socket by the store's fan-out pass
+        # itself (_watch), once per commit window, and pays no
+        # coalescing tick; a storage frontend's relay re-serializes the
+        # backend's events on the dict path and never comes here.
         self._buffer_max = int(os.environ.get(
             "KCP_WATCH_BUFFER_MAX", str(2 * 1024 * 1024)))
         self._coalescer = None
@@ -269,6 +275,11 @@ class RestHandler:
         self._stream_events = REGISTRY.counter(
             "watch_stream_events_total",
             "watch events encoded for and handed to HTTP watch streams")
+        self._relay_seconds = REGISTRY.histogram(
+            "watch_relay_seconds",
+            "one watch event through a storage frontend: from its arrival "
+            "off the backend's stream to its frame handed to the tenant's "
+            "stream (a parse, the watch's queue, the pull relay, a dump)")
         # per-server bookmark cadence (KCP_WATCH_BOOKMARK_S): how often
         # an idle stream that asked for bookmarks gets a progress marker
         # at the store RV — what keeps a quiet informer's resume point
@@ -294,8 +305,11 @@ class RestHandler:
         """Run a store call; offloaded to the I/O pool for remote stores."""
         if self._store_pool is None:
             return fn(*args, **kwargs)
+        # a remote store notes the submit itself (``offloaded``), so
+        # that its queue histogram holds the wait for a thread
+        job = getattr(self.store, "offloaded", functools.partial)
         return await asyncio.get_running_loop().run_in_executor(
-            self._store_pool, functools.partial(fn, *args, **kwargs))
+            self._store_pool, job(fn, *args, **kwargs))
 
     def _forbidden(self, req, action: str) -> Response:
         user = self.authenticator.user_for(req.headers)
@@ -1571,13 +1585,28 @@ class RestHandler:
                             raise _SlowWatcher()
                     else:
                         await send_raw(lines)
-                elif send_many is not None:
-                    await send_many(
-                        [{"type": e.type, "object": e.object} for e in batch])
                 else:
+                    # the dict path: a storage frontend (its store's
+                    # events are the backend's lines, parsed), a store
+                    # without the encode cache
+                    if send_many is not None:
+                        sent = await send_many(
+                            [{"type": e.type, "object": e.object}
+                             for e in batch])
+                        self._stream_bytes.inc(sent or 0)
+                    else:
+                        for e in batch:
+                            await stream.send_json({"type": e.type,
+                                                    "object": e.object})
+                    self._stream_events.inc(len(batch))
+                    # the relay hop of a storage frontend: each event
+                    # from its arrival off the backend's stream
+                    # (RestWatch._feed) to its frame handed over here
+                    now = time.monotonic()
                     for e in batch:
-                        await stream.send_json({"type": e.type,
-                                                "object": e.object})
+                        ta = e.__dict__.get("_ta")
+                        if ta is not None:
+                            self._relay_seconds.observe(now - ta)
                 stamp_observed(batch)
                 self._relay_batches.inc()
 

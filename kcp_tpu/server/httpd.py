@@ -217,20 +217,22 @@ class StreamResponse:
         self._writer.write(f"{len(data):x}\r\n".encode() + data + b"\r\n")
         await self._writer.drain()
 
-    async def send_json_many(self, objs) -> None:
+    async def send_json_many(self, objs) -> int:
         """All objects as ndjson lines in ONE chunk + one drain — the
         watch relay's wire-level fan-out batching. Clients reassemble by
         newline (RestWatch already splits chunk payloads on ``\\n``), so
         framing is unchanged; a burst of N events costs one syscall
-        instead of N."""
+        instead of N. Returns the bytes of the lines (the frame's
+        payload), for the relay's byte counter."""
         assert self._writer is not None
         if not objs:
-            return
+            return 0
         data = b"".join(json.dumps(o).encode() + b"\n" for o in objs)
         self._writer.write(f"{len(data):x}\r\n".encode() + data + b"\r\n")
         _FLUSHES.inc()
         _FLUSH_BATCH.observe(len(objs))
         await self._writer.drain()
+        return len(data)
 
     async def send_raw_many(self, lines) -> None:
         """Pre-encoded newline-terminated JSON lines in ONE chunk + one

@@ -194,6 +194,10 @@ class RestWatch:
     # source name for peer-scoped link faults (link.partition/link.delay);
     # the destination is the watched server's host:port
     link_src = "watch"
+    # time.monotonic() of the chunk being reassembled (_feed); rides
+    # each of its events out of band as ``_ta``, where a storage
+    # frontend's relay reads it (watch_relay_seconds)
+    _arrived = None
 
     def __init__(self, host: str, port: int, path: str, resource: str,
                  token: str = "", ssl_context=None,
@@ -312,6 +316,7 @@ class RestWatch:
         carries many events). The incomplete trailing line — and any
         multi-byte UTF-8 sequence the chunk boundary split — carries
         over to the next chunk."""
+        self._arrived = time.monotonic()
         lines = (self._buf + self._decoder.decode(chunk)).split("\n")
         self._buf = lines.pop()  # partial trailing line (usually empty)
         for line in lines:
@@ -366,7 +371,7 @@ class RestWatch:
         if self._session is not None:
             self._session.note(self._session_cluster
                                or meta.get("clusterName", ""), rv)
-        self._events.put_nowait(Event(
+        ev = Event(
             type=msg["type"],
             resource=self.resource,
             cluster=meta.get("clusterName", ""),
@@ -374,7 +379,9 @@ class RestWatch:
             name=meta.get("name", ""),
             object=obj,
             rv=rv,
-        ))
+        )
+        ev.__dict__["_ta"] = self._arrived
+        self._events.put_nowait(ev)
 
     def __aiter__(self) -> "RestWatch":
         self._ensure_started()

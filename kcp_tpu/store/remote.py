@@ -33,8 +33,11 @@ from __future__ import annotations
 import contextlib
 import os
 import threading
+import time
 
+from .. import obs
 from ..utils.errors import UnavailableError
+from ..utils.trace import REGISTRY
 from .selectors import LabelSelector
 from .store import WILDCARD
 
@@ -90,6 +93,13 @@ class ConnectionPool:
     def breaker(self):
         """The peer's shared circuit breaker (one per pool)."""
         return self._proto._breaker
+
+    @property
+    def max_inflight(self) -> int:
+        """Borrows that can be out at once (``cap`` x ``depth``): what a
+        thread pool in front of this pool is sized to, so that neither
+        threads wait for connections nor connections for threads."""
+        return self._max_inflight
 
     @property
     def ssl_context(self):
@@ -181,11 +191,65 @@ class RemoteStore:
         # LogicalStore duck-type attributes the handler/client read
         self.openapi_doc: dict | None = None
         self.namespace_lifecycle = False  # backend stamps finalizers
+        # where a verb waits on its way to the backend: for a thread of
+        # the handler's store-I/O pool and a pooled connection (from the
+        # submit `offloaded` noted, or from the verb's own entry where
+        # nobody noted one), then for the backend's answer
+        self._submitted = threading.local()
+        self._queue_seconds = REGISTRY.histogram(
+            "remote_store_queue_seconds",
+            "one remote-store verb from its submit to the store-I/O pool "
+            "to a pooled backend connection borrowed: the wait for a "
+            "thread plus the wait for a connection")
+        self._call_seconds = REGISTRY.histogram(
+            "remote_store_call_seconds",
+            "one remote-store verb from its connection borrowed to the "
+            "backend's answer returned")
 
     # ---------------------------------------------------------- plumbing
 
-    def _call(self, cluster: str, verb: str, *args, **kwargs):
+    @property
+    def io_concurrency(self) -> int:
+        """Verbs that can be in flight at once: the size of the pool of
+        threads the handler runs them on."""
+        return self._pool.max_inflight
+
+    def offloaded(self, fn, *args, **kwargs):
+        """``fn(*args, **kwargs)`` as a callable for the store-I/O pool,
+        with the instant of this call (the submit) noted for the first
+        verb it runs: that verb's ``remote_store_queue_seconds`` then
+        holds the wait for a thread too."""
+        t_submit = time.monotonic()
+
+        def run():
+            self._submitted.t = t_submit
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                self._submitted.t = None
+
+        return run
+
+    @contextlib.contextmanager
+    def _client(self, cluster: str, verb: str):
+        """A pooled client scoped to ``cluster`` for one verb, the wait
+        for it and the verb itself timed."""
+        t0 = getattr(self._submitted, "t", None)
+        if t0 is None:
+            t0 = time.monotonic()
+        else:
+            self._submitted.t = None  # a later verb of this job waits anew
         with self._pool.client(cluster) as c:
+            t1 = time.monotonic()
+            self._queue_seconds.observe(t1 - t0)
+            try:
+                with obs.annotate("kcp.remote.call", verb=verb):
+                    yield c
+            finally:
+                self._call_seconds.observe(time.monotonic() - t1)
+
+    def _call(self, cluster: str, verb: str, *args, **kwargs):
+        with self._client(cluster, verb) as c:
             return getattr(c, verb)(*args, **kwargs)
 
     # ------------------------------------------------------------- verbs
@@ -219,7 +283,7 @@ class RemoteStore:
 
     def delete(self, resource: str, cluster: str, name: str,
                namespace: str = "") -> None:
-        with self._pool.client(cluster) as client:
+        with self._client(cluster, "delete") as client:
             if cluster == WILDCARD:
                 # RestClient refuses wildcard deletes (an in-process
                 # store needs an explicit tenant), but here the backend's
@@ -250,7 +314,7 @@ class RemoteStore:
 
     @property
     def resource_version(self) -> int:
-        with self._pool.client(WILDCARD) as client:
+        with self._client(WILDCARD, "version") as client:
             body = client._request("GET", "/version")
         if "resourceVersion" not in body:
             # an authz'd backend withholds the RV from tokens lacking the
@@ -266,7 +330,7 @@ class RemoteStore:
         return self._call(WILDCARD, "resources")
 
     def clusters(self) -> list[str]:
-        with self._pool.client(WILDCARD) as client:
+        with self._client(WILDCARD, "clusters") as client:
             body = client._request("GET", "/clusters")
         return list(body.get("clusters", []))
 
