@@ -26,6 +26,21 @@ every device decision is re-verified against the real objects before a
 write (the host escape hatch), and a periodic informer resync replays the
 caches (reference: resyncPeriod, pkg/syncer/syncer.go:27).
 
+One decision does not wait for the device: on the fused backend the
+status that answers a write this engine carried down (the key's
+convergence entry stands between its downstream write and its first
+status) is handed to the applier by the event that brought it
+(``_on_down_event``, as the reference compares in its informer handler,
+statussyncer.go:32-36). The row still rides the next tick, which is that
+write's backstop: it re-emits the upsync if the apply failed, was skipped
+behind a pending apply of the key, or the status changed again. A later
+status of the same object (a controller that reports progress) has only
+the tick: nobody this engine knows of waits for it, and two that meet
+there go up as one write. A key whose location was seen to report
+progress keeps the tick for the answer to its next write too
+(``_reports``): its first status is not what that write's author waits
+for, and the slower trip is where it meets the second.
+
 Decision application parity with the reference:
 - CREATE/UPDATE downstream: strip volatile metadata + ownerReferences +
   status, ensure namespace, create-then-update-on-conflict
@@ -51,6 +66,7 @@ from ..client import Client, Informer
 from ..ops.diff import (
     DECISION_CREATE,
     DECISION_DELETE,
+    DECISION_NOOP,
     DECISION_UPDATE,
 )
 from ..ops.encode import BucketEncoder, BucketOverflow, pad_pow2
@@ -80,10 +96,14 @@ _STATUS_REPEATS = REGISTRY.counter(
     "kcp_sync_status_upsync_repeats_total",
     "status upsyncs of a key made before the echo of its previous one "
     "came up the informer")
+_STATUS_DIRECT = REGISTRY.counter(
+    "kcp_sync_status_upsyncs_direct_total",
+    "upstream status writes made by an apply that the downstream event "
+    "itself queued, with no tick between the event and the write")
 _PATCHES_DEFERRED = REGISTRY.counter(
     "kcp_sync_patches_deferred_total",
-    "patches of a collected tick skipped because the key's apply was "
-    "still pending")
+    "patches (of a collected tick, or a status handed over by its "
+    "downstream event) skipped because the key's apply was still pending")
 
 # states of a key's convergence entry, in timeline order
 _STAGED, _TICKED, _PATCHED, _DOWNSTAGED, _DONE = range(5)
@@ -317,6 +337,12 @@ class BatchSyncEngine:
         # downstream never answers is evicted, not kept forever.
         self._dirty: dict[tuple[str, str], _Convergence] = {}
         self._dirty_max = 8192
+        # keys whose location reports progress: a status came AFTER the
+        # one that answered a write (True: during the key's newest write,
+        # False: during the one before it; a write with none retires the
+        # key). Nobody waits for the first status of such a key, and the
+        # tick's trip is where statuses that meet go up as one
+        self._reports: dict[tuple[str, str], bool] = {}
 
     def tick_count(self) -> int:
         """Reconcile ticks that covered this engine's rows (fused mode
@@ -376,6 +402,8 @@ class BatchSyncEngine:
             if tw is not None:
                 obs.phase("write", ctx, tw, tm, rv=rv, obj=name)
             obs.phase("propagate", ctx, tm, now, rv=rv)
+        if self._reports.pop(key, False):  # a new write: the mark ages,
+            self._reports[key] = False     # then goes
         self._admit(key, _Convergence(tw or tm or now, now, rv, name, ctx))
 
     def _admit(self, key, ent: _Convergence) -> None:
@@ -390,19 +418,40 @@ class BatchSyncEngine:
         # status write) re-stages the row: the LAST arrival before the
         # upsync is where `downstream` ends and `upstatus` begins
         ent = self._dirty.get(key)
+        wrote = new is not None and old is not new  # no replay, no delete
+        answers = False  # the status a write we carried down awaits
+        if new is None:
+            self._reports.pop(key, None)
         if ent is None:
-            if new is not None and old is not new:
+            if wrote:
                 # no write of the tenant's is under way: a status the
                 # location wrote after the first one went up (and its
                 # echo retired the entry) opens a status trip of its own
                 self._admit(key, _Convergence.later_trip(time.monotonic(),
                                                          key[1]))
+                self._reports[key] = True
         elif ent.state >= _PATCHED:
             ent.t_down = time.monotonic()
-            if ent.state < _DONE:  # _DONE: a later status trip opens
+            if ent.state < _DONE:
                 ent.state = _DOWNSTAGED
+                answers = wrote
+            elif wrote:  # _DONE: a later status trip opens
+                self._reports[key] = True
         if self.fused:
             if self._section is not None:
+                if answers and key not in self._reports:
+                    # a write of the tenant's waits for this status. The
+                    # device's rule for an upsync (both sides exist, the
+                    # statuses differ) on the two objects in hand: the
+                    # applier makes the same check before it writes, so
+                    # the write need not wait for a tick to ask for it
+                    up = self.up_informer.get(self._up_cluster(), key[1],
+                                              key[0])
+                    if up is not None and new.get("status") != up.get("status"):
+                        self._hand_over(key, DECISION_NOOP, True, direct=True)
+                # the device mirror follows the downstream side, and the
+                # tick that carries the row is the backstop: it re-emits
+                # the upsync if the apply above failed or was deferred
                 self.core.enqueue(self._section, True, key)
         else:
             self.controller.enqueue(("down", key))
@@ -515,23 +564,29 @@ class BatchSyncEngine:
                 ent = dirty.get(key)
                 if ent is not None and ent.state == _STAGED:
                     self._ticked(ent, t0, t1, tick_n)
-        pending = self._apply_pending
         for key, code, upsync in patches:
-            handed = pending.get(key)
-            if handed is not None:
-                # an apply reads the live caches, so a pending one of the
-                # same decision does this patch's work too; one of
-                # another decision (a status upsync pending when the
-                # spec patch arrives) does not, and ticks are
-                # event-driven: a fleet gone quiet never re-emits it
-                _PATCHES_DEFERRED.inc()
-                if handed != (code, upsync):
-                    pending[key] = _REARM
-                continue
-            if self._apply_failures.get(key, 0) > self.max_apply_retries:
-                continue  # dropped until a new event resets the budget
-            pending[key] = (code, upsync)
-            self._apply_q.put_nowait((key, code, upsync))
+            self._hand_over(key, code, upsync)
+
+    def _hand_over(self, key, code: int, upsync: bool,
+                   direct: bool = False) -> None:
+        """One decision for the applier pool, from a collected tick or
+        (``direct``) from the downstream event that carried a status."""
+        pending = self._apply_pending
+        handed = pending.get(key)
+        if handed is not None:
+            # an apply reads the live caches, so a pending one of the
+            # same decision does this one's work too; one of another
+            # decision (a status upsync pending when the spec patch
+            # arrives, or the reverse) does not, and ticks are
+            # event-driven: a fleet gone quiet never re-emits it
+            _PATCHES_DEFERRED.inc()
+            if handed != (code, upsync):
+                pending[key] = _REARM
+            return
+        if self._apply_failures.get(key, 0) > self.max_apply_retries:
+            return  # dropped until a new event resets the budget
+        pending[key] = (code, upsync)
+        self._apply_q.put_nowait((key, code, upsync, direct))
 
     def fused_overflow(self) -> None:
         """Vocabulary outgrew the bucket: grow the encoder (vocab is a
@@ -556,7 +611,7 @@ class BatchSyncEngine:
 
     async def _apply_worker(self) -> None:
         while True:
-            key, code, upsync = await self._apply_q.get()
+            key, code, upsync, direct = await self._apply_q.get()
             try:
                 applied = await self._apply_async(key, code, upsync)
             except Exception as err:  # noqa: BLE001 — reconcile errors are data
@@ -565,6 +620,8 @@ class BatchSyncEngine:
                 self._apply_failures.pop(key, None)
                 if applied:
                     self.stats["decisions_applied"] += 1
+                    if direct:  # a NOOP decision: what applied is the status
+                        _STATUS_DIRECT.inc()
             finally:
                 # pending holds until the apply FINISHES: a slow apply
                 # must suppress the level-triggered re-patches every tick
@@ -621,7 +678,7 @@ class BatchSyncEngine:
         await asyncio.sleep(delay)
         if key not in self._apply_pending:
             self._apply_pending[key] = (code, upsync)
-            self._apply_q.put_nowait((key, code, upsync))
+            self._apply_q.put_nowait((key, code, upsync, False))
 
     # ------------------------------------------------------------- rows
 

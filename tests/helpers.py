@@ -12,6 +12,8 @@ copy; they are re-exported here unchanged for the existing suites."""
 
 import asyncio
 
+import numpy as np
+
 from kcp_tpu.scenarios.topology import (  # noqa: F401 — re-exports
     restart_shard,
     shard_fleet,
@@ -27,3 +29,14 @@ async def wait_until(cond, timeout: float, interval: float = 0.02) -> bool:
             break
         await asyncio.sleep(interval)
     return cond()
+
+
+def device_mirror_equal(eng, key) -> bool:
+    """The two sides of ``key``'s row in a fused engine's device state, as
+    the last step left them: both exist and encode equal (single-bucket
+    fleets: a bucket row is its fleet row)."""
+    row = eng._section.rows[key]
+    st = eng.core._fleet._state
+    return (bool(st.up_exists[row]) and bool(st.down_exists[row])
+            and bool((np.asarray(st.up_vals[row])
+                      == np.asarray(st.down_vals[row])).all()))

@@ -12,6 +12,7 @@ import asyncio
 import time
 
 import pytest
+from helpers import device_mirror_equal
 
 from kcp_tpu.client import Client
 from kcp_tpu.store import LogicalStore
@@ -65,14 +66,18 @@ def test_engines_share_one_fused_bucket():
         await eventually(lambda: down.get("configmaps", "a", "default"))
         await eventually(lambda: down.get("widgets", "w", "default"))
 
-        # status upsync through the shared bucket: each row uses its own
-        # engine's status mask
+        # a status written downstream arrives upstream (its own event
+        # hands it to the applier; the tick that carries the row is the
+        # backstop), and the row's two sides end equal on the device,
+        # each row under its own engine's status mask
         dobj = down.get("widgets", "w", "default")
         dobj["status"] = {"ready": True}
         down.update_status("widgets", dobj)
         await eventually(
             lambda: up.get("widgets", "w", "default").get("status") == {"ready": True}
         )
+        await eventually(
+            lambda: device_mirror_equal(s2.engines[0], ("default", "w")))
         # the configmap row must not have been disturbed
         assert down.get("configmaps", "a", "default")["data"] == {"k": "v"}
         assert up.get("configmaps", "a", "default").get("status") is None

@@ -501,11 +501,11 @@ def test_the_quiet_tenant_gets_its_last_status(backend, slow_apply_ms,
     assert (rise["upstatus"] + rise["restatus"] + rise["repeats"]
             == rise["upsyncs"]), rise
     if backend == "host":
-        # a tick a status write: at least the final status of each
-        # rollout is a trip of its own (on the fused backend, 2 ms
-        # apart, a whole rollout may ride the first upsync)
+        # at least the final status of each rollout is a trip of its own
+        # (on the fused backend, 2 ms apart, a status that arrives while
+        # the previous one's apply is pending rides that apply)
         assert rise["restatus"] >= reps
-        assert rise["deferred"] == 0  # it applies inside its tick
+        assert rise["deferred"] == 0  # the host backend applies in its tick
     elif slow_apply_ms:
         # the test is known to reach the branch
         assert rise["deferred"] > 0

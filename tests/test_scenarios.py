@@ -92,7 +92,7 @@ def test_slo_violation_fails_the_scenario(tmp_path):
     metric that was never measured must fail loudly, not pass by
     vacuity."""
     broken = dataclasses.replace(TINY, name="crud-churn-broken", slos=(
-        SLO("impossible-convergence", "p99_convergence_ms", "<=", 0.0),
+        SLO("impossible-convergence", "p99_convergence_ms", "<=", -1.0),
         SLO("typo-metric", "no_such_metric", "==", 0),
     ))
     r = run_scenario(broken, seed=5, workdir=str(tmp_path))
