@@ -1,6 +1,6 @@
-"""The serving process's own runtime, measured from inside: how late the
-event loop runs its callbacks, and how long the cyclic collector stops
-every thread.
+"""The serving process's own runtime, measured from inside: what the
+event loop's one thread did with its time, how late it runs its
+callbacks, and how long the cyclic collector stops every thread.
 
 One interpreter runs httpd, store, watch fan-out, informers, appliers
 and the tick loop, so "queueing on the one interpreter" is a first-order
@@ -8,14 +8,38 @@ term of every latency the server has. :class:`RuntimeProbes` is owned by
 the :class:`~kcp_tpu.server.server.Server` (started and stopped with
 it); nothing here runs at import.
 
+- the pass ledger (:class:`LoopLedger`): the loop's selector is wrapped
+  once. A ``select`` that may wait is IDLE time (nothing was ready);
+  everything else is BUSY, counted pass by pass (a call of ``select``
+  to the next: one pass's callbacks; a ``select`` with ready work
+  behind it comes straight back in 2-5 us and is counted with the pass
+  it starts, so a pass costs the ledger ONE clock read).
+  ``server_loop_busy_seconds_total`` + ``server_loop_idle_seconds_total``
+  is wall time by construction. ``server_loop_cpu_seconds_total`` is
+  the thread's CPU time between waits (``time.thread_time()`` is a
+  system call: read on both sides of a ``select`` that may wait, not
+  once a pass), so busy − cpu is time the loop was held and did not
+  run (an ``fsync``, the wait for the device, the GIL in another
+  thread's hands, descheduling). Inside a pass, every
+  :func:`kcp_tpu.obs.annotate` section adds its SELF seconds to
+  ``server_loop_self_seconds_<section>``; busy − their sum is work
+  nobody has named. A pass of :data:`LONG_PASS_S` or more is counted
+  (``server_loop_long_passes_total``, ``_long_pass_seconds_total``) and
+  kept in a ring of the last :data:`RING` with its three largest
+  sections (``GET /debug/loop``). The ledger's slots are plain floats
+  with one writer; the beat below publishes their rise into the
+  registry's counters, so a reader is at most a beat stale and no pass
+  or section takes a lock. All of it is work on a loop that queues: at
+  91 % busy half a percent of loop work was 8 % of the median
+  (``PERF.md`` §6, PR 42), which is why a pass reads one clock;
 - ``server_loop_lag_seconds``: a timer that re-arms itself every
   :data:`LAG_INTERVAL_S` on the serving loop and observes how late it
   fired — the time a ready callback waits behind whatever the loop is
   doing (one observation per beat, about 20 a second);
 - ``py_gc_pause_seconds``: a ``gc.callbacks`` hook, start to stop of
-  every collection; while a profiler session is open each one is also a
-  ``kcp.gc`` annotation (generation as a stat), so an idle gap of the
-  device under a full collection reads as that.
+  every collection; each one is also a ``kcp.gc`` section (generation
+  as a stat), so an idle gap of the device, or a long pass, under a
+  full collection reads as that.
 """
 
 from __future__ import annotations
@@ -23,11 +47,221 @@ from __future__ import annotations
 import asyncio
 import gc
 import time
+from collections import deque
+from threading import get_ident
 
 from .. import obs
 from ..utils.trace import REGISTRY
+from . import trace as _trace
+from .trace import _LEDGERS, _NOOP, Section
 
 LAG_INTERVAL_S = 0.05
+# a pass this long is a stall worth a line of its own: the threshold
+# benchmarks/run.py reports the collector's pauses at
+LONG_PASS_S = 0.05
+# the LAST so many long passes, not the longest since start: set-up's
+# compiles would fill the latter before anyone looks
+RING = 64
+
+_TOTALS = {
+    "busy_seconds": REGISTRY.counter(
+        "server_loop_busy_seconds_total",
+        "the serving loop's thread between a return of select and its "
+        "next call: one pass's callbacks"),
+    "idle_seconds": REGISTRY.counter(
+        "server_loop_idle_seconds_total",
+        "the serving loop's thread inside select: nothing was ready"),
+    "cpu_seconds": REGISTRY.counter(
+        "server_loop_cpu_seconds_total",
+        "CPU time of the serving loop's thread over its passes (busy "
+        "less this: a pass that held the loop and did not run — a "
+        "blocking call, the GIL elsewhere, descheduling)"),
+    "passes": REGISTRY.counter(
+        "server_loop_passes_total",
+        "passes of the serving loop (returns of select)"),
+    "long_passes": REGISTRY.counter(
+        "server_loop_long_passes_total",
+        "passes of the serving loop of 50 ms or more"),
+    "long_pass_seconds": REGISTRY.counter(
+        "server_loop_long_pass_seconds_total",
+        "wall time of the serving loop's passes of 50 ms or more"),
+    "section_leaks": REGISTRY.counter(
+        "server_loop_section_leaks_total",
+        "obs.annotate sections found open when the serving loop went "
+        "back to select (a section may not span an await): swept, not "
+        "timed"),
+}
+
+
+class LoopLedger:
+    """What one event loop's thread did with its time. Installed by
+    :meth:`attach` as the ``select`` of the loop's selector (an
+    instance attribute over the class's method, removed again by the
+    last :meth:`detach`); every number is absent, never wrong, on a
+    loop without ``_selector`` (CPython's private name).
+
+    Single writer: :meth:`select`, and the sections of
+    :func:`kcp_tpu.obs.annotate`, run on the loop's thread and add plain
+    floats. :meth:`publish` (the probes' beat, same thread) moves the
+    rise into the registry's counters, one ``inc`` per counter that
+    moved."""
+
+    def __init__(self, selector) -> None:
+        self._selector = selector
+        self._select = selector.select
+        self.users = 0
+        self.busy_seconds = self.idle_seconds = self.cpu_seconds = 0.0
+        self.long_pass_seconds = 0.0
+        self.passes = self.long_passes = self.section_leaks = 0
+        self._published = dict.fromkeys(_TOTALS, 0.0)
+        # the section running now, the stamp of its last boundary, and
+        # the sections open around it (obs/trace.py Section)
+        self.cur: Section | None = None
+        self.mark = 0.0
+        self.stack: list = []
+        self.log: list = []  # section, seconds, ... of this pass
+        # waiting is not work: select's own annotation has no slot
+        self.sections: dict[str, Section] = {"kcp.loop.select": _NOOP}
+        self.ring: deque = deque(maxlen=RING)
+        self._tid = 0
+        # a profiler session is open (asked once a beat, not a section)
+        self.profiling = False
+        self._t = time.monotonic()
+        self._c = 0.0
+
+    # ---------------------------------------------------------- install
+
+    @classmethod
+    def attach(cls, loop: asyncio.AbstractEventLoop) -> "LoopLedger | None":
+        selector = getattr(loop, "_selector", None)
+        select = getattr(selector, "select", None)
+        if select is None:
+            return None
+        led = getattr(select, "__self__", None)
+        if not isinstance(led, cls):
+            led = cls(selector)
+            selector.select = led.select
+            # its next pass tells the ledger which thread the loop's is
+            loop.call_soon_threadsafe(led._on_loop)
+        led.users += 1
+        return led
+
+    def _on_loop(self) -> None:
+        if self.users:
+            self._tid = get_ident()
+            self._c = time.thread_time()
+            _LEDGERS[self._tid] = self
+
+    @staticmethod
+    def of_this_thread() -> "LoopLedger | None":
+        return _LEDGERS.get(get_ident())
+
+    def detach(self) -> None:
+        self.users -= 1
+        self.publish()
+        if self.users == 0:
+            del self._selector.select
+            if _LEDGERS.get(self._tid) is self:
+                del _LEDGERS[self._tid]
+
+    def section(self, name: str) -> Section:
+        sec = self.sections[name] = Section(name, self)
+        return sec
+
+    # ------------------------------------------------------------- pass
+
+    def select(self, timeout=None):
+        now = time.monotonic()
+        busy = now - self._t
+        self.busy_seconds += busy
+        self.passes += 1
+        if busy >= LONG_PASS_S:
+            self._long_pass(busy)
+        if self.log:
+            self.log.clear()
+        if self.cur is not None:
+            self.section_leaks += len(self.stack)
+            self.stack.clear()
+            self.cur = None
+        if timeout == 0 and not self.profiling:
+            # ready work is behind this select, so it comes straight
+            # back (2-5 us): counted as the next pass's first
+            # microseconds, at one clock read a pass
+            self._t = now
+            return self._select(0)
+        # a select that may wait: idle is measured, and the thread's
+        # clock (a system call) read on both sides of it, so CPU time
+        # is taken between waits and not once a pass
+        cpu = time.thread_time()
+        if self._tid:
+            self.cpu_seconds += cpu - self._c
+        if self.profiling:
+            with obs.annotate("kcp.loop.select"):
+                events = self._select(timeout)
+        else:
+            events = self._select(timeout)
+        self._c = time.thread_time()
+        t = self._t = time.monotonic()
+        self.idle_seconds += t - now
+        return events
+
+    def _long_pass(self, wall: float) -> None:
+        self.long_passes += 1
+        self.long_pass_seconds += wall
+        by_name: dict[str, float] = {}
+        log = self.log
+        for sec, seconds in zip(log[::2], log[1::2]):
+            by_name[sec.name] = by_name.get(sec.name, 0.0) + seconds
+        top = sorted(by_name.items(), key=lambda kv: -kv[1])[:3]
+        self.ring.append({
+            "start": self._t, "wall_s": wall,
+            "sections": [[n, s] for n, s in top]})
+
+    # ---------------------------------------------------------- publish
+
+    def publish(self) -> None:
+        ta = _trace._trace_annotation or _trace.profiler_annotation()
+        self.profiling = ta is not None and ta.is_enabled()
+        if self._tid == get_ident():
+            # CPU time up to here: a loop that never waits (a closed
+            # loop at saturation) would otherwise account none of it
+            cpu = time.thread_time()
+            self.cpu_seconds += cpu - self._c
+            self._c = cpu
+        done = self._published
+        for slot, counter in _TOTALS.items():
+            value = getattr(self, slot)
+            if value != done[slot]:
+                counter.inc(value - done[slot])
+                done[slot] = value
+        for sec in list(self.sections.values()):
+            if sec is _NOOP or sec.seconds == sec.published:
+                continue
+            if sec.counter is None:
+                sec.counter = REGISTRY.counter(
+                    f"server_loop_self_seconds_{sec.name.replace('.', '_')}",
+                    "self time of one obs.annotate section on the serving "
+                    "loop: its duration less the sections inside it")
+            sec.counter.inc(sec.seconds - sec.published)
+            sec.published = sec.seconds
+
+    def report(self) -> dict:
+        """What ``GET /debug/loop`` serves: the totals as they stand
+        and the ring, stamps on ``time.monotonic()``'s clock."""
+        out = {slot: getattr(self, slot) for slot in _TOTALS}
+        out.update(now=time.monotonic(), long_pass_threshold_s=LONG_PASS_S,
+                   self_seconds={s.name: s.seconds
+                                 for s in self.sections.values()
+                                 if s is not _NOOP},
+                   long_passes_recent=list(self.ring))
+        return out
+
+
+def long_passes() -> list[dict]:
+    """The rings of every ledger of this process, oldest first (a
+    benchmark's run process holds its server: it reads them here)."""
+    return sorted((p for led in list(_LEDGERS.values()) for p in led.ring),
+                  key=lambda p: p["start"])
 
 
 class RuntimeProbes:
@@ -41,12 +275,14 @@ class RuntimeProbes:
         self._loop = loop
         self._handle: asyncio.TimerHandle | None = None
         self._due = 0.0
+        self.ledger: LoopLedger | None = None
         self._lag = REGISTRY.histogram(
             "server_loop_lag_seconds",
             "how late a timer on the serving loop fired: the wait of a "
             "ready callback behind the loop's current work")
 
     def start(self) -> "RuntimeProbes":
+        self.ledger = LoopLedger.attach(self._loop)
         self._arm()
         cls = RuntimeProbes
         if cls._gc_users == 0:
@@ -58,6 +294,9 @@ class RuntimeProbes:
         if self._handle is not None:
             self._handle.cancel()
             self._handle = None
+            if self.ledger is not None:
+                self.ledger.detach()
+                self.ledger = None
             cls = RuntimeProbes
             cls._gc_users -= 1
             if cls._gc_users == 0 and _on_gc in gc.callbacks:
@@ -69,6 +308,8 @@ class RuntimeProbes:
 
     def _beat(self) -> None:
         self._lag.observe(max(0.0, time.monotonic() - self._due))
+        if self.ledger is not None:
+            self.ledger.publish()
         self._arm()
 
 

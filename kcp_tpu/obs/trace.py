@@ -32,10 +32,13 @@ layer for the kcp-tpu fleet, Dapper-style:
   stamps (all ``time.monotonic()``), so their sum telescopes to the
   end-to-end time by construction (the ``bench.py --trace``
   reconciliation gate);
-- the same boundaries on the profiler's clock: :func:`annotate` is a
-  ``jax.profiler.TraceAnnotation`` (live exactly while a profiler
-  session is open) around the synchronous ``kcp.*`` sections of the
-  tick, the store, the applier and the watch relay.
+- the same boundaries as host SECTIONS: :func:`annotate` names the
+  synchronous ``kcp.*`` sections of the tick, the store, the applier,
+  the HTTP path and the watch relay. On a serving loop's thread each
+  section adds its self time to that loop's ledger
+  (:class:`~kcp_tpu.obs.runtime.LoopLedger`, always on); while a
+  profiler session is open it is also a
+  ``jax.profiler.TraceAnnotation`` on the profiler's clock.
 
 Wire neutrality is a hard contract: tracing adds a request header on
 client hops and nothing else — response bytes, watch streams, and stored
@@ -57,6 +60,8 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass
+from threading import get_ident as _get_ident
+from time import perf_counter as _perf_counter
 from typing import Any, Iterator
 
 from ..analysis.sanitize import make_lock
@@ -122,7 +127,8 @@ class TraceContext:
 
 class _Noop:
     """Reusable no-op context manager: the unsampled-path cost of
-    :func:`span` is one contextvar read and this singleton."""
+    :func:`span` is one contextvar read and this singleton. ``begin`` /
+    ``end`` are the no-op of a host section (:func:`annotate`)."""
 
     __slots__ = ()
 
@@ -131,6 +137,12 @@ class _Noop:
 
     def __exit__(self, *exc: object) -> bool:
         return False
+
+    def begin(self, now: float) -> None:
+        return None
+
+    def end(self, now: float) -> None:
+        return None
 
 
 _NOOP = _Noop()
@@ -390,26 +402,150 @@ def phase(name: str, ctx: TraceContext | None, t0: float, t1: float,
 
 _trace_annotation = None  # jax.profiler.TraceAnnotation, once jax is imported
 
+# a stretch of a section's self time is remembered for its pass (and shown
+# if the pass turns out long, ``GET /debug/loop``) from this many seconds
+# on: most sections are shorter and cost the log nothing; a long one that
+# young collections cut into pieces (each ``kcp.gc`` is a boundary) still
+# shows, because its pieces are longer
+PASS_LOG_S = 0.0001
 
-def annotate(name: str, **stats: Any):
-    """A host annotation on the JAX profiler's clock around a
-    SYNCHRONOUS section (it is a thread-scoped begin/end: no ``await``
-    inside): a ``jax.profiler.TraceAnnotation`` while a profiler session
-    is open, the no-op singleton otherwise — so "tracing off" costs one
-    flag read per section (these sections run thousands of times a
-    second on a loop whose queueing multiplies every microsecond). A
-    process that never imported jax (router, load generator) never
-    imports it here. ``stats`` ride the event (``kcp.tick`` carries
-    ``tick`` and ``mono``, the ``time.monotonic()`` of its start, so a
-    reader can place monotonic stamps on the profiler's timeline)."""
+# thread ident -> the ledger of the serving loop that runs on that thread
+# (kcp_tpu/obs/runtime.py LoopLedger registers and removes itself)
+_LEDGERS: dict[int, Any] = {}
+
+
+class Section:
+    """One named section of one loop's ledger: the slot its self seconds
+    are added to, and the context manager every :func:`annotate` of
+    that name on that loop's thread returns (one object per name;
+    nothing is allocated per use). SELF time is the section's duration
+    less what the sections opened inside it covered: the ledger keeps
+    the section that is running now (``cur``) and the stamp of the last
+    boundary (``mark``), and every begin and end adds the time since
+    that stamp to the section that was running — one clock read, one
+    float add. ``begin`` / ``end`` take a ``time.perf_counter()`` stamp
+    the caller has already read; the ``with`` form reads the clock
+    itself. Single writer (the loop's thread): no lock."""
+
+    __slots__ = ("name", "seconds", "published", "counter", "_led")
+
+    def __init__(self, name: str, ledger: Any):
+        self.name = name
+        self.seconds = 0.0
+        # what the ledger's beat has moved into ``counter`` so far
+        self.published = 0.0
+        self.counter = None
+        self._led = ledger
+
+    def __enter__(self, now: float | None = None) -> None:
+        led = self._led
+        if now is None:
+            now = _perf_counter()
+        cur = led.cur
+        if cur is not None:
+            ran = now - led.mark
+            cur.seconds += ran
+            if ran >= PASS_LOG_S:  # what a long pass can be made of
+                led.log.extend((cur, ran))
+        led.stack.append(cur)
+        led.cur = self
+        led.mark = now
+
+    def __exit__(self, et: object, ev: object, tb: object,
+                 now: float | None = None) -> bool:
+        led = self._led
+        if led.cur is not self:
+            return False  # left open across an await: the ledger swept it
+        if now is None:
+            now = _perf_counter()
+        ran = now - led.mark
+        self.seconds += ran
+        if ran >= PASS_LOG_S:
+            led.log.extend((self, ran))
+        led.cur = led.stack.pop()
+        led.mark = now
+        return False
+
+    begin = __enter__
+
+    def end(self, now: float) -> None:
+        self.__exit__(None, None, None, now)
+
+
+class _Traced:
+    """A section while a profiler session is open: the
+    ``TraceAnnotation`` around the ledger's section (or around
+    :data:`_NOOP` on a thread that has no ledger)."""
+
+    __slots__ = ("_ann", "_sec")
+
+    def __init__(self, ann: Any, sec: Any):
+        self._ann = ann
+        self._sec = sec
+
+    def begin(self, now: float) -> None:
+        self._ann.__enter__()
+        self._sec.begin(now)
+
+    def end(self, now: float) -> None:
+        self._sec.end(now)
+        self._ann.__exit__(None, None, None)
+
+    def __enter__(self) -> None:
+        self.begin(_perf_counter())
+
+    def __exit__(self, et: object, ev: object, tb: object) -> bool:
+        self.end(_perf_counter())
+        return False
+
+
+def profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` once jax is imported, else None
+    (ask its ``is_enabled()`` whether a session is open). A process
+    that never imported jax (router, load generator) never imports it
+    here, and a jax that is half imported (``sys.modules`` has it
+    before ``jax.profiler`` exists: a gc callback can fire there) reads
+    as no profiler."""
     global _trace_annotation
     ta = _trace_annotation
     if ta is None:
         jax = sys.modules.get("jax")
-        if jax is None:
-            return _NOOP
-        ta = _trace_annotation = jax.profiler.TraceAnnotation
-    return ta(name, **stats) if ta.is_enabled() else _NOOP
+        if jax is not None:
+            ta = _trace_annotation = getattr(
+                getattr(jax, "profiler", None), "TraceAnnotation", None)
+    return ta
+
+
+def annotate(name: str, **stats: Any):
+    """One named SYNCHRONOUS host section (a thread-scoped begin/end:
+    no ``await`` inside), two things at one pair of clock reads:
+
+    - on a thread whose serving loop keeps a ledger
+      (:class:`~kcp_tpu.obs.runtime.LoopLedger`), the section's self
+      seconds are added to ``server_loop_self_seconds_<name>`` — always
+      on, no lock, nothing allocated but a stack frame;
+    - while a profiler session is open, a
+      ``jax.profiler.TraceAnnotation`` on the profiler's clock;
+      ``stats`` ride that event (``kcp.gc`` carries ``generation``).
+
+    With neither it is the no-op singleton: "tracing off" costs one
+    thread check and one flag read per section — on a ledger's thread
+    the flag is the ledger's, which asks the profiler once a beat, so a
+    session is seen at most 50 ms late (these sections run
+    thousands of times a second on a loop whose queueing multiplies
+    every microsecond). A site that has read the clock anyway hands its
+    stamps over (``begin(now)`` / ``end(now)``) in place of ``with``."""
+    led = _LEDGERS.get(_get_ident())
+    if led is None:
+        sec = _NOOP
+    else:
+        sec = led.sections.get(name) or led.section(name)
+        if not led.profiling:  # the ledger asks the profiler once a beat
+            return sec
+    ta = _trace_annotation or profiler_annotation()
+    if ta is None or not ta.is_enabled():
+        return sec
+    return _Traced(ta(name, **stats), sec)
 
 
 def write_ctx() -> TraceContext | None:

@@ -27,6 +27,7 @@ import asyncio
 import logging
 from typing import Awaitable, Callable, Iterable, Sequence
 
+from .. import obs
 from ..utils.errors import is_retryable
 from .fairqueue import make_queue
 from .queue import Item, WorkQueue
@@ -193,5 +194,6 @@ class BatchController(Controller):
             # one queue crossing for the whole batch (forget successes,
             # done everything) — the per-item form cost ~30% of the
             # serving loop's wall time at bench scale
-            self.queue.complete_many(
-                batch, [item not in failed_items for item in batch])
+            with obs.annotate("kcp.queue.drain"):
+                self.queue.complete_many(
+                    batch, [item not in failed_items for item in batch])

@@ -24,6 +24,7 @@ import ctypes
 import time
 from typing import Callable, Hashable
 
+from .. import obs
 from .queue import WorkQueue, queue_metrics
 
 Item = Hashable
@@ -217,7 +218,9 @@ class FairWorkQueue:
         buf = (ctypes.c_uint64 * max_items)()
         now = time.monotonic()
         n = self._lib.wq_drain(self._q, now, buf, max_items)
-        if n:
+        if not n:
+            return []
+        with obs.annotate("kcp.queue.drain"):  # one a pop that found work
             enq = self._enq_t
             observe = self._wait_hist.observe
             for i in range(n):
@@ -225,7 +228,7 @@ class FairWorkQueue:
                 if t is not None:
                     observe(now - t)
             self._depth_gauge.set(self._lib.wq_len(self._q))
-        return [self._items[buf[i]] for i in range(n)]
+            return [self._items[buf[i]] for i in range(n)]
 
     async def get(self) -> Item | None:
         while True:

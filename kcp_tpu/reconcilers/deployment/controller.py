@@ -247,6 +247,13 @@ class DeploymentSplitter:
     # -------------------------------------------------------------- tick
 
     async def _process_batch(self, items: Sequence) -> list[tuple[object, Exception]]:
+        # one splitter tick is a synchronous section of the loop; the
+        # leaf writes (kcp.split) and status writes (kcp.aggregate) are
+        # sections inside it
+        with obs.annotate("kcp.splitter.tick"):
+            return self._tick(items)
+
+    def _tick(self, items: Sequence) -> list[tuple[object, Exception]]:
         self.stats["ticks"] += 1
         roots: dict[tuple[str, str, str], None] = {}
         aggregates: dict[tuple[str, str, str], None] = {}
@@ -402,7 +409,8 @@ class DeploymentSplitter:
         while True:
             key, counts = await self._apply_q.get()
             try:
-                self._apply_one_fused(key, counts)
+                with obs.annotate("kcp.splitter.place"):
+                    self._apply_one_fused(key, counts)
             except Exception:  # noqa: BLE001 — worker must survive
                 log.exception("deployment-splitter: fused apply crashed")
             finally:
@@ -454,13 +462,16 @@ class DeploymentSplitter:
         """Placement-eligible clusters: evacuated (sustained-NotReady)
         clusters are excluded, so every split — host or fused lane —
         routes replicas only onto healthy capacity."""
-        return sorted(
-            (c for c in self.cluster_informer.list()
-             if c["metadata"].get("clusterName", "") == logical_cluster
-             and not self.inventory.is_evacuated(
-                 logical_cluster, c["metadata"]["name"])),
-            key=lambda c: c["metadata"]["name"],
-        )
+        # a section of its own: a scan of EVERY registered cluster, once
+        # a root in the tick and once more in its fused apply
+        with obs.annotate("kcp.splitter.clusters"):
+            return sorted(
+                (c for c in self.cluster_informer.list()
+                 if c["metadata"].get("clusterName", "") == logical_cluster
+                 and not self.inventory.is_evacuated(
+                     logical_cluster, c["metadata"]["name"])),
+                key=lambda c: c["metadata"]["name"],
+            )
 
     def _apply_placement(
         self,

@@ -495,6 +495,17 @@ class RestHandler:
             except (TypeError, ValueError):
                 seconds = 2.0
             return Response.of_json(await sample_profile(seconds))
+        if head == "debug" and segs[1:] == ["loop"]:
+            # "what held the loop": the serving loop's ledger as it
+            # stands and its last long passes, each with its three
+            # largest sections (obs/runtime.py); stamps are
+            # time.monotonic()'s. Server-global like /debug/profile.
+            if not await self._server_scope_allowed(req):
+                return self._forbidden(req, "read /debug/loop")
+            from ..obs.runtime import LoopLedger
+
+            led = LoopLedger.of_this_thread()
+            return Response.of_json(led.report() if led else {})
         if head == "debug" and segs[1:] == ["trace"]:
             # distributed-trace queries (?id= / ?slowest=N) serve this
             # process's span ring buffer; without either param the legacy
@@ -1541,10 +1552,15 @@ class RestHandler:
                 # encode-once: every stream serving this store splices
                 # the same cached event-line bytes — a 64-watcher
                 # fan-out encodes each event once
-                t0 = loop.time()
-                with obs.annotate("kcp.watch.encode", events=len(batch)):
+                sec = obs.annotate("kcp.watch.encode")
+                t0 = time.perf_counter()
+                sec.begin(t0)
+                try:
                     lines = self.store.encode_events(batch)
-                self._enc_seconds.observe(loop.time() - t0)
+                finally:
+                    now = time.perf_counter()
+                    sec.end(now)
+                self._enc_seconds.observe(now - t0)
                 self._stream_bytes.inc(sum(map(len, lines)))
                 self._stream_events.inc(len(lines))
                 return lines
@@ -1555,8 +1571,9 @@ class RestHandler:
                 # `observe` stamp where the frame is handed over. One
                 # write per watch per fan-out pass is the coalescer's
                 # promise with the commit window as the tick.
-                stream.write_raw_many(encode_lines(batch))
-                stamp_observed(batch)
+                with obs.annotate("kcp.watch.push"):
+                    stream.write_raw_many(encode_lines(batch))
+                    stamp_observed(batch)
                 self._push_batches.inc()
 
             async def send_batch(batch) -> None:

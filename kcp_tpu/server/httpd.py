@@ -18,6 +18,7 @@ from dataclasses import dataclass
 from typing import Awaitable, Callable
 from urllib.parse import parse_qs, unquote, urlsplit
 
+from .. import obs
 from ..utils.trace import REGISTRY, SIZE_BUCKETS
 
 log = logging.getLogger(__name__)
@@ -528,22 +529,8 @@ class HttpServer:
                         # requests on a server that is going away
                         keep = (req.headers.get("connection", "keep-alive")
                                 != "close") and not self._draining
-                        head = (
-                            f"HTTP/1.1 {resp.status} {_reason(resp.status)}\r\n"
-                            f"Content-Type: {resp.content_type}\r\n"
-                            f"Content-Length: {resp.body_len()}\r\n"
-                        )
-                        for k, v in resp.headers.items():
-                            head += f"{k}: {v}\r\n"
-                        head += ("Connection: "
-                                 f"{'keep-alive' if keep else 'close'}\r\n\r\n")
-                        if resp.spans is not None and scatter_enabled():
-                            # zero-copy body: the encode-once spans go to
-                            # the transport without the whole-body join
-                            _write_parts(writer,
-                                         [head.encode(), *resp.spans])
-                        else:
-                            writer.write(head.encode() + resp.body)
+                        with obs.annotate("kcp.http.respond"):
+                            self._respond(writer, resp, keep)
                         await writer.drain()
                 finally:
                     self._busy -= 1
@@ -592,6 +579,27 @@ class HttpServer:
             except (ConnectionError, RuntimeError, TimeoutError,
                     asyncio.CancelledError):
                 pass
+
+    @staticmethod
+    def _respond(writer: asyncio.StreamWriter, resp: Response,
+                 keep: bool) -> None:
+        """The head and the body handed to the transport (which sends
+        what the socket takes at once)."""
+        head = (
+            f"HTTP/1.1 {resp.status} {_reason(resp.status)}\r\n"
+            f"Content-Type: {resp.content_type}\r\n"
+            f"Content-Length: {resp.body_len()}\r\n"
+        )
+        for k, v in resp.headers.items():
+            head += f"{k}: {v}\r\n"
+        head += ("Connection: "
+                 f"{'keep-alive' if keep else 'close'}\r\n\r\n")
+        if resp.spans is not None and scatter_enabled():
+            # zero-copy body: the encode-once spans go to
+            # the transport without the whole-body join
+            _write_parts(writer, [head.encode(), *resp.spans])
+        else:
+            writer.write(head.encode() + resp.body)
 
     async def _read_request(self, reader: asyncio.StreamReader) -> Request | None:
         try:

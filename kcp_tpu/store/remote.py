@@ -231,7 +231,7 @@ class RemoteStore:
         return run
 
     @contextlib.contextmanager
-    def _client(self, cluster: str, verb: str):
+    def _client(self, cluster: str):
         """A pooled client scoped to ``cluster`` for one verb, the wait
         for it and the verb itself timed."""
         t0 = getattr(self._submitted, "t", None)
@@ -243,13 +243,13 @@ class RemoteStore:
             t1 = time.monotonic()
             self._queue_seconds.observe(t1 - t0)
             try:
-                with obs.annotate("kcp.remote.call", verb=verb):
+                with obs.annotate("kcp.remote.call"):
                     yield c
             finally:
                 self._call_seconds.observe(time.monotonic() - t1)
 
     def _call(self, cluster: str, verb: str, *args, **kwargs):
-        with self._client(cluster, verb) as c:
+        with self._client(cluster) as c:
             return getattr(c, verb)(*args, **kwargs)
 
     # ------------------------------------------------------------- verbs
@@ -283,7 +283,7 @@ class RemoteStore:
 
     def delete(self, resource: str, cluster: str, name: str,
                namespace: str = "") -> None:
-        with self._client(cluster, "delete") as client:
+        with self._client(cluster) as client:
             if cluster == WILDCARD:
                 # RestClient refuses wildcard deletes (an in-process
                 # store needs an explicit tenant), but here the backend's
@@ -314,7 +314,7 @@ class RemoteStore:
 
     @property
     def resource_version(self) -> int:
-        with self._client(WILDCARD, "version") as client:
+        with self._client(WILDCARD) as client:
             body = client._request("GET", "/version")
         if "resourceVersion" not in body:
             # an authz'd backend withholds the RV from tokens lacking the
@@ -330,7 +330,7 @@ class RemoteStore:
         return self._call(WILDCARD, "resources")
 
     def clusters(self) -> list[str]:
-        with self._client(WILDCARD, "clusters") as client:
+        with self._client(WILDCARD) as client:
             body = client._request("GET", "/clusters")
         return list(body.get("clusters", []))
 

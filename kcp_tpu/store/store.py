@@ -1971,6 +1971,11 @@ class LogicalStore:
         others."""
         dirty, self._sink_dirty = self._sink_dirty, []
         self._sinking = True
+        # one section a pass: what the sinks do with their batches (an
+        # informer's cache and handlers, a controller's answer; a
+        # pushed watch's write is kcp.watch.push inside it)
+        sec = obs.annotate("kcp.store.sinks")
+        sec.__enter__()
         try:
             for i, watch in enumerate(dirty):
                 w = self._gc_window
@@ -1995,6 +2000,7 @@ class LogicalStore:
                     watch._sink = None
                     watch.close()  # on_close tells the watch's consumer
         finally:
+            sec.__exit__(None, None, None)
             self._sinking = False
         if self._sink_dirty and not self._flush_scheduled:
             # touched from inside a sink: the next pass comes for them
@@ -2004,13 +2010,16 @@ class LogicalStore:
     def _fanout_pending(self) -> None:
         batch, self._pending = self._pending, []
         self._flushing = True
+        sec = obs.annotate("kcp.store.fanout")
         t0 = time.perf_counter()
+        sec.begin(t0)
         try:
-            with obs.annotate("kcp.store.fanout", events=len(batch)):
-                self._fanout(batch)
+            self._fanout(batch)
         finally:
             self._flushing = False
-            dt = time.perf_counter() - t0
+            now = time.perf_counter()
+            sec.end(now)
+            dt = now - t0
             self._fanout_size.observe(len(batch))
             self._emit_seconds.observe(dt)
             if obs.TRACER.enabled:
@@ -2343,7 +2352,7 @@ class LogicalStore:
             return
         try:
             _inject("store.commit_window")
-            with obs.annotate("kcp.wal.sync", records=len(recs)):
+            with obs.annotate("kcp.wal.sync"):
                 if self._engine is not None:
                     self._append_engine_batch(recs)
                 elif self._wal is not None and self._wal.fh is not None:
@@ -2825,6 +2834,10 @@ class LogicalStore:
     def snapshot(self) -> None:
         """Write a snapshot and truncate the WAL (etcd compaction analog)."""
         self._gc_barrier()  # compaction must not strand buffered records
+        with obs.annotate("kcp.wal.snapshot"):
+            self._snapshot()
+
+    def _snapshot(self) -> None:
         if self._engine is not None:
             self._engine.snapshot_stream(
                 (_wal_key(k), json.dumps(v, separators=(",", ":")).encode("utf-8"))

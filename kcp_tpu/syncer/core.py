@@ -118,10 +118,12 @@ class _Phases:
     """A cursor over the consecutive phases of one synchronous section
     of a tick: ``enter(name)`` closes the phase before it at the same
     clock read, so adjacent phases share their boundary. Each phase is a
-    ``fused_<name>_seconds`` observation and, while a profiler session
-    is open, a ``kcp.tick.<name>`` annotation on the profiler's clock.
-    One perf_counter read per boundary, never per row; ``close()``
-    belongs in a ``finally`` (an annotation is a begin/end pair)."""
+    ``fused_<name>_seconds`` observation and a ``kcp.tick.<name>``
+    section (``obs.annotate``: the loop ledger's self seconds, and an
+    annotation on the profiler's clock while a session is open), which
+    is handed the stamp read here. One perf_counter read per boundary,
+    never per row; ``close()`` belongs in a ``finally`` (a section is a
+    begin/end pair)."""
 
     __slots__ = ("_name", "_t", "_ann")
 
@@ -133,7 +135,7 @@ class _Phases:
         self._end(now)
         self._name, self._t = name, now
         self._ann = obs.annotate(f"kcp.tick.{name}")
-        self._ann.__enter__()
+        self._ann.begin(now)
 
     def close(self) -> None:
         self._end(time.perf_counter())
@@ -141,12 +143,12 @@ class _Phases:
     def discard(self) -> None:
         """End the open phase without an observation."""
         if self._name is not None:
-            self._ann.__exit__(None, None, None)
+            self._ann.end(time.perf_counter())
             self._name = None
 
     def _end(self, now: float) -> None:
         if self._name is not None:
-            self._ann.__exit__(None, None, None)
+            self._ann.end(now)
             _PHASE_H[self._name].observe(now - self._t)
             self._name = None
 
@@ -1247,7 +1249,6 @@ class FusedCore:
         )
         # (wire, layout meta, start stamp of the submitting tick)
         self._inflight: list[tuple[jax.Array, FleetMeta, float]] = []
-        self._ticks = 0
         # start stamp (time.monotonic()) of the tick whose wire is being
         # collected, while its patches are handed to the owners
         self.collecting_tick_start: float | None = None
@@ -1456,11 +1457,9 @@ class FusedCore:
 
     async def _process_batch(self, items: Sequence) -> list:
         # one tick: a synchronous section of the loop, so one kcp.tick
-        # annotation spans it (``mono`` places the generator's and the
-        # collector's monotonic stamps on the profiler's timeline)
+        # section spans it
         t_tick = time.monotonic()
-        self._ticks += 1
-        with obs.annotate("kcp.tick", tick=self._ticks, mono=t_tick):
+        with obs.annotate("kcp.tick"):
             return self._tick(items, t_tick)
 
     def _tick(self, items: Sequence, t_tick: float) -> list:
@@ -1749,7 +1748,7 @@ class FusedCore:
     def _collect_late(self, entry: tuple) -> None:
         """A collect between ticks (the idle flush, the shutdown drain):
         its own ``kcp.tick`` on the profiler's timeline."""
-        with obs.annotate("kcp.tick", mono=time.monotonic()):
+        with obs.annotate("kcp.tick"):
             self._collect(*entry)
 
     async def _drain_inflight(self) -> None:
