@@ -21,10 +21,10 @@ Three paths:
 - :func:`fanout_match` — N objects x C single-pair selectors (the syncer
   fan-out shape, one ``kcp.dev/cluster=<id>`` per cluster) as one
   [N, C] compare reduce (device)
-- :func:`match_batch_np` / :func:`fanout_match_np` — numpy twins of the
-  same kernels for host-side consumers (the store's batched watch
-  fan-out) where a device round trip per micro-batch would cost more
-  than it saves
+- :func:`match_batch_np` — numpy twin of :func:`match_batch` for
+  host-side consumers (the store's watch fan-out, for wildcard-cluster
+  watches with a general selector) where a device round trip per
+  micro-batch would cost more than it saves
 
 The hash functions are pluggable: the device path uses the 32-bit FNV
 hashes (collision-tolerant — the syncer re-verifies on the host before
@@ -174,11 +174,6 @@ def match_batch_np(
     contains = (eq & (cs.alts != 0)[:, None, None, :]).any(axis=(2, 3))  # [R,N]
     satisfied = np.logical_xor(contains, cs.negate[:, None]) | ~cs.valid[:, None]
     return satisfied.all(axis=0)
-
-
-def fanout_match_np(pair_hashes: np.ndarray, selector_hashes: np.ndarray) -> np.ndarray:
-    """Numpy twin of :func:`fanout_match`: bool [N, C]."""
-    return (pair_hashes[:, None, :] == selector_hashes[None, :, None]).any(axis=-1)
 
 
 def match_host(sel: LabelSelector, labels_list: list[dict | None]) -> np.ndarray:

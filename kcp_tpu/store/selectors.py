@@ -63,9 +63,9 @@ class LabelSelector:
     def single_equality(self) -> tuple[str, str] | None:
         """(key, value) when this is exactly one ``=`` requirement — the
         dominant watch shape (the syncer registers one
-        ``kcp.dev/cluster=<id>`` per cluster) and the one the batched
-        fan-out can answer with a single pair-presence compare
-        (ops/labelmatch.fanout_match)."""
+        ``kcp.dev/cluster=<id>`` per cluster) and the one the store's
+        fan-out finds by the pair's interned id (a dict lookup per label
+        of the event; on the device, ops/labelmatch.fanout_match)."""
         if len(self.requirements) == 1:
             r = self.requirements[0]
             if r.op == "=" and len(r.values) == 1:

@@ -310,6 +310,49 @@ def _rewritten(ev: Event, etype: str) -> Event:
     return out
 
 
+class _FanoutBucket:
+    """The watches of one fan-out plan that share a cluster scope (one
+    logical cluster, or the wildcard), by what their selector shows."""
+
+    __slots__ = ("by_pid", "all", "transform")
+
+    def __init__(self):
+        # single-equality selectors, by interned pair id
+        self.by_pid: dict[int, list[Watch]] = {}
+        # empty selectors: a delivery of every event in scope
+        self.all: list[Watch] = []
+        # anything else: Watch._transform decides, event by event
+        self.transform: list[Watch] = []
+
+
+def _push_in_scope(ws: "list[Watch]", ev: Event, namespace: str) -> int:
+    """Push ``ev`` to those of ``ws`` whose namespace scope holds it;
+    returns their number."""
+    n = 0
+    for w in ws:
+        wns = w.namespace
+        if wns is None or wns == namespace:
+            w._push(ev)
+            n += 1
+    return n
+
+
+class _FanoutPlan:
+    """One resource's live watches as an index from an event to its
+    candidates (``LogicalStore._fanout_plan``)."""
+
+    __slots__ = ("ver", "by_cluster", "wild", "mx_ws", "w_ns")
+
+    def __init__(self, ver: int):
+        self.ver = ver
+        self.by_cluster: dict[str, _FanoutBucket] = {}
+        self.wild: _FanoutBucket | None = None
+        # the residual: wildcard-cluster watches with a compiled
+        # selector, and their namespace ids (-2 = every namespace)
+        self.mx_ws: list[Watch] = []
+        self.w_ns: np.ndarray | None = None
+
+
 class Watch:
     """A filtered subscription to store events.
 
@@ -347,9 +390,10 @@ class Watch:
         self._on_close: Callable[[], None] | None = None
         self._sink_marked = False
         # batched fan-out (indexed stores): a single-equality selector
-        # matches via one interned pair id (the fanout_match shape), a
-        # general kernel-shaped one via a CompiledSelector; both None =>
-        # exact per-event python matching (_transform)
+        # is found by its interned pair id, a general kernel-shaped one
+        # carries a CompiledSelector (matched as a matrix column where
+        # the watch is wildcard-cluster); both None => exact per-event
+        # python matching (_transform)
         self._eq_pid: int | None = None
         self._compiled = None
 
@@ -666,12 +710,12 @@ class LogicalStore:
         self._watches: list[Watch] = []
         # watch hub index: resource -> live watches, maintained on
         # subscribe/unsubscribe with a version stamp per resource so the
-        # fan-out's per-watch scope/selector arrays are built once per
+        # fan-out's plan (event -> candidate watches) is built once per
         # watch-set change, not once per flush (at 10k watchers the
         # per-flush rebuild WAS the fan-out cost)
         self._watches_by_res: dict[str, list[Watch]] = {}
         self._watch_ver: dict[str, int] = {}
-        self._fanout_cache: dict[str, tuple] = {}
+        self._fanout_cache: dict[str, _FanoutPlan] = {}
         # the watch-cache window (KCP_WATCH_WINDOW events): both the
         # resume source and the bound on how far back since_rv may reach
         self._history: deque[Event] = deque(maxlen=_env_watch_window())
@@ -751,6 +795,22 @@ class LogicalStore:
             "events coalesced per watch fan-out pass", buckets=SIZE_BUCKETS)
         self._emit_seconds = REGISTRY.histogram(
             "store_emit_seconds", "time delivering one fan-out batch")
+        # how often the fan-out's index engages and how precise it is
+        # (deliveries per candidate): one add each per flushed resource
+        self._fanout_counters = (
+            REGISTRY.counter(
+                "store_fanout_events_total",
+                "events fanned out to a resource's watches"),
+            REGISTRY.counter(
+                "store_fanout_indexed_events_total",
+                "fanned-out events that met no residual (matrix) watch: "
+                "their cost did not depend on the number of watches"),
+            REGISTRY.counter(
+                "store_fanout_candidates_total",
+                "(event, watch) pairs the fan-out evaluated"),
+            REGISTRY.counter(
+                "store_fanout_deliveries_total",
+                "events the fan-out pushed to a watch"))
         # convergence-phase stamps (obs/trace.py PHASES), all
         # time.monotonic(): the serving handler hands a request's entry
         # stamp to the write verb it is about to call through
@@ -759,11 +819,9 @@ class LogicalStore:
         # ``last_commit`` is the commit stamp of the newest event
         self.write_t0: float | None = None
         self.last_commit = 0.0
-        # global cluster/namespace interning for the fan-out scope
-        # matrices: ids are stable across batches, so the per-watch
-        # scope arrays can be cached per watch-set version instead of
-        # re-interned against every batch
-        self._intern_cl: dict[str, int] = {}
+        # namespace interning for the residual fan-out's scope matrix:
+        # ids are stable across batches, so the per-watch scope array
+        # is cached with the plan instead of re-interned every batch
         self._intern_ns: dict[str, int] = {}
         self._wal: _WalConfig | None = None
         self._engine = None
@@ -2045,135 +2103,205 @@ class LogicalStore:
             if self._watches_by_res.get(res):
                 self._fanout_resource(res, evs)
 
-    def _cid(self, cluster: str) -> int:
-        i = self._intern_cl.get(cluster)
-        if i is None:
-            i = self._intern_cl[cluster] = len(self._intern_cl)
-        return i
+    def _fanout_plan(self, res: str) -> _FanoutPlan:
+        """The per-resource fan-out plan — an index from an event to the
+        watches that can match it — cached per watch-set version, so a
+        flush costs O(events x labels + deliveries) whatever the number
+        of watches. The partition follows what each watch shows:
 
-    def _nid(self, namespace: str) -> int:
-        i = self._intern_ns.get(namespace)
-        if i is None:
-            i = self._intern_ns[namespace] = len(self._intern_ns)
-        return i
+        - a cluster-scoped watch sits in its cluster's bucket;
+        - a wildcard-cluster watch sits in the wildcard bucket, unless
+          its selector is a compiled (kernel-shaped) one: those are the
+          residual, matched as [N events x C residual watches] matrices
+          (``_fanout_residual``), so many general wildcard selectors
+          stay vectorized.
 
-    def _fanout_plan(self, res: str):
-        """The per-resource fan-out plan — the watch partition plus the
-        per-watch scope/selector arrays — cached per watch-set version.
-        Rebuilding this per flush was O(watches) python per mutation
-        batch; at 10k live watchers the cache makes a flush O(events +
-        deliveries) with the [N, C] algebra in numpy."""
+        Inside a bucket a single-equality selector is found by its
+        interned pair id, an empty selector is a delivery of every event
+        in scope, and everything else (compiled, oversized) is handed to
+        ``Watch._transform`` event by event."""
         ver = self._watch_ver.get(res, 0)
         plan = self._fanout_cache.get(res)
-        if plan is not None and plan[0] == ver:
+        if plan is not None and plan.ver == ver:
             return plan
-        ws = [w for w in self._watches_by_res.get(res, ()) if not w._closed]
-        fb_ws = [w for w in ws if not w.selector.empty
-                 and w._eq_pid is None and w._compiled is None]
-        mx_ws = [w for w in ws if w.selector.empty
-                 or w._eq_pid is not None or w._compiled is not None]
-        w_cl = np.array([-2 if w.cluster == WILDCARD
-                         else self._cid(w.cluster) for w in mx_ws], np.int32)
-        w_ns = np.array([-2 if w.namespace is None
-                         else self._nid(w.namespace) for w in mx_ws], np.int32)
-        eq_cols = [ci for ci, w in enumerate(mx_ws) if w._eq_pid is not None]
-        gen_cols = [ci for ci, w in enumerate(mx_ws) if w._compiled is not None]
-        empty_cols = [ci for ci, w in enumerate(mx_ws) if w.selector.empty]
-        sels = (np.array([mx_ws[ci]._eq_pid for ci in eq_cols], np.uint32)
-                if eq_cols else None)
-        plan = (ver, mx_ws, fb_ws, w_cl, w_ns, eq_cols, gen_cols,
-                empty_cols, sels)
+        plan = _FanoutPlan(ver)
+        for w in self._watches_by_res.get(res, ()):
+            if w._closed:
+                continue
+            if w.cluster != WILDCARD:
+                bucket = plan.by_cluster.get(w.cluster)
+                if bucket is None:
+                    bucket = plan.by_cluster[w.cluster] = _FanoutBucket()
+            elif w._compiled is not None:
+                plan.mx_ws.append(w)
+                continue
+            else:
+                if plan.wild is None:
+                    plan.wild = _FanoutBucket()
+                bucket = plan.wild
+            if w.selector.empty:
+                bucket.all.append(w)
+            elif w._eq_pid is not None:
+                bucket.by_pid.setdefault(w._eq_pid, []).append(w)
+            else:
+                bucket.transform.append(w)
+        if plan.mx_ws:
+            ns_id = self._intern_ns
+            plan.w_ns = np.array(
+                [-2 if w.namespace is None
+                 else ns_id.setdefault(w.namespace, len(ns_id))
+                 for w in plan.mx_ws], np.int32)
         self._fanout_cache[res] = plan
         return plan
 
+    def _label_pids(self, obj: dict | None) -> list[int]:
+        """The interned ids of ``obj``'s label pairs that some selector
+        names. Looked up, never interned: a pair no watch ever selected
+        cannot match one, and tenants' label values are unbounded."""
+        intern = self._intern_pairs
+        out: list[int] = []
+        for k, v in Watch._labels(obj).items():
+            pid = intern.get((k, v) if v.__class__ is str
+                             else self._pair_token(k, v))
+            if pid is not None:
+                out.append(pid)
+        return out
+
+    def _seen_by_pair(self, ev: Event) -> list[tuple[int, str]]:
+        """(pair id, type) for the selected label pairs of ``ev``'s new
+        and old object: the type under which a watch whose selector is
+        that one pair sees the event — :meth:`Watch._transform`'s rules
+        with new_match / old_match read off the pair's presence."""
+        old = self._label_pids(ev.old_object)
+        if ev.type == DELETED:  # nothing matches anew on DELETED
+            return [(pid, DELETED) for pid in old]
+        new = self._label_pids(ev.object)
+        if ev.type == ADDED:
+            return [(pid, ADDED) for pid in new]
+        return ([(pid, MODIFIED if pid in old else ADDED) for pid in new]
+                + [(pid, DELETED) for pid in old if pid not in new])
+
     def _fanout_resource(self, res: str, evs: list[Event]) -> None:
-        """One resource's events x that resource's watches, as matrices.
+        """One resource's events to that resource's watches, by index.
 
-        Selector matching is one vectorized pass over interned label ids:
-        single-equality selectors (the syncer shape) via fanout_match_np,
-        kernel-shaped ones via match_batch_np, oversized ones via the
-        exact per-event python path. Scope and the old-match/new-match
-        ADDED/MODIFIED/DELETED rewrite of :meth:`Watch._transform` are
-        then [N, C] boolean algebra; python touches only the (sparse)
-        deliveries. Per-watch arrays come from the cached fan-out plan.
-        """
-        n = len(evs)
-        (_ver, mx_ws, fb_ws, w_cl, w_ns, eq_cols, gen_cols, empty_cols,
-         sels) = self._fanout_plan(res)
-        if mx_ws:
-            c = len(mx_ws)
-            # scope[N, C]: cluster/namespace ids from the store-global
-            # intern tables (stable across batches, so the w_cl/w_ns
-            # arrays are cached in the plan); wildcards are -2
-            cl_ids = np.fromiter((self._cid(ev.cluster) for ev in evs),
-                                 np.int32, n)
-            ns_ids = np.fromiter((self._nid(ev.namespace) for ev in evs),
-                                 np.int32, n)
-            scope = ((w_cl[None, :] == -2) | (cl_ids[:, None] == w_cl[None, :])) \
-                & ((w_ns[None, :] == -2) | (ns_ids[:, None] == w_ns[None, :]))
-
-            is_add = np.fromiter((ev.type == ADDED for ev in evs), bool, n)
-            is_del = np.fromiter((ev.type == DELETED for ev in evs), bool, n)
-            is_mod = ~(is_add | is_del)
-
-            nm = np.zeros((n, c), bool)
-            om = np.zeros((n, c), bool)
-            if eq_cols or gen_cols:
-                from ..ops import labelmatch as lm
-
-                pair_new, key_new = self._encode_labels(evs, old=False)
-                pair_old, key_old = self._encode_labels(evs, old=True)
-                if eq_cols:
-                    nm[:, eq_cols] = lm.fanout_match_np(pair_new, sels)
-                    om[:, eq_cols] = lm.fanout_match_np(pair_old, sels)
-                for ci in gen_cols:
-                    cs = mx_ws[ci]._compiled
-                    nm[:, ci] = lm.match_batch_np(pair_new, key_new, cs)
-                    om[:, ci] = lm.match_batch_np(pair_old, key_old, cs)
-            if empty_cols:
-                nm[:, empty_cols] = om[:, empty_cols] = True
-            nm &= ~is_del[:, None]  # _transform: new_match is False on DELETED
-
-            as_is = scope & ((is_add[:, None] & nm)
-                             | (is_del[:, None] & (om | nm))
-                             | (is_mod[:, None] & nm & om))
-            to_add = scope & is_mod[:, None] & nm & ~om
-            to_del = scope & is_mod[:, None] & ~nm & om
-            # argwhere is row-major: per-watch delivery stays in rv order.
-            # Rewritten (label-transition) events are built once per
-            # source event and shared across every matched watch, so the
-            # encode-once wire cache on the Event pays off for them too.
-            rw_add: dict[int, Event] = {}
-            rw_del: dict[int, Event] = {}
-            for ni, ci in np.argwhere(as_is | to_add | to_del):
-                w = mx_ws[ci]
-                if w._closed:
+        Walks the events in rv order; each one meets its cluster's bucket
+        and the wildcard bucket of the fan-out plan and nothing else.
+        Scope and the ADDED / MODIFIED / DELETED rewrite are
+        :meth:`Watch._transform`'s: decided once per pair id for the
+        single-equality watches found under it, trivially for an empty
+        selector, and by ``_transform`` itself for any other candidate.
+        A rewritten (label-transition) event is built once per source
+        event and type and shared by every watch it reaches, so the
+        encode-once wire line on the Event pays off for it too. A watch
+        closed from inside ``_push`` (a fault drill, an eviction) is out
+        of the NEXT plan; in this pass ``_push`` skips it."""
+        plan = self._fanout_plan(res)
+        by_cluster, wild = plan.by_cluster, plan.wild
+        cands = delivs = 0
+        # rewritten events by (source index, type)
+        rewrites: dict[tuple[int, str], Event] = {}
+        for ni, ev in enumerate(evs):
+            scoped = by_cluster.get(ev.cluster)
+            if scoped is None and wild is None:
+                continue
+            etype, ns = ev.type, ev.namespace
+            seen = None
+            for bucket in (scoped, wild):
+                if bucket is None:
                     continue
-                ev = evs[ni]
-                if as_is[ni, ci]:
-                    w._push(ev)
-                elif to_add[ni, ci]:
-                    out = rw_add.get(ni)
+                if bucket.by_pid:
+                    if seen is None:
+                        seen = self._seen_by_pair(ev)
+                    for pid, sees in seen:
+                        ws = bucket.by_pid.get(pid)
+                        if ws is None:
+                            continue
+                        out = ev
+                        if sees != etype:
+                            out = rewrites.get((ni, sees))
+                            if out is None:
+                                out = rewrites[ni, sees] = _rewritten(ev, sees)
+                        cands += len(ws)
+                        delivs += _push_in_scope(ws, out, ns)
+                if bucket.all:
+                    cands += len(bucket.all)
+                    delivs += _push_in_scope(bucket.all, ev, ns)
+                for w in bucket.transform:
+                    cands += 1
+                    out = w._transform(ev)
                     if out is None:
-                        out = rw_add[ni] = _rewritten(ev, ADDED)
+                        continue
+                    if out is not ev:  # a rewrite: share the first one built
+                        out = rewrites.setdefault((ni, out.type), out)
                     w._push(out)
-                else:
-                    out = rw_del.get(ni)
-                    if out is None:
-                        out = rw_del[ni] = _rewritten(ev, DELETED)
-                    w._push(out)
-        for w in fb_ws:
-            # oversized selector: exact per-event fallback
-            for ev in evs:
-                if w._closed:
-                    break
-                out = w._transform(ev)
-                if out is not None:
-                    w._push(out)
+                    delivs += 1
+        n = len(evs)
+        events, indexed, candidates, deliveries = self._fanout_counters
+        events.inc(n)
+        if plan.mx_ws:
+            cands += n * len(plan.mx_ws)
+            delivs += self._fanout_residual(plan, evs, rewrites)
+        else:
+            indexed.inc(n)
+        candidates.inc(cands)
+        deliveries.inc(delivs)
+
+    def _fanout_residual(self, plan: _FanoutPlan, evs: list[Event],
+                         rewrites: dict[tuple[int, str], Event]) -> int:
+        """The plan's residual — wildcard-cluster watches with a compiled
+        selector — as [N events x C residual watches] matrices: selector
+        matching is one ``match_batch_np`` per column over interned label
+        ids, namespace scope and the old-match/new-match rewrite of
+        :meth:`Watch._transform` are boolean algebra, and python touches
+        only the (sparse) deliveries. Returns their number."""
+        lm = self._labelmatch  # loaded when the first selector compiled
+        mx_ws = plan.mx_ws
+        n, c = len(evs), len(mx_ws)
+        # a namespace no watch is scoped to has no id and matches none
+        ns_id = self._intern_ns
+        ns_ids = np.fromiter((ns_id.get(ev.namespace, -1) for ev in evs),
+                             np.int32, n)
+        w_ns = plan.w_ns
+        scope = (w_ns[None, :] == -2) | (ns_ids[:, None] == w_ns[None, :])
+
+        is_add = np.fromiter((ev.type == ADDED for ev in evs), bool, n)
+        is_del = np.fromiter((ev.type == DELETED for ev in evs), bool, n)
+        is_mod = ~(is_add | is_del)
+
+        pair_new, key_new = self._encode_labels(evs, old=False)
+        pair_old, key_old = self._encode_labels(evs, old=True)
+        nm = np.empty((n, c), bool)
+        om = np.empty((n, c), bool)
+        for ci, w in enumerate(mx_ws):
+            nm[:, ci] = lm.match_batch_np(pair_new, key_new, w._compiled)
+            om[:, ci] = lm.match_batch_np(pair_old, key_old, w._compiled)
+        nm &= ~is_del[:, None]  # _transform: new_match is False on DELETED
+
+        as_is = scope & ((is_add[:, None] & nm)
+                         | (is_del[:, None] & (om | nm))
+                         | (is_mod[:, None] & nm & om))
+        to_add = scope & is_mod[:, None] & nm & ~om
+        to_del = scope & is_mod[:, None] & ~nm & om
+        # argwhere is row-major: per-watch delivery stays in rv order
+        hits = np.argwhere(as_is | to_add | to_del)
+        for ni, ci in hits.tolist():
+            ev = evs[ni]
+            if not as_is[ni, ci]:
+                sees = ADDED if to_add[ni, ci] else DELETED
+                out = rewrites.get((ni, sees))
+                if out is None:
+                    out = rewrites[ni, sees] = _rewritten(ev, sees)
+                ev = out
+            mx_ws[ci]._push(ev)
+        return len(hits)
 
     def _encode_labels(self, evs: list[Event], old: bool) -> tuple[np.ndarray, np.ndarray]:
         """Interned (pair ids, key ids), 0-padded to the batch's widest
-        label set — the host-twin encoding of ops/encode.encode_label_batch."""
+        label set — the host-twin encoding of ops/encode.encode_label_batch.
+        A pair or key no selector names reads 0, the padding: it can
+        equal no alternative of a compiled selector (those were interned
+        when it was compiled), and the tables stay as large as the
+        selectors, not the tenants' labels."""
         labels_list = []
         width = 1
         for ev in evs:
@@ -2183,10 +2311,11 @@ class LogicalStore:
             width = max(width, len(labels))
         pair = np.zeros((len(evs), width), np.uint32)
         keyh = np.zeros((len(evs), width), np.uint32)
+        pairs, keys = self._intern_pairs, self._intern_keys
         for i, labels in enumerate(labels_list):
             for j, (k, v) in enumerate(labels.items()):
-                pair[i, j] = self._pid(k, v)
-                keyh[i, j] = self._kid(k)
+                pair[i, j] = pairs.get(self._pair_token(k, v), 0)
+                keyh[i, j] = keys.get(k, 0)
         return pair, keyh
 
     @staticmethod

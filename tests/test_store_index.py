@@ -319,3 +319,296 @@ def test_modified_rewrites_inside_one_batch():
     assert types == [ADDED, DELETED, ADDED, DELETED]
     # MODIFIED never surfaced: every event was a boundary transition
     assert MODIFIED not in types
+
+
+# ---------------------------------------------------------------------------
+# the fan-out's index (cluster and label-pair buckets): every watch sees
+# what Watch._transform gives event by event, whatever the mix of watches
+# ---------------------------------------------------------------------------
+
+from kcp_tpu import faults  # noqa: E402
+
+MIX_CLUSTERS = (WILDCARD, "c0", "c1", "c2", "c3")
+MIX_NAMESPACES = (None, None, "ns0", "ns1")
+MIX_SELECTORS = (
+    "", "",  # empty
+    "team=a", "team=a", "team=b", "tier=web", "team=nobody",  # one pair id
+    "team in (a,b),tier!=db", "!tier", "team notin (b),tier",  # compiled
+    "team=a,k1,k2,!k3,k4,k5,k6,k7,k8",  # 9 requirements: oversized
+    "team in (a,b,c,d,e,f,g,h,i)",  # 9 alternatives: oversized
+)
+MIX_LABELS = (
+    None, None,
+    {"team": "a"}, {"team": "b"}, {"team": "a", "tier": "web"},
+    {"tier": "db"}, {"team": "c", "tier": "web", "owner": "x"},
+    {"team": "a", "k1": "1", "k2": "1", "k4": "1", "k5": "1", "k6": "1",
+     "k7": "1", "k8": "1"},
+)
+
+
+@pytest.fixture
+def _no_faults():
+    yield
+    faults.clear()
+
+
+def _mixed_watches(store, rng, n):
+    out = []
+    for _ in range(n):
+        sel = rng.choice(MIX_SELECTORS)
+        out.append(store.watch(
+            rng.choice(("configmaps", "configmaps", "secrets")),
+            rng.choice(MIX_CLUSTERS), rng.choice(MIX_NAMESPACES),
+            parse_selector(sel) if sel else None))
+    return out
+
+
+def _mixed_event(store, rng, serial):
+    """One random ADDED / MODIFIED (labels starting, stopping, changing,
+    absent, unchanged) / DELETED; False where the roll met no object."""
+    resource = rng.choice(("configmaps", "configmaps", "secrets"))
+    cluster = rng.choice(MIX_CLUSTERS[1:])
+    namespace = rng.choice(("ns0", "ns1", "ns2"))
+    name = f"n{rng.randrange(6)}"
+    roll = rng.random()
+    try:
+        if roll < 0.35:
+            obj = _cm(name, ns=namespace, labels=rng.choice(MIX_LABELS))
+            obj["data"] = {"v": str(serial)}
+            store.create(resource, cluster, obj, namespace)
+        elif roll < 0.8:
+            cur = store.get(resource, cluster, name, namespace)
+            cur["data"] = {"v": str(serial)}
+            if rng.random() < 0.6:
+                labels = rng.choice(MIX_LABELS)
+                cur["metadata"].pop("labels", None)
+                if labels:
+                    cur["metadata"]["labels"] = dict(labels)
+            store.update(resource, cluster, cur, namespace)
+        else:
+            store.delete(resource, cluster, name, namespace)
+    except (errors.NotFoundError, errors.AlreadyExistsError):
+        return False
+    return True
+
+
+def _drive_flushes(store, rng, flush, events):
+    """``events`` mutations, fanned out in flushes of ``flush``."""
+    store._emit_batch = 10 ** 9  # the test decides where a flush ends
+    done = 0
+    while done < events:
+        done += _mixed_event(store, rng, done)
+        if len(store._pending) >= flush:
+            store._flush_events()
+    store._flush_events()
+
+
+def _expected(w, source):
+    """What ``Watch._transform`` gives ``w``, event by event."""
+    return [(ev, out) for ev in source
+            if (out := w._transform(ev)) is not None]
+
+
+def _assert_stream(w, got, source, rewritten, prefix=False):
+    """``got`` is what ``_transform`` gives event by event: type, key,
+    rv, the source's own object (identity), and the source EVENT itself
+    wherever the type did not change. ``rewritten`` collects the others
+    by (rv, type) across watches."""
+    want = _expected(w, source)
+    if prefix:
+        assert len(got) <= len(want)
+        want = want[:len(got)]
+    assert [(e.type, e.key, e.rv) for e in got] \
+        == [(out.type, out.key, out.rv) for _, out in want], \
+        (w.resource, w.cluster, w.namespace, str(w.selector))
+    for e, (ev, out) in zip(got, want):
+        assert e.object is ev.object and e.old_object is ev.old_object
+        if out is ev:
+            assert e is ev  # the shared event: one wire line for all
+        else:
+            assert e is not ev and e.type != ev.type
+            rewritten.setdefault((e.rv, e.type), []).append(e)
+
+
+@pytest.mark.parametrize("flush", [1, 3, 300])
+@pytest.mark.parametrize("seed", [5, 23, 77])
+def test_fanout_index_delivers_what_transform_gives(seed, flush):
+    rng = random.Random(seed * 1000 + flush)
+    s = LogicalStore(indexed=True)
+    watches = _mixed_watches(s, rng, 48)
+    _drive_flushes(s, rng, flush, 600)
+    source = list(s._history)
+    assert len(source) == 600
+    rewritten: dict = {}
+    delivered = 0
+    for w in watches:
+        got = list(w._events)
+        delivered += len(got)
+        _assert_stream(w, got, source, rewritten)
+    assert delivered > 600  # the mix does reach its watches
+    assert rewritten, "no label transition was rewritten: the mix is too tame"
+    assert any(len(es) > 1 for es in rewritten.values())
+    # a rewritten event is ONE object across the watches it reaches
+    assert all(e is es[0] for es in rewritten.values() for e in es)
+    s.close()
+
+
+@pytest.mark.parametrize("how", ["watch:drop@tick=40", "watch.evict:drop@tick=25",
+                                 "queue-bound-1"])
+@pytest.mark.parametrize("flush", [1, 300])
+def test_watch_closed_inside_the_pass_loses_nothing_for_the_others(
+        how, flush, _no_faults):
+    rng = random.Random(len(how) * 1000 + flush)
+    s = LogicalStore(indexed=True)
+    if how == "queue-bound-1":
+        s._watch_queue = 1
+        doomed = [s.watch("configmaps"),
+                  s.watch("configmaps", "c1", None, parse_selector("team=a")),
+                  s.watch("configmaps", "c2", None, parse_selector("!tier"))]
+        s._watch_queue = 0
+    else:
+        doomed = []
+    watches = _mixed_watches(s, rng, 40)
+    if how != "queue-bound-1":
+        faults.install(faults.FaultInjector(how))
+    _drive_flushes(s, rng, flush, 400)
+    faults.clear()
+    source = list(s._history)
+    closed = [w for w in watches + doomed if w.closed]
+    assert closed, "nothing was dropped: the drill did not fire"
+    if how == "queue-bound-1":
+        assert all(w.closed and w.evicted for w in doomed)
+    else:
+        assert len(closed) == 1
+    rewritten: dict = {}
+    for w in watches + doomed:
+        # a closed watch holds a prefix of its stream, every other one
+        # the whole of it
+        _assert_stream(w, list(w._events), source, rewritten,
+                       prefix=w.closed)
+    # a closed watch is out of every later plan
+    for res in ("configmaps", "secrets"):
+        plan = s._fanout_plan(res)
+        live = [w for b in list(plan.by_cluster.values()) + [plan.wild]
+                if b is not None
+                for w in b.all + b.transform
+                + [x for ws in b.by_pid.values() for x in ws]] + plan.mx_ws
+        assert not any(w.closed for w in live)
+        assert len(live) == len(s._watches_by_res.get(res, ()))
+    s.close()
+
+
+def test_watch_added_or_closed_between_flushes_is_in_or_out_of_the_next_plan():
+    s = LogicalStore(indexed=True)
+    first = s.watch("configmaps", "c0", None, parse_selector("team=a"))
+    s.create("configmaps", "c0", _cm("x0", labels={"team": "a"}))
+    s._flush_events()
+    late = [s.watch("configmaps", "c0", None, parse_selector("team=a")),
+            s.watch("configmaps", WILDCARD, None, parse_selector("team=a")),
+            s.watch("configmaps", WILDCARD, None, None),
+            s.watch("configmaps", WILDCARD, None, parse_selector("team,!tier")),
+            s.watch("configmaps", "c0", "default", None)]
+    s.create("configmaps", "c0", _cm("x1", labels={"team": "a"}))
+    s._flush_events()
+    assert [e.name for e in first.drain()] == ["x0", "x1"]
+    for w in late:
+        assert [e.name for e in w.drain()] == ["x1"]
+    for w in late[::2]:
+        w.close()
+    s.create("configmaps", "c0", _cm("x2", labels={"team": "a"}))
+    s._flush_events()
+    assert [e.name for e in first.drain()] == ["x2"]
+    for i, w in enumerate(late):
+        assert [e.name for e in w.drain()] == ([] if i % 2 == 0 else ["x2"])
+    s.close()
+
+
+@pytest.mark.parametrize("residual", [False, True])
+def test_intern_tables_do_not_grow_with_labels_no_watch_selects(residual):
+    s = LogicalStore(indexed=True)
+    w = s.watch("configmaps", "c0", None, parse_selector("team=a"))
+    wild = s.watch("configmaps", WILDCARD, None, parse_selector("team=a"))
+    if residual:  # a compiled wildcard selector: the matrix path encodes
+        s.watch("configmaps", WILDCARD, None, parse_selector("team in (a,b),tier"))
+    pairs, keys, nss = (len(s._intern_pairs), len(s._intern_keys),
+                        len(s._intern_ns))
+    for i in range(200):
+        s.create("configmaps", "c0", _cm(
+            f"n{i}", ns=f"ns{i}",
+            labels={"team": "a" if i % 2 else f"t{i}", f"key{i}": f"value{i}",
+                    "tier": f"tier{i}"}))
+        if i % 7 == 0:
+            s._flush_events()
+    s._flush_events()
+    assert len(w.drain()) == 100 and len(wild.drain()) == 100
+    assert (len(s._intern_pairs), len(s._intern_keys), len(s._intern_ns)) \
+        == (pairs, keys, nss)
+    s.close()
+
+
+def _fanout_counters():
+    return tuple(REGISTRY.counter(f"store_fanout_{n}_total").value for n in
+                 ("events", "indexed_events", "candidates", "deliveries"))
+
+
+def test_fanout_cost_does_not_grow_with_scoped_watches(monkeypatch):
+    """A cost guard, not a timing: behind 2,000 scoped watches a flush
+    of one event evaluates the watch of its cluster and the wildcard
+    ones, and never enters the matrix path."""
+    s = LogicalStore(indexed=True)
+    scoped = [s.watch("configmaps", f"t{i}", None,
+                      parse_selector(f"kcp.dev/cluster=loc{i}"))
+              for i in range(2000)]
+    wild = s.watch("configmaps")
+    monkeypatch.setattr(
+        LogicalStore, "_fanout_residual",
+        lambda *a, **k: pytest.fail("the matrix path was entered"))
+    monkeypatch.setattr(
+        LogicalStore, "_encode_labels",
+        lambda *a, **k: pytest.fail("labels were encoded for numpy"))
+    s.create("configmaps", "t7", _cm("x", labels={"kcp.dev/cluster": "loc7"}))
+    s._flush_events()  # builds the plan
+    for labels in ({"kcp.dev/cluster": "loc7", "app": "web"},
+                   {"kcp.dev/cluster": "loc8"},  # leaves t7's watch: DELETED
+                   None):
+        before = _fanout_counters()
+        obj = s.get("configmaps", "t7", "x", "default")
+        obj["metadata"].pop("labels", None)
+        if labels:
+            obj["metadata"]["labels"] = labels
+        s.update("configmaps", "t7", obj)
+        s._flush_events()
+        events, indexed, cands, delivs = (
+            b - a for a, b in zip(before, _fanout_counters()))
+        assert events == indexed == 1
+        assert 1 <= cands <= 4 and delivs <= cands
+    assert [e.type for e in scoped[7].drain()] == [ADDED, MODIFIED, DELETED]
+    assert not scoped[8].drain()  # another cluster's pair: out of scope
+    assert len(wild.drain()) == 4
+    assert s._labelmatch is None  # no selector was ever compiled
+    s.close()
+
+
+def test_fanout_residual_is_taken_and_counted():
+    """Wildcard watches with general selectors keep the matrix path, over
+    their own columns only; the counters say so."""
+    s = LogicalStore(indexed=True)
+    general = [s.watch("configmaps", WILDCARD, None,
+                       parse_selector(f"team in (a,b{i}),tier!=db"))
+               for i in range(10)]
+    scoped = [s.watch("configmaps", f"t{i}", None, parse_selector("team=a"))
+              for i in range(50)]
+    before = _fanout_counters()
+    for i in range(6):
+        s.create("configmaps", f"t{i}", _cm("x", labels={"team": "a"}))
+    s.create("secrets", "t0", _cm("s", labels={"team": "a"}))  # no watch
+    s._flush_events()
+    events, indexed, cands, delivs = (
+        b - a for a, b in zip(before, _fanout_counters()))
+    assert events == 6 and indexed == 0
+    assert cands == 6 * 10 + 6 and delivs == 6 * 10 + 6
+    plan = s._fanout_plan("configmaps")
+    assert len(plan.mx_ws) == 10 and len(plan.by_cluster) == 50
+    assert all(len(w.drain()) == 6 for w in general)
+    assert [len(w.drain()) for w in scoped[:7]] == [1] * 6 + [0]
+    s.close()
