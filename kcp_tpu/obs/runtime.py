@@ -39,13 +39,20 @@ it); nothing here runs at import.
 - ``py_gc_pause_seconds``: a ``gc.callbacks`` hook, start to stop of
   every collection; each one is also a ``kcp.gc`` section (generation
   as a stat), so an idle gap of the device, or a long pass, under a
-  full collection reads as that.
+  full collection reads as that;
+- ``jax_backend_compile_seconds``: a ``jax.monitoring`` listener for
+  the backend's compile events, with their seconds: every program this
+  process asked the backend for, whoever asked — compiled by XLA, or
+  read from the disk cache (the event spans both; a read is tens of
+  milliseconds where a compile is seconds). Absent in a process that
+  never imports jax.
 """
 
 from __future__ import annotations
 
 import asyncio
 import gc
+import sys
 import time
 from collections import deque
 from threading import get_ident
@@ -270,6 +277,8 @@ class RuntimeProbes:
     _gc_users = 0
     _gc_t0 = 0.0
     _gc_ann = None
+    # jax.monitoring keeps a listener for the life of the process: one
+    _compiles_heard = False
 
     def __init__(self, loop: asyncio.AbstractEventLoop):
         self._loop = loop
@@ -288,7 +297,17 @@ class RuntimeProbes:
         if cls._gc_users == 0:
             gc.callbacks.append(_on_gc)
         cls._gc_users += 1
+        cls._hear_compiles()
         return self
+
+    @classmethod
+    def _hear_compiles(cls) -> None:
+        """Register the compile listener, once, where jax is imported
+        (the beat asks again: a server may import jax after it starts)."""
+        monitoring = getattr(sys.modules.get("jax"), "monitoring", None)
+        if monitoring is not None and not cls._compiles_heard:
+            cls._compiles_heard = True
+            monitoring.register_event_duration_secs_listener(_on_compile)
 
     def stop(self) -> None:
         if self._handle is not None:
@@ -310,6 +329,8 @@ class RuntimeProbes:
         self._lag.observe(max(0.0, time.monotonic() - self._due))
         if self.ledger is not None:
             self.ledger.publish()
+        if not RuntimeProbes._compiles_heard:
+            self._hear_compiles()
         self._arm()
 
 
@@ -317,6 +338,18 @@ _GC_PAUSE = REGISTRY.histogram(
     "py_gc_pause_seconds",
     "one run of the interpreter's cyclic collector, start to stop "
     "(every thread of the process waits for it)")
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILE = REGISTRY.histogram(
+    "jax_backend_compile_seconds",
+    "one program asked of the backend in this process (jax.monitoring's "
+    "backend_compile_duration): XLA's compile, or the disk cache's read")
+
+
+def _on_compile(event: str, seconds: float, **_kw) -> None:
+    if event == _COMPILE_EVENT:
+        _COMPILE.observe(seconds)
 
 
 def _on_gc(phase: str, info: dict) -> None:

@@ -24,14 +24,17 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 from enum import Enum
 
+from ... import obs
 from ...apis import apiresource as ar
 from ...apis import cluster as clusterapi
 from ...client import Client, Informer
 from ...reconciler.controller import Controller
 from ...syncer import Syncer
 from ...utils import errors
+from ...utils.trace import REGISTRY
 from ..cluster.apiimporter import APIImporter
 from . import installer
 from .installer import DEFAULT_SYNCER_IMAGE
@@ -46,6 +49,16 @@ class SyncerMode(Enum):
 
 
 DEFAULT_POLL_INTERVAL = 60.0  # reference: cluster.go:22
+
+_SYNCER_START = REGISTRY.histogram(
+    "cluster_syncer_start_seconds",
+    "a Cluster with no syncer first taken by a worker -> its push-mode "
+    "syncer started and Ready written (importer, negotiation and the "
+    "syncer's initial lists included)")
+_SYNCER_RESTARTS = REGISTRY.counter(
+    "cluster_syncer_restarts_total",
+    "running push-mode syncers stopped and started again because the "
+    "Cluster's synced resource set read differently from the one computed")
 
 
 class ClusterController:
@@ -93,6 +106,8 @@ class ClusterController:
         self.importers: dict[tuple[str, str], APIImporter] = {}
         self.syncers: dict[tuple[str, str], Syncer] = {}
         self._deleted: dict[tuple[str, str], dict] = {}
+        # when a worker first took a key that has no syncer yet
+        self._taken: dict[tuple[str, str], float] = {}
 
     # ------------------------------------------------------------ events
 
@@ -119,11 +134,14 @@ class ClusterController:
         if cluster is None:
             await self._cleanup(key)
             return
+        if self.mode == SyncerMode.PUSH and key not in self.syncers:
+            self._taken.setdefault(key, time.monotonic())
         await self._reconcile(key, cluster)
 
-    async def _reconcile(self, key, cluster: dict) -> None:
+    def _plan(self, key, cluster: dict, scoped: Client):
+        """(physical cluster, resources to sync) of one Cluster, or None
+        for an invalid kubeconfig (Ready=False written)."""
         lc, name = key
-        scoped = self.client.scoped(lc)
 
         # 1. resolve the physical cluster (invalid => Ready=False, no retry)
         kubeconfig = cluster.get("spec", {}).get("kubeconfig", "")
@@ -132,7 +150,7 @@ class ClusterController:
         except ValueError as err:
             self._set_status(scoped, cluster, ready=False,
                              reason=clusterapi.REASON_INVALID_KUBECONFIG, message=str(err))
-            return  # don't retry (cluster.go:38)
+            return None
 
         # 2. one importer per cluster (cluster.go:49-59)
         if key not in self.importers:
@@ -154,6 +172,17 @@ class ClusterController:
         from ...apis.scheme import GVR
         synced |= {GVR.parse(r).storage_name for r in self.resources_to_sync
                    if GVR.parse(r).storage_name in builtin}
+        return physical, synced
+
+    async def _reconcile(self, key, cluster: dict) -> None:
+        lc, name = key
+        scoped = self.client.scoped(lc)
+        # steps 1-3 hold no await: one section of the serving loop
+        with obs.annotate("kcp.cluster.reconcile"):
+            planned = self._plan(key, cluster, scoped)
+        if planned is None:
+            return  # don't retry (cluster.go:38)
+        physical, synced = planned
 
         if sorted(synced) != clusterapi.synced_resources(cluster):
             await self._restart_syncer(key, cluster, scoped, physical, sorted(synced))
@@ -187,6 +216,7 @@ class ClusterController:
         lc, name = key
         old = self.syncers.pop(key, None)
         if old is not None:
+            _SYNCER_RESTARTS.inc()
             await old.stop()
         if not synced:
             self._set_status(scoped, cluster, ready=True, synced=synced)
@@ -203,6 +233,9 @@ class ClusterController:
                                  message=str(err))
                 raise
             self._set_status(scoped, cluster, ready=True, synced=synced)
+            taken = self._taken.pop(key, None)
+            if taken is not None:
+                _SYNCER_START.observe(time.monotonic() - taken)
         elif self.mode == SyncerMode.PULL:
             try:
                 installer.install_syncer(
@@ -252,6 +285,7 @@ class ClusterController:
         syncer = self.syncers.pop(key, None)
         if syncer is not None:
             await syncer.stop()
+        self._taken.pop(key, None)
         if self.mode == SyncerMode.PULL:
             deleted = self._deleted.pop(key, None)
             if deleted is not None:

@@ -92,11 +92,14 @@ def _grown(a: np.ndarray, shape, dtype) -> np.ndarray:
 
 #: the phases of a tick, host time each (``fused_<phase>_seconds``): the
 #: 'where does tick time go' answer that /debug/profile and bench.py
-#: report. ``encode`` is observed only on ticks that touched keys and
+#: report. ``encode`` is observed only on ticks that touched keys,
 #: ``full_upload`` replaces ``pack`` on a tick that re-uploads the whole
-#: mirror, so the means stay meaningful. Histograms fetched once.
+#: mirror, and ``compile`` replaces ``step_dispatch`` on the first
+#: dispatch of a set of shapes and static arguments (XLA compiles it, or
+#: reads it from the disk cache, on the loop), so the means stay
+#: meaningful. Histograms fetched once.
 TICK_PHASES = ("encode", "pack", "full_upload", "put", "step_dispatch",
-               "collect_wait", "dispatch")
+               "compile", "collect_wait", "dispatch")
 _PHASE_H = {p: REGISTRY.histogram(f"fused_{p}_seconds",
                                   "host time of one tick phase")
             for p in TICK_PHASES}
@@ -112,6 +115,23 @@ _ENCODED_ROWS = REGISTRY.counter(
     "rows (touched keys, both sides of each) re-encoded by the ticks' "
     "encode phase: fused_encode_seconds' sum over this is the host cost "
     "of one row")
+# growth of the fleet batch: each is a full upload or a new program for
+# the jitted step, on the serving loop
+_ROW_GROWTHS = REGISTRY.counter(
+    "fused_fleet_row_growths_total",
+    "changes of the fleet batch's row count B after its first layout "
+    "(a bucket passed a power of two, or a bucket joined)")
+_SEGMENT_GROWTHS = REGISTRY.counter(
+    "fused_fleet_segment_growths_total",
+    "changes of the fleet step's static segment capacity (registered "
+    "sections passed a power of two)")
+_PATCH_GROWTHS = REGISTRY.counter(
+    "fused_fleet_patch_growths_total",
+    "changes of the fleet step's static pooled patch capacity (a patch "
+    "overflow doubled it, or B grew beneath it)")
+_UPLOAD_BYTES = REGISTRY.counter(
+    "fused_fleet_state_upload_bytes_total",
+    "bytes of resident fleet state put on the device by full uploads")
 
 
 class _Phases:
@@ -731,6 +751,10 @@ class FleetBatch:
         self._state: ReconcileState | None = None
         self._seg_ids = None  # device int32 [B]: row -> section segment
         self._seg_capacity = 8
+        self._patch_k: int | None = None  # pooled patch capacity last dispatched
+        # (shapes, static arguments) the step has been dispatched with:
+        # the first dispatch of another one is the tick's `compile` phase
+        self._dispatched: set[tuple] = set()
         self._stale = True
         # acks-lane wire capacity: sticky high-water doubling, so the
         # (packed, acks) shape pair stays stable after warmup — per-tick
@@ -777,6 +801,8 @@ class FleetBatch:
             base += b.B
             self._ends.append(base)
             s = max(s, b.S)
+        if self.B and base != self.B:
+            _ROW_GROWTHS.inc()
         self.B = base
         self.S = s
         self._pl_members, self._pl_bases, self._pl_ends = [], [], []
@@ -862,6 +888,7 @@ class FleetBatch:
             pair_hashes=np.zeros((self.B, 1), np.uint32),
             sel_hashes=np.zeros(8, np.uint32),
         )
+        _UPLOAD_BYTES.inc(seg.nbytes + sum(leaf.nbytes for leaf in state))
         if self.mesh is not None:
             from ..parallel.mesh import shard_state, state_shardings
 
@@ -893,7 +920,10 @@ class FleetBatch:
 
     def _submit(self, ph: _Phases) -> tuple[jax.Array, FleetMeta]:
         s = self.S
-        self._seg_capacity = pad_pow2(max(self.core._next_seg, 1), floor=8)
+        seg_capacity = pad_pow2(max(self.core._next_seg, 1), floor=8)
+        if seg_capacity != self._seg_capacity:
+            self._seg_capacity = seg_capacity
+            _SEGMENT_GROWTHS.inc()
         was_stale = self._stale or any(b._stale for b in self._members)
         # a stale tick re-uploads the whole mirror to the device, which
         # is not the steady-state pack — the histograms stay separable
@@ -1020,8 +1050,15 @@ class FleetBatch:
         else:
             packed_d = jax.device_put(packed)
             acks_d = jax.device_put(acks)
-        ph.enter("step_dispatch")
         k = self._patch_capacity()
+        if k != self._patch_k:
+            if self._patch_k is not None:
+                _PATCH_GROWTHS.inc()
+            self._patch_k = k
+        shapes = (self.B, s, self._state.replicas.shape[0],
+                  self._state.avail.shape[1], packed.shape[0], acks.shape[0],
+                  k, self._seg_capacity)
+        ph.enter("step_dispatch" if shapes in self._dispatched else "compile")
         # KCP_FAULTS `device.step` injection point (raise@tick / error /
         # poison_row): fires HERE, where a real XLA dispatch failure
         # would surface — the quarantine machinery recovers either way.
@@ -1036,6 +1073,7 @@ class FleetBatch:
         # the staging buffers may be re-acquired only once this step has
         # read them (see WireBuffers)
         self._wire_bufs.commit(buf_slot, packed_d, acks_d, wire)
+        self._dispatched.add(shapes)
         self._step_failures = 0
         wire.copy_to_host_async()
         ph.close()

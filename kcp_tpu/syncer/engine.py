@@ -100,6 +100,11 @@ _STATUS_DIRECT = REGISTRY.counter(
     "kcp_sync_status_upsyncs_direct_total",
     "upstream status writes made by an apply that the downstream event "
     "itself queued, with no tick between the event and the write")
+_INITIAL_ROWS = REGISTRY.counter(
+    "kcp_sync_initial_rows_total",
+    "rows staged by the replay of an upstream informer's initial list "
+    "(objects that existed before their syncer started), as against "
+    "rows staged by a live event")
 _PATCHES_DEFERRED = REGISTRY.counter(
     "kcp_sync_patches_deferred_total",
     "patches (of a collected tick, or a status handed over by its "
@@ -1016,6 +1021,9 @@ class BatchSyncEngine:
         # informers after the section exists: their initial list replays
         # the cache through the handlers, which enqueue into the core
         await self.up_informer.start()
+        # what the cache holds now is what the initial list replayed
+        # through _stage_up: one add a start, nothing a row
+        _INITIAL_ROWS.inc(len(self.up_informer.cache))
         await self.down_informer.start()
         if self.controller is not None:
             await self.controller.start()
