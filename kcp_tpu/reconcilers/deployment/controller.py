@@ -118,6 +118,8 @@ class DeploymentSplitter:
         self.cluster_informer = Informer(client, CLUSTERS)
         self.informer.add_indexer("owned_by", self._owned_by_index)
         self.informer.add_indexer("by_workspace", self._by_workspace_index)
+        self.cluster_informer.add_indexer(
+            "by_workspace", self._cluster_workspace_index)
         self.controller = BatchController(
             "deployment-splitter", self._process_batch,
             # item = ("root"|"leaf", (clusterName, ns, name)): fairness is
@@ -136,6 +138,23 @@ class DeploymentSplitter:
         self._apply_tasks: list = []
         self.stats = {"ticks": 0, "splits": 0, "aggregations": 0,
                       "fused_placements": 0}
+        # how often the cluster index engages and how much of the
+        # device's placement output is applied: one add each per lookup
+        # and per row taken from the apply queue, none per cluster
+        self._cluster_lookups = REGISTRY.counter(
+            "splitter_cluster_lookups_total",
+            "lookups of a workspace's placement-eligible clusters")
+        self._cluster_candidates = REGISTRY.counter(
+            "splitter_cluster_candidates_total",
+            "clusters those lookups read before the evacuation filter: "
+            "the workspace's own, by index")
+        self._placement_rows = REGISTRY.counter(
+            "splitter_placement_rows_total",
+            "placement rows the fused step handed to the applier (every "
+            "row it re-emits included)")
+        self._placements_applied = REGISTRY.counter(
+            "splitter_fused_placements_total",
+            "placement rows whose device-computed split was written")
         # root key -> time.monotonic() of the first event not yet
         # answered: a root's own (split) and its leaves' (aggregate).
         # Popped by the pass that answers the key or finds nothing to do
@@ -156,6 +175,12 @@ class DeploymentSplitter:
         instead of scanning every object of every tenant."""
         if not is_root(obj):
             return []
+        return [obj["metadata"].get("clusterName", "")]
+
+    @staticmethod
+    def _cluster_workspace_index(obj: dict) -> list[str]:
+        """Clusters keyed by logical cluster — a placement looks up ONE
+        workspace's clusters instead of scanning the whole fleet's."""
         return [obj["metadata"].get("clusterName", "")]
 
     @property
@@ -417,6 +442,7 @@ class DeploymentSplitter:
                 self._apply_q.task_done()
 
     def _apply_one_fused(self, key, counts: np.ndarray) -> None:
+        self._placement_rows.inc()
         root = self.informer.cache.get(key)
         if root is None or not is_root(root):
             return
@@ -438,6 +464,7 @@ class DeploymentSplitter:
         try:
             self._apply_placement(key, root, clusters, leafs, counts)
             self.stats["fused_placements"] += 1
+            self._placements_applied.inc()
         except Exception as err:  # noqa: BLE001
             log.info("deployment-splitter: fused placement apply for %r "
                      "failed (%s); requeued", key, err)
@@ -462,13 +489,19 @@ class DeploymentSplitter:
         """Placement-eligible clusters: evacuated (sustained-NotReady)
         clusters are excluded, so every split — host or fused lane —
         routes replicas only onto healthy capacity."""
-        # a section of its own: a scan of EVERY registered cluster, once
-        # a root in the tick and once more in its fused apply
+        # a section of its own: an indexed read of ONE workspace's
+        # clusters, once a root in the tick and once for every placement
+        # row the device emits. Evacuation is the inventory's timer's to
+        # decide, not a Cluster event's: filtered on every call, nothing
+        # cached
         with obs.annotate("kcp.splitter.clusters"):
+            candidates = self.cluster_informer.index(
+                "by_workspace", logical_cluster)
+            self._cluster_lookups.inc()
+            self._cluster_candidates.inc(len(candidates))
             return sorted(
-                (c for c in self.cluster_informer.list()
-                 if c["metadata"].get("clusterName", "") == logical_cluster
-                 and not self.inventory.is_evacuated(
+                (c for c in candidates
+                 if not self.inventory.is_evacuated(
                      logical_cluster, c["metadata"]["name"])),
                 key=lambda c: c["metadata"]["name"],
             )

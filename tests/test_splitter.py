@@ -9,6 +9,7 @@ from kcp_tpu.client import MultiClusterClient
 from kcp_tpu.reconcilers.deployment import DeploymentSplitter
 from kcp_tpu.reconcilers.deployment.controller import DEPLOYMENTS
 from kcp_tpu.store import LogicalStore
+from kcp_tpu.utils.trace import REGISTRY
 
 
 def deployment(name, replicas, ns="default"):
@@ -316,6 +317,195 @@ def test_flap_inside_hysteresis_is_zero_churn_and_replans_touch_one_workspace():
                 - resolves0) == 2
         assert t2.get(DEPLOYMENTS, "db--solo",
                       "default")["metadata"]["resourceVersion"] == other_rv
+        await splitter.stop()
+
+    asyncio.run(main())
+
+
+# ---------------------------------------------- the Cluster informer's index
+
+
+def _scan_clusters_for(splitter, lc):
+    """The scan `_clusters_for` made before the index: every registered
+    cluster read, the workspace's kept, evacuated ones dropped, by name."""
+    return sorted(
+        (c for c in splitter.cluster_informer.list()
+         if c["metadata"].get("clusterName", "") == lc
+         and not splitter.inventory.is_evacuated(lc, c["metadata"]["name"])),
+        key=lambda c: c["metadata"]["name"],
+    )
+
+
+def _cluster_counter(name):
+    return REGISTRY.counter(name).value
+
+
+@pytest.mark.parametrize("backend", ["tpu", "host"])
+def test_indexed_clusters_for_equals_the_scan(backend):
+    """Adds, deletes and status updates over workspaces that reuse
+    cluster NAMES, one cluster evacuated and readmitted, one workspace
+    with none: after every step the index hands `_clusters_for` the same
+    clusters in the same order as a scan of the whole informer."""
+    from kcp_tpu.apis.cluster import (CLUSTERS, REASON_SYNCER_NOT_READY,
+                                      set_not_ready, set_ready)
+
+    async def main():
+        store = LogicalStore()
+        mc = MultiClusterClient(store)
+        tenants = {lc: mc.cluster_client(lc) for lc in ("w1", "w2", "w3", "bare")}
+        splitter = DeploymentSplitter(mc, backend=backend, evac_hysteresis=0.2)
+        await splitter.start()
+
+        def names(lc):
+            return [c["metadata"]["name"] for c in splitter._clusters_for(lc)]
+
+        async def same(want: dict):
+            # the informer has caught up when the scan reads what was written
+            await eventually(lambda: all(
+                [c["metadata"]["name"] for c in _scan_clusters_for(splitter, lc)]
+                == want.get(lc, []) for lc in tenants))
+            for lc in tenants:
+                assert splitter._clusters_for(lc) == _scan_clusters_for(splitter, lc)
+                assert names(lc) == want.get(lc, [])
+
+        def add(lc, name):
+            obj = new_cluster(name)
+            set_ready(obj)
+            tenants[lc].create(CLUSTERS, obj)
+
+        def flip(lc, name, ready):
+            obj = tenants[lc].get(CLUSTERS, name)
+            if ready:
+                set_ready(obj)
+            else:
+                set_not_ready(obj, REASON_SYNCER_NOT_READY, "down")
+            tenants[lc].update_status(CLUSTERS, obj)
+
+        await same({})
+        # the same names in three workspaces, created out of name order
+        for lc in ("w1", "w2", "w3"):
+            for name in ("west", "east", "north"):
+                add(lc, name)
+        add("w2", "south")
+        await same({"w1": ["east", "north", "west"],
+                    "w2": ["east", "north", "south", "west"],
+                    "w3": ["east", "north", "west"]})
+        # a delete in one workspace leaves its namesakes where they are
+        tenants["w1"].delete(CLUSTERS, "north")
+        await same({"w1": ["east", "west"],
+                    "w2": ["east", "north", "south", "west"],
+                    "w3": ["east", "north", "west"]})
+        # status updates re-index the object under the same bucket; a
+        # NotReady held past the window evacuates w2/east and only it
+        flip("w3", "east", False)
+        flip("w3", "east", True)
+        flip("w2", "east", False)
+        await eventually(lambda: splitter.inventory.is_evacuated("w2", "east"))
+        await same({"w1": ["east", "west"],
+                    "w2": ["north", "south", "west"],
+                    "w3": ["east", "north", "west"]})
+        # the evacuated cluster is still in the index: the filter, not
+        # the bucket, keeps it out — and Ready lets it in again
+        assert len(splitter.cluster_informer.index("by_workspace", "w2")) == 4
+        flip("w2", "east", True)
+        await eventually(
+            lambda: not splitter.inventory.is_evacuated("w2", "east"))
+        tenants["w1"].delete(CLUSTERS, "east")
+        tenants["w1"].delete(CLUSTERS, "west")
+        add("w1", "north")
+        await same({"w1": ["north"],
+                    "w2": ["east", "north", "south", "west"],
+                    "w3": ["east", "north", "west"]})
+        # a workspace with no cluster: an empty bucket, the reference's
+        # NoRegisteredClusters as before
+        tenants["bare"].create(DEPLOYMENTS, deployment("web", 3))
+        await eventually(
+            lambda: (tenants["bare"].get(DEPLOYMENTS, "web", "default")
+                     .get("status", {}).get("conditions")
+                     or [{}])[0].get("reason") == "NoRegisteredClusters")
+        await splitter.stop()
+    asyncio.run(main())
+
+
+def test_a_lookup_reads_its_workspaces_clusters_not_the_fleets():
+    """125 workspaces x 8 clusters: `_clusters_for` reads 8 candidates a
+    call (`splitter_cluster_candidates_total`), whatever the fleet's
+    size, and one lookup is one `splitter_cluster_lookups_total`."""
+    from kcp_tpu.apis.cluster import CLUSTERS
+
+    async def main():
+        store = LogicalStore()
+        mc = MultiClusterClient(store)
+        for w in range(125):
+            t = mc.cluster_client(f"ws-{w}")
+            for c in range(8):
+                t.create(CLUSTERS, new_cluster(f"loc-{c}"))
+        splitter = DeploymentSplitter(mc, backend="host")
+        await splitter.start()
+        assert len(splitter.cluster_informer.list()) == 1000
+        for lc in ("ws-0", "ws-77", "ws-124"):
+            lookups0 = _cluster_counter("splitter_cluster_lookups_total")
+            read0 = _cluster_counter("splitter_cluster_candidates_total")
+            got = splitter._clusters_for(lc)
+            assert [c["metadata"]["name"] for c in got] == [
+                f"loc-{c}" for c in range(8)]
+            assert all(c["metadata"]["clusterName"] == lc for c in got)
+            assert _cluster_counter("splitter_cluster_lookups_total") - lookups0 == 1
+            assert _cluster_counter("splitter_cluster_candidates_total") - read0 == 8
+        # an unknown workspace reads nothing at all
+        read0 = _cluster_counter("splitter_cluster_candidates_total")
+        assert splitter._clusters_for("nobody") == []
+        assert _cluster_counter("splitter_cluster_candidates_total") == read0
+        await splitter.stop()
+    asyncio.run(main())
+
+
+def test_a_retired_root_re_emits_every_placement_row_and_applies_none():
+    """A root deleted on the fused path frees its placement row, the
+    resident state is rebuilt with `current` zeroed and the device hands
+    back EVERY remaining row: `splitter_placement_rows_total` rises by
+    the rows re-emitted, `splitter_fused_placements_total` by none (their
+    roots have their leaves), and no leaf is rewritten."""
+    from kcp_tpu.apis.cluster import CLUSTERS
+
+    async def main():
+        store = LogicalStore()
+        mc = MultiClusterClient(store)
+        t = mc.cluster_client("t")
+        for name in ("a", "b"):
+            t.create(CLUSTERS, new_cluster(name))
+        splitter = DeploymentSplitter(mc)
+        await splitter.start()
+        roots = ("r0", "r1", "r2", "r3")
+        for i, name in enumerate(roots):
+            t.create(DEPLOYMENTS, deployment(name, 4 + i))
+        for name in roots:
+            await eventually(lambda: t.get(DEPLOYMENTS, f"{name}--b", "default"))
+        await eventually(lambda: splitter._apply_q.empty()
+                         and not splitter._pbucket._stale)
+        await asyncio.sleep(0.1)
+        rows0 = _cluster_counter("splitter_placement_rows_total")
+        applied0 = _cluster_counter("splitter_fused_placements_total")
+        assert applied0 >= 4 and rows0 >= applied0
+        lookups0 = _cluster_counter("splitter_cluster_lookups_total")
+        rvs = {n: t.get(DEPLOYMENTS, f"{n}--a", "default")["metadata"]["resourceVersion"]
+               for n in roots[1:]}
+
+        t.delete(DEPLOYMENTS, "r0", "default")
+        await eventually(
+            lambda: ("t", "default", "r0") not in splitter._pbucket.pl_rows)
+        splitter.core.kick()
+        await eventually(lambda: _cluster_counter(
+            "splitter_placement_rows_total") - rows0 >= 3)
+        await asyncio.sleep(0.1)
+        # the three roots that stay, each handed back once and answered
+        # after one lookup; none applied, no leaf touched
+        assert _cluster_counter("splitter_placement_rows_total") - rows0 == 3
+        assert _cluster_counter("splitter_fused_placements_total") == applied0
+        assert _cluster_counter("splitter_cluster_lookups_total") - lookups0 == 3
+        for n, rv in rvs.items():
+            assert t.get(DEPLOYMENTS, f"{n}--a",
+                         "default")["metadata"]["resourceVersion"] == rv
         await splitter.stop()
 
     asyncio.run(main())
