@@ -132,6 +132,17 @@ _PATCH_GROWTHS = REGISTRY.counter(
 _UPLOAD_BYTES = REGISTRY.counter(
     "fused_fleet_state_upload_bytes_total",
     "bytes of resident fleet state put on the device by full uploads")
+# what a serving mesh costs the tick: the wire and the ack lane go to
+# every device of the mesh, replicated
+_MESH_SHARDS = REGISTRY.gauge(
+    "fused_fleet_mesh_shards",
+    "devices the fleet state's rows are sharded over (the serving "
+    "mesh's row factor; 1 with no mesh)")
+_PUT_BYTES = REGISTRY.counter(
+    "fused_fleet_put_bytes_total",
+    "bytes the ticks' put phase handed to the devices: the packed event "
+    "wire and the ack lane, times the devices each is written to (every "
+    "device of a serving mesh; 1 with no mesh)")
 
 
 class _Phases:
@@ -805,6 +816,7 @@ class FleetBatch:
             _ROW_GROWTHS.inc()
         self.B = base
         self.S = s
+        _MESH_SHARDS.set(members[0]._row_factor if members else 1)
         self._pl_members, self._pl_bases, self._pl_ends = [], [], []
         r, p = 0, 8
         for b in members:
@@ -1047,9 +1059,11 @@ class FleetBatch:
             repl = NamedSharding(self.mesh, PartitionSpec())
             packed_d = jax.device_put(packed, repl)
             acks_d = jax.device_put(acks, repl)
+            _PUT_BYTES.inc((packed.nbytes + acks.nbytes) * self.mesh.size)
         else:
             packed_d = jax.device_put(packed)
             acks_d = jax.device_put(acks)
+            _PUT_BYTES.inc(packed.nbytes + acks.nbytes)
         k = self._patch_capacity()
         if k != self._patch_k:
             if self._patch_k is not None:

@@ -614,6 +614,14 @@ def test_the_configuration_is_syncer_1k_behind_a_frontend():
             if "frontend-1k.steady" in m.get("workloads", [])}
     steadys = {m["name"] for m in manifest["per_layer"]
                if "syncer-1k.steady" in m.get("workloads", [])}
-    assert ours == steadys | set(READERS) | {"frontend_cpu_pct"}
+    # PR 46's three readers of the tick's put and dispatch list
+    # syncer-1k.steady as the CONTROL of the mesh cell, and no other cell
+    control_only = {m["name"] for m in manifest["per_layer"]
+                    if m.get("workloads") == ["syncer-1k.steady",
+                                              "mesh4-1k.steady"]}
+    assert control_only == {"tick_put_ms", "tick_step_dispatch_ms",
+                            "put_bytes_per_tick"}
+    assert ours == (steadys - control_only) | set(READERS) | {
+        "frontend_cpu_pct"}
     assert {m["layer"] for m in manifest["per_layer"]
             if m["name"].startswith("frontend_")} == {"storage frontend"}
