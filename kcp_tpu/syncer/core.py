@@ -34,10 +34,16 @@ stages back-to-back as the A/B reference):
                 N+1's packing)
   dispatch    — device_put + fused step (donated resident state) +
                 wire.copy_to_host_async(); the host never blocks here
-  fetch/apply — wires beyond the in-flight window (2 ticks old, or any
-                age via the idle flusher) are fetched — blocking ONLY on
-                the compact patch wire, never the donated state — then
-                unpacked and routed to owning sections; engines'
+  fetch/apply — a wire is fetched when the device has finished it:
+                on an asynchronous backend (the chip) a waiter thread
+                blocks on each submitted wire with the GIL released and
+                WAKES the loop (call_soon_threadsafe -> _on_wire_ready),
+                nothing polls; on the synchronous CPU backend once the
+                loop has been quiet for IDLE_FLUSH_S. A wire beyond the
+                in-flight window (2 ticks old) is fetched by the tick
+                itself — blocking ONLY on the compact patch wire, never
+                the donated state. Collects run on the loop, in submit
+                order: unpacked and routed to owning sections; engines'
                 appliers take it from there without blocking the tick
 
 Patch overflow: the wire carries at most ``patch_capacity`` actionable
@@ -56,6 +62,8 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
+import queue
+import threading
 import time
 from typing import Callable, NamedTuple, Protocol, Sequence
 
@@ -143,6 +151,25 @@ _PUT_BYTES = REGISTRY.counter(
     "bytes the ticks' put phase handed to the devices: the packed event "
     "wire and the ack lane, times the devices each is written to (every "
     "device of a serving mesh; 1 with no mesh)")
+# who began a collect, and what is left of the wait for a wire: stamped
+# with perf_counter on the loop (submit, collect) and on the waiter
+# thread (ready), observed on the loop
+_COLLECT_WOKEN = REGISTRY.counter(
+    "fused_collect_woken_total",
+    "collects begun by the waiter thread's wake (the wire was on the "
+    "host; asynchronous backends only)")
+_COLLECT_DEPTH = REGISTRY.counter(
+    "fused_collect_depth_total",
+    "collects made by a tick's depth rule (a wire beyond the in-flight "
+    "window: the fetch may block) or by the shutdown drain")
+_WIRE_READY_H = REGISTRY.histogram(
+    "fused_wire_ready_seconds",
+    "end of a tick's submit -> the waiter thread saw its wire on the "
+    "host: launch, device step and fetch, as the host sees them")
+_COLLECT_LAG_H = REGISTRY.histogram(
+    "fused_collect_lag_seconds",
+    "the waiter thread saw a wire ready -> its collect began on the "
+    "loop: the GIL hand-over and the wake's turn in the loop's queue")
 
 
 class _Phases:
@@ -1312,8 +1339,15 @@ class FusedCore:
             "fused_pipeline_overlap_ticks_total",
             "submits issued while a previous step was still in flight "
             "(overlapped ticks)")
+        # how a wire left in flight between ticks is collected turns on
+        # the backend (resolved at the first such wire): woken by the
+        # waiter thread where dispatch is asynchronous, by the quiet-loop
+        # flush task on the synchronous CPU backend
+        self._eager_collect: bool | None = None
         self._flush_task: asyncio.Task | None = None
-        self._eager_collect: bool | None = None  # resolved on first flush
+        # (wire, its submit's end stamp) for the waiter thread; None ends it
+        self._wires: queue.SimpleQueue = queue.SimpleQueue()
+        self._waiter: threading.Thread | None = None
         # quarantined keys awaiting their bounded-backoff requeue
         self._quarantine_retries: dict[tuple[int, object], int] = {}
         self._refs = 0
@@ -1453,6 +1487,13 @@ class FusedCore:
             if self._flush_task is not None:
                 self._flush_task.cancel()
                 self._flush_task = None
+            if self._waiter is not None:
+                # it ends once every wire handed to it is on the host —
+                # what the drain below would block on; its late wakes
+                # find nothing in flight
+                self._wires.put(None)
+                self._waiter.join()
+                self._waiter = None
             await self._drain_inflight()
             # drop the registry entry so closed cores (and their device-
             # resident fleet state) do not accumulate across loops
@@ -1570,6 +1611,8 @@ class FusedCore:
                 self._overlap_ticks.inc()
             # the wire carries its tick's start stamp to its collect
             self._inflight.append((*submitted, t_tick))
+            if self.fetch_depth and self._collects_by_wake():
+                self._watch(submitted[0])
 
         # 3. collect the oldest in-flight wires beyond the pipeline
         #    window (blocking is fine by then — their data has had
@@ -1580,8 +1623,9 @@ class FusedCore:
         #    instantly "ready", which serializes dispatch into the tick
         #    and cost ~15% throughput at bench scale.)
         while len(self._inflight) > self.fetch_depth:
+            _COLLECT_DEPTH.inc()
             self._collect(*self._inflight.pop(0))
-        if self._inflight:
+        if self._inflight and not self._collects_by_wake():
             self._schedule_flush()
         return []
 
@@ -1752,57 +1796,83 @@ class FusedCore:
         self.collecting_tick_start = tick_start
         return self._fleet.dispatch(host_wire, meta)
 
+    def _collects_by_wake(self) -> bool:
+        """True where dispatch is asynchronous (the chip): a wire turns
+        ready some time after its submit, and the waiter thread's wake
+        collects it. On the synchronous CPU backend every wire is ready
+        at once, so an eager collect would serialise dispatch into the
+        loop (measured ~15% of serving throughput): there the quiet-loop
+        flush below collects what a tick leaves in flight."""
+        if self._eager_collect is None:
+            self._eager_collect = jax.default_backend() != "cpu"
+        return self._eager_collect
+
+    def _watch(self, wire) -> None:
+        """Hand a submitted wire to the waiter thread (started with the
+        first one; ``stop()`` joins it)."""
+        if self._waiter is None:
+            self._waiter = threading.Thread(
+                target=self._wait_for_wires,
+                args=(asyncio.get_running_loop(),),
+                name="fused-wire-waiter", daemon=True)
+            self._waiter.start()
+        self._wires.put((wire, time.perf_counter()))
+
+    def _wait_for_wires(self, loop: asyncio.AbstractEventLoop) -> None:
+        """The waiter thread: block on each wire in submit order, GIL
+        released, until the host copy that ``copy_to_host_async``
+        started is in (and cached for the loop's own ``np.asarray``),
+        then wake the loop. It touches nothing of the core's state."""
+        while (item := self._wires.get()) is not None:
+            wire, t_submit = item
+            try:
+                np.asarray(wire)
+            except Exception:  # noqa: BLE001 — the collect on the loop
+                pass           # meets the same failure and reports it
+            try:
+                loop.call_soon_threadsafe(
+                    self._on_wire_ready, wire, t_submit, time.perf_counter())
+            except RuntimeError:  # the loop is closed: nobody to wake
+                return
+
+    def _on_wire_ready(self, wire, t_submit: float, t_ready: float) -> None:
+        """On the loop, woken by the waiter thread: collect, in submit
+        order, every head of ``_inflight`` that is ready. A head the
+        depth rule took meanwhile is not in the list any more, and a
+        wake that arrives after ``stop()`` finds the list empty."""
+        _WIRE_READY_H.observe(t_ready - t_submit)
+        lag = time.perf_counter() - t_ready
+        while self._inflight:
+            head = self._inflight[0][0]
+            if head is not wire and not head.is_ready():
+                break
+            if head is wire:
+                _COLLECT_LAG_H.observe(lag)
+            _COLLECT_WOKEN.inc()
+            self._collect_late(self._inflight.pop(0))
+
     def _schedule_flush(self) -> None:
         if self._flush_task is not None:
             self._flush_task.cancel()
         self._flush_task = asyncio.create_task(self._idle_flush())
 
     async def _idle_flush(self) -> None:
-        """Collect in-flight wires off the tick path.
-
-        On an asynchronous backend (TPU), this polls ``wire.is_ready()``
-        between ticks and collects the moment the device finishes —
-        patches apply ~one device round trip after dispatch instead of
-        waiting for the NEXT tick's depth-based collect (about a full
-        tick of convergence latency under continuous load), and it never
-        blocks a submit because only ready wires are popped. On the
-        synchronous CPU backend every wire is instantly "ready", so eager
-        collection would serialize dispatch into the loop (measured ~15%
-        of serving throughput) — there, keep the original behavior: only
-        collect once the loop has been quiet for IDLE_FLUSH_S (without
-        which the last tick's patches would wait for the next informer
-        event)."""
-        if self._eager_collect is None:
-            self._eager_collect = jax.default_backend() != "cpu"
-        try:
-            if not self._eager_collect:
-                await asyncio.sleep(IDLE_FLUSH_S)
-            while self._inflight:
-                wire = self._inflight[0][0]
-                # exponential poll backoff: a step over a large fleet
-                # runs for milliseconds, so a flat 1 ms poll would wake
-                # the loop many times per wire for no data; cap at 8 ms
-                # so a ready wire is still collected promptly
-                poll = 0.001
-                while not wire.is_ready():
-                    await asyncio.sleep(poll)
-                    poll = min(poll * 2, 0.008)
-                # the head can change across the awaits (a tick's depth-
-                # based collect pops it, and a collect failure means
-                # _schedule_flush never cancelled this task) — pop only
-                # the wire this iteration actually inspected
-                if not self._inflight or self._inflight[0][0] is not wire:
-                    continue
-                self._collect_late(self._inflight.pop(0))
-        except asyncio.CancelledError:
-            pass
+        """The synchronous CPU backend's collect off the tick path: once
+        the loop has been quiet for IDLE_FLUSH_S (every tick re-arms
+        it), collect what is in flight — without it the last tick's
+        patches would wait for the next informer event."""
+        await asyncio.sleep(IDLE_FLUSH_S)
+        while self._inflight:
+            self._collect_late(self._inflight.pop(0))
 
     def _collect_late(self, entry: tuple) -> None:
-        """A collect between ticks (the idle flush, the shutdown drain):
-        its own ``kcp.tick`` on the profiler's timeline."""
+        """A collect between ticks (a wake, the quiet-loop flush, the
+        shutdown drain): its own ``kcp.tick`` on the profiler's
+        timeline."""
         with obs.annotate("kcp.tick"):
             self._collect(*entry)
 
     async def _drain_inflight(self) -> None:
         while self._inflight:
+            _COLLECT_DEPTH.inc()
             self._collect_late(self._inflight.pop(0))

@@ -284,12 +284,16 @@ def test_ack_lane_compresses_feedback_and_stays_correct():
 
 
 def test_idle_flush_head_guard_survives_collect_failure():
-    """If a tick's depth-based collect pops the in-flight head and FAILS
-    (so _schedule_flush never cancels the parked flusher), the resumed
-    flusher must not collect its stale captured tuple against a
-    different head wire (eager-collect review finding)."""
+    """If a tick's depth-based collect pops the in-flight head while the
+    waiter thread is still blocked on it, the wake that follows must not
+    collect that wire a second time nor take the next head before it is
+    ready: every wire is collected once, in submit order (the
+    eager-collect review finding, kept through the poll's replacement
+    by a completion wake)."""
 
     async def main():
+        import threading
+
         import numpy as np
 
         from kcp_tpu.syncer.core import FusedCore
@@ -305,38 +309,48 @@ def test_idle_flush_head_guard_survives_collect_failure():
                 return False
 
         class FakeWire:
+            """Ready when its event is set: ``__array__`` blocks as a
+            device array's host copy does."""
+
             def __init__(self, tag):
                 self.tag = tag
-                self.ready = False
+                self.done = threading.Event()
 
             def is_ready(self):
-                return self.ready
+                return self.done.is_set()
 
             def __array__(self, dtype=None, copy=None):
+                assert self.done.wait(5.0)
                 return np.array([self.tag])
 
         core._fleet = FakeFleet()
         wire_a, wire_b = FakeWire(1), FakeWire(2)
         core._inflight = [(wire_a, (0, 8)), (wire_b, (0, 8))]
-        # park the flusher in its not-ready poll, holding the head tuple
-        core._schedule_flush()
+        # park the waiter thread on the head
+        core._watch(wire_a)
+        core._watch(wire_b)
         await asyncio.sleep(0.005)
-        assert core._inflight  # parked, nothing collected yet
-        # simulate the tick's own collect popping wire_a while the
-        # flusher is parked (the failure case leaves it uncancelled)
+        assert len(core._inflight) == 2  # blocked, nothing collected yet
+        # the tick's own collect pops wire_a while the waiter is parked
+        # on it (the depth rule's blocking fetch)
         head = core._inflight.pop(0)
+        wire_a.done.set()
         core._collect(*head)
-        wire_a.ready = wire_b.ready = True
-        # let the parked flusher resume: it must collect wire_b (the new
-        # head), never its stale wire_a capture against wire_b's slot
+        # wire_a's wake must leave wire_b, which is not ready, in flight
+        await asyncio.sleep(0.01)
+        assert collected == [1], collected
+        assert len(core._inflight) == 1
+        wire_b.done.set()
         for _ in range(20):
             await asyncio.sleep(0.002)
             if not core._inflight:
                 break
         assert collected == [1, 2], collected
         assert not core._inflight
-        if core._flush_task is not None:
-            core._flush_task.cancel()
+        assert core._flush_task is None  # no timer-driven task on this path
+        core._started = True
+        await core.stop()
+        assert core._waiter is None
 
     asyncio.run(main())
 
