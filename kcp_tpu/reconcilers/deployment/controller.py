@@ -155,6 +155,11 @@ class DeploymentSplitter:
         self._placements_applied = REGISTRY.counter(
             "splitter_fused_placements_total",
             "placement rows whose device-computed split was written")
+        self._placement_invalidations = REGISTRY.counter(
+            "splitter_placement_invalidations_total",
+            "device counts rejected by the applier (cluster set, spec or "
+            "row assignment changed in flight): each rebuilds the resident "
+            "state and makes the device re-emit every placement row")
         # root key -> time.monotonic() of the first event not yet
         # answered: a root's own (split) and its leaves' (aggregate).
         # Popped by the pass that answers the key or finds nothing to do
@@ -455,6 +460,7 @@ class DeploymentSplitter:
             # rows to re-emit — identical re-staged inputs would never
             # re-dirty otherwise
             if self._pbucket is not None:
+                self._placement_invalidations.inc()
                 self._pbucket.invalidate_placement()
             self.controller.enqueue(("root", key))
             return

@@ -151,6 +151,11 @@ _PUT_BYTES = REGISTRY.counter(
     "bytes the ticks' put phase handed to the devices: the packed event "
     "wire and the ack lane, times the devices each is written to (every "
     "device of a serving mesh; 1 with no mesh)")
+_PL_RETIRED = REGISTRY.counter(
+    "fused_placement_rows_retired_total",
+    "placement rows retired by their owner (free_pl_row found the key): "
+    "each is zeroed on the device by the next tick's placement-leaves "
+    "swap and comes free when that tick's wire has been dispatched")
 # who began a collect, and what is left of the wait for a wire: stamped
 # with perf_counter on the loop (submit, collect) and on the waiter
 # thread (ready), observed on the loop
@@ -402,6 +407,9 @@ class FusedBucket:
         self.pl_rows: dict[object, int] = {}
         self.pl_row_keys: dict[int, object] = {}
         self._pl_free: list[int] = []
+        # rows retired since the last submit: zeroed on the host, their
+        # `current` on the device not yet (see free_pl_row)
+        self._pl_retiring: list[int] = []
         self._pl_next = 0
         self._pl_staged = False
         self._stale = True
@@ -512,18 +520,42 @@ class FusedBucket:
         return row
 
     def free_pl_row(self, key) -> None:
+        """Retire a root's placement row: zero its inputs and let the
+        next tick's placement-leaves swap carry them — never a rebuild
+        of the resident state.
+
+        The invariant: a row is in ``_pl_free`` only when the device's
+        ``current[row]`` is zero AND no wire still to be dispatched can
+        name it. The device's ``current[row]`` holds the retired root's
+        last split; a later occupant whose split EQUALS it would never
+        come back dirty. The step sets ``current`` to the split of the
+        row's inputs on every tick, and the split of zeroed inputs is
+        all zeros: the tick that carries them zeroes ``current[row]``
+        by itself and emits that one row once, all zeros, which
+        ``route_placement`` drops (the row has no key). So the row
+        waits in ``_pl_retiring`` until a submit takes it
+        (``_take_retiring``) and is released when THAT wire is
+        dispatched (``FleetBatch.dispatch``) — released at the submit,
+        a new occupant could meet the zero emission, fail the owner's
+        sum check and force ``invalidate_placement``. Until then
+        ``pl_row_for`` allocates a fresh row. A full upload zeroes
+        ``current`` wholesale and takes the same road."""
         row = self.pl_rows.pop(key, None)
         if row is None:
             return
         self.pl_row_keys.pop(row, None)
         self.pl_replicas[row] = 0
         self.pl_avail[row] = False
-        self._pl_free.append(row)
-        # the device-resident `current` still holds this row's last split;
-        # a future occupant staging inputs whose split EQUALS it would
-        # never re-dirty — rebuild the resident state (root retirement is
-        # rare relative to ticks, so the full upload is acceptable)
-        self.mark_stale()
+        self._pl_retiring.append(row)
+        self._pl_staged = True
+        _PL_RETIRED.inc()
+
+    def _take_retiring(self) -> list[int]:
+        """The rows whose zeroed inputs the submit just made carried (by
+        its placement-leaves swap or its full upload): they ride that
+        wire's FleetMeta to its dispatch."""
+        rows, self._pl_retiring = self._pl_retiring, []
+        return rows
 
     def invalidate_placement(self) -> None:
         """Force every placement row to re-emit on the next tick (rebuilds
@@ -742,6 +774,9 @@ class FleetMeta(NamedTuple):
     pl_bases: tuple[int, ...]
     pl_ends: tuple[int, ...]
     seg_capacity: int
+    # (bucket, rows) retired before this submit: the step zeroed their
+    # `current`; they come free when this wire is dispatched
+    pl_retired: tuple
 
 
 class FleetBatch:
@@ -1141,6 +1176,10 @@ class FleetBatch:
             ends=tuple(self._ends), pl_members=tuple(self._pl_members),
             pl_bases=tuple(self._pl_bases), pl_ends=tuple(self._pl_ends),
             seg_capacity=self._seg_capacity,
+            # taken only now that the step accepted the inputs: a failed
+            # submit leaves them for the retry's full upload
+            pl_retired=tuple((b, b._take_retiring()) for b in self._members
+                             if b._pl_retiring),
         )
         return wire, meta
 
@@ -1172,6 +1211,10 @@ class FleetBatch:
                         pw = min(b.P, meta.p)
                         b.route_placement(rows[sel] - meta.pl_bases[j],
                                           counts[sel][:, :pw])
+        # this wire's zero-count emissions of the rows retired before its
+        # submit found no key above: the rows may be taken again
+        for b, retired in meta.pl_retired:
+            b._pl_free.extend(retired)
         # per-segment live-row counts -> the admission quota ledger
         self.core._publish_fleet_counts(
             unpack_seg_counts(wire, meta.k, meta.r_total, meta.p,

@@ -413,3 +413,226 @@ def test_fleet_scheduler_churn_driver_reconverges():
         await sched.stop()
         await splitter.stop()
     asyncio.run(main())
+
+
+# ---------------------------------------------------------------------------
+# the fused core's placement lane: a retired row rides the placement-leaves
+# swap (syncer/core.py free_pl_row), driven tick by tick with no loop
+# ---------------------------------------------------------------------------
+
+class _PlacementOwner:
+    """What `FusedBucket.route_placement` hands its owner, kept."""
+
+    def __init__(self):
+        self.applied: list[tuple[object, list[int]]] = []
+
+    def placement_apply(self, applies):
+        self.applied.extend((k, c.tolist()) for k, c in applies)
+
+
+def _placement_core(mesh_devices):
+    from kcp_tpu.parallel.mesh import make_mesh
+    from kcp_tpu.syncer.core import FusedCore
+
+    mesh = (make_mesh(n_devices=mesh_devices, slots=1)
+            if mesh_devices else None)
+    core = FusedCore(mesh=mesh)
+    owner = _PlacementOwner()
+    return core, core.register_placement(owner, 8), owner
+
+
+def _dispatch(core, submitted):
+    wire, meta = submitted
+    core._fleet.dispatch(np.asarray(wire), meta)
+
+
+def _one_tick(core):
+    _dispatch(core, core._fleet.submit())
+
+
+SPLIT_11_OVER_3 = [5, 3, 3, 0, 0, 0, 0, 0]
+# no mesh, and the suite's virtual CPU devices as a 4x1 serving mesh: the
+# swap's two leaves then go up sharded by rows over four devices
+MESHES = pytest.mark.parametrize("mesh_devices", [0, 4], ids=["one", "mesh4"])
+
+
+@MESHES
+def test_a_reused_placement_row_emits_a_split_equal_to_the_retired_ones(
+        mesh_devices):
+    """The hazard `free_pl_row` guards: the device's `current[row]` keeps
+    the retired root's split, so an equal split on the re-used row would
+    never come back dirty. One tick with the row's zeroed inputs zeroes
+    it — no full upload, no other row handed back."""
+    core, b, owner = _placement_core(mesh_devices)
+    b.stage_placement("stays", 7, 2)
+    b.stage_placement("old", 11, 3)
+    _one_tick(core)
+    assert sorted(owner.applied) == [("old", SPLIT_11_OVER_3),
+                                     ("stays", [4, 3, 0, 0, 0, 0, 0, 0])]
+    row, uploads = b.pl_rows["old"], b.stats["full_uploads"]
+    retired0 = REGISTRY.counter("fused_placement_rows_retired_total").value
+    del owner.applied[:]
+
+    b.free_pl_row("old")
+    b.free_pl_row("old")  # gone already: finds no row, counts nothing
+    assert REGISTRY.counter(
+        "fused_placement_rows_retired_total").value - retired0 == 1
+    assert b._pl_retiring == [row] and b._pl_free == [] and b.dirty
+    _one_tick(core)
+    # the row's own zero emission met no key; "stays" was not re-emitted
+    assert owner.applied == []
+    assert b._pl_free == [row] and b._pl_retiring == []
+    assert b.stats["full_uploads"] == uploads
+
+    b.stage_placement("new", 11, 3)
+    assert b.pl_rows["new"] == row
+    _one_tick(core)
+    assert owner.applied == [("new", SPLIT_11_OVER_3)]
+    assert b.stats["full_uploads"] == uploads
+    # level-triggered: a quiet tick hands nothing back
+    b._pl_staged = True
+    _one_tick(core)
+    assert owner.applied == [("new", SPLIT_11_OVER_3)]
+
+
+@MESHES
+def test_a_root_created_in_the_retirements_tick_window_takes_another_row(
+        mesh_devices):
+    """Retire and create between two ticks: the retired row is not free
+    yet, so the new root gets a fresh one and is placed by the tick that
+    zeroes the old one; the old row comes free only when THAT tick's wire
+    has been dispatched — not at its submit."""
+    core, b, owner = _placement_core(mesh_devices)
+    b.stage_placement("old", 11, 3)
+    _one_tick(core)
+    row, uploads = b.pl_rows["old"], b.stats["full_uploads"]
+    del owner.applied[:]
+
+    b.free_pl_row("old")
+    b.stage_placement("new", 11, 3)
+    assert b.pl_rows["new"] != row
+    submitted = core._fleet.submit()
+    assert b._pl_retiring == [] and b._pl_free == []  # submitted, in flight
+    b.stage_placement("newer", 11, 3)
+    assert b.pl_rows["newer"] not in (row, b.pl_rows["new"])
+    _dispatch(core, submitted)
+    assert owner.applied == [("new", SPLIT_11_OVER_3)]
+    assert b._pl_free == [row]
+    _one_tick(core)
+    assert owner.applied[1:] == [("newer", SPLIT_11_OVER_3)]
+    b.stage_placement("last", 11, 3)
+    assert b.pl_rows["last"] == row
+    _one_tick(core)
+    assert owner.applied[2:] == [("last", SPLIT_11_OVER_3)]
+    assert b.stats["full_uploads"] == uploads
+
+
+def test_wires_in_flight_across_a_retirement_name_no_live_root():
+    """Two wires cross a retirement: one submitted BEFORE it with the
+    root's real split, one after it with the row's zero emission. Both
+    are dispatched with the row keyless — nothing reaches the owner, and
+    a root that takes the row afterwards sees its own split only."""
+    core, b, owner = _placement_core(0)
+    b.stage_placement("stays", 6, 3)
+    _one_tick(core)
+    del owner.applied[:]
+    b.stage_placement("old", 11, 3)
+    before = core._fleet.submit()      # carries old's [5, 3, 3]
+    b.free_pl_row("old")
+    b.stage_placement("other", 9, 3)   # cannot take old's row
+    after = core._fleet.submit()       # carries old's row, all zeros
+    row = after[1].pl_retired[0][1][0]
+    assert after[1].pl_retired == ((b, [row]),) and before[1].pl_retired == ()
+    _dispatch(core, before)
+    assert owner.applied == [] and b._pl_free == []
+    _dispatch(core, after)
+    assert owner.applied == [("other", [3, 3, 3, 0, 0, 0, 0, 0])]
+    assert b._pl_free == [row]
+    b.stage_placement("new", 11, 3)
+    _one_tick(core)
+    assert owner.applied[1:] == [("new", SPLIT_11_OVER_3)]
+
+
+def test_a_full_upload_for_another_reason_releases_the_retiring_rows():
+    """Row growth (`_pl_grow`) rebuilds the resident state with `current`
+    zeroed: the retiring rows ride that tick like any other and come free
+    at its dispatch; the rows that stay are handed back once, as a full
+    upload always did."""
+    core, b, owner = _placement_core(0)
+    for i in range(8):
+        b.stage_placement(f"r{i}", 8 + i, 2)
+    _one_tick(core)
+    assert b.R == 8 and len(owner.applied) == 8
+    uploads = b.stats["full_uploads"]
+    rows = sorted(b.pl_rows[k] for k in ("r1", "r5"))
+    b.free_pl_row("r1")
+    b.free_pl_row("r5")
+    # no free row while two retire: the ninth root grows the lane
+    b.stage_placement("r8", 16, 2)
+    assert b.R == 16 and b.pl_rows["r8"] == 8 and b._stale
+    del owner.applied[:]
+    _one_tick(core)
+    assert b.stats["full_uploads"] == uploads + 1
+    assert sorted(b._pl_free) == rows and b._pl_retiring == []
+    assert sorted(k for k, _c in owner.applied) == [
+        "r0", "r2", "r3", "r4", "r6", "r7", "r8"]
+    b.stage_placement("again", 13, 2)
+    assert b.pl_rows["again"] in rows
+    del owner.applied[:]
+    _one_tick(core)
+    assert owner.applied == [("again", [7, 6, 0, 0, 0, 0, 0, 0])]
+
+
+def test_a_failed_submit_keeps_the_retiring_rows_for_the_retry():
+    """The rows leave `_pl_retiring` only when the step has accepted the
+    tick that carries them: a submit that raises leaves them for the
+    retry (a full upload), whose dispatch releases them."""
+    from kcp_tpu import faults
+
+    core, b, owner = _placement_core(0)
+    b.stage_placement("old", 11, 3)
+    _one_tick(core)
+    row = b.pl_rows["old"]
+    b.free_pl_row("old")
+    faults.install(faults.FaultInjector("device.step:raise", seed=0))
+    try:
+        with pytest.raises(faults.InjectedFault):
+            core._fleet.submit()
+    finally:
+        faults.clear()
+    assert b._pl_retiring == [row] and b._pl_free == []
+    core._fleet.mark_stale()  # what FusedCore._retick does after a failure
+    _one_tick(core)
+    assert b._pl_free == [row] and b._pl_retiring == []
+
+
+def test_full_upload_ticks_pct_reads_zero_where_no_tick_was_stale():
+    """The benchmark's reader of how often a tick rebuilt the resident
+    state: the count of the `full_upload` phase per hundred fleet ticks.
+    The histogram is registered with the phases, so a window with no
+    full upload reads 0 (not None), and a parent reads like a change."""
+    import importlib
+
+    read = importlib.import_module(
+        "benchmarks.layer_metrics.full_upload_ticks_pct").read
+    parent = {"fused_fleet_ticks_total": 2000.0,
+              "fused_full_upload_seconds": 3.1,
+              "fused_full_upload_seconds_count": 600.0}
+    assert read({"registry": parent}) == pytest.approx(30.0)
+    quiet = dict(parent, fused_full_upload_seconds=0.0,
+                 fused_full_upload_seconds_count=0.0)
+    assert read({"registry": quiet}) == 0.0
+    assert read({"registry": dict(parent, fused_fleet_ticks_total=0.0)}) is None
+    assert read({"registry": {}}) is None
+    # and on the live registry: a retirement's tick is not a stale one
+    core, b, _owner = _placement_core(0)
+    b.stage_placement("old", 11, 3)
+    _one_tick(core)
+    snap0 = REGISTRY.snapshot()
+    b.free_pl_row("old")
+    _one_tick(core)
+    snap1 = REGISTRY.snapshot()
+    rise = {k: snap1[k] - snap0[k] for k in
+            ("fused_fleet_ticks_total", "fused_full_upload_seconds_count")}
+    assert rise["fused_fleet_ticks_total"] == 1
+    assert read({"registry": rise}) == 0.0

@@ -460,52 +460,149 @@ def test_a_lookup_reads_its_workspaces_clusters_not_the_fleets():
     asyncio.run(main())
 
 
-def test_a_retired_root_re_emits_every_placement_row_and_applies_none():
-    """A root deleted on the fused path frees its placement row, the
-    resident state is rebuilt with `current` zeroed and the device hands
-    back EVERY remaining row: `splitter_placement_rows_total` rises by
-    the rows re-emitted, `splitter_fused_placements_total` by none (their
-    roots have their leaves), and no leaf is rewritten."""
+async def _fused_splitter_with_roots(replicas):
+    """A fused-path splitter over two clusters with one placed root a
+    replica count, settled: every leaf written, nothing queued, the
+    bucket's state resident."""
     from kcp_tpu.apis.cluster import CLUSTERS
 
+    store = LogicalStore()
+    mc = MultiClusterClient(store)
+    t = mc.cluster_client("t")
+    for name in ("a", "b"):
+        t.create(CLUSTERS, new_cluster(name))
+    splitter = DeploymentSplitter(mc)
+    await splitter.start()
+    for i, n in enumerate(replicas):
+        t.create(DEPLOYMENTS, deployment(f"r{i}", n))
+    for i in range(len(replicas)):
+        await eventually(lambda: t.get(DEPLOYMENTS, f"r{i}--b", "default"))
+    await _settled(splitter)
+    return t, splitter
+
+
+async def _settled(splitter):
+    b = splitter._pbucket
+    await eventually(lambda: splitter._apply_q.empty() and not b.dirty
+                     and not b._pl_retiring and not splitter.core._inflight)
+    await asyncio.sleep(0.1)
+
+
+def test_a_retired_root_re_emits_no_resident_roots_row():
+    """A root deleted on the fused path retires ITS placement row through
+    the placement-leaves swap: the resident state is not rebuilt, the
+    device hands back no resident root's row (the retired row's own
+    all-zero emission finds no key), nothing is looked up and no leaf is
+    rewritten. (Until PR 49 it was the opposite: a rebuild with `current`
+    zeroed, and EVERY remaining row handed back to be answered with
+    nothing to write.)"""
+
     async def main():
-        store = LogicalStore()
-        mc = MultiClusterClient(store)
-        t = mc.cluster_client("t")
-        for name in ("a", "b"):
-            t.create(CLUSTERS, new_cluster(name))
-        splitter = DeploymentSplitter(mc)
-        await splitter.start()
-        roots = ("r0", "r1", "r2", "r3")
-        for i, name in enumerate(roots):
-            t.create(DEPLOYMENTS, deployment(name, 4 + i))
-        for name in roots:
-            await eventually(lambda: t.get(DEPLOYMENTS, f"{name}--b", "default"))
-        await eventually(lambda: splitter._apply_q.empty()
-                         and not splitter._pbucket._stale)
-        await asyncio.sleep(0.1)
+        t, splitter = await _fused_splitter_with_roots((4, 5, 6, 7))
+        b = splitter._pbucket
         rows0 = _cluster_counter("splitter_placement_rows_total")
         applied0 = _cluster_counter("splitter_fused_placements_total")
         assert applied0 >= 4 and rows0 >= applied0
         lookups0 = _cluster_counter("splitter_cluster_lookups_total")
-        rvs = {n: t.get(DEPLOYMENTS, f"{n}--a", "default")["metadata"]["resourceVersion"]
-               for n in roots[1:]}
+        retired0 = _cluster_counter("fused_placement_rows_retired_total")
+        invalid0 = _cluster_counter("splitter_placement_invalidations_total")
+        uploads0, ticks0 = b.stats["full_uploads"], b.stats["ticks"]
+        row = b.pl_rows[("t", "default", "r0")]
+        rvs = {f"r{i}--{c}": t.get(DEPLOYMENTS, f"r{i}--{c}",
+                                   "default")["metadata"]["resourceVersion"]
+               for i in (1, 2, 3) for c in "ab"}
 
         t.delete(DEPLOYMENTS, "r0", "default")
-        await eventually(
-            lambda: ("t", "default", "r0") not in splitter._pbucket.pl_rows)
+        await eventually(lambda: ("t", "default", "r0") not in b.pl_rows)
+        assert row not in b._pl_free  # retiring: not free before its tick
         splitter.core.kick()
-        await eventually(lambda: _cluster_counter(
-            "splitter_placement_rows_total") - rows0 >= 3)
-        await asyncio.sleep(0.1)
-        # the three roots that stay, each handed back once and answered
-        # after one lookup; none applied, no leaf touched
-        assert _cluster_counter("splitter_placement_rows_total") - rows0 == 3
+        await eventually(lambda: row in b._pl_free)
+        await _settled(splitter)
+        assert b.stats["ticks"] > ticks0
+        assert b.stats["full_uploads"] == uploads0
+        assert _cluster_counter("fused_placement_rows_retired_total") - retired0 == 1
+        assert _cluster_counter("splitter_placement_rows_total") == rows0
         assert _cluster_counter("splitter_fused_placements_total") == applied0
-        assert _cluster_counter("splitter_cluster_lookups_total") - lookups0 == 3
-        for n, rv in rvs.items():
-            assert t.get(DEPLOYMENTS, f"{n}--a",
+        # not even one: the retired root's own pass of the splitter's
+        # tick is answered at `root is None`, ahead of the lookup
+        assert _cluster_counter("splitter_cluster_lookups_total") == lookups0
+        assert _cluster_counter(
+            "splitter_placement_invalidations_total") == invalid0
+        for leaf, rv in rvs.items():
+            assert t.get(DEPLOYMENTS, leaf,
                          "default")["metadata"]["resourceVersion"] == rv
+        await splitter.stop()
+
+    asyncio.run(main())
+
+
+def test_a_root_with_the_retired_roots_split_is_placed_on_its_row():
+    """The hazard the rebuild was there for, end to end: the device's
+    `current[row]` held the retired root's split, so a root with an EQUAL
+    split on the re-used row would never come back dirty. The tick that
+    carried the row's zeroed inputs zeroed it: the new root is placed, by
+    the device, with no full upload and no rejected counts."""
+
+    async def main():
+        t, splitter = await _fused_splitter_with_roots((4, 7))
+        b = splitter._pbucket
+        row = b.pl_rows[("t", "default", "r1")]
+        t.delete(DEPLOYMENTS, "r1", "default")
+        for c in "ab":
+            t.delete(DEPLOYMENTS, f"r1--{c}", "default")
+        await eventually(lambda: ("t", "default", "r1") not in b.pl_rows)
+        splitter.core.kick()
+        await eventually(lambda: b._pl_free == [row])
+        await _settled(splitter)
+        uploads0 = b.stats["full_uploads"]
+        applied0 = _cluster_counter("splitter_fused_placements_total")
+        rows0 = _cluster_counter("splitter_placement_rows_total")
+        invalid0 = _cluster_counter("splitter_placement_invalidations_total")
+
+        t.create(DEPLOYMENTS, deployment("twin", 7))
+        await eventually(lambda: t.get(DEPLOYMENTS, "twin--b", "default"))
+        assert b.pl_rows[("t", "default", "twin")] == row
+        assert [t.get(DEPLOYMENTS, f"twin--{c}", "default")["spec"]["replicas"]
+                for c in "ab"] == [4, 3]
+        await _settled(splitter)
+        assert b.stats["full_uploads"] == uploads0
+        assert _cluster_counter("splitter_fused_placements_total") - applied0 == 1
+        assert _cluster_counter("splitter_placement_rows_total") - rows0 == 1
+        assert _cluster_counter(
+            "splitter_placement_invalidations_total") == invalid0
+        await splitter.stop()
+
+    asyncio.run(main())
+
+
+def test_rollouts_that_retire_and_create_together_reject_no_counts():
+    """What the rollout cell does: roots retired and created in the same
+    tick windows, equal replica counts among them, wires in flight across
+    the retirements. Every root is placed by the device, none of the
+    all-zero emissions reaches the applier as a live root's counts
+    (`splitter_placement_invalidations_total` stays), and the resident
+    state is uploaded again only where the lane grows."""
+
+    async def main():
+        t, splitter = await _fused_splitter_with_roots((6, 6, 6, 6))
+        b = splitter._pbucket
+        invalid0 = _cluster_counter("splitter_placement_invalidations_total")
+        applied0 = _cluster_counter("splitter_fused_placements_total")
+        uploads0, lane0 = b.stats["full_uploads"], b.R
+        for gen in range(6):
+            old, new = f"r{gen % 4}" if gen < 4 else f"n{gen - 4}", f"n{gen}"
+            t.delete(DEPLOYMENTS, old, "default")
+            t.create(DEPLOYMENTS, deployment(new, 6))
+            await eventually(lambda: t.get(DEPLOYMENTS, f"{new}--b", "default"))
+            assert [t.get(DEPLOYMENTS, f"{new}--{c}", "default")["spec"]["replicas"]
+                    for c in "ab"] == [3, 3]
+        await _settled(splitter)
+        assert _cluster_counter(
+            "splitter_placement_invalidations_total") == invalid0
+        assert _cluster_counter("splitter_fused_placements_total") - applied0 == 6
+        assert b.R == lane0 and b.stats["full_uploads"] == uploads0
+        # four resident roots: no more rows than those and the retiring
+        assert b._pl_next <= 6 and len(b.pl_rows) == 4
         await splitter.stop()
 
     asyncio.run(main())
