@@ -8,7 +8,7 @@ One process, one TPU, the entry points a user would call:
   phase 1  served      ``kcp start``'s own Server (WAL, controllers, push
                        syncers, backend "tpu") over REST at the reference's
                        target: 1k logical clusters x 50 objects
-  phase 2  fused core  the closed loop bench.py drives at 131,072 resident
+  phase 2  fused core  a closed churn/reconcile/apply loop at 131,072 resident
                        rows x 64 slots, plus one full-width step held to a
                        numpy oracle written here
   phase 3  pallas      the off-by-default Pallas lane, compiled (not
@@ -413,6 +413,68 @@ def phase_served(n_clusters: int = 1000, objs_per: int = 50, writers: int = 8,
 # --------------------------------------------------------------- phase 2
 
 
+class SyntheticOwner:
+    """A SectionOwner with mirror arrays in place of informer caches and
+    mirror copies in place of store writes; everything between (queue,
+    staging, fused step, pipeline, dispatch) is the serving code. Phase 2,
+    the mesh phase and ``__graft_entry__``'s dry run register one on a
+    FusedCore."""
+
+    def __init__(self, core, b: int, s: int, seed: int = 7):
+        self.core = core
+        self.B, self.S = b, s
+        self.rng = np.random.default_rng(seed)
+        # status slots: the top s//8 columns, as example_state lays out
+        mask = np.zeros(s, bool)
+        mask[-max(1, s // 8):] = True
+        self._mask = mask
+        self.section = core.register(self, s)
+        bucket = self.section.bucket
+        for i in range(b):
+            self.section.row_for(i)
+        bucket.up_vals[:b] = self.rng.integers(1, 2**32, (b, s), dtype=np.uint32)
+        bucket.down_vals[:b] = bucket.up_vals[:b]
+        flip = self.rng.random(b) < 0.005
+        bucket.down_vals[:b][flip, :1] ^= 1
+        bucket.up_exists[:b] = True
+        bucket.down_exists[:b] = True
+        bucket.mark_stale()
+        self.bucket = bucket
+        self.patch_rows = 0
+
+    def fused_status_mask(self) -> np.ndarray:
+        return self._mask
+
+    def fused_encode(self, key: int):
+        b = self.bucket
+        return b.up_vals[key], True, b.down_vals[key], True
+
+    def fused_encode_many(self, keys):
+        b = self.bucket
+        idx = np.fromiter(keys, np.int64, len(keys))
+        return (b.up_vals[idx], np.ones(idx.size, bool),
+                b.down_vals[idx], np.ones(idx.size, bool))
+
+    def fused_overflow(self) -> None:  # pragma: no cover - fixed vocab
+        raise AssertionError("the synthetic vocabulary never grows")
+
+    def fused_apply(self, patches) -> None:
+        """The applier seam: sync each patch row downstream and enqueue
+        the feedback event."""
+        rows = np.fromiter((k for k, _c, _u in patches), np.int32, len(patches))
+        self.patch_rows += rows.size
+        self.bucket.down_vals[rows] = self.bucket.up_vals[rows]
+        self.core.enqueue_many(self.section, True, rows.tolist())
+
+    def emit_churn(self, n: int) -> None:
+        """New upstream specs for ``n`` random rows, enqueued key by key
+        through the serving work queue."""
+        rows = self.rng.choice(self.B, size=n, replace=False)
+        self.bucket.up_vals[rows] = self.rng.integers(
+            1, 2**32, (n, self.S), dtype=np.uint32)
+        self.core.enqueue_many(self.section, False, rows.tolist())
+
+
 class _CompileCounter:
     """Counts backend compiles through jax.monitoring."""
 
@@ -594,11 +656,11 @@ def phase_fused_core(b: int = 131072, s: int = 64, churn: int = 768,
                      warmup: int = 24, ticks: int = 100,
                      r: int = 16384, d: int = 1024, k: int = 8192,
                      seed: int = 0, timeout: float = 300.0) -> dict:
-    """The closed loop bench.py's main drives — FusedCore with the serving
-    defaults and a synthetic section owner — then the full-width step."""
+    """A closed control loop — FusedCore with the serving defaults and a
+    synthetic section owner, churned once per tick — then the full-width
+    step."""
     import jax
 
-    from bench import _BenchOwner
     from kcp_tpu.syncer.core import FusedCore
 
     say(f"phase 2 fused core: B={b} S={s} over {b // 13} tenants, {churn} "
@@ -610,7 +672,7 @@ def phase_fused_core(b: int = 131072, s: int = 64, churn: int = 768,
         core = FusedCore(batch_window=0.0005)
         check(core.pipeline == "double",
               "not the serving defaults")
-        owner = _BenchOwner(core, b, s, seed=seed + 7)
+        owner = SyntheticOwner(core, b, s, seed=seed + 7)
         bucket = owner.bucket
         bucket.patch_capacity = k
         ack_floor = max(8192, b // 64, 2 * churn)
@@ -786,11 +848,10 @@ def phase_mesh(n_devices: int = 4, b: int = 131072, s: int = 64,
 
     import jax
 
-    from bench import _BenchOwner
     from kcp_tpu.parallel.mesh import SLOTS_AXIS, TENANTS_AXIS, mesh_from_spec
     from kcp_tpu.syncer.core import FusedCore
 
-    class Owner(_BenchOwner):
+    class Owner(SyntheticOwner):
         def __init__(self, *a, **kw):
             self.stream: list[tuple[int, int, bool]] = []
             super().__init__(*a, **kw)

@@ -1,7 +1,7 @@
 """cow-mutation: flag in-place mutation of CoW store snapshots.
 
-The PR 3 read-path contract (docs/operations.md "CoW contract"): with
-``KCP_STORE_INDEX=1`` the store shares references between storage,
+The PR 3 read-path contract (docs/operations.md "CoW contract"): the
+indexed store shares references between storage,
 ``list`` results, ``get_snapshot`` (and the ``*_snapshot`` write verbs'
 results), informer caches, watch ``Event`` payloads, and
 ``_sync_view_ro`` views. Mutating any of them corrupts the

@@ -16,10 +16,10 @@ from .frozenbytes import FrozenBytesChecker
 from .lockorder import LockOrderChecker
 from .metricsdoc import MetricsDocChecker
 
-#: the linted surface: the package + the bench harness. Tests are
+#: the linted surface: the package + the chip smoke. Tests are
 #: deliberately excluded — fixtures violate contracts on purpose — but
 #: repo-level checkers still read tests/ for evidence (fault drills).
-DEFAULT_TARGETS = ("kcp_tpu", "bench.py", "__graft_entry__.py")
+DEFAULT_TARGETS = ("kcp_tpu", "chip_smoke.py", "__graft_entry__.py")
 
 ALL_CHECKERS: tuple[FileChecker | RepoChecker, ...] = (
     CowChecker(),

@@ -16,7 +16,7 @@ import sys
 
 # the one compile-cache location of a checkout (already in .gitignore):
 # the directory is part of the cache key, so every entry point — the
-# binaries, bench.py, chip_smoke.py, the tests — must agree on it
+# binaries, chip_smoke.py, the tests — must agree on it
 REPO_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     ".jax_cache")

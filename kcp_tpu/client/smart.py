@@ -36,8 +36,7 @@ sits idle (no traffic means no 410 to trigger the reactive refresh).
 
 Responses on the direct path are byte-identical to routed responses
 (modulo hop-specific headers) — the differential fuzz in
-tests/test_smartclient.py and the sha256 cross-check in
-``bench.py --smartclient`` hold that line.
+tests/test_smartclient.py holds that line.
 """
 
 from __future__ import annotations

@@ -2,8 +2,8 @@
 
 Consumers of the per-process span buffers (:mod:`.trace`): the router's
 ``/debug/trace`` scatter-gather, ``scripts/tracetool.py``, the scenario
-engine's scorecard attachments, and the ``bench.py --trace``
-sum-reconciliation gate all share these pure functions.
+engine's scorecard attachments, and the sum-reconciliation test
+(``tests/test_tracing.py``) all share these pure functions.
 
 A *trace tree* is just a list of span dicts (possibly from several
 processes) sharing a trace id; :func:`build_tree` nests them by parent

@@ -30,8 +30,8 @@ layer for the kcp-tpu fleet, Dapper-style:
   ``convergence_<phase>_seconds`` observation for EVERY write and as a
   ``conv.<phase>`` span for a sampled one — phases share boundary
   stamps (all ``time.monotonic()``), so their sum telescopes to the
-  end-to-end time by construction (the ``bench.py --trace``
-  reconciliation gate);
+  end-to-end time by construction
+  (``tests/test_tracing.py::test_convergence_phases_sum_reconcile_in_process``);
 - the same boundaries as host SECTIONS: :func:`annotate` names the
   synchronous ``kcp.*`` sections of the tick, the store, the applier,
   the HTTP path and the watch relay. On a serving loop's thread each

@@ -252,7 +252,7 @@ SCALE_OUT = ScenarioSpec(
 WRITE_STORM = ScenarioSpec(
     name="write-storm",
     description="The whole tenant fleet writes flat-out with group "
-                "commit on (KCP_GROUP_COMMIT=1, the default) and the "
+                "commit on and the "
                 "primary is SIGKILLed mid-storm behind a router with "
                 "standby + replica: the standby promotes and zero "
                 "ACKED writes are lost — an unsynced commit window was "
@@ -262,7 +262,6 @@ WRITE_STORM = ScenarioSpec(
     topology="replicated",
     tenants=6,
     watchers_per_tenant=1,
-    env={"KCP_GROUP_COMMIT": "1"},
     phases=(Phase("warm", ops_per_tenant=20),
             Phase("storm", ops_per_tenant=120, action="kill_primary",
                   settle_s=1.5),

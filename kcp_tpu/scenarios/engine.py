@@ -398,7 +398,7 @@ async def _drive(sspec: ScenarioSpec, seed: int, schedule, topology,
 
 def _fetch_slowest_traces(base_url: str, n: int = 3) -> list[dict]:
     """The 3 slowest assembled traces at a phase boundary, compacted for
-    the scorecard (kcp_tpu/obs): an SLO breach in SCENARIOS_rNN.json
+    the scorecard (kcp_tpu/obs): an SLO breach in a scorecard
     ships with its own explanation. On a router topology the endpoint
     scatter-gathers every shard's buffer; best-effort — a topology mid-
     chaos may refuse, and the scorecard then simply has no trace."""

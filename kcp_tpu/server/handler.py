@@ -31,17 +31,10 @@ from ..store.store import INITIAL_EVENTS_END, WILDCARD, LogicalStore
 from ..utils import errors
 from ..utils.routing import resolve_write_cluster
 from ..utils.trace import REGISTRY
-from .httpd import FlushCoalescer, Request, Response, StreamResponse
+from .httpd import Request, Response, StreamResponse
 
 DEFAULT_CLUSTER = "admin"
 CLUSTER_HEADER = "x-kubernetes-cluster"
-
-
-class _SlowWatcher(Exception):
-    """A watch stream fell past KCP_WATCH_BUFFER_MAX on its socket: the
-    coalescer refused further buffering and the producer must end the
-    stream with a terminal typed 410 (the informer relists and resumes
-    — bounded memory beats an unbounded goodbye)."""
 
 
 _QUEUE_EVICTED = ("watch queue overflowed (KCP_WATCH_QUEUE): slow watcher "
@@ -144,8 +137,8 @@ class RestHandler:
             self._store_pool = ThreadPoolExecutor(
                 max_workers=getattr(store, "io_concurrency", 8),
                 thread_name_prefix="store-io")
-        # encode-once serving (KCP_ENCODE_CACHE, in-process CoW stores
-        # only): list responses splice cached item bytes, single GETs
+        # encode-once serving (in-process CoW stores with the encode
+        # cache only): list responses splice cached item bytes, single GETs
         # splice the cached body, and the watch relay threads pre-encoded
         # event lines — remote-store frontends re-serialize what the
         # backend sent, so they keep the dict path.
@@ -236,27 +229,10 @@ class RestHandler:
         # answer probes/audits and writes must reach the store's own
         # fenced refusal (repl_fenced_writes_total)
         self.watch_fence = _StreamSignal(self._stream_wakes)
-        # watcher-scale serving (KCP_WATCH_COALESCE, default on): one
-        # shared flush coalescer gathers every watch stream's encode-once
-        # lines and writes each socket once per coalescing tick —
-        # O(sockets) buffered writes of shared bytes per tick instead of
-        # a write+drain round trip per watcher per event batch. =0 keeps
-        # the per-batch send_raw_many path for A/B (bench.py --watchers).
-        # Both govern only a PULL relay of encode-once lines (a local
-        # store's watch that offers no push half): a watch on a local
-        # store is pushed to its socket by the store's fan-out pass
-        # itself (_watch), once per commit window, and pays no
-        # coalescing tick; a storage frontend's relay re-serializes the
-        # backend's events on the dict path and never comes here.
+        # a push stream whose socket backlog passes this bound is
+        # evicted with a terminal typed 410, never awaited (_watch)
         self._buffer_max = int(os.environ.get(
             "KCP_WATCH_BUFFER_MAX", str(2 * 1024 * 1024)))
-        self._coalescer = None
-        if os.environ.get("KCP_WATCH_COALESCE", "1").lower() not in (
-                "0", "false", "off"):
-            self._coalescer = FlushCoalescer(
-                tick_s=float(os.environ.get("KCP_WATCH_FLUSH_MS", "2"))
-                / 1000.0,
-                buffer_max=self._buffer_max)
         # which of the two delivered a batch: one increment per batch
         # written to a watch stream
         self._push_batches = REGISTRY.counter(
@@ -931,7 +907,6 @@ class RestHandler:
         # response body (Response.spans): the wire layer writes the
         # spans scatter-style, so at 100k objects the tens-of-MB body is
         # never materialized as one joined copy at all
-        # (KCP_WIRE_SCATTER; =0 restores the single join for A/B)
         head = json.dumps({
             "kind": info.list_kind, "apiVersion": gv,
             "metadata": {"resourceVersion": str(rv)},
@@ -1493,7 +1468,7 @@ class RestHandler:
                 # a remote-store backend can refuse the watch itself
                 # (403 bad --store-token, 404 unknown resource, ...):
                 # relay the mapped Status in-stream instead of silently
-                # dropping the client connection (ADVICE r5)
+                # dropping the client connection
                 await stream.send_json({
                     "type": "ERROR",
                     "object": _status_body(e.code, e.reason, e.message)})
@@ -1569,8 +1544,8 @@ class RestHandler:
                 # the push path's whole delivery, nothing awaited:
                 # encode-once lines, one chunk on the transport, the
                 # `observe` stamp where the frame is handed over. One
-                # write per watch per fan-out pass is the coalescer's
-                # promise with the commit window as the tick.
+                # write per watch per fan-out pass: the commit window is
+                # the coalescing tick.
                 with obs.annotate("kcp.watch.push"):
                     stream.write_raw_many(encode_lines(batch))
                     stamp_observed(batch)
@@ -1588,20 +1563,7 @@ class RestHandler:
                             if self._encode else None)
                 send_many = getattr(stream, "send_json_many", None)
                 if send_raw is not None:
-                    lines = encode_lines(batch)
-                    if (self._coalescer is not None
-                            and getattr(stream, "write_raw_many", None)
-                            is not None):
-                        # batched flush: lines park with every other
-                        # stream's and each socket is written once per
-                        # coalescing tick; False = this socket is past
-                        # the buffer bound — evict, don't buffer more.
-                        # Duck-typed streams without the buffered write
-                        # half (test sinks) keep the direct path.
-                        if not await self._coalescer.write(stream, lines):
-                            raise _SlowWatcher()
-                    else:
-                        await send_raw(lines)
+                    await send_raw(encode_lines(batch))
                 else:
                     # the dict path: a storage frontend (its store's
                     # events are the backend's lines, parsed), a store
@@ -1796,7 +1758,7 @@ class RestHandler:
                             # any other backend refusal mid-relay (403/404/
                             # 5xx mapped by the REST client) ends the stream
                             # with a terminal Status carrying the real code,
-                            # not a silent connection drop (ADVICE r5)
+                            # not a silent connection drop
                             await stream.send_json({
                                 "type": "ERROR",
                                 "object": _status_body(err.code, err.reason,
@@ -1841,13 +1803,6 @@ class RestHandler:
                         else:
                             rv_now = self.store.resource_version
                         await stream.send_json(_bookmark(rv_now))
-            except _SlowWatcher:
-                # the socket sat past KCP_WATCH_BUFFER_MAX: terminal
-                # typed 410 buffered without a drain (draining a full
-                # slow socket is exactly what eviction exists to avoid)
-                REGISTRY.counter("watch_evicted_total").inc()
-                self._send_evicted(stream, _SOCKET_EVICTED)
-                return
             finally:
                 # reap outstanding helper tasks without awaiting (this
                 # block also runs under cancellation): the callback

@@ -24,9 +24,7 @@ from ..utils.trace import REGISTRY, SIZE_BUCKETS
 log = logging.getLogger(__name__)
 
 #: stream flush operations — one buffered chunk write (plus, on the
-#: non-coalesced paths, its drain round trip) per socket. The watcher-
-#: scale A/B (`bench.py --watchers`) reads the per-fan-out reduction off
-#: this counter.
+#: relay paths, its drain round trip) per socket.
 _FLUSHES = REGISTRY.counter(
     "watch_flush_total",
     "watch-stream flush operations (one chunk write per socket)")
@@ -35,16 +33,17 @@ _FLUSH_BATCH = REGISTRY.histogram(
     "event lines merged into one stream flush", buckets=SIZE_BUCKETS)
 #: the zero-copy wire meters: spans handed to the transport through the
 #: scatter path (no whole-body b"".join), and the bytes that skipped the
-#: full-body join copy because of it. `bench.py --smartclient` proves the
+#: full-body join copy because of it.
+#: ``tests/test_smartclient.py::test_wire_scatter_byte_identity`` proves the
 #: scatter path byte-identical to the join path (sha256 over the wire).
 _SPANS_WRITTEN = REGISTRY.counter(
     "wire_spans_written_total",
     "encode-once byte spans written through the scatter wire path "
-    "(KCP_WIRE_SCATTER) without an intermediate whole-body join")
+    "without an intermediate whole-body join")
 _JOIN_AVOIDED = REGISTRY.counter(
     "wire_join_avoided_total",
     "response-body bytes written without the whole-body b''.join copy "
-    "the legacy wire path paid (scatter path only)")
+    "(scatter path only)")
 
 MAX_HEADER_BYTES = 64 * 1024
 # listener accept backlog: a 10k-watcher reconnect storm lands thousands
@@ -65,15 +64,13 @@ MAX_BODY_BYTES = int(os.environ.get("KCP_MAX_BODY_BYTES", str(3 * 1024 * 1024)))
 SCATTER_MIN = int(os.environ.get("KCP_WIRE_SCATTER_MIN", str(16 * 1024)))
 
 
-def scatter_enabled() -> bool:
-    """KCP_WIRE_SCATTER (default on): scatter/writev-style body writes —
-    span lists are handed to the transport without the whole-body
-    ``b"".join`` (big spans go as-is; small ones coalesce into bounded
-    <= SCATTER_MIN join buffers). ``=0`` restores the single-join wire
-    path for A/B; both produce byte-identical streams. Read per response
-    (one dict probe) so tests and benches can flip it on a live server."""
-    return os.environ.get("KCP_WIRE_SCATTER", "1").lower() not in (
-        "0", "false", "off")
+#: scatter/writev-style body writes: span lists are handed to the transport
+#: without the whole-body ``b"".join`` (big spans go as-is; small ones
+#: coalesce into bounded <= SCATTER_MIN join buffers). False is the
+#: single-join wire path, the reference
+#: ``tests/test_smartclient.py::test_wire_scatter_byte_identity`` patches
+#: in: both produce byte-identical streams.
+SCATTER = True
 
 
 def _write_parts(writer: asyncio.StreamWriter, parts) -> None:
@@ -250,21 +247,21 @@ class StreamResponse:
     async def send_spans(self, lines) -> None:
         """The raw-spans twin of :meth:`send_json_many`: encode-once byte
         spans framed as ONE chunk and written scatter-style (no
-        whole-chunk ``b"".join`` while ``KCP_WIRE_SCATTER`` is on) + one
-        drain. The replication hub's batch sends ride this — a catchup
-        tail of N pre-encoded WAL records costs zero re-encodes and zero
-        whole-batch join copies."""
+        whole-chunk ``b"".join``) + one drain. The replication hub's
+        batch sends ride this — a catchup tail of N pre-encoded WAL
+        records costs zero re-encodes and zero whole-batch join
+        copies."""
         await self.send_raw_many(lines)
 
     def write_raw_many(self, lines) -> None:
         """Frame pre-encoded lines as ONE chunk and buffer them on the
-        transport WITHOUT draining — the :class:`FlushCoalescer`'s write
-        half. Backpressure is handled by eviction (the coalescer checks
-        the transport buffer against ``KCP_WATCH_BUFFER_MAX``), never by
-        awaiting a slow socket. With ``KCP_WIRE_SCATTER`` on, the lines
-        go to the transport as spans (bounded coalescing, no whole-chunk
-        join); ``=0`` keeps the legacy single-join write — byte-identical
-        either way (same bytes, same single chunk frame)."""
+        transport WITHOUT draining — the push path's write half.
+        Backpressure is handled by eviction (the handler's push sink
+        checks the transport buffer against ``KCP_WATCH_BUFFER_MAX``),
+        never by awaiting a slow socket. The lines go to the transport
+        as spans (bounded coalescing, no whole-chunk join); with
+        ``SCATTER`` off, one single-join write — byte-identical either
+        way (same bytes, same single chunk frame)."""
         assert self._writer is not None
         if not lines:
             return
@@ -274,7 +271,7 @@ class StreamResponse:
         total = sum(len(ln) for ln in lines)
         if not total:
             return  # an all-empty batch must not emit a terminal 0-chunk
-        if scatter_enabled():
+        if SCATTER:
             _write_parts(self._writer,
                          [f"{total:x}\r\n".encode(), *lines, b"\r\n"])
         else:
@@ -299,7 +296,7 @@ class StreamResponse:
 
     def write_buffer_size(self) -> int:
         """Bytes buffered on this stream's transport — the slow-client
-        signal the coalescer's eviction policy reads."""
+        signal the push path's eviction reads."""
         w = self._writer
         if w is None or w.transport is None:
             return 0
@@ -315,64 +312,6 @@ class StreamResponse:
                 await self._writer.drain()
             except (ConnectionError, RuntimeError):
                 pass
-
-
-class FlushCoalescer:
-    """Batches watch-stream writes across many sockets into one
-    event-loop pass (``KCP_WATCH_COALESCE``).
-
-    Producers call ``await write(stream, lines)``; lines park per-stream
-    and the whole map flushes after one coalescing tick
-    (``KCP_WATCH_FLUSH_MS``): each socket gets ONE joined chunk write
-    per tick no matter how many event batches accumulated, so a
-    sustained fan-out to N watchers costs O(sockets) buffered writes of
-    shared encode-once bytes per tick instead of O(batches × watchers)
-    write+drain round trips.
-
-    Backpressure is by eviction, not drain: the flush never awaits a
-    slow socket. A stream whose transport buffer exceeds ``buffer_max``
-    (``KCP_WATCH_BUFFER_MAX``) resolves its producer's future ``False``
-    — the producer ends the stream with a terminal typed 410 and the
-    informer's relist-NOW path takes over. Everyone else's tick is never
-    held hostage by the slowest reader.
-    """
-
-    def __init__(self, tick_s: float = 0.002,
-                 buffer_max: int = 2 * 1024 * 1024):
-        self.tick_s = tick_s
-        self.buffer_max = buffer_max
-        self._pending: dict[StreamResponse,
-                            tuple[list[bytes], asyncio.Future]] = {}
-        self._scheduled = False
-
-    def write(self, stream: StreamResponse, lines) -> "asyncio.Future[bool]":
-        """Park ``lines`` for ``stream``; the returned future resolves
-        True once flushed (False = over the buffer bound: evict)."""
-        loop = asyncio.get_running_loop()
-        ent = self._pending.get(stream)
-        if ent is None:
-            ent = self._pending[stream] = ([], loop.create_future())
-        ent[0].extend(lines)
-        if not self._scheduled:
-            self._scheduled = True
-            if self.tick_s > 0:
-                loop.call_later(self.tick_s, self._flush)
-            else:
-                loop.call_soon(self._flush)
-        return ent[1]
-
-    def _flush(self) -> None:
-        self._scheduled = False
-        pending, self._pending = self._pending, {}
-        for stream, (lines, fut) in pending.items():
-            if fut.done():
-                continue  # producer cancelled (client went away)
-            try:
-                stream.write_raw_many(lines)
-            except Exception as e:  # noqa: BLE001 — surfaced to the producer
-                fut.set_exception(e)
-                continue
-            fut.set_result(stream.write_buffer_size() <= self.buffer_max)
 
 
 _REASONS = {200: "OK", 201: "Created", 400: "Bad Request", 403: "Forbidden",
@@ -594,7 +533,7 @@ class HttpServer:
             head += f"{k}: {v}\r\n"
         head += ("Connection: "
                  f"{'keep-alive' if keep else 'close'}\r\n\r\n")
-        if resp.spans is not None and scatter_enabled():
+        if resp.spans is not None and SCATTER:
             # zero-copy body: the encode-once spans go to
             # the transport without the whole-body join
             _write_parts(writer, [head.encode(), *resp.spans])

@@ -481,7 +481,7 @@ class RestClient:
         # process-wide discovery cache), and RemoteStore's per-cluster
         # store-pool threads refresh it concurrently — guard it with an
         # explicit lock instead of relying on the GIL making dict ops
-        # atomic (ADVICE r5). The lock is shared by the clones too;
+        # atomic. The lock is shared by the clones too;
         # refreshes run under it on the caller's own connection, so
         # holding it never waits on another client's in-flight verb.
         self._disc_lock = make_lock("rest.discovery")

@@ -215,7 +215,7 @@ class Server:
                 os.makedirs(self.config.root_dir, exist_ok=True)
             if self.install_controllers:
                 if not self.config.force_remote_controllers:
-                    # hard error, not a warning (ADVICE r5): in-process
+                    # hard error, not a warning: in-process
                     # controllers run their RemoteStore HTTP verbs (30 s
                     # timeouts) directly on the serving loop — a slow or
                     # unreachable backend freezes watches and /healthz —

@@ -25,7 +25,7 @@ kcp-dev/kubernetes fork is not vendored there):
   compaction (restart resumes from durable storage, matching the
   reference's restart-resumes-from-etcd model, server.go:80-97)
 
-Read path (KCP_STORE_INDEX=1, the default):
+Read path:
 
 - secondary ``resource -> cluster -> namespace`` buckets are maintained
   on every mutation (and rebuilt on WAL/snapshot restore), so ``list``
@@ -54,10 +54,10 @@ Read path (KCP_STORE_INDEX=1, the default):
   (``call_soon``), on a size threshold, and lazily whenever a consumer
   touches a watch, so delivery semantics are unchanged.
 
-``KCP_STORE_INDEX=0`` (or ``indexed=False``) keeps the pre-index scan +
-per-event deepcopy path for A/B measurement (``bench.py --store``).
+``indexed=False`` keeps the pre-index scan + per-event deepcopy path:
+the reference ``tests/test_store_index.py`` compares against.
 
-Encode-once serving (KCP_ENCODE_CACHE=1, the default, indexed stores):
+Encode-once serving (indexed stores):
 
 - the CoW contract above makes serialized bytes a *pure function of the
   snapshot object*: a per-record byte cache (:meth:`encode_obj`) is
@@ -70,9 +70,10 @@ Encode-once serving (KCP_ENCODE_CACHE=1, the default, indexed stores):
   out to 64 relays is encoded once, not 64 times — rewritten
   (label-transition) events are shared across matched watches for the
   same reason;
-- ``KCP_ENCODE_CACHE=0`` keeps the per-call ``json.dumps`` serving path
-  for A/B (``bench.py --encode``), and the ``encode.cache`` KCP_FAULTS
-  point force-drops cached entries to exercise the re-encode fallback.
+- ``encode_cache=False`` keeps the per-call ``json.dumps`` serving path,
+  the reference ``tests/test_encode_cache.py`` compares against, and the
+  ``encode.cache`` KCP_FAULTS point force-drops cached entries to
+  exercise the re-encode fallback.
 
 Watcher scale (PR 11):
 
@@ -89,7 +90,7 @@ Watcher scale (PR 11):
   arrays (rebuilt only when the watch set changes), so a flush is
   O(events + deliveries), not O(live watchers).
 
-Write path: group commit (KCP_GROUP_COMMIT=1, the default):
+Write path: group commit:
 
 - concurrent mutations apply to the in-memory state one at a time as
   always (RV allocation, conflict checks, event emission unchanged),
@@ -109,9 +110,9 @@ Write path: group commit (KCP_GROUP_COMMIT=1, the default):
   and commits NONE of its records (``store.commit_window`` faults
   drill the split/failure/abort paths); sync-context callers (no
   running loop) keep the serial append — durable on return;
-- ``KCP_GROUP_COMMIT=0`` keeps the serial path as the A/B reference:
-  state, event streams and WAL bytes are identical either way
-  (tests/test_group_commit.py differential fuzz; bench.py --writes).
+- ``group_commit=False`` keeps the serial path for every caller, the
+  reference of tests/test_group_commit.py's differential fuzz: state,
+  event streams and WAL bytes are identical either way.
 
 Thread-model: single-threaded synchronous core intended to be called from
 one asyncio event loop; watches buffer into deques and optionally notify an
@@ -184,14 +185,6 @@ def decode_continue(token: str) -> tuple[int, tuple | None]:
         raise ValueError(f"malformed continue token: {e}") from None
 
 
-def _env_indexed() -> bool:
-    return os.environ.get("KCP_STORE_INDEX", "1").lower() not in ("0", "false", "off")
-
-
-def _env_encode_cache() -> bool:
-    return os.environ.get("KCP_ENCODE_CACHE", "1").lower() not in ("0", "false", "off")
-
-
 def _env_watch_window() -> int:
     """Retained watch-cache window (events): how far back a
     ``watch(since_rv=...)`` resume can reach before answering 410."""
@@ -205,17 +198,6 @@ def _env_watch_queue() -> int:
     turns into a terminal in-stream typed 410 (informers relist-NOW and
     resume) — instead of buffering the window into unbounded memory."""
     return int(os.environ.get("KCP_WATCH_QUEUE", "65536"))
-
-
-def _env_group_commit() -> bool:
-    """Group commit (KCP_GROUP_COMMIT, default on): concurrent mutations
-    coalesce into one commit window — the window's WAL records append as
-    ONE buffered write + ONE sync, ship to replication as ONE batch, and
-    fan out to watchers in ONE flush. ``=0`` keeps the serial
-    append-per-record path (the A/B reference; byte-identical WAL/state
-    either way)."""
-    return os.environ.get("KCP_GROUP_COMMIT", "1").lower() not in (
-        "0", "false", "off")
 
 
 def _env_commit_window_max() -> int:
@@ -630,18 +612,23 @@ class LogicalStore:
         wal_backend: str = "auto",
         wal_sync_every: int = 256,
         namespace_lifecycle: bool = False,
-        indexed: bool | None = None,
-        encode_cache: bool | None = None,
+        indexed: bool = True,
+        encode_cache: bool = True,
+        group_commit: bool = True,
     ):
-        """``indexed``: None reads ``KCP_STORE_INDEX`` (default on) —
-        False keeps the pre-index linear-scan/deepcopy read path and the
-        per-watch python fan-out for A/B measurement.
+        """``indexed``: False keeps the pre-index linear-scan/deepcopy
+        read path and the per-watch python fan-out, the reference
+        ``tests/test_store_index.py`` compares against.
 
-        ``encode_cache``: None reads ``KCP_ENCODE_CACHE`` (default on) —
-        False keeps per-call ``json.dumps`` serving for A/B
-        (``bench.py --encode``). Only effective on indexed stores: the
-        cache's validity rests on the CoW snapshot contract, which the
-        legacy deepcopy-per-read path does not provide.
+        ``encode_cache``: False keeps per-call ``json.dumps`` serving,
+        the reference ``tests/test_encode_cache.py`` compares against.
+        Only effective on indexed stores: the cache's validity rests on
+        the CoW snapshot contract, which the deepcopy-per-read path does
+        not provide.
+
+        ``group_commit``: False keeps the serial append-per-record write
+        path for every caller, the reference
+        ``tests/test_group_commit.py`` compares against.
 
         ``wal_backend``: "auto" uses the native C++ engine
         (native/walstore.cc — binary records, CRC32 torn-write recovery,
@@ -731,7 +718,7 @@ class LogicalStore:
         self._hist_start = 0
         self._watch_queue = _env_watch_queue()
         self._clock = clock
-        self._indexed = _env_indexed() if indexed is None else bool(indexed)
+        self._indexed = indexed
         # secondary index: resource -> cluster -> namespace -> {key: obj};
         # maintained on every mutation (both modes — clusters()/
         # resources()/locate() read it), pruned empty so the bucket keys
@@ -759,8 +746,7 @@ class LogicalStore:
         # identity. Mutation replaces the snapshot (CoW), which is the
         # whole invalidation story; _put_obj/_del_obj evict replaced
         # snapshots purely to bound memory to the live object set.
-        self._encode_cache = (_env_encode_cache() if encode_cache is None
-                              else bool(encode_cache)) and self._indexed
+        self._encode_cache = encode_cache and indexed
         self._enc_bytes: dict[int, tuple[dict, bytes]] = {}
         # per-bucket list spans: (resource, cluster, namespace) ->
         # (bucket version, b", ".join of the bucket's sorted item
@@ -831,13 +817,13 @@ class LogicalStore:
         # engine opens — fsync/off take over sync scheduling explicitly,
         # so the engine's own sync_every batching is disabled for them
         self._wal_sync = _env_wal_sync()
-        # group commit (KCP_GROUP_COMMIT, default on): concurrent
-        # mutations coalesce into a bounded commit window that appends as
+        # group commit: concurrent mutations coalesce into a bounded
+        # commit window that appends as
         # ONE buffered write + ONE sync, ships ONE replication batch, and
         # fires ONE watch fan-out flush. Windows only form on stores with
         # a sink (WAL or replication hook) under a running event loop;
         # sync-context callers keep the serial path record for record.
-        self._gc_enabled = _env_group_commit()
+        self._gc_enabled = group_commit
         self._gc_max = _env_commit_window_max()
         self._gc_linger_s = _env_commit_window_us() / 1e6
         self._gc_window: _CommitWindow | None = None
@@ -1369,7 +1355,7 @@ class LogicalStore:
     @property
     def encode_cache_enabled(self) -> bool:
         """True when serving paths may splice cached snapshot bytes
-        (KCP_ENCODE_CACHE on an indexed/CoW store)."""
+        (``encode_cache`` on an indexed/CoW store)."""
         return self._encode_cache
 
     def encode_obj(self, obj: dict) -> bytes:

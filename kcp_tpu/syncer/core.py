@@ -99,8 +99,9 @@ def _grown(a: np.ndarray, shape, dtype) -> np.ndarray:
 
 
 #: the phases of a tick, host time each (``fused_<phase>_seconds``): the
-#: 'where does tick time go' answer that /debug/profile and bench.py
-#: report. ``encode`` is observed only on ticks that touched keys,
+#: 'where does tick time go' answer that /debug/profile and the
+#: benchmark's layer readers report. ``encode`` is observed only on
+#: ticks that touched keys,
 #: ``full_upload`` replaces ``pack`` on a tick that re-uploads the whole
 #: mirror, and ``compile`` replaces ``step_dispatch`` on the first
 #: dispatch of a set of shapes and static arguments (XLA compiles it, or
@@ -1352,8 +1353,8 @@ class FusedCore:
         # tick pipelining mode: "double" (default) keeps up to
         # PIPELINE_DEPTH steps in flight — pack N+1 and apply N-1 while
         # the device runs N; "serial" collects every wire in the tick
-        # that submitted it (the A/B reference for bench.py --pipeline
-        # and the equivalence fuzz)
+        # that submitted it (the reference of the equivalence fuzz,
+        # tests/test_pipeline.py)
         pipeline = pipeline or "double"
         if pipeline not in PIPELINE_MODES:
             raise ValueError(f"pipeline must be one of {PIPELINE_MODES}, "

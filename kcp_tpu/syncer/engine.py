@@ -54,7 +54,6 @@ from __future__ import annotations
 
 import asyncio
 import logging
-import os
 import time
 from typing import Sequence
 
@@ -236,9 +235,9 @@ class BatchSyncEngine:
     ``backend="tpu"`` registers a row section in the process-wide
     :class:`~kcp_tpu.syncer.core.FusedCore`: every engine's rows live in a
     shared schema bucket and each reconcile tick runs ONE fused
-    ``reconcile_step_packed`` over the whole fleet — the same program
-    ``bench.py`` measures. ``backend="host"`` computes identical decisions
-    in pure Python per engine — the differential-testing reference
+    ``reconcile_step_packed`` over the whole fleet. ``backend="host"``
+    computes identical decisions in pure Python per engine — the
+    differential-testing reference
     (SURVEY.md §7.1).
 
     Applies are pipelined: the tick never waits on a store write. Patches
@@ -299,8 +298,6 @@ class BatchSyncEngine:
         # stay valid as the vocabulary grows). Periodic resyncs and
         # level-triggered re-touches of unchanged keys hit this instead
         # of re-flattening the object.
-        self._enc_memo_on = os.environ.get(
-            "KCP_ENCODE_CACHE", "1").lower() not in ("0", "false", "off")
         self._enc_memo: dict[int, tuple[dict, np.ndarray]] = {}
         self._enc_memo_max = 65536
         self.rows: dict[tuple[str, str], int] = {}  # (ns, name) -> row
@@ -521,8 +518,6 @@ class BatchSyncEngine:
         """Encode-once ``enc.encode(_sync_view_ro(obj))``: memoized per
         snapshot identity. The returned row is shared — callers copy it
         into staging buffers, never mutate it."""
-        if not self._enc_memo_on:
-            return self.enc.encode(_sync_view_ro(obj))
         ent = self._enc_memo.get(id(obj))
         if ent is not None and ent[0] is obj:
             return ent[1]

@@ -3,22 +3,28 @@
 # (.github/workflows/ci.yaml: vet + race-checked tests; demos.yaml:
 # golden demo runs). A fresh checkout runs this green; every stage is
 # CPU-pinned (tests via conftest, demo via DEMO_JAX_PLATFORM, dryrun via
-# its XLA_FLAGS guard) so it is safe to run while a TPU bench is in
-# flight elsewhere.
+# its XLA_FLAGS guard). Correctness only: no stage holds a speed — the
+# benchmark (benchmarks/, on the chip) and PERF_LEDGER.jsonl are the
+# ratchet for that.
 #
 # Usage: scripts/ci.sh [--fast]   (--fast skips the demo + dryrun)
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
+# what the stages write (lint report, scenario scorecards) stays out of
+# the checkout
+out=$(mktemp -d)
+trap 'rm -rf "$out"' EXIT
+
 fast=0
 [[ "${1:-}" == "--fast" ]] && fast=1
 
 echo "== vet: syntax-compile every tracked python file"
-python -m compileall -q kcp_tpu tests contrib bench.py chip_smoke.py __graft_entry__.py
+python -m compileall -q kcp_tpu tests contrib chip_smoke.py __graft_entry__.py
 
 if command -v ruff >/dev/null 2>&1; then
     echo "== lint: ruff (present on this host)"
-    ruff check kcp_tpu tests bench.py chip_smoke.py __graft_entry__.py
+    ruff check kcp_tpu tests chip_smoke.py __graft_entry__.py
 else
     echo "== lint: ruff not installed here, skipped (vet stage above still gates syntax)"
 fi
@@ -26,18 +32,18 @@ fi
 echo "== kcp-lint: contract checkers (CoW / frozen-bytes / async / lock-order / fault points / metrics docs)"
 # zero active findings required; waivers are counted and reported so
 # exemptions stay visible in every CI log (scripts/lint.py --help)
-python scripts/lint.py --format json > /tmp/_lint.json || {
+python scripts/lint.py --format json > "$out/lint.json" || {
     python scripts/lint.py; exit 1; }
 python -c '
-import json
-r = json.load(open("/tmp/_lint.json"))
+import json, sys
+r = json.load(open(sys.argv[1]))
 assert r["ok"], r["summary"]
 for w in r["waived"]:
     print("  waived: %s:%s %s -- %s"
           % (w["path"], w["line"], w["rule"], w["justification"]))
 print("kcp-lint ok: 0 findings | %d waiver(s), all justified | %d files"
       % (r["summary"]["waived"], r["files_checked"]))
-'
+' "$out/lint.json"
 
 echo "== typecheck: mypy baseline gate for kcp_tpu/analysis + kcp_tpu/utils"
 scripts/typecheck.sh
@@ -64,358 +70,15 @@ KCP_SANITIZE=1 python -m pytest \
     tests/test_sanitize.py tests/test_store_index.py \
     tests/test_encode_cache.py -q
 
-echo "== bench: CPU smoke of the serial-vs-pipelined tick A/B (tiny shape)"
-# on the CPU bench.py exits 3 (ran, but no accelerator: a functional
-# smoke, no device measurement) — the only non-zero code accepted here
-ab_line=$({ JAX_PLATFORMS=cpu KCP_BENCH_ROWS=2048 \
-    KCP_BENCH_CHURN=64 KCP_BENCH_WARMUP=6 KCP_BENCH_SEGMENTS=1 \
-    KCP_BENCH_SEGMENT_S=1 python bench.py --pipeline double \
-    || [ $? -eq 3 ]; } | tail -1)
-printf '%s\n' "$ab_line" | python -c '
-import json, sys
-r = json.loads(sys.stdin.readline())
-ab = r.get("pipeline_ab") or {}
-assert r["device"]["platform"] == "cpu", r.get("device")
-assert set(ab) == {"serial", "double"}, f"A/B modes missing: {sorted(ab)}"
-for mode, res in ab.items():
-    assert res.get("value", 0) > 0, f"{mode}: no measured rate"
-    assert res.get("segment_rates"), f"{mode}: no per-segment rates"
-    assert "convergence_p99_ms" in res, f"{mode}: no convergence percentiles"
-print("pipeline A/B smoke ok:",
-      {m: res["value"] for m, res in ab.items()},
-      "| speedup:", r.get("pipeline_speedup"))
-'
-
-echo "== placement: fleet bin-pack smoke (batched-vs-per-workspace floor + assignment byte-equality)"
-# reduced-scale --placement lane (2k workspaces x 8 pclusters, 400-row
-# loop sample): the batched device solve must beat the pre-fleet
-# per-workspace host loop >=4x (the committed full-scale
-# BENCH_r11_placement.json measured ~15x at 10k x 8), stay byte-identical
-# to the numpy host twin AND the per-workspace answers, never overcommit
-# or land on a non-candidate, and the incremental re-solve must touch
-# exactly the dirty rows while matching a from-scratch recompute
-pl_line=$(JAX_PLATFORMS=cpu KCP_BENCH_PLACEMENT_WORKSPACES=2000 \
-    KCP_BENCH_PLACEMENT_LOOP_ROWS=400 KCP_BENCH_PLACEMENT_ITERS=3 \
-    python bench.py --placement | tail -1)
-printf '%s\n' "$pl_line" | python -c '
-import json, sys
-r = json.loads(sys.stdin.readline())
-pb = r["placement_bench"]
-assert pb["assignment_equal_host"], "batched assignment diverged from host twin"
-assert pb["assignment_equal_per_workspace"], (
-    "per-workspace loop diverged from the batched answer")
-assert pb["overcommit_rows"] == 0, pb
-assert pb["noncandidate_replicas"] == 0, pb
-inc = pb["incremental"]
-assert inc["rows_solved"] == inc["dirty_rows"], (
-    "incremental re-solve touched %d rows for %d dirty"
-    % (inc["rows_solved"], inc["dirty_rows"]))
-assert inc["mismatches"] == 0, inc
-assert r["value"] >= 4.0, "batched speedup %sx < 4x floor" % r["value"]
-print("placement smoke ok: %sx batched vs per-workspace | %d rows byte-identical"
-      " | incremental %d/%d rows, 0 mismatches"
-      % (r["value"], pb["workspaces"], inc["rows_solved"], inc["dirty_rows"]))
-'
-
-echo "== store: CPU microbench smoke (10k objects, 64 watches) with regression floor"
-store_line=$(KCP_BENCH_STORE_OBJECTS=10000 KCP_BENCH_STORE_MUTS=1500 \
-    python bench.py --store | tail -1)
-printf '%s\n' "$store_line" | python -c '
-import json, sys
-r = json.loads(sys.stdin.readline())
-v = r["value"]
-sb = r["store_bench"]
-assert sb["events_equal"], "indexed/legacy watch event counts diverged"
-# regression floor: the indexed read path measured ~9x combined at this
-# shape when it landed; 4x leaves slack for slow CI hosts while still
-# catching a lost index or a reintroduced per-event deepcopy
-assert v >= 4.0, "store read-path speedup regressed: %sx < 4x floor" % v
-print("store smoke ok: %sx combined | %sx list | %sx fan-out"
-      % (v, sb["list_speedup"], sb["fanout_speedup"]))
-'
-
-echo "== encode: encode-once serving A/B smoke (10k objects, 64 watchers) with regression floor"
-enc_line=$(KCP_BENCH_ENCODE_OBJECTS=10000 KCP_BENCH_ENCODE_MUTS=300 \
-    python bench.py --encode | tail -1)
-printf '%s\n' "$enc_line" | python -c '
-import json, sys
-r = json.loads(sys.stdin.readline())
-eb = r["encode_bench"]
-assert eb["bytes_equal"], "cached and uncached serving bytes diverged"
-assert eb["events_equal"], "cached/uncached watch event counts diverged"
-# regression floor: the encode-once path measured ~7x combined at this
-# shape when it landed; 3x leaves slack for slow CI hosts while still
-# catching a lost cache or a reintroduced per-watcher re-encode
-assert r["value"] >= 3.0, "encode-once speedup regressed: %sx < 3x floor" % r["value"]
-print("encode smoke ok: %sx combined | %sx churned-list | %sx fan-out-encode"
-      % (r["value"], eb["churn_list_speedup"], eb["fanout_encode_speedup"]))
-'
-
-echo "== admission: happy-path overhead + noisy-neighbor storm smoke"
-# 1 tenant floods writes at 10x its token rate alongside quiet tenants:
-# quiet p99 must stay within 2x of its no-storm baseline with ZERO quiet
-# rejections, the flood must see 429 + Retry-After, and the chain's
-# happy-path overhead on the serving path must stay under 5%
-adm_line=$(KCP_BENCH_ADM_WRITES=3000 KCP_BENCH_ADM_TENANTS=40 \
-    KCP_BENCH_ADM_STORM_S=2 python bench.py --admission | tail -1)
-printf '%s\n' "$adm_line" | python -c '
-import json, sys
-r = json.loads(sys.stdin.readline())
-st = r["admission_bench"]["storm"]
-assert r["value"] < 5.0, "happy-path admission overhead %s%% >= 5%%" % r["value"]
-assert st["quiet_rejected"] == 0, st
-assert st["quiet_p99_ratio"] <= 2.0, st
-assert st["flood_429"] > 0 and st["flood_retry_after_seen"], st
-assert st["flood_ok"] < st["flood_sent"] // 2, "flood was not throttled: %s" % st
-print("admission smoke ok: overhead %.2f%% (direct %.2f%%) | quiet p99 ratio"
-      " %.2f | flood throttled %d/%d with Retry-After"
-      % (r["value"], r["admission_bench"]["happy"]["direct_overhead_pct"],
-         st["quiet_p99_ratio"], st["flood_429"], st["flood_sent"]))
-'
-
-echo "== sharded: 2-shard fleet smoke (capacity scaling, shard-kill drill)"
-# real kcp subprocesses: 2 shards + a --role router frontend. Gates the
-# shared-nothing capacity floor (time-sliced per-shard rates — honest on
-# 1-core CI hosts; see docs/operations.md "Benchmarking"), the router's
-# fail-fast 503 once the breaker trips on a SIGKILLed shard, the merged
-# watch's terminal in-stream 410, and zero acked writes lost after the
-# WAL-restored restart + relist catchup.
-sh_line=$(KCP_BENCH_SHARD_FLEETS=1,2 KCP_BENCH_SHARD_SECONDS=1.5 \
-    KCP_BENCH_SHARD_CLUSTERS=16 KCP_BENCH_SHARD_EVENTS=12 \
-    python bench.py --sharded | tail -1)
-printf '%s\n' "$sh_line" | python -c '
-import json, sys
-r = json.loads(sys.stdin.readline())
-sb = r["sharded_bench"]
-kill = sb["kill"]
-cap = sb["capacity_speedup"]["2"]
-# floor 1.6x: a skewed ring or cross-shard write traffic drags the
-# shared-nothing capacity sum toward 1x; near-linear is ~2x
-assert cap >= 1.6, "2-shard capacity speedup %sx < 1.6x floor" % cap
-assert kill["watch_terminal_410"], "merged watch did not end with 410: %s" % kill
-assert kill["failfast_ms"] < 1000, "breaker not failing fast: %s" % kill
-assert kill["lost_after_catchup"] == 0, "lost writes after catchup: %s" % kill
-print("sharded smoke ok: capacity %sx @2 shards (concurrent %sx on %s cpu)"
-      " | kill: 410 in %sms, fail-fast %sms, %d acked / 0 lost"
-      % (cap, sb["concurrent_speedup"]["2"], sb["host_cpus"],
-         kill["watch_410_ms"], kill["failfast_ms"], kill["acked_writes"]))
-'
-
-echo "== smartclient: direct-vs-routed smoke (2-shard fleet, byte equality, ring-change drill)"
-# smart clients compute the HRW owner from GET /ring and skip the
-# router hop. Floors: direct single-cluster write CAPACITY (per-shard
-# time slices summed — see docs/operations.md "Benchmarking") >=1.5x
-# the one-router routed ceiling (the committed BENCH_r08 measured
-# 3.7x @2 shards), routed and direct
-# responses byte-identical, the scatter wire path sha256-identical to
-# the join path, and the mid-bench ring-change drill (shard drains,
-# restarts on a NEW port, /ring republishes, all under an injected
-# router.proxy fault schedule) completing with zero lost acked writes
-# and zero surfaced client errors — one-shot fallbacks absorb the move.
-smart_line=$(KCP_BENCH_SMART_SECONDS=1.5 KCP_BENCH_SMART_CLUSTERS=8 \
-    python bench.py --smartclient | tail -1)
-printf '%s\n' "$smart_line" | python -c '
-import json, sys
-r = json.loads(sys.stdin.readline())
-sb = r["smartclient_bench"]
-ab, wire, drill = sb["ab"], sb["wire"], sb["ring_change_drill"]
-assert r["value"] >= 1.5, "direct/routed capacity %sx < 1.5x floor" % r["value"]
-assert ab["bytes_equal"], "routed vs direct responses diverged"
-assert ab["direct_requests"] > 0, "smart client never went direct: %s" % ab
-assert wire["identical"], "scatter wire path diverged from join path"
-assert wire["spans_written"] > 0, "scatter path never exercised: %s" % wire
-assert drill["lost_after_move"] == 0, "acked writes lost in ring change: %s" % drill
-assert drill["errors_surfaced"] == 0, "client errors surfaced in drill: %s" % drill
-assert drill["fallbacks"] >= 1 and drill["ring_epoch_after"] >= 2, drill
-print("smartclient smoke ok: %sx direct/routed capacity (p99 %s->%sms) | bytes equal"
-      " | wire scatter identical (%d spans, %d bytes join-free)"
-      " | ring-change drill: %d acked / 0 lost, %d fallbacks, epoch %d"
-      % (r["value"], ab["routed_p99_ms"], ab["direct_p99_ms"],
-         wire["spans_written"], wire["join_avoided_bytes"],
-         drill["acked_writes"], drill["fallbacks"],
-         drill["ring_epoch_after"]))
-'
-
-echo "== elastic: live scale-out smoke (fleet doubles mid-workload, zero lost acked writes, capacity floor)"
-# in-process fleet doubles 2->4 shards while smart + routed writers keep
-# going: every moving cluster's WAL streams to its new owner behind a
-# fence, the ring flips atomically per cluster, and the acked-write
-# ledger must come back intact. Floors: post-move capacity >=1.2x the
-# 2-shard baseline (the committed BENCH_r10_elastic.json measured 1.88x
-# on this shape; 1.2x leaves slack for loaded CI hosts while still
-# catching a migration that parks clusters or a ring that never flips),
-# zero lost acked writes, zero surfaced client errors (fence 503s are
-# absorbed by retry), and real migration traffic on the wire.
-el_line=$(KCP_BENCH_ELASTIC_SECONDS=0.8 KCP_BENCH_ELASTIC_CLUSTERS=16 \
-    python bench.py --elastic | tail -1)
-printf '%s\n' "$el_line" | python -c '
-import json, sys
-r = json.loads(sys.stdin.readline())
-eb = r["elastic_bench"]
-mv = eb["during_move"]
-assert r["value"] >= 1.2, "post-scale-out capacity %sx < 1.2x CI floor" % r["value"]
-assert mv["lost_after_move"] == 0, "acked writes lost across scale-out: %s" % mv
-assert mv["errors_surfaced"] == 0, "client errors surfaced during move: %s" % mv
-assert mv["migrated_clusters"] >= 1 and mv["migration_records"] >= 1, mv
-assert len(eb["per_shard_after"]) == eb["shards_after"], (
-    "scaled-out ring left shards idle: %s" % eb["per_shard_after"])
-print("elastic smoke ok: %sx capacity %d->%d shards | move %ss:"
-      " %d acked / 0 lost, %d clusters / %d records migrated,"
-      " %d fence 503s absorbed (epoch %d)"
-      % (r["value"], eb["shards_before"], eb["shards_after"],
-         mv["move_seconds"], mv["acked_writes"], mv["migrated_clusters"],
-         mv["migration_records"], mv["fenced_write_503s"],
-         mv["ring_epoch_after"]))
-'
-
-echo "== replica: HA replication smoke (read scaling, lag, kill-the-primary drill)"
-# primary + 0/1/2 WAL-fed read replicas, then a durable primary+standby
-# kill drill. Floors: read capacity >=1.5x at 2 replicas (each endpoint
-# measured in its own time slice — honest on 1-core hosts; near-linear
-# is ~3x), list bytes identical to the primary at the same RV (the
-# encode-once differential), and ZERO acknowledged writes lost after
-# the standby promotes.
-repl_line=$(KCP_BENCH_REPL_OBJECTS=500 KCP_BENCH_REPL_SECONDS=0.8 \
-    KCP_BENCH_REPL_LAG_WRITES=60 KCP_BENCH_REPL_DRILL_WRITES=40 \
-    python bench.py --replica | tail -1)
-printf '%s\n' "$repl_line" | python -c '
-import json, sys
-r = json.loads(sys.stdin.readline())
-rb = r["replica_bench"]
-assert rb["bytes_equal"], "replica list bytes diverged from primary at same RV"
-assert r["value"] >= 1.5, "read capacity %sx < 1.5x floor at 2 replicas" % r["value"]
-kill = rb["kill"]
-assert kill["lost_after_promotion"] == 0, "acked writes lost: %s" % kill
-assert kill["promoted_role"] == "primary" and kill["epoch"] >= 1, kill
-print("replica smoke ok: %sx read capacity @2 | lag p99 %sms | kill: %d acked"
-      " / 0 lost, promoted in %sms (epoch %d)"
-      % (r["value"], rb["lag"].get("p99_ms"), kill["acked_writes"],
-         kill["promote_ms"], kill["epoch"]))
-'
-
-echo "== consistent: RV-barrier consistent-read smoke (read-your-writes, replica-local share, capacity A/B)"
-# 1 primary + lagged replicas (repl.ship delay active): every session
-# read-your-write through the router must come back fresh (zero stale —
-# the barrier parks the read until the replica applies the session
-# floor), >=80% of those consistent reads must be served replica-local
-# (parked, not fallen back to the primary), and consistent-read
-# capacity at 2 replicas must hold >=1.5x the primary-only pin at
-# matched freshness (each endpoint in its own time slice; near-linear
-# is ~3x). Bytes stay sha256-identical to the primary at the same RV.
-cons_line=$(KCP_BENCH_CONS_OBJECTS=500 KCP_BENCH_CONS_SECONDS=0.8 \
-    KCP_BENCH_CONS_LAG_WRITES=60 KCP_BENCH_CONS_RYWR_STEPS=60 \
-    python bench.py --consistent | tail -1)
-printf '%s\n' "$cons_line" | python -c '
-import json, sys
-r = json.loads(sys.stdin.readline())
-cb = r["consistent_bench"]
-assert cb["bytes_equal"], "consistent replica bytes diverged at same RV"
-rw = cb["read_your_writes"]
-assert rw["stale"] == 0, "stale read-your-writes: %s" % rw
-share = rw["replica_local_share"]
-assert share >= 0.8, "replica-local share %s < 0.8 floor" % share
-assert r["value"] >= 1.5, (
-    "consistent read capacity %sx < 1.5x floor at 2 replicas" % r["value"])
-w = cb["wait_for_frontier"]
-print("consistent smoke ok: %sx capacity @2 | rywr %d/%d fresh,"
-      " %.0f%% replica-local | frontier wait p50 %sms p99 %sms"
-      % (r["value"], rw["reads"] - rw["stale"], rw["reads"],
-         share * 100, w["p50_ms"], w["p99_ms"]))
-'
-
-echo "== writes: group-commit A/B smoke (write-path speedup floor, state equality, kill-mid-window drill)"
-# serial vs grouped under KCP_WAL_SYNC=fsync: the write-path component
-# (store commit + WAL sync, the thing the commit window batches) must
-# hold >=2x at 64 concurrent writers on a loaded CI host (the committed
-# BENCH_r09_writes.json gate is 3x), grouped/serial state + RV sequences
-# must match, and the kill-mid-window drill must lose zero acked writes
-# with commit windows + batched standby acks actually moving.
-wr_line=$(KCP_BENCH_WRITES_SECONDS=0.6 KCP_BENCH_WRITES_CONC=1,64 \
-    KCP_BENCH_WRITES_EQ_OPS=150 KCP_BENCH_WRITES_STORE_OPS=120 \
-    python bench.py --writes | tail -1)
-printf '%s\n' "$wr_line" | python -c '
-import json, sys
-r = json.loads(sys.stdin.readline())
-wb = r["writes_bench"]
-drill = wb["kill_drill"]
-assert r["value"] >= 2.0, "write-path speedup %sx < 2x CI floor at 64 writers" % r["value"]
-assert wb["state_equal"], "grouped vs serial final state diverged"
-assert wb["rv_sequence_equal"], "grouped vs serial RV sequences diverged"
-assert drill["ok"], "kill-mid-window drill failed: %s" % drill
-assert drill["lost_after_kill"] == 0, drill
-assert drill["commit_windows"] > 0 and drill["acks_batched"] > 0, drill
-print("writes smoke ok: %sx write-path @64 (http end-to-end %sx) | p99@1 %s->%sms"
-      " | state equal | drill: %d acked / 0 lost, %d windows, %d batched acks"
-      % (r["value"], wb["end_to_end_http"]["speedup_at_top"],
-         wb["p99_1_writer_ms"]["serial"], wb["p99_1_writer_ms"]["grouped"],
-         drill["acked_writes"], drill["commit_windows"], drill["acks_batched"]))
-'
-
-echo "== watchers: 1k-stream watcher-scale smoke (bounded RSS, delivery floor, flush A/B, evict drill)"
-# reduced-scale --watchers lane: the server runs in its own child process
-# (fd budget), 1k live streams at 10k objects. Floors: every stream
-# established, bounded per-watcher memory with a soak plateau, a delivery
-# p99 ceiling generous enough for loaded CI hosts, the flush-coalescing
-# A/B byte-identical with a >=4x reduction (13x at the full-scale default
-# tick on an idle host), and the slow-watcher eviction drill green.
-w_line=$(KCP_BENCH_WATCHERS=1000 KCP_BENCH_WATCH_OBJECTS=10000 \
-    KCP_BENCH_WATCH_CLUSTERS=20 KCP_BENCH_WATCH_MUTS=400 \
-    KCP_BENCH_WATCH_AB=48 KCP_BENCH_WATCH_AB_MUTS=300 \
-    python bench.py --watchers | tail -1)
-printf '%s\n' "$w_line" | python -c '
-import json, sys
-r = json.loads(sys.stdin.readline())
-wb = r["watchers_bench"]
-sc, ab, drill = wb["scale"], wb["ab"], wb["evict_drill"]
-assert sc["streams_established"] == sc["watchers"], sc
-assert sc["rss_per_watcher_kb"] < 100, "per-watcher RSS %s kb" % sc["rss_per_watcher_kb"]
-assert sc["rss_soak_growth"] < 1.15, "RSS grew under soak: %s" % sc["rss_soak_growth"]
-assert sc["delivery_p99_ms"] is not None and sc["delivery_p99_ms"] < 3000, sc
-assert ab["bytes_equal"] and ab["lines_equal"], "A/B streams diverged: %s" % ab
-assert r["value"] >= 4.0, "flush reduction %sx < 4x floor" % r["value"]
-assert drill["ok"], "evict drill failed: %s" % drill
-print("watchers smoke ok: %d streams | p99 %sms | %s kb/watcher (soak %s)"
-      " | flush A/B %sx byte-identical | evict drill green"
-      % (sc["streams_established"], sc["delivery_p99_ms"],
-         sc["rss_per_watcher_kb"], sc["rss_soak_growth"], r["value"]))
-'
-
-echo "== trace: distributed-tracing smoke (off-path overhead floor, wire neutrality, assembled convergence trace)"
-# reduced-scale --trace lane: paired-block A/B of the serving and
-# fan-out hot paths across KCP_TRACE=0 / default 1-in-64 / always-on
-# (CI floor 5%; the committed BENCH_r07_trace.json gate is 3%),
-# byte-identical wires across all three modes, and a router + 2-shard +
-# standby convergence trace whose per-phase durations sum-reconcile
-# (±5%) with the measured spec→status wall time.
-tr_line=$(KCP_BENCH_TRACE_OBJECTS=1500 KCP_BENCH_TRACE_REQS=320 \
-    KCP_BENCH_TRACE_WATCHES=24 KCP_BENCH_TRACE_MUTS=240 \
-    KCP_BENCH_TRACE_CONV=2 python bench.py --trace | tail -1)
-printf '%s\n' "$tr_line" | python -c '
-import json, sys
-r = json.loads(sys.stdin.readline())
-tb = r["trace_bench"]
-assert tb["bytes_equal"], "wire bytes diverged under tracing"
-assert r["value"] < 5.0, "p50 overhead %s%% >= 5%% CI floor at default sampling" % r["value"]
-conv = tb["convergence"]
-assert conv["all_sum_ok"], conv["sum_reconciles"]
-need = {"write", "stage", "tick", "patch", "downstream", "upstatus"}
-assert need <= set(conv["phases_seen"]), conv["phases_seen"]
-names = set(conv["traces"][0]["names"])
-for s in ("server.request", "router.relay", "store.commit", "repl.ack", "repl.apply"):
-    assert s in names, (s, sorted(names))
-print("trace smoke ok: overhead %.2f%% | bytes equal | %d convergence traces sum-reconcile | %d span kinds"
-      % (r["value"], conv["runs"], len(names)))
-'
-
 echo "== trace: crud-churn scenario under always-on tracing (scorecard carries assembled traces)"
 # the scenario engine attaches the slowest assembled traces per phase
 # to the scorecard: assert at least one fully-assembled write trace
 # (driver conv.write + server span + store commit + fan-out) rode along
 KCP_TRACE=1 KCP_TRACE_SAMPLE=1 JAX_PLATFORMS=cpu python scripts/scenarios.py run \
-    --scenarios crud-churn --seed 7 --scale 0.25 --out SCENARIOS_trace_smoke.json
+    --scenarios crud-churn --seed 7 --scale 0.25 --out "$out/trace_smoke.json"
 python -c '
-import json
-r = json.load(open("SCENARIOS_trace_smoke.json"))
+import json, sys
+r = json.load(open(sys.argv[1]))
 s = r["scenarios"][0]
 assert s["passed"], s["slos"]
 traces = s.get("traces") or {}
@@ -428,69 +91,27 @@ for need in ("conv.write", "server.request", "store.commit", "store.fanout"):
     assert need in names, (need, sorted(names))
 print("scenario trace smoke ok: %d attached traces across %d phases; %d distinct span names"
       % (len(attached), len(traces), len(names)))
-'
+' "$out/trace_smoke.json"
 
 echo "== scenarios: seeded end-to-end chaos smoke (churn + reconnect storm + kill-the-primary drill)"
 # reduced-scale subset of the scenario harness (scripts/scenarios.py):
 # real topologies over real HTTP, hard SLO floors (zero lost acked
 # writes, zero lost watch events, convergence bounds, failover
 # re-homing) asserted by the engine itself — exit 1 on any miss. The
-# scorecard JSON persists as a build artifact alongside the BENCH_*
-# files; the full catalog (incl. rolling-restart drain-vs-kill) runs
-# via `scripts/scenarios.py run --all --seed 42`.
+# full catalog (incl. rolling-restart drain-vs-kill) runs via
+# `scripts/scenarios.py run --all --seed 42`.
 JAX_PLATFORMS=cpu python scripts/scenarios.py run \
     --scenarios crud-churn,reconnect-storm,kill-primary,ring-change-under-load,scale-out-under-load,partition-during-promotion \
-    --seed 42 --scale 0.4 --out SCENARIOS_smoke.json
+    --seed 42 --scale 0.4 --out "$out/smoke.json"
 python -c '
-import json
-r = json.load(open("SCENARIOS_smoke.json"))
+import json, sys
+r = json.load(open(sys.argv[1]))
 assert r["passed"], "scenario smoke failed"
 for s in r["scenarios"]:
     miss = [row["name"] for row in s["slos"] if not row["passed"]]
     assert not miss, (s["name"], miss)
 print("scenario smoke ok:", {s["name"]: s["schedule"]["hash"] for s in r["scenarios"]})
-'
-
-echo "== pagination: paged-vs-unpaged relist A/B (bytes identical, bounded peak)"
-# reduced-scale --pagination lane: limit/continue pages through the
-# real handler must concatenate byte-identically (sha256) to the
-# one-shot body at the same RV, and cut peak relist allocation >=4x
-# at 10k objects (the committed full-scale A/B floor is 5x at 100k)
-pag_line=$(KCP_BENCH_PAG_OBJECTS=10000 KCP_BENCH_PAG_PAGE=1000 \
-    python bench.py --pagination | tail -1)
-printf '%s\n' "$pag_line" | python -c '
-import json, sys
-r = json.loads(sys.stdin.readline())
-pb = r["pagination_bench"]
-assert pb["bytes_equal"], "concatenated pages != one-shot body"
-assert pb["rv_equal"], "paged rv pin diverged from one-shot rv"
-assert r["value"] >= 4.0, "peak cut %sx < 4x CI floor" % r["value"]
-print("pagination smoke ok: %d pages | bytes equal | peak cut %.2fx (%d KB -> %d KB)"
-      % (pb["pages"], r["value"], pb["unpaged_peak_kb"], pb["paged_peak_kb"]))
-'
-
-echo "== gauntlet: composed BASELINE-shape smoke (1 config, 1/50th scale)"
-# one gauntlet config end to end at CI scale: the demo-fleet shape (200
-# clusters at 1/50th of the 10k-workspace config, ~2k acked objects)
-# with smart-client writers — floors on zero loss and a real
-# reconciles/sec number, plus the embedded relist A/B staying byte-equal
-gl_line=$(KCP_GAUNTLET_CONFIGS=2 KCP_GAUNTLET_SCALE=50 KCP_GAUNTLET_OPS=10 \
-    KCP_BENCH_PAG_OBJECTS=2000 KCP_BENCH_PAG_PAGE=250 \
-    python bench.py --gauntlet | tail -1)
-printf '%s\n' "$gl_line" | python -c '
-import json, sys
-r = json.loads(sys.stdin.readline())
-rows = r["rows"]
-assert rows, "gauntlet emitted no scorecard rows"
-for row in rows:
-    assert row.get("passed"), (row.get("name"), row.get("slos"), row.get("error"))
-    assert row.get("lost_acked_writes") == 0, row
-    assert (row.get("reconciles_per_sec") or 0) > 20, row
-assert r["relist"]["bytes_equal"], "gauntlet relist A/B bytes diverged"
-print("gauntlet smoke ok: %s | %.0f acked/s | conv p99 %.1fms | rss growth %.3f"
-      % (rows[0]["name"], rows[0]["reconciles_per_sec"],
-         rows[0]["convergence_p99_ms"], rows[0]["memory_growth_ratio"]))
-'
+' "$out/smoke.json"
 
 if [[ "$fast" == "0" ]]; then
     echo "== demo: both golden scenarios, checked against committed output"

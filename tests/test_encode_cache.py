@@ -1,9 +1,9 @@
 """Encode-once serving: cached vs uncached byte-identity.
 
-The encode-once stack (KCP_ENCODE_CACHE=1: per-snapshot byte cache,
+The encode-once stack (``encode_cache=True``: per-snapshot byte cache,
 per-bucket list spans, RV-keyed list bodies, shared watch-event lines)
 must serve wires byte-identical to the per-call ``json.dumps`` path
-(KCP_ENCODE_CACHE=0). The differential fuzz drives two full
+(``encode_cache=False``). The differential fuzz drives two full
 RestHandler+LogicalStore stacks side-by-side through random REST traffic
 and compares every observable: response status + body bytes for lists
 (repeated at the same RV, churned, selector-filtered, namespaced,

@@ -387,8 +387,12 @@ def test_pipeline_equivalence_holds_under_fault_schedule(seed, monkeypatch):
     an observationally-invisible part of the pipeline: same seeded fault
     schedule -> byte-identical serial and double patch streams."""
     # keep the quarantine requeue out of the run: its wall-clock backoff
-    # timing would legitimately (and irrelevantly) fork the schedules
+    # timing would legitimately (and irrelevantly) fork the schedules.
+    # Both constants: the delay is min(base * 2**n, max), so the base
+    # alone left a requeue 5 s after each quarantine, inside the run on
+    # a loaded machine
     monkeypatch.setattr(core_mod, "QUARANTINE_BASE_BACKOFF", 120.0)
+    monkeypatch.setattr(core_mod, "QUARANTINE_MAX_BACKOFF", 120.0)
 
     async def main():
         serial, serial_ticks, serial_q = await _run_faulted_schedule(
@@ -901,11 +905,10 @@ def test_repl_promote_fault_drill():
 # ---------------------------------------------------------------------------
 
 
-def test_commit_window_forced_split_drill(tmp_path, monkeypatch):
+def test_commit_window_forced_split_drill(tmp_path):
     """`store.commit_window:drop` forces a window split mid-fill: the
     records before the split flush as their own window, everything still
     commits, and the window counter shows the extra flush."""
-    monkeypatch.setenv("KCP_GROUP_COMMIT", "1")
     faults.install(faults.FaultInjector(
         "store.commit_window:drop@tick=2", seed=0))
     store = LogicalStore(wal_path=str(tmp_path / "split.wal"),
@@ -933,12 +936,11 @@ def test_commit_window_forced_split_drill(tmp_path, monkeypatch):
     restored.close()
 
 
-def test_commit_window_abort_drill_wraps_typed(tmp_path, monkeypatch):
+def test_commit_window_abort_drill_wraps_typed(tmp_path):
     """`store.commit_window:raise` (an InjectedFault, not an ApiError)
     aborts the flush: every writer still gets a TYPED 503 — non-API
     sync failures must not escape as bare 500s — and none of the
     window's records commit."""
-    monkeypatch.setenv("KCP_GROUP_COMMIT", "1")
     faults.install(faults.FaultInjector(
         "store.commit_window:raise", seed=0))
     wal = str(tmp_path / "abort.wal")
@@ -965,12 +967,10 @@ def test_commit_window_abort_drill_wraps_typed(tmp_path, monkeypatch):
         assert [ln for ln in f if ln.strip()] == []
 
 
-def test_commit_window_sync_failure_is_typed_5xx_over_http(tmp_path,
-                                                          monkeypatch):
+def test_commit_window_sync_failure_is_typed_5xx_over_http(tmp_path):
     """The HTTP half of the commit-none drill: a write whose window
     sync fails answers a typed 503 Status (the client can retry), the
     WAL carries nothing, and the next write commits normally."""
-    monkeypatch.setenv("KCP_GROUP_COMMIT", "1")
     from kcp_tpu.server.server import Config
     from kcp_tpu.server.threaded import ServerThread
 

@@ -101,6 +101,29 @@ def test_solver_differential_fuzz_device_equals_host(seed):
             assert ((host > 0).sum(axis=-1) <= spread).all()
 
 
+def test_per_workspace_solves_equal_the_batched_assignment():
+    """Rows are independent: the pre-fleet splitter's loop (one host
+    solve per workspace) and ONE device batch give byte-identical
+    assignments at the splitter's shape (8 clusters a workspace,
+    lognormal-skewed capacity), never above demand, never onto a
+    non-candidate."""
+    rng = np.random.default_rng(17)
+    W, P, spread = 300, 8, 2
+    demand = rng.integers(0, 48, W).astype(np.int32)
+    alloc = np.clip(rng.lognormal(3.0, 1.2, P), 1, 30000).astype(np.int32)
+    cand = rng.random((W, P)) < 0.9
+    region = rng.integers(0, 4, P).astype(np.int32)
+    home = rng.integers(-1, 4, W).astype(np.int32)
+    dev = FleetSolver(spread=spread).solve(
+        demand, cand, alloc, region, home).copy()
+    per = np.stack([
+        solve_host(demand[i:i + 1], cand[i:i + 1], alloc, region,
+                   home[i:i + 1], spread)[0] for i in range(W)])
+    assert np.array_equal(dev, per)
+    assert not (dev.sum(axis=1) > demand).any()
+    assert dev[~cand].sum() == 0
+
+
 def test_solver_prefers_home_region_then_capacity():
     # two regions; the home region has less capacity but wins on locality
     cand = np.ones((1, 3), bool)

@@ -1,4 +1,4 @@
-"""Group commit (KCP_GROUP_COMMIT): the write-path commit window.
+"""Group commit: the write-path commit window.
 
 The contract under test: grouping is a LATENCY/THROUGHPUT transform,
 never a semantic one — a seeded concurrent CRUD workload produces a
@@ -90,10 +90,9 @@ def _run_workload(tmp_path, backend: str, grouped: bool, monkeypatch):
     seq = itertools.count()
     monkeypatch.setattr(store_mod.uuid, "uuid4",
                         lambda: _FakeUUID(next(seq)))
-    monkeypatch.setenv("KCP_GROUP_COMMIT", "1" if grouped else "0")
     wal = str(tmp_path / f"{backend}-{'g' if grouped else 's'}.wal")
     store = LogicalStore(wal_path=wal, wal_backend=backend,
-                         clock=lambda: 0.0)
+                         clock=lambda: 0.0, group_commit=grouped)
     watches = {c: store.watch("configmaps", c) for c in ("c0", "c1")}
 
     async def drive():
@@ -167,7 +166,6 @@ def test_backends_replay_to_the_same_store(tmp_path, monkeypatch):
 
 
 def test_window_size_bound_splits(tmp_path, monkeypatch):
-    monkeypatch.setenv("KCP_GROUP_COMMIT", "1")
     monkeypatch.setenv("KCP_COMMIT_WINDOW_MAX", "4")
     store = LogicalStore(wal_path=str(tmp_path / "b.wal"),
                          wal_backend="json")
@@ -190,7 +188,6 @@ def test_window_size_bound_splits(tmp_path, monkeypatch):
 
 
 def test_linger_window_flushes(tmp_path, monkeypatch):
-    monkeypatch.setenv("KCP_GROUP_COMMIT", "1")
     monkeypatch.setenv("KCP_COMMIT_WINDOW_US", "2000")
 
     async def drive(store):
@@ -206,10 +203,9 @@ def test_linger_window_flushes(tmp_path, monkeypatch):
     store.close()
 
 
-def test_sync_context_stays_serial(tmp_path, monkeypatch):
+def test_sync_context_stays_serial(tmp_path):
     """No running loop = nothing to drive a window flush: writes take
     the serial append path and are durable on return."""
-    monkeypatch.setenv("KCP_GROUP_COMMIT", "1")
     wal = str(tmp_path / "s.wal")
     store = LogicalStore(wal_path=wal, wal_backend="json")
     store.create("configmaps", "c0", _cm("c0", "one", 0))
@@ -219,10 +215,9 @@ def test_sync_context_stays_serial(tmp_path, monkeypatch):
     store.close()
 
 
-def test_group_commit_off_is_serial(tmp_path, monkeypatch):
-    monkeypatch.setenv("KCP_GROUP_COMMIT", "0")
+def test_group_commit_off_is_serial(tmp_path):
     store = LogicalStore(wal_path=str(tmp_path / "o.wal"),
-                         wal_backend="json")
+                         wal_backend="json", group_commit=False)
 
     async def drive():
         store.create("configmaps", "c0", _cm("c0", "one", 0))
@@ -272,9 +267,7 @@ def test_wal_sync_rejects_unknown_mode(tmp_path, monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def test_failed_window_fails_every_writer_and_commits_none(
-        tmp_path, monkeypatch):
-    monkeypatch.setenv("KCP_GROUP_COMMIT", "1")
+def test_failed_window_fails_every_writer_and_commits_none(tmp_path):
     wal = str(tmp_path / "fail.wal")
     store = LogicalStore(wal_path=wal, wal_backend="json")
     # probability-1 (not @tick): the split check at every record append
@@ -342,11 +335,10 @@ def _hammer(address: str, n_writers: int, per_writer: int,
     return acked
 
 
-def test_semi_sync_window_acks_batch_over_http(tmp_path, monkeypatch):
+def test_semi_sync_window_acks_batch_over_http(tmp_path):
     """Primary + standby with group commit: concurrent writers all ack,
     the standby converges, and the commit-window + batched-ack counters
     prove the path actually grouped."""
-    monkeypatch.setenv("KCP_GROUP_COMMIT", "1")
     p = ServerThread(Config(durable=True, install_controllers=False,
                             tls=False,
                             root_dir=str(tmp_path / "p"))).start()
@@ -388,7 +380,6 @@ def test_kill_mid_window_loses_no_acked_write(tmp_path, monkeypatch):
     the restarted WAL carries EVERY acked write (an unsynced window was
     never acked — that is the whole point of releasing acks only after
     the window's sync)."""
-    monkeypatch.setenv("KCP_GROUP_COMMIT", "1")
     monkeypatch.setenv("KCP_WAL_SYNC", "fsync")
     root = tmp_path / "kill"
     p = ServerThread(Config(durable=True, install_controllers=False,

@@ -144,7 +144,7 @@ def test_backend_refusal_surfaces_through_frontend_watch():
     """A backend refusal that is NOT a 410 (here: 403 from a missing
     --store-token against an authz'd backend) must reach the watching
     client as a terminal in-stream Status with the mapped code — not a
-    silently dropped connection (ADVICE r5, handler watch relay).
+    silently dropped connection (handler watch relay).
 
     tls=False: this path exercises the relay's error mapping, not
     transport security (and the slim test image has no cryptography)."""

@@ -16,7 +16,7 @@ Covers the tentpole contracts:
   smart-direct clients and through router-only clients produces
   byte-identical final state and per-cluster event streams (the PR 6
   sharded-vs-monolith pattern, reused);
-- the scatter wire path (``KCP_WIRE_SCATTER``) is byte-identical to the
+- the scatter wire path (``httpd.SCATTER``) is byte-identical to the
   join path on list bodies AND watch streams, toggled live.
 """
 
@@ -400,7 +400,7 @@ def _read_watch_lines(address: str, path: str, n: int,
 
 
 def test_wire_scatter_byte_identity(monkeypatch):
-    """The scatter-write path (KCP_WIRE_SCATTER=1, the default) must be
+    """The scatter-write path (``httpd.SCATTER``, what a server runs) must be
     byte-identical to the join path on list bodies and watch streams —
     toggled live against ONE server so even RVs and timestamps match."""
     with ServerThread(Config(durable=False, install_controllers=False,
@@ -417,10 +417,12 @@ def test_wire_scatter_byte_identity(monkeypatch):
         lpath = "/clusters/wire/api/v1/namespaces/default/configmaps"
         wpath = (lpath + f"?watch=true&resourceVersion={rv0}")
 
-        monkeypatch.setenv("KCP_WIRE_SCATTER", "1")
+        from kcp_tpu.server import httpd
+
+        assert httpd.SCATTER
         st1, body_scatter = _http_get_raw(srv.address, lpath)
         lines_scatter = _read_watch_lines(srv.address, wpath, 12)
-        monkeypatch.setenv("KCP_WIRE_SCATTER", "0")
+        monkeypatch.setattr(httpd, "SCATTER", False)
         st2, body_join = _http_get_raw(srv.address, lpath)
         lines_join = _read_watch_lines(srv.address, wpath, 12)
 

@@ -1,6 +1,6 @@
 """Indexed store read path + batched watch fan-out: equivalence vs naive.
 
-The indexed store (KCP_STORE_INDEX=1: secondary buckets, CoW shared
+The indexed store (``indexed=True``: secondary buckets, CoW shared
 references, vectorized micro-batched fan-out) must be observably
 byte-identical to the legacy path (linear scan, per-match/per-event
 deepcopy, per-watch python matching). The fuzz drives both side-by-side

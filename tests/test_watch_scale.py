@@ -221,7 +221,6 @@ def test_slow_socket_evicted_with_terminal_410(monkeypatch):
     from kcp_tpu.server.threaded import ServerThread
 
     monkeypatch.setenv("KCP_WATCH_BUFFER_MAX", "2048")
-    monkeypatch.setenv("KCP_WATCH_FLUSH_MS", "1")
     srv = ServerThread(Config(durable=False, install_controllers=False,
                               tls=False)).start()
     client = RestClient(srv.address, cluster="t0")
@@ -266,36 +265,32 @@ def test_slow_socket_evicted_with_terminal_410(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# flush coalescing: byte-identical to the per-batch wire
+# push (one write per fan-out pass): byte-identical to the per-batch relay
 # ---------------------------------------------------------------------------
 
 
 def test_coalesced_stream_byte_identical_to_per_batch(monkeypatch):
-    """The same seeded mutation run served three ways — the pull relay
-    with KCP_WATCH_COALESCE off, the pull relay with it on, and the push
-    path a local store's watch takes by default — yields the exact same
-    reassembled line stream (chunk framing may differ; the payload and
-    its order may not), while the coalesced relay uses fewer flushes
-    than the per-batch one."""
+    """The same seeded mutation run served both ways — the pull relay
+    (a write and a drain per event batch) and the push path a local
+    store's watch takes (the fan-out pass writes each socket once per
+    commit window) — yields the exact same reassembled line stream
+    (chunk framing may differ; the payload and its order may not)."""
     from kcp_tpu.store import store as store_mod
 
-    async def one_mode(mode: str) -> tuple[list[bytes], float, float]:
+    async def one_mode(mode: str) -> tuple[list[bytes], float]:
         with monkeypatch.context() as mp:
             if mode != "push":
                 # no knob selects the relay: the choice is made on the
                 # watch object, so a watch without the push half (the
                 # REST client's, of a storage frontend) is relayed
                 mp.delattr(store_mod.Watch, "set_sink")
-            mp.setenv("KCP_WATCH_COALESCE",
-                      "1" if mode == "coalesced" else "0")
-            mp.setenv("KCP_WATCH_FLUSH_MS", "5")
             push0 = REGISTRY.counter("watch_push_batches_total").value
-            lines, flushes = await serve()
-            return (lines, flushes,
+            lines = await serve()
+            return (lines,
                     REGISTRY.counter("watch_push_batches_total").value
                     - push0)
 
-    async def serve() -> tuple[list[bytes], float]:
+    async def serve() -> list[bytes]:
         store = LogicalStore(clock=lambda: 0.0)
         for i in range(8):
             store.create("configmaps", "t0", {
@@ -307,7 +302,6 @@ def test_coalesced_stream_byte_identical_to_per_batch(monkeypatch):
         handler.ready = True
         srv = HttpServer(handler)
         await srv.start()
-        flush0 = REGISTRY.counter("watch_flush_total").value
         reader, writer = await asyncio.open_connection(srv.host, srv.port)
         lines: list[bytes] = []
         try:
@@ -346,16 +340,14 @@ def test_coalesced_stream_byte_identical_to_per_batch(monkeypatch):
             await srv.stop()
             handler.close()
             store.close()
-        return lines, REGISTRY.counter("watch_flush_total").value - flush0
+        return lines
 
     async def run() -> None:
-        per_batch, f_pb, p_pb = await one_mode("per-batch")
-        coalesced, f_co, p_co = await one_mode("coalesced")
-        pushed, _f_pu, p_pu = await one_mode("push")
-        assert per_batch == coalesced == pushed
+        per_batch, p_pb = await one_mode("per-batch")
+        pushed, p_pu = await one_mode("push")
+        assert per_batch == pushed
         assert len(per_batch) == 40
-        assert f_co < f_pb
-        assert p_pb == p_co == 0 and p_pu > 0
+        assert p_pb == 0 and p_pu > 0
 
     asyncio.run(run())
 
