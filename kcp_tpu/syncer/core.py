@@ -157,6 +157,27 @@ _PL_RETIRED = REGISTRY.counter(
     "placement rows retired by their owner (free_pl_row found the key): "
     "each is zeroed on the device by the next tick's placement-leaves "
     "swap and comes free when that tick's wire has been dispatched")
+# a row follows the LIVE object: retired when both sides of its key are
+# gone, held back while a wire in flight can still name it, then reused
+_ROWS_RETIRED = REGISTRY.counter(
+    "fused_rows_retired_total",
+    "sync rows retired because both sides of their key were gone (the "
+    "section forgot the key; the row waits for its tick's wire)")
+_ROWS_REUSED = REGISTRY.counter(
+    "fused_rows_reused_total",
+    "rows a new key took from a bucket's free list")
+_ROWS_FRESH = REGISTRY.counter(
+    "fused_rows_fresh_total",
+    "rows a new key took past the bucket's high-water mark (the only "
+    "allocations that can make B grow)")
+_ROWS_HELD = REGISTRY.gauge(
+    "fused_rows_held_back",
+    "retired rows not yet free: waiting for a submit, or riding a wire "
+    "that has not been dispatched")
+_ROW_RETIRE_H = REGISTRY.histogram(
+    "fused_row_retire_seconds",
+    "both sides of a key seen gone -> its row free for another key "
+    "(the wait for the tick's submit and for that wire's dispatch)")
 # who began a collect, and what is left of the wait for a wire: stamped
 # with perf_counter on the loop (submit, collect) and on the waiter
 # thread (ready), observed on the loop
@@ -298,6 +319,10 @@ class SectionOwner(Protocol):
         encoder, re-register in a larger bucket, replay all rows."""
         ...
 
+    # optional: ``fused_retired(keys)`` — both sides of these keys are
+    # gone and the section has forgotten them (Section.retire): whatever
+    # the owner keeps per key goes too
+
 
 class Section:
     """One engine's row allocation inside a bucket."""
@@ -326,7 +351,10 @@ class Section:
             self.rows[key] = row
             self.row_keys[row] = key
             # stamp with the cached mask; refresh_mask restamps everything
-            # if the owner's vocabulary has drifted since
+            # if the owner's vocabulary has drifted since. A row taken
+            # from the free list still holds its last occupant's mask,
+            # which may be another section's
+            self.bucket.status_mask[row] = False
             self.bucket.status_mask[row, : self._mask.shape[0]] = self._mask
             # the DEVICE must see this stamp too: the delta wire carries
             # values only, and without a mask stamp a row allocated after
@@ -353,6 +381,60 @@ class Section:
             self.bucket.status_mask[row] = False
             self.bucket.status_mask[row, : mask.shape[0]] = mask
         self.bucket.mark_stale()
+
+    def retire_gone(self, keys: Sequence) -> None:
+        """Of ``keys`` — the informer caches hold neither of their
+        objects, and this tick's events are staged — retire those whose
+        host mirrors read absent on both sides, as the device's row
+        will once this tick's wire lands. A side not staged in this
+        tick keeps what an earlier tick staged: its own event is still
+        to come, and the key keeps its row until then."""
+        up, down = self.bucket.up_exists, self.bucket.down_exists
+        gone = [k for k in keys
+                if not (up[row := self.rows[k]] or down[row])]
+        if gone:
+            self.retire(gone)
+
+    def retire(self, keys: Sequence) -> None:
+        """Forget keys whose objects are gone on BOTH sides and give
+        their rows back by the ordinary delta wire — no ``mark_stale``,
+        no upload, no wire entry of its own.
+
+        The caller has staged this tick's events: the host mirrors of
+        each row read ``up_exists == down_exists == False`` with zero
+        values, and the tick's wire (or its full upload) makes the
+        device's row read the same. The invariant, as ``free_pl_row``
+        has it for placement rows: a row is in ``_free`` only when the
+        device's row reads absent-and-zero AND no wire still to be
+        dispatched can name it. A wire submitted before this tick's may
+        still hold a patch of the old key (a level-triggered DELETE
+        re-emitted while the downstream delete was in flight), and
+        ``route_patches`` maps row -> key when that wire is dispatched:
+        released now, the patch would be routed to the row's next
+        occupant. So the row waits in the bucket's ``_retiring`` until a
+        submit takes it (``_take_rows_retiring``) and enters ``_free``
+        when THAT wire is dispatched (``FleetBatch.dispatch``); wires
+        are dispatched in submit order, and every later wire was built
+        on a device row that reads absent. Until then a patch for the
+        row is dropped and counted (``route_patches``), and the key,
+        written again, gets another row.
+
+        The next occupant is a key new to its section:
+        ``_encode_section`` stages both sides for it and ``row_for``
+        stamps its own status mask and segment id, whichever section
+        held the row before."""
+        bucket = self.bucket
+        now = time.monotonic()
+        for key in keys:
+            row = self.rows.pop(key)
+            del self.row_keys[row]
+            del bucket.row_owner[row]
+            bucket._retiring.append(row)
+            bucket._held[row] = now
+        _ROWS_RETIRED.inc(len(keys))
+        retired = getattr(self.owner, "fused_retired", None)
+        if retired is not None:
+            retired(keys)
 
     def release(self) -> None:
         self.released = True
@@ -395,8 +477,15 @@ class FusedBucket:
         self.status_mask = np.zeros((0, slots), bool)
         self.sections: list[Section] = []
         self.row_owner: dict[int, Section] = {}
+        # LIFO, so the rows in use stay dense at the low end
         self._free: list[int] = []
         self._next = 0
+        # rows retired since the last submit (Section.retire): the next
+        # wire carries their absent-and-zero mirrors
+        self._retiring: list[int] = []
+        # every retired row that is not free yet (in _retiring, or riding
+        # a wire's FleetMeta to its dispatch) -> when it was retired
+        self._held: dict[int, float] = {}
         # placement lanes (the deployment splitter's serving section):
         # root rows with replicas + per-cluster availability, returned as
         # compacted dirty rows in the wire's placement segment
@@ -453,11 +542,13 @@ class FusedBucket:
     def alloc_row(self, section: Section) -> int:
         if self._free:
             row = self._free.pop()
+            _ROWS_REUSED.inc()
         else:
             if self._next >= self.B:
                 self._grow(self._next + 1)
             row = self._next
             self._next += 1
+            _ROWS_FRESH.inc()
         self.row_owner[row] = section
         return row
 
@@ -550,6 +641,21 @@ class FusedBucket:
         self._pl_retiring.append(row)
         self._pl_staged = True
         _PL_RETIRED.inc()
+
+    def _take_rows_retiring(self) -> list[int]:
+        """The sync rows whose absent-and-zero mirrors the submit just
+        made carried (by its delta wire or its full upload): they ride
+        that wire's FleetMeta to its dispatch (see Section.retire)."""
+        rows, self._retiring = self._retiring, []
+        return rows
+
+    def _release_retired(self, rows: Sequence[int]) -> None:
+        """The wire that carried these rows' last events has been
+        dispatched: no wire still in flight can name them."""
+        now = time.monotonic()
+        for row in rows:
+            _ROW_RETIRE_H.observe(now - self._held.pop(row))
+        self._free.extend(rows)
 
     def _take_retiring(self) -> list[int]:
         """The rows whose zeroed inputs the submit just made carried (by
@@ -719,9 +825,12 @@ class FusedBucket:
             if key is None:
                 # an unowned/unkeyed patch row (released section, freed or
                 # quarantined row, in-flight wire racing a retirement):
-                # benign by design, but it must be COUNTED, not silent
+                # benign by design, but it must be COUNTED, not silent.
+                # A row held back by Section.retire is the expected case
+                # (a wire submitted before its key's last events): it is
+                # counted and not logged
                 dropped += 1
-                if r not in self._dropped_logged:
+                if r not in self._held and r not in self._dropped_logged:
                     self._dropped_logged.add(r)
                     log.warning(
                         "fused-core: dropping patch for row %d (%s); "
@@ -778,6 +887,9 @@ class FleetMeta(NamedTuple):
     # (bucket, rows) retired before this submit: the step zeroed their
     # `current`; they come free when this wire is dispatched
     pl_retired: tuple
+    # (bucket, sync rows) retired before this submit (Section.retire):
+    # this wire carried their last events; free at its dispatch
+    rows_retired: tuple
 
 
 class FleetBatch:
@@ -1181,6 +1293,8 @@ class FleetBatch:
             # submit leaves them for the retry's full upload
             pl_retired=tuple((b, b._take_retiring()) for b in self._members
                              if b._pl_retiring),
+            rows_retired=tuple((b, b._take_rows_retiring())
+                               for b in self._members if b._retiring),
         )
         return wire, meta
 
@@ -1216,6 +1330,12 @@ class FleetBatch:
         # submit found no key above: the rows may be taken again
         for b, retired in meta.pl_retired:
             b._pl_free.extend(retired)
+        # and no wire still in flight can name the sync rows whose last
+        # events this one carried
+        for b, retired in meta.rows_retired:
+            b._release_retired(retired)
+        if meta.rows_retired:
+            _ROWS_HELD.set(sum(len(b._held) for b in meta.members))
         # per-segment live-row counts -> the admission quota ledger
         self.core._publish_fleet_counts(
             unpack_seg_counts(wire, meta.k, meta.r_total, meta.p,
@@ -1748,14 +1868,20 @@ class FusedCore:
         bucket = section.bucket
         keys = list(keymasks)
         # a key new to the bucket must initialize BOTH device mirror
-        # sides; an existing row ships only the side(s) its events touched
+        # sides (whoever held its row before: Section.retire); an
+        # existing row ships only the side(s) its events touched
         masks = np.fromiter(
             (keymasks[k] | (0 if k in section.rows else 3) for k in keys),
             np.uint8, len(keys))
         many = getattr(section.owner, "fused_encode_many", None)
+        # keys whose objects are gone on both sides: the rare case,
+        # found without a pass of its own over the others
+        absent: list = []
         try:
             if many is not None:
                 up_v, up_e, down_v, down_e = many(keys)
+                up_e, down_e = np.asarray(up_e), np.asarray(down_e)
+                absent = [keys[i] for i in np.flatnonzero(~(up_e | down_e))]
             else:
                 ups, upes, downs, downes = [], [], [], []
                 for key in keys:
@@ -1764,6 +1890,8 @@ class FusedCore:
                     upes.append(ue)
                     downs.append(dv)
                     downes.append(de)
+                    if not (ue or de):
+                        absent.append(key)
                 try:
                     up_v, down_v = np.stack(ups), np.stack(downs)
                 except ValueError:
@@ -1775,6 +1903,8 @@ class FusedCore:
                         bucket.stage(row, False, u, ue)
                         bucket.stage(row, True, dv, de)
                     section.refresh_mask()
+                    if absent:
+                        section.retire_gone(absent)
                     return
                 up_e = np.asarray(upes, bool)
                 down_e = np.asarray(downes, bool)
@@ -1783,10 +1913,23 @@ class FusedCore:
             # re-registers in a larger bucket and replays its rows
             section.owner.fused_overflow()
             return
+        up_v, down_v = np.asarray(up_v), np.asarray(down_v)
+        if absent and not all(k in section.rows for k in absent):
+            # a key the section does not know with nothing on either
+            # side (a replayed DELETED, a key re-enqueued after its row
+            # was retired) needs no row
+            ghosts = {k for k in absent if k not in section.rows}
+            keep = np.fromiter((k not in ghosts for k in keys), bool,
+                               len(keys))
+            keys = [k for k in keys if k not in ghosts]
+            if not keys:
+                return
+            absent = [k for k in absent if k not in ghosts]
+            masks = masks[keep]
+            up_v, up_e = up_v[keep], up_e[keep]
+            down_v, down_e = down_v[keep], down_e[keep]
         rows = np.fromiter((section.row_for(k) for k in keys),
                            np.int64, len(keys))
-        up_v, up_e = np.asarray(up_v), np.asarray(up_e)
-        down_v, down_e = np.asarray(down_v), np.asarray(down_e)
         up_sel = (masks & 1) != 0
         if up_sel.all():
             bucket.stage_many(rows, False, up_v, up_e)
@@ -1799,6 +1942,8 @@ class FusedCore:
             bucket.stage_many(rows[down_sel], True, down_v[down_sel],
                               down_e[down_sel])
         section.refresh_mask()
+        if absent:
+            section.retire_gone(absent)
 
     def _collect(self, wire: jax.Array, meta: FleetMeta,
                  tick_start: float | None = None) -> None:

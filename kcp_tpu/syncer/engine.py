@@ -588,6 +588,18 @@ class BatchSyncEngine:
         pending[key] = (code, upsync)
         self._apply_q.put_nowait((key, code, upsync, direct))
 
+    def fused_retired(self, keys) -> None:
+        """Both sides of these keys are gone and their rows given back
+        (``Section.retire``): what is kept per key goes with them, so
+        that a tenant that keeps creating names costs the memory of its
+        live objects. ``_observed_equal(gone=True)`` has closed each
+        key's timeline; an apply still pending owns its own entry of
+        ``_apply_pending`` and removes it when it ends."""
+        for key in keys:
+            self._dirty.pop(key, None)
+            self._reports.pop(key, None)
+            self._apply_failures.pop(key, None)
+
     def fused_overflow(self) -> None:
         """Vocabulary outgrew the bucket: grow the encoder (vocab is a
         prefix, so existing slot assignments stay valid), move to the
