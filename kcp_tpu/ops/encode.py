@@ -117,6 +117,9 @@ class BucketEncoder:
     slot_paths: list[str] = field(default_factory=list)
     _native: Any = field(default=None, repr=False, compare=False)
     _native_tried: bool = field(default=False, repr=False, compare=False)
+    # (len(slot_paths), mask) of the last status_mask(): a vocabulary
+    # only grows, so its length says whether the mask still holds
+    _status_memo: Any = field(default=None, repr=False, compare=False)
 
     def _slot_for(self, path: str) -> int:
         slot = self.slots.get(path)
@@ -205,11 +208,21 @@ class BucketEncoder:
         return EncodedBatch(values, exists, list(keys) if keys is not None else list(range(n)))
 
     def status_mask(self) -> np.ndarray:
-        """bool[capacity]: True where the slot is a ``status.*`` path."""
+        """bool[capacity]: True where the slot is a ``status.*`` path.
+
+        Rebuilt only when the vocabulary has grown since the last call;
+        until then every caller gets the SAME read-only array (the
+        fused core asks once a touched section a tick and tells "no
+        change" by identity): copy it to change it."""
+        memo = self._status_memo
+        if memo is not None and memo[0] == len(self.slot_paths):
+            return memo[1]
         mask = np.zeros(self.capacity, dtype=bool)
         for path, slot in self.slots.items():
             if path == "status" or path.startswith("status."):
                 mask[slot] = True
+        mask.flags.writeable = False
+        self._status_memo = (len(self.slot_paths), mask)
         return mask
 
     def grown(self) -> "BucketEncoder":

@@ -124,6 +124,17 @@ _ENCODED_ROWS = REGISTRY.counter(
     "rows (touched keys, both sides of each) re-encoded by the ticks' "
     "encode phase: fused_encode_seconds' sum over this is the host cost "
     "of one row")
+# how far the encode phase shares its per-call cost: sections a tick
+# gathered over the bucket-wide stagings that carried them
+_ENCODED_SECTIONS = REGISTRY.counter(
+    "fused_encoded_sections_total",
+    "sections whose touched keys an encode phase gathered into a "
+    "bucket's batch")
+_STAGE_BATCHES = REGISTRY.counter(
+    "fused_stage_batches_total",
+    "batches an encode phase staged: one a tick for each bucket with "
+    "touched rows, however many sections brought them (one a section "
+    "where their vectors' widths differ)")
 # growth of the fleet batch: each is a full upload or a new program for
 # the jitted step, on the serving loop
 _ROW_GROWTHS = REGISTRY.counter(
@@ -306,7 +317,11 @@ class SectionOwner(Protocol):
         ...
 
     def fused_status_mask(self) -> np.ndarray:
-        """bool[S] — the engine's current status-slot mask."""
+        """bool[S] — the engine's current status-slot mask. Asked once a
+        touched section a tick: a mask that has not changed is the SAME
+        array as last time (``Section.refresh_mask`` returns on
+        identity), one that has is another array; the section copies
+        what it keeps."""
         ...
 
     def fused_apply(self, patches: list[tuple[object, int, bool]]) -> None:
@@ -334,7 +349,8 @@ class Section:
         self.row_keys: dict[int, object] = {}  # global row -> key
         # seed the mask cache now: row_for stamps every new row with the
         # current mask, so refresh_mask must only fire on real changes
-        self._mask: np.ndarray = owner.fused_status_mask().copy()
+        self._owner_mask: np.ndarray = owner.fused_status_mask()
+        self._mask: np.ndarray = self._owner_mask.copy()
         # fleet segment id (FusedCore.register assigns it): the per-row
         # identity the ragged fleet batch carries on device so the
         # per-segment counters can attribute live rows to this section
@@ -374,6 +390,9 @@ class Section:
         """Restamp this section's rows after the owner's vocabulary grew
         new status slots (rare; triggers a full re-upload)."""
         mask = self.owner.fused_status_mask()
+        if mask is self._owner_mask:
+            return
+        self._owner_mask = mask
         if np.array_equal(self._mask, mask):
             return
         self._mask = mask.copy()
@@ -419,10 +438,11 @@ class Section:
         row is dropped and counted (``route_patches``), and the key,
         written again, gets another row.
 
-        The next occupant is a key new to its section:
-        ``_encode_section`` stages both sides for it and ``row_for``
-        stamps its own status mask and segment id, whichever section
-        held the row before."""
+        The next occupant is a key new to its section: the tick's
+        encode stages both sides for it (``_gather_section``'s side
+        mask, in its bucket's batch) and ``row_for`` stamps its own
+        status mask and segment id, whichever section held the row
+        before."""
         bucket = self.bucket
         now = time.monotonic()
         for key in keys:
@@ -737,8 +757,16 @@ class FusedBucket:
     def stage_many(self, rows: np.ndarray, side: bool, vals: np.ndarray,
                    exists: np.ndarray) -> None:
         """Vectorized :meth:`stage` for one side of a unique row batch
-        (the fused_encode_many path): fancy-indexed mirror writes plus a
-        single slot-map pass, no per-event python loop."""
+        of ONE BUCKET, any number of sections (the tick's encode stages
+        every touched section of a bucket in one call a side; rows of
+        different sections never coincide, ``row_owner``): fancy-indexed
+        mirror writes plus a single slot-map pass, no per-event python
+        loop. Up before down for a row touched on both sides in one
+        tick: the down side's ``ack_ok`` must see the up entry staged.
+        The ORDER of the entries in ``_staged_*`` means nothing: the
+        packed wire's entries are scattered on the device by (row,
+        side), one entry each (last-wins through ``_staged_slot``), and
+        ``FleetBatch._submit`` reads them as a set."""
         n, w = vals.shape
         ack_ok = False
         if side:
@@ -864,6 +892,47 @@ class FusedBucket:
     def note_overflow(self) -> None:
         self.stats["overflows"] += 1
         self.patch_capacity = min(self.patch_capacity * 2, max(self.B, MIN_ROWS))
+
+
+class _Gathered(NamedTuple):
+    """What one tick's encode has gathered for ONE touched section,
+    before anything is staged: the rows and side masks (bit 1 = up, bit
+    2 = down) of its kept keys as plain lists in one order, and for
+    each side (0 = up, 1 = down) the exists flags, a list, and the
+    vectors as its owner brought them (a list of [S] vectors from the
+    per-key loop, or an [n, S] block from ``fused_encode_many``).
+    ``FusedCore._stage_batch`` makes one array of each over all the
+    sections of a bucket and stages the lot."""
+
+    section: Section
+    absent: list            # its keys gone on both sides
+    rows: list
+    masks: list
+    vals: tuple             # (ups, downs)
+    exists: tuple           # (up_e, down_e)
+
+
+def _joined(parts) -> np.ndarray:
+    """One [n, w] array of the sections' vectors, in order. ValueError
+    where their widths differ (``np.stack`` and ``np.concatenate``
+    both refuse)."""
+    blocks: list = []
+    run: list = []
+    for part in parts:
+        if isinstance(part, list):
+            run += part
+        else:
+            if run:
+                blocks.append(np.stack(run))
+                run = []
+            blocks.append(part)
+    if run:
+        blocks.append(np.stack(run))
+    return blocks[0] if len(blocks) == 1 else np.concatenate(blocks)
+
+
+def _taken(seq, keep: list[int]):
+    return seq[keep] if isinstance(seq, np.ndarray) else [seq[i] for i in keep]
 
 
 class FleetMeta(NamedTuple):
@@ -1720,13 +1789,14 @@ class FusedCore:
             return self._tick(items, t_tick)
 
     def _tick(self, items: Sequence, t_tick: float) -> list:
-        # 1. encode touched keys (engines re-read their informer caches);
-        #    section=None items are retick markers — whatever they
-        #    dirtied is submitted by this tick's fleet step. Items
-        #    whose section was released (engine stop or vocabulary
-        #    migration) are stale: touching them would resurrect rows in
-        #    the old bucket — drop them, the replacement section was
-        #    re-enqueued with the same keys.
+        # 1. encode touched keys (engines re-read their informer caches):
+        #    gathered section by section, staged once a BUCKET
+        #    (_encode_sections). section=None items are retick markers —
+        #    whatever they dirtied is submitted by this tick's fleet
+        #    step. Items whose section was released (engine stop or
+        #    vocabulary migration) are stale: touching them would
+        #    resurrect rows in the old bucket — drop them, the
+        #    replacement section was re-enqueued with the same keys.
         ph = _Phases()
         ph.enter("encode")
         # per key, remember WHICH side(s) this batch's events touched —
@@ -1741,8 +1811,7 @@ class FusedCore:
                 km = touched.setdefault(section, {})
                 km[key] = km.get(key, 0) | (2 if side else 1)
         try:
-            for section, keymasks in touched.items():
-                self._encode_section(section, keymasks)
+            self._encode_sections(touched)
         finally:
             if touched:
                 ph.close()
@@ -1863,87 +1932,133 @@ class FusedCore:
         loop.call_later(delay, _requeue)
 
     def _encode_section(self, section: Section, keymasks: dict) -> None:
+        """The one-section form of :meth:`_encode_sections` (as
+        ``stage`` is the one-row form of ``stage_many``)."""
+        self._encode_sections({section: keymasks})
+
+    def _encode_sections(self, touched: dict[Section, dict]) -> None:
+        """The tick's encode: gather per touched section (Python: the
+        owner's vectors, the side masks, the rows — every row allocated
+        before anything is staged, a ``_grow`` reallocates the mirrors),
+        stage once per bucket, then each section's epilogue. The fixed
+        cost of a staging (about twenty small numpy calls a side) is
+        paid once for all the sections of a bucket, not once for each:
+        with a thousand engines that bring a key or two a tick it was
+        most of the phase."""
+        batches: dict[FusedBucket, list[_Gathered]] = {}
+        try:
+            for section, keymasks in touched.items():
+                got = self._gather_section(section, keymasks)
+                if got is not None:
+                    batches.setdefault(section.bucket, []).append(got)
+        finally:
+            # what was gathered is staged even if a later owner raised:
+            # its rows are allocated, and a key that came back with a
+            # row and no mirrors would ship one side only
+            for bucket, batch in batches.items():
+                self._stage_batch(bucket, batch)
+                _ENCODED_SECTIONS.inc(len(batch))
+                # the epilogue reads the mirrors this tick just wrote
+                for got in batch:
+                    got.section.refresh_mask()
+                    if got.absent:
+                        got.section.retire_gone(got.absent)
+
+    def _gather_section(self, section: Section,
+                        keymasks: dict) -> _Gathered | None:
+        """One section's part of the tick's batch (its rows allocated),
+        or None where it brings nothing: its owner overflowed, or every
+        key was a ghost."""
         from ..ops.encode import BucketOverflow
 
-        bucket = section.bucket
         keys = list(keymasks)
-        # a key new to the bucket must initialize BOTH device mirror
-        # sides (whoever held its row before: Section.retire); an
-        # existing row ships only the side(s) its events touched
-        masks = np.fromiter(
-            (keymasks[k] | (0 if k in section.rows else 3) for k in keys),
-            np.uint8, len(keys))
+        known = section.rows
         many = getattr(section.owner, "fused_encode_many", None)
         # keys whose objects are gone on both sides: the rare case,
         # found without a pass of its own over the others
         absent: list = []
         try:
             if many is not None:
-                up_v, up_e, down_v, down_e = many(keys)
-                up_e, down_e = np.asarray(up_e), np.asarray(down_e)
+                ups, up_e, downs, down_e = many(keys)
+                ups, downs = np.asarray(ups), np.asarray(downs)
+                up_e, down_e = np.asarray(up_e, bool), np.asarray(down_e, bool)
                 absent = [keys[i] for i in np.flatnonzero(~(up_e | down_e))]
+                up_e, down_e = up_e.tolist(), down_e.tolist()
             else:
-                ups, upes, downs, downes = [], [], [], []
+                encode = section.owner.fused_encode
+                ups, up_e, downs, down_e = [], [], [], []
                 for key in keys:
-                    u, ue, dv, de = section.owner.fused_encode(key)
+                    u, ue, dv, de = encode(key)
                     ups.append(u)
-                    upes.append(ue)
+                    up_e.append(ue)
                     downs.append(dv)
-                    downes.append(de)
+                    down_e.append(de)
                     if not (ue or de):
                         absent.append(key)
-                try:
-                    up_v, down_v = np.stack(ups), np.stack(downs)
-                except ValueError:
-                    # ragged widths (an engine mid-vocabulary-migration):
-                    # per-key slow path, both sides as before
-                    for key, u, ue, dv, de in zip(keys, ups, upes, downs,
-                                                  downes):
-                        row = section.row_for(key)
-                        bucket.stage(row, False, u, ue)
-                        bucket.stage(row, True, dv, de)
-                    section.refresh_mask()
-                    if absent:
-                        section.retire_gone(absent)
-                    return
-                up_e = np.asarray(upes, bool)
-                down_e = np.asarray(downes, bool)
         except BucketOverflow:
             # engine's vocabulary outgrew this bucket: the engine
-            # re-registers in a larger bucket and replays its rows
+            # re-registers in a larger bucket and replays its rows; the
+            # section stays out of the tick's batch
             section.owner.fused_overflow()
-            return
-        up_v, down_v = np.asarray(up_v), np.asarray(down_v)
-        if absent and not all(k in section.rows for k in absent):
+            return None
+        if absent and not all(k in known for k in absent):
             # a key the section does not know with nothing on either
             # side (a replayed DELETED, a key re-enqueued after its row
             # was retired) needs no row
-            ghosts = {k for k in absent if k not in section.rows}
-            keep = np.fromiter((k not in ghosts for k in keys), bool,
-                               len(keys))
-            keys = [k for k in keys if k not in ghosts]
-            if not keys:
-                return
+            ghosts = {k for k in absent if k not in known}
+            keep = [i for i, k in enumerate(keys) if k not in ghosts]
+            if not keep:
+                return None
+            keys = [keys[i] for i in keep]
             absent = [k for k in absent if k not in ghosts]
-            masks = masks[keep]
-            up_v, up_e = up_v[keep], up_e[keep]
-            down_v, down_e = down_v[keep], down_e[keep]
-        rows = np.fromiter((section.row_for(k) for k in keys),
-                           np.int64, len(keys))
-        up_sel = (masks & 1) != 0
-        if up_sel.all():
-            bucket.stage_many(rows, False, up_v, up_e)
-        elif up_sel.any():
-            bucket.stage_many(rows[up_sel], False, up_v[up_sel], up_e[up_sel])
-        down_sel = (masks & 2) != 0
-        if down_sel.all():
-            bucket.stage_many(rows, True, down_v, down_e)
-        elif down_sel.any():
-            bucket.stage_many(rows[down_sel], True, down_v[down_sel],
-                              down_e[down_sel])
-        section.refresh_mask()
-        if absent:
-            section.retire_gone(absent)
+            ups, downs = _taken(ups, keep), _taken(downs, keep)
+            up_e, down_e = _taken(up_e, keep), _taken(down_e, keep)
+        # a key new to the bucket must initialize BOTH device mirror
+        # sides (whoever held its row before: Section.retire); an
+        # existing row ships only the side(s) its events touched
+        masks = [keymasks[k] | (0 if k in known else 3) for k in keys]
+        rows = [section.row_for(k) for k in keys]
+        return _Gathered(section, absent, rows, masks, (ups, downs),
+                         (up_e, down_e))
+
+    def _stage_batch(self, bucket: FusedBucket,
+                     batch: list[_Gathered]) -> None:
+        """At most one ``stage_many`` a side for everything the tick
+        gathered for this bucket; up before down (``stage_many``)."""
+        masks = np.asarray([m for got in batch for m in got.masks], np.uint8)
+        sels = ((masks & 1) != 0, (masks & 2) != 0)
+        try:
+            # a side no event touched is not stacked at all
+            vals = [_joined(got.vals[side] for got in batch)
+                    if sels[side].any() else None for side in (0, 1)]
+        except ValueError:
+            # vectors of more than one width (an engine in a vocabulary
+            # migration): each section is staged by itself, and one that
+            # is ragged inside key by key, both sides as before
+            if len(batch) > 1:
+                for got in batch:
+                    self._stage_batch(bucket, [got])
+            else:
+                got = batch[0]
+                for row, u, ue, dv, de in zip(got.rows, got.vals[0],
+                                              got.exists[0], got.vals[1],
+                                              got.exists[1]):
+                    bucket.stage(row, False, u, ue)
+                    bucket.stage(row, True, dv, de)
+            return
+        _STAGE_BATCHES.inc()
+        rows = np.asarray([r for got in batch for r in got.rows], np.int64)
+        for side in (0, 1):
+            if vals[side] is None:
+                continue
+            sel = sels[side]
+            exists = np.asarray([e for got in batch for e in got.exists[side]],
+                                bool)
+            if sel.all():
+                bucket.stage_many(rows, bool(side), vals[side], exists)
+            else:
+                bucket.stage_many(rows[sel], bool(side), vals[side][sel],
+                                  exists[sel])
 
     def _collect(self, wire: jax.Array, meta: FleetMeta,
                  tick_start: float | None = None) -> None:

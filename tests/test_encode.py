@@ -59,6 +59,29 @@ def test_status_mask_classifies_lanes():
         assert mask[slot] == (slot in status_slots)
 
 
+@pytest.mark.parametrize("native", [False, True])
+def test_status_mask_is_rebuilt_only_when_the_vocabulary_has_grown(native):
+    """The mask is kept with the vocabulary's length: until a new path is
+    seen every caller gets the same read-only array, and a grown
+    encoder builds its own at its own capacity."""
+    enc = BucketEncoder(capacity=8)
+    if not native:
+        enc._native_tried = True  # the Python flatten+hash path
+    enc.encode(cm({"k": "v"}, status={"phase": "Ready"}))
+    mask = enc.status_mask()
+    assert enc.status_mask() is mask and not mask.flags.writeable
+    enc.encode(cm({"k": "other"}, status={"phase": "Pending"}))
+    assert enc.status_mask() is mask  # new values, no new path
+    enc.encode(cm({"k": "v"}, status={"phase": "Ready", "replicas": 3}))
+    grown = enc.status_mask()
+    assert grown is not mask and grown[enc.slots["status.replicas"]]
+    assert not mask[enc.slots["status.replicas"]]
+    wider = enc.grown()
+    assert wider.status_mask().shape == (16,)
+    np.testing.assert_array_equal(wider.status_mask()[:8], grown)
+    assert enc.status_mask() is grown
+
+
 def test_overflow_and_grow():
     enc = BucketEncoder(capacity=8)
     with pytest.raises(BucketOverflow):
