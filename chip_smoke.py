@@ -848,6 +848,7 @@ def phase_mesh(n_devices: int = 4, b: int = 131072, s: int = 64,
 
     import jax
 
+    from kcp_tpu.models.reconcile_model import ack_lane_rows
     from kcp_tpu.parallel.mesh import SLOTS_AXIS, TENANTS_AXIS, mesh_from_spec
     from kcp_tpu.syncer.core import FusedCore
 
@@ -898,10 +899,10 @@ def phase_mesh(n_devices: int = 4, b: int = 131072, s: int = 64,
             repl = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
             text = fleet._step.lower(
                 jax.tree.map(struct, fleet._state), struct(fleet._seg_ids),
-                jax.ShapeDtypeStruct((1024, s + 2), np.uint32, sharding=repl),
-                jax.ShapeDtypeStruct((fleet.ack_capacity,), np.int32,
-                                     sharding=repl),
-                patch_capacity=k, seg_capacity=fleet._seg_capacity,
+                jax.ShapeDtypeStruct(
+                    (1024 + ack_lane_rows(fleet.ack_capacity, s + 2), s + 2),
+                    np.uint32, sharding=repl),
+                ack_capacity=fleet.ack_capacity, patch_capacity=k, seg_capacity=fleet._seg_capacity,
                 use_pallas=False, mesh=mesh).compile().as_text()
             check("all-reduce" in text,
                   "the sharded step holds no all-reduce for the stats")

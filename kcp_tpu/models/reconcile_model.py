@@ -342,6 +342,13 @@ def reconcile_step_packed(state: ReconcileState, packed: jax.Array,
     exactly that value); the copy runs before the delta scatter, which
     by the eligibility rule cannot touch an acked row's up side.
 
+    Here the lane is an argument of its own (``None``: no lane, the form
+    ``ReconcileModel``-style callers and the tests use). The serving
+    path never ships it as a second array: it rides in the tail rows of
+    the ONE packed array a tick puts on the device, and
+    :func:`reconcile_step_fleet` splits it off at its head
+    (:func:`split_ack_lane`) before calling this function.
+
     Output layout: [0]=patch count, [1]=overflow flag, [2:10]=stats,
     [PACK_HDR:]=packed patch entries (see module comment).
     """
@@ -441,13 +448,38 @@ def apply_seg_stamps(seg_ids: jax.Array, packed: jax.Array) -> jax.Array:
     return seg_ids.at[tgt].set(seg, mode="drop")
 
 
+def ack_lane_rows(ack_capacity: int, width: int) -> int:
+    """Rows of a ``width``-column packed array that hold an ack lane of
+    ``ack_capacity`` entries (the last row padded to the width)."""
+    return -(-ack_capacity // width)
+
+
+def split_ack_lane(packed: jax.Array,
+                   ack_capacity: int) -> tuple[jax.Array, jax.Array]:
+    """Device-side (inside jit): the one array a fleet tick puts on the
+    device -> (``uint32 [d, S+2]`` event entries, ``int32
+    [ack_capacity]`` ack lane). The lane lies row-major in the array's
+    last :func:`ack_lane_rows` rows, its int32 entries bit for bit as
+    uint32 (-1 padding is 0xFFFFFFFF there and -1 again here)."""
+    d = packed.shape[0] - ack_lane_rows(ack_capacity, packed.shape[1])
+    acks = jax.lax.bitcast_convert_type(
+        packed[d:].reshape(-1)[:ack_capacity], jnp.int32)
+    return packed[:d], acks
+
+
 def reconcile_step_fleet(state: ReconcileState, seg_ids: jax.Array,
-                         packed: jax.Array, acks: jax.Array | None = None,
+                         packed: jax.Array, ack_capacity: int,
                          patch_capacity: int = 8192, seg_capacity: int = 8,
                          use_pallas: bool = False, mesh=None,
                          ) -> tuple[ReconcileState, jax.Array, jax.Array]:
     """The fleet-batch step: :func:`reconcile_step_packed` plus the
     resident segment lane and per-segment live-row counters.
+
+    ``packed`` is the ONE array a tick sends: ``uint32 [d + ack_rows,
+    S+2]``, the event entries in its first ``d`` rows and the ack lane
+    of the static ``ack_capacity`` in its tail (:func:`split_ack_lane`;
+    :class:`WireBuffers` lays it out on the host). Everything below the
+    split sees the two arrays it would see had they crossed apart.
 
     ``seg_ids`` (int32 [B], device-resident like the state) maps each
     fleet row to its owning section's segment id (SEG_NONE = unowned).
@@ -457,6 +489,8 @@ def reconcile_step_fleet(state: ReconcileState, seg_ids: jax.Array,
     ids (padding, unowned rows) drop out of the scatter-add.
     """
     with jax.named_scope("reconcile_step_fleet"):
+        with jax.named_scope("split_ack_lane"):
+            packed, acks = split_ack_lane(packed, ack_capacity)
         with jax.named_scope("apply_seg_stamps"):
             seg_ids = apply_seg_stamps(seg_ids, packed)
         new_state, wire = reconcile_step_packed(
@@ -480,6 +514,11 @@ def unpack_seg_counts(wire: np.ndarray, patch_capacity: int, r: int, p: int,
 class WireBuffers:
     """Rotating host staging for the packed-delta wire.
 
+    One buffer a slot: ``uint32 [d + ack_rows, width]``, the tick's event
+    entries in the first ``d`` rows and the ack lane in the tail rows
+    (:func:`split_ack_lane` is its device-side reader), so a tick hands
+    the runtime ONE array and makes one transfer a device.
+
     The staging/donation contract of :func:`reconcile_step_packed`: the
     resident state is donated every tick, but the packed array is NOT,
     and the host buffer behind it stays in use after ``jax.device_put``
@@ -490,30 +529,30 @@ class WireBuffers:
     buffer lost a whole tick's events with only the put gating it).
     Fresh ``np.zeros`` per tick is safe but pays an allocation +
     page-fault cost on every tick of the hot loop. Rotating buffers make
-    reuse safe: ``acquire`` hands out the least-recently-used (packed,
-    acks) pair, first blocking until the arrays committed for it are
-    ready — the puts AND the output of the step that consumed them, the
-    one signal every backend gives that the buffer has been read. With
-    one slot more than the pipeline's in-flight window that step has
-    always been collected by then, so the gate does not wait.
+    reuse safe: ``acquire`` hands out the least-recently-used buffer,
+    first blocking until the arrays committed for it are ready — the put
+    AND the output of the step that consumed it, the one signal every
+    backend gives that the buffer has been read. With one slot more than
+    the pipeline's in-flight window that step has always been collected
+    by then, so the gate does not wait.
     """
 
     def __init__(self, depth: int = 2):
         self.depth = depth
         self._packed: list[np.ndarray | None] = [None] * depth
-        self._acks: list[np.ndarray | None] = [None] * depth
-        # the puts that last read each slot's host buffers, and the output
-        # of the step that consumed them
+        # the put that last read each slot's host buffer, and the output
+        # of the step that consumed it
         self._pending: list[tuple | None] = [None] * depth
         self._i = 0
         self.reuse_waits = 0  # acquires that had to block on a transfer
 
     def acquire(self, d: int, width: int,
                 ack_capacity: int) -> tuple[int, np.ndarray, np.ndarray]:
-        """A zeroed ``uint32 [d, width]`` packed buffer plus a -1-filled
-        ``int32 [ack_capacity]`` acks buffer, safe to fill immediately.
-        Returns ``(slot, packed, acks)``; pass ``slot`` to :meth:`commit`
-        with the device arrays produced from these buffers."""
+        """A ``uint32 [d + ack_rows, width]`` buffer, its first ``d`` rows
+        zeroed, plus the -1-filled ``int32 [ack_capacity]`` VIEW of its
+        tail rows (the ack lane), both safe to fill immediately. Returns
+        ``(slot, packed, acks)``; pass ``slot`` to :meth:`commit` with
+        the device arrays produced from the buffer."""
         i = self._i
         self._i = (i + 1) % self.depth
         pending = self._pending[i]
@@ -523,21 +562,19 @@ class WireBuffers:
                 if not arr.is_ready():
                     self.reuse_waits += 1
                     arr.block_until_ready()
+        shape = (d + ack_lane_rows(ack_capacity, width), width)
         packed = self._packed[i]
-        if packed is None or packed.shape != (d, width):
-            packed = self._packed[i] = np.zeros((d, width), np.uint32)
-        else:
-            packed.fill(0)
-        acks = self._acks[i]
-        if acks is None or acks.shape != (ack_capacity,):
-            acks = self._acks[i] = np.full(ack_capacity, -1, np.int32)
-        else:
-            acks.fill(-1)
+        if packed is None or packed.shape != shape:
+            packed = self._packed[i] = np.empty(shape, np.uint32)
+        packed[:d].fill(0)
+        # all-ones is -1, padding, in every int32 entry of the lane
+        packed[d:].fill(0xFFFFFFFF)
+        acks = packed[d:].reshape(-1)[:ack_capacity].view(np.int32)
         return i, packed, acks
 
     def commit(self, slot: int, *device_arrays) -> None:
         """Record the device arrays whose readiness means the slot's
-        buffers have been read — the puts and the consuming step's output;
+        buffer has been read — the put and the consuming step's output;
         the next acquire of this slot gates on them."""
         self._pending[slot] = device_arrays
 

@@ -31,8 +31,11 @@ stages back-to-back as the A/B reference):
                 encode touched keys, buckets stage rows, the fleet packs
                 them into one of its rotating pre-allocated wire buffers
                 (WireBuffers — tick N's device_put never races tick
-                N+1's packing)
-  dispatch    — device_put + fused step (donated resident state) +
+                N+1's packing); the ack lane is laid into that buffer's
+                tail rows, so the tick's input is ONE array
+  dispatch    — ONE device_put (the packed array, ack lane and all; to
+                every device of a serving mesh) + fused step (donated
+                resident state; it splits the lane off at its head) +
                 wire.copy_to_host_async(); the host never blocks here
   fetch/apply — a wire is fetched when the device has finished it:
                 on an asynchronous backend (the chip) a waiter thread
@@ -152,8 +155,8 @@ _PATCH_GROWTHS = REGISTRY.counter(
 _UPLOAD_BYTES = REGISTRY.counter(
     "fused_fleet_state_upload_bytes_total",
     "bytes of resident fleet state put on the device by full uploads")
-# what a serving mesh costs the tick: the wire and the ack lane go to
-# every device of the mesh, replicated
+# what a serving mesh costs the tick: the packed wire (the ack lane in its
+# tail rows) goes to every device of the mesh, replicated
 _MESH_SHARDS = REGISTRY.gauge(
     "fused_fleet_mesh_shards",
     "devices the fleet state's rows are sharded over (the serving "
@@ -161,8 +164,15 @@ _MESH_SHARDS = REGISTRY.gauge(
 _PUT_BYTES = REGISTRY.counter(
     "fused_fleet_put_bytes_total",
     "bytes the ticks' put phase handed to the devices: the packed event "
-    "wire and the ack lane, times the devices each is written to (every "
-    "device of a serving mesh; 1 with no mesh)")
+    "wire with the ack lane in its tail rows, times the devices it is "
+    "written to (every device of a serving mesh; 1 with no mesh)")
+_PUTS = REGISTRY.counter(
+    "fused_fleet_puts_total",
+    "host->device transfers the ticks' submits made: every "
+    "jax.device_put call — the packed wire, and the placement-leaves "
+    "swap's two on a tick that has one — times the devices each is "
+    "written to (a full upload's state leaves are not counted here: "
+    "fused_fleet_state_upload_bytes_total)")
 _PL_RETIRED = REGISTRY.counter(
     "fused_placement_rows_retired_total",
     "placement rows retired by their owner (free_pl_row found the key): "
@@ -1012,22 +1022,31 @@ class FleetBatch:
         self._dispatched: set[tuple] = set()
         self._stale = True
         # acks-lane wire capacity: sticky high-water doubling, so the
-        # (packed, acks) shape pair stays stable after warmup — per-tick
-        # pow2 padding here would multiply compiled-shape variants
+        # (event rows, lane capacity) pair stays stable after warmup —
+        # per-tick pow2 padding here would multiply compiled-shape variants
         self.ack_capacity = 1024
         # rotating packed-wire staging (models/reconcile_model.py): tick
         # N+1 packs into another buffer while tick N's device_put may
         # still be reading this one — the allocation-free hot path that
         # makes the 2-deep pipeline window safe
         self._wire_bufs = WireBuffers(PIPELINE_DEPTH + 1)
+        # where the packed wire is put: every device of a serving mesh
+        # (replicated: it is O(events), not O(fleet)), else the default
+        self._wire_sharding = None
+        self._wire_devices = 1
+        if self.mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            self._wire_sharding = NamedSharding(self.mesh, PartitionSpec())
+            self._wire_devices = self.mesh.size
         # the resident state is donated on every backend: steady state
         # lives in device memory and only deltas cross the link, and the
         # tests' CPU backend runs the same donated program as the chip
         self._step = jax.jit(
             reconcile_step_fleet,
             donate_argnums=(0, 1),
-            static_argnames=("patch_capacity", "seg_capacity",
-                             "use_pallas", "mesh"),
+            static_argnames=("ack_capacity", "patch_capacity",
+                             "seg_capacity", "use_pallas", "mesh"),
         )
         # degraded-mode bookkeeping (poison-row quarantine): the rows the
         # last submission covered (the bisection's suspect set), the
@@ -1185,6 +1204,7 @@ class FleetBatch:
         # is not the steady-state pack — the histograms stay separable
         ph.enter("full_upload" if was_stale else "pack")
         local_rows: list[int] = []  # bucket-local ids for KCP_FAULTS
+        puts = 1  # jax.device_put calls of this submit: the packed wire
         if was_stale:
             self._state, self._seg_ids = self._device_state()
             self._stale = False
@@ -1202,8 +1222,8 @@ class FleetBatch:
             self.stats["full_uploads"] += 1
             # full upload replaces the mirrors wholesale; still run the
             # step so decisions for the new state come back
-            buf_slot, packed, acks = self._wire_bufs.acquire(
-                MIN_EVENTS, s + 2, self.ack_capacity)
+            d, cap = MIN_EVENTS, self.ack_capacity
+            buf_slot, packed, acks = self._wire_bufs.acquire(d, s + 2, cap)
         else:
             if any(b._pl_staged for b in self._members):
                 # placement inputs changed (roots staged/retired): swap
@@ -1221,11 +1241,13 @@ class FleetBatch:
                 else:
                     reps = jax.device_put(replicas)
                     av = jax.device_put(avail)
+                puts += 2
                 self._state = self._state._replace(replicas=reps, avail=av)
             # gather the members' staged arrays (already the packed-wire
             # layout) into one fleet wire: row indices shift by the
-            # member's base, ack-eligible slots pool on one acks lane,
-            # mask stamps gain the owning section's segment id
+            # member's base, ack-eligible slots pool on the one ack lane
+            # (the wire's tail rows), mask stamps gain the owning
+            # section's segment id
             per: list[tuple] = []
             nf_total = na_total = nm_total = 0
             for b, base in zip(self._members, self._bases):
@@ -1238,10 +1260,10 @@ class FleetBatch:
                 na_total += na
                 nm_total += nm
             d = pad_pow2(nf_total + nm_total, floor=MIN_EVENTS)
-            # always ship the acks array, even all-padding: an acks=None
-            # fast path would be a SECOND jit trace variant, and the
-            # first ack-bearing tick would then compile it mid-serving —
-            # a seconds-long loop stall (measured) vs the ~nothing an
+            # always ship the ack lane, even all-padding: a wire without
+            # it would be a SECOND jit trace variant, and the first
+            # ack-bearing tick would then compile it mid-serving — a
+            # seconds-long loop stall (measured) vs the ~nothing an
             # all-dropped scatter pass costs per tick. The capacity
             # honors each member's floor (bench pre-warms
             # bucket.ack_capacity to dodge mid-serving recompiles)
@@ -1297,25 +1319,17 @@ class FleetBatch:
                 self._last_rows.extend(base + r for r in sorted(touched))
                 b._clear_staged()
         ph.enter("put")
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            repl = NamedSharding(self.mesh, PartitionSpec())
-            packed_d = jax.device_put(packed, repl)
-            acks_d = jax.device_put(acks, repl)
-            _PUT_BYTES.inc((packed.nbytes + acks.nbytes) * self.mesh.size)
-        else:
-            packed_d = jax.device_put(packed)
-            acks_d = jax.device_put(acks)
-            _PUT_BYTES.inc(packed.nbytes + acks.nbytes)
+        # ONE array crosses: the event rows and, in its tail, the ack lane
+        packed_d = jax.device_put(packed, self._wire_sharding)
+        _PUT_BYTES.inc(packed.nbytes * self._wire_devices)
+        _PUTS.inc(puts * self._wire_devices)
         k = self._patch_capacity()
         if k != self._patch_k:
             if self._patch_k is not None:
                 _PATCH_GROWTHS.inc()
             self._patch_k = k
         shapes = (self.B, s, self._state.replicas.shape[0],
-                  self._state.avail.shape[1], packed.shape[0], acks.shape[0],
-                  k, self._seg_capacity)
+                  self._state.avail.shape[1], d, cap, k, self._seg_capacity)
         ph.enter("step_dispatch" if shapes in self._dispatched else "compile")
         # KCP_FAULTS `device.step` injection point (raise@tick / error /
         # poison_row): fires HERE, where a real XLA dispatch failure
@@ -1324,13 +1338,13 @@ class FleetBatch:
         # poison_row spec names an owner's row whatever the layout
         faults.maybe_fail("device.step", rows=local_rows)
         self._state, self._seg_ids, wire = self._step(
-            self._state, self._seg_ids, packed_d, acks_d,
+            self._state, self._seg_ids, packed_d, ack_capacity=cap,
             patch_capacity=k, seg_capacity=self._seg_capacity,
             use_pallas=self.use_pallas, mesh=self.mesh,
         )
         # the staging buffers may be re-acquired only once this step has
         # read them (see WireBuffers)
-        self._wire_bufs.commit(buf_slot, packed_d, acks_d, wire)
+        self._wire_bufs.commit(buf_slot, packed_d, wire)
         self._dispatched.add(shapes)
         self._step_failures = 0
         wire.copy_to_host_async()
@@ -1442,14 +1456,16 @@ class FleetBatch:
             if self._probe_step is None:
                 self._probe_step = jax.jit(
                     reconcile_step_fleet,
-                    static_argnames=("patch_capacity", "seg_capacity",
-                                     "use_pallas", "mesh"))
+                    static_argnames=("ack_capacity", "patch_capacity",
+                                     "seg_capacity", "use_pallas", "mesh"))
             if self._state is None:
                 self._state, self._seg_ids = self._device_state()
                 self._stale = False
             s = self.S
             d = pad_pow2(max(2 * len(rows), 1), floor=MIN_EVENTS)
-            packed = np.zeros((d, s + 2), np.uint32)
+            # a wire of its own, laid out like a tick's: an empty ack lane
+            cap = self.ack_capacity
+            _slot, packed, _acks = WireBuffers(1).acquire(d, s + 2, cap)
             for i, ((b, lr), fr) in enumerate(zip(locs, rows)):
                 packed[2 * i, :b.S] = b.up_vals[lr]
                 packed[2 * i, s] = fr
@@ -1458,16 +1474,10 @@ class FleetBatch:
                 packed[2 * i + 1, s] = fr
                 packed[2 * i + 1, s + 1] = (
                     (1 if b.down_exists[lr] else 0) | 2 | 4)
-            acks = np.full(self.ack_capacity, -1, np.int32)
-            if self.mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec
-
-                repl = NamedSharding(self.mesh, PartitionSpec())
-                packed = jax.device_put(packed, repl)
-                acks = jax.device_put(acks, repl)
             _state, _seg, wire = self._probe_step(
-                self._state, self._seg_ids, packed, acks,
-                patch_capacity=self._patch_capacity(),
+                self._state, self._seg_ids,
+                jax.device_put(packed, self._wire_sharding),
+                ack_capacity=cap, patch_capacity=self._patch_capacity(),
                 seg_capacity=self._seg_capacity,
                 use_pallas=self.use_pallas, mesh=self.mesh)
             np.asarray(wire)  # force execution; async backends defer errors

@@ -550,6 +550,32 @@ def test_a_root_created_in_the_retirements_tick_window_takes_another_row(
     assert b.stats["full_uploads"] == uploads
 
 
+@MESHES
+def test_puts_total_counts_the_wire_and_a_placement_swaps_two(mesh_devices):
+    """`fused_fleet_puts_total`: every `jax.device_put` a submit makes,
+    times the devices it writes — ONE a tick for the packed wire (the ack
+    lane rides in it), two more on a tick with a placement-leaves swap;
+    a full upload's state leaves are not the put phase's."""
+    core, b, _owner = _placement_core(mesh_devices)
+    devices = mesh_devices or 1
+    puts = REGISTRY.counter("fused_fleet_puts_total")
+    ticks = REGISTRY.counter("fused_fleet_ticks_total")
+    b.stage_placement("a", 7, 2)
+    p0, t0 = puts.value, ticks.value
+    _one_tick(core)  # the first tick uploads the whole state
+    assert b.stats["full_uploads"] == 1
+    assert puts.value - p0 == devices
+    b.stage_placement("b", 11, 3)
+    p1 = puts.value
+    _one_tick(core)  # replicas + avail + the wire
+    assert puts.value - p1 == 3 * devices
+    core._fleet.mark_stale()
+    p2 = puts.value
+    _one_tick(core)  # stale again: the wire alone
+    assert puts.value - p2 == devices
+    assert ticks.value - t0 == 3
+
+
 def test_wires_in_flight_across_a_retirement_name_no_live_root():
     """Two wires cross a retirement: one submitted BEFORE it with the
     root's real split, one after it with the row's zero emission. Both

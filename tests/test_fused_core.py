@@ -395,3 +395,128 @@ def test_mask_stamp_wire_entry_updates_device_mask():
     assert idx.tolist() == [3] and code.tolist() == [0] and bool(upsync[0])
     # the stamp did not corrupt the mirrors (it is not a delta)
     np.testing.assert_array_equal(np.asarray(state2.down_vals), down)
+
+
+# ---------------------------------------------------------------------------
+# the ack lane rides in the packed wire: ONE array a tick crosses to the
+# device (models/reconcile_model.py WireBuffers / split_ack_lane)
+# ---------------------------------------------------------------------------
+
+def _two_array_fleet_step(state, seg_ids, packed, acks, patch_capacity,
+                          seg_capacity):
+    """The fleet step as it was while the ack lane crossed as a second
+    array — this test's reference: the event wire and the lane apart."""
+    import jax.numpy as jnp
+
+    from kcp_tpu.models.reconcile_model import (
+        apply_seg_stamps,
+        reconcile_step_packed,
+    )
+
+    seg_ids = apply_seg_stamps(seg_ids, packed)
+    new_state, wire = reconcile_step_packed(state, packed, acks,
+                                            patch_capacity)
+    counts = jnp.zeros(seg_capacity, jnp.int32).at[seg_ids].add(
+        new_state.up_exists.astype(jnp.int32), mode="drop")
+    return new_state, seg_ids, jnp.concatenate([wire, counts])
+
+
+# a tick: (event rows d, ack capacity, full entries, acks, mask stamps)
+LANE_CASES = {
+    # (a) nothing acked: the lane is padding from end to end
+    "padding-only-lane": (14, [(8, 32, 5, 0, 0), (8, 32, 8, 0, 0)]),
+    # (b) everything a tick can carry, in one tick
+    "acks-rows-and-stamps": (14, [(16, 32, 6, 9, 4), (16, 32, 3, 30, 2)]),
+    # (c) the sticky capacity doubles between two ticks of one run
+    "capacity-doubles": (14, [(8, 16, 2, 16, 1), (8, 32, 2, 17, 0),
+                              (8, 64, 1, 40, 1)]),
+    # (d) the tick of a full upload: MIN_EVENTS empty rows, an empty lane
+    "full-upload-tick": (14, [(64, 32, 0, 0, 0), (8, 32, 3, 4, 1)]),
+    # (e) S + 2 = 18 divides neither 32 nor 100: the lane's last row is
+    # padded, and a lane shorter than one row is too
+    "width-divides-nothing": (16, [(8, 8, 2, 7, 1), (8, 32, 4, 20, 2),
+                                   (16, 100, 5, 61, 3)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LANE_CASES))
+def test_one_array_step_equals_the_two_array_step(case):
+    """The fleet step fed ONE array (the ack lane in the tail rows of the
+    packed wire, laid out by WireBuffers) leaves the bit-identical state,
+    segment lane and wire that the two-array step leaves, tick after
+    tick, each run carrying its own resident state forward."""
+    import jax
+    import numpy as np
+
+    from kcp_tpu.models.reconcile_model import (
+        MASK_STAMP_BIT,
+        SEG_NONE,
+        SEG_SHIFT,
+        WireBuffers,
+        ack_lane_rows,
+        example_state,
+        reconcile_step_fleet,
+    )
+
+    s, ticks = LANE_CASES[case]
+    b, k, segs = 128, 64, 8
+    rng = np.random.default_rng(sum(case.encode()))
+    base = example_state(b=b, s=s, r=8, p=8, l=4, c=8, seed=3,
+                         dirty_frac=0.2)
+    base = base._replace(status_mask=np.zeros((b, s), bool))
+    one = jax.jit(reconcile_step_fleet,
+                  static_argnames=("ack_capacity", "patch_capacity",
+                                   "seg_capacity"))
+    two = jax.jit(_two_array_fleet_step,
+                  static_argnames=("patch_capacity", "seg_capacity"))
+    st1 = st2 = jax.tree.map(jax.device_put, base)
+    seg1 = seg2 = jax.device_put(np.full(b, SEG_NONE, np.int32))
+    bufs = WireBuffers(depth=2)
+    for d, cap, n_full, n_acks, n_stamps in ticks:
+        slot, packed, acks = bufs.acquire(d, s + 2, cap)
+        assert packed.shape == (d + ack_lane_rows(cap, s + 2), s + 2)
+        assert acks.shape == (cap,) and acks.base is not None
+        assert not packed[:d].any() and (acks == -1).all()
+        rows = rng.permutation(b)[:n_full + n_acks + n_stamps]
+        full, acked, stamped = np.split(rows, [n_full, n_full + n_acks])
+        packed[:n_full, :s] = rng.integers(1, 2**32, (n_full, s),
+                                           dtype=np.uint32)
+        packed[:n_full, s] = full
+        packed[:n_full, s + 1] = 4 | rng.integers(0, 4, n_full)  # exists, side
+        m = slice(n_full, n_full + n_stamps)
+        packed[m, :s] = rng.random((n_stamps, s)) < 0.3
+        packed[m, s] = stamped
+        packed[m, s + 1] = (4 | MASK_STAMP_BIT
+                            | (rng.integers(0, segs, n_stamps) << SEG_SHIFT))
+        acks[:n_acks] = acked
+        # what the two arrays of the parent's tick would have held
+        events, lane = packed[:d].copy(), acks.copy()
+        st1, seg1, wire1 = one(st1, seg1, jax.device_put(packed),
+                               ack_capacity=cap, patch_capacity=k,
+                               seg_capacity=segs)
+        st2, seg2, wire2 = two(st2, seg2, jax.device_put(events),
+                               jax.device_put(lane), patch_capacity=k,
+                               seg_capacity=segs)
+        bufs.commit(slot, wire1)
+        np.testing.assert_array_equal(np.asarray(wire1), np.asarray(wire2))
+        np.testing.assert_array_equal(np.asarray(seg1), np.asarray(seg2))
+        for name, x, y in zip(st1._fields, st1, st2):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                          err_msg=name)
+        if n_acks:  # the lane did its work: an acked row's down IS its up
+            r = int(acked[0])
+            np.testing.assert_array_equal(np.asarray(st1.down_vals)[r],
+                                          np.asarray(st1.up_vals)[r])
+    assert bufs.reuse_waits == 0
+
+
+def test_the_lane_in_the_wire_converges_like_the_host_engine():
+    """The differential run: the served engine (acks riding the packed
+    wire) and the host-backend engine converge one seeded random op
+    sequence to the same state."""
+    from test_differential_fuzz import _run_backend
+
+    async def main():
+        assert await _run_backend("tpu", 53) == await _run_backend("host", 53)
+
+    asyncio.run(main())

@@ -12,8 +12,9 @@ locations and the benchmark's ``StatusEcho`` controllers: the deployment
     single-device server gives under the same seed;
 (b) the resident state lies on four devices in shards of ``B / 4`` rows;
     ``fused_fleet_mesh_shards`` reads 4 (1 without a mesh) and
-    ``fused_fleet_put_bytes_total`` rises by a tick's packed and ack
-    bytes times 4 (times 1 without a mesh) every tick;
+    ``fused_fleet_put_bytes_total`` rises by the bytes of a tick's ONE
+    packed array (the ack lane in its tail rows) times 4 (times 1
+    without a mesh) every tick, and ``fused_fleet_puts_total`` by 4 (1);
 (c) the fleet grows past a power of two WHILE serving on the mesh
     (row-factor padding, a sharded full upload) and loses no staged row,
     mask stamp or patch;
@@ -35,6 +36,7 @@ if ROOT not in sys.path:
 from benchmarks import mesh_deploy  # noqa: E402
 from benchmarks.agents import StatusEcho  # noqa: E402
 from kcp_tpu.apis import cluster as capi  # noqa: E402
+from kcp_tpu.models.reconcile_model import ack_lane_rows  # noqa: E402
 from kcp_tpu.physical import PhysicalRegistry  # noqa: E402
 from kcp_tpu.server import Config, RestClient  # noqa: E402
 from kcp_tpu.server.threaded import ServerThread  # noqa: E402
@@ -44,7 +46,8 @@ from kcp_tpu.utils.trace import REGISTRY  # noqa: E402
 
 LOC = "loc0"
 LABEL = "kcp.dev/cluster"
-COUNTERS = ("fused_fleet_put_bytes_total", "fused_fleet_ticks_total",
+COUNTERS = ("fused_fleet_put_bytes_total", "fused_fleet_puts_total",
+            "fused_fleet_ticks_total",
             "fused_fleet_row_growths_total",
             "fused_fleet_state_upload_bytes_total",
             "fused_step_failures_total", "quarantined_rows")
@@ -196,7 +199,9 @@ def served(mesh: str, seed: int, n_tenants: int = 6, residents: int = 5,
                        if c._loop is srv._loop]
             fleet = core._fleet
             shards = fleet._state.up_vals.addressable_shards
-            per_tick = (MIN_EVENTS * (fleet.S + 2) + fleet.ack_capacity) * 4
+            width = fleet.S + 2
+            per_tick = (MIN_EVENTS
+                        + ack_lane_rows(fleet.ack_capacity, width)) * width * 4
             return {"B": fleet.B, "S": fleet.S, "per_tick_bytes": per_tick,
                     "devices": sorted({sh.device.id for sh in shards}),
                     "shard_rows": sorted({sh.data.shape[0] for sh in shards}),
@@ -257,10 +262,13 @@ def test_gauge_and_put_bytes_count_the_replication(pair):
     for got, devices in ((mesh, 4), (single, 1)):
         rise, lay = got["rise"], got["layout"]
         # no tick of this size carries more than MIN_EVENTS events, so
-        # every tick puts one packed wire and one ack lane of the floor's
-        # size, to every device of the mesh
+        # every tick puts ONE array — the packed wire with the floor's ack
+        # lane in its tail rows — to every device of the mesh
         assert rise["fused_fleet_put_bytes_total"] == (
             rise["fused_fleet_ticks_total"] * lay["per_tick_bytes"] * devices)
+        # and nothing else: the plain syncer stages no placement lane
+        assert rise["fused_fleet_puts_total"] == (
+            rise["fused_fleet_ticks_total"] * devices)
     assert mesh["layout"]["per_tick_bytes"] == single["layout"]["per_tick_bytes"]
 
 

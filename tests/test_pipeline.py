@@ -16,7 +16,8 @@ import asyncio
 import numpy as np
 import pytest
 
-from kcp_tpu.syncer.core import PIPELINE_DEPTH, FusedCore
+from kcp_tpu.models.reconcile_model import WireBuffers, ack_lane_rows
+from kcp_tpu.syncer.core import MIN_EVENTS, PIPELINE_DEPTH, FusedCore
 
 from helpers import wait_until
 
@@ -67,10 +68,13 @@ def _stream_bytes(stream) -> bytes:
 
 
 async def _run_schedule(pipeline: str, seed: int, rows: int = 512,
-                        steps: int = 30) -> tuple[bytes, int]:
+                        steps: int = 30, bufs=None) -> tuple[bytes, int]:
     """Drive one deterministic churn schedule in lockstep (one enqueued
-    batch per tick) and return the fully-drained patch stream."""
+    batch per tick) and return the fully-drained patch stream. ``bufs``
+    replaces the fleet's wire staging (the reuse gate's test)."""
     core = FusedCore(batch_window=0.0005, pipeline=pipeline)
+    if bufs is not None:
+        core._fleet._wire_bufs = bufs
     owner = RecordingOwner(core, rows)
     await core.start()
     bucket = owner.section.bucket
@@ -194,15 +198,18 @@ def test_pipeline_modes_validated_and_metered():
 
 
 class _Lagging:
-    """A device array stand-in that is not ready until waited on."""
+    """A device array stand-in that is not ready until waited on; around
+    a real array (``arr``) the wait is the real one."""
 
-    def __init__(self):
-        self.waited = False
+    def __init__(self, arr=None):
+        self.arr, self.waited = arr, False
 
     def is_ready(self) -> bool:
         return self.waited
 
     def block_until_ready(self) -> None:
+        if self.arr is not None:
+            self.arr.block_until_ready()
         self.waited = True
 
 
@@ -215,23 +222,28 @@ def test_staging_reuse_waits_for_the_step_that_read_the_buffer():
     from kcp_tpu.models.reconcile_model import WireBuffers
 
     bufs = WireBuffers(depth=2)
-    slot, packed, _acks = bufs.acquire(64, S + 2, 8)
+    slot, packed, acks = bufs.acquire(64, S + 2, 8)
+    # one buffer a slot: the ack lane is a view of the array's tail row
+    assert packed.shape == (64 + 1, S + 2) and acks.base is not None
+    assert np.shares_memory(acks, packed[64:])
     put, step_out = _Lagging(), _Lagging()
     put.waited = True  # the transfer is long done; the step is not
     bufs.commit(slot, put, step_out)
     packed[:] = 7
     bufs.acquire(64, S + 2, 8)  # the other slot: no wait
     assert not step_out.waited and bufs.reuse_waits == 0
-    slot2, packed2, _acks = bufs.acquire(64, S + 2, 8)
+    slot2, packed2, acks2 = bufs.acquire(64, S + 2, 8)
     assert slot2 == slot and packed2 is packed
     assert step_out.waited and bufs.reuse_waits == 1
-    assert not packed.any()  # zeroed only after the wait
+    # reset only after the wait: event rows zero, the lane all padding
+    assert not packed[:64].any() and (acks2 == -1).all()
 
 
 def test_serving_core_commits_the_step_output_with_the_puts():
-    """The submit path gates staging reuse on (packed, acks, wire), over
-    one slot more than the in-flight window — so on a backend that keeps
-    pace the gate never waits."""
+    """The submit path gates staging reuse on (packed, wire) — the ONE
+    array it put, the ack lane in its tail rows, and the output of the
+    step that read it — over one slot more than the in-flight window, so
+    on a backend that keeps pace the gate never waits."""
     async def main():
         core = FusedCore(batch_window=0.0005)
         owner = RecordingOwner(core, 64)
@@ -243,10 +255,59 @@ def test_serving_core_commits_the_step_output_with_the_puts():
         bufs = core._fleet._wire_bufs
         assert bufs.depth == PIPELINE_DEPTH + 1
         committed = [p for p in bufs._pending if p is not None]
-        assert committed and all(len(p) == 3 for p in committed)
-        packed_d, _acks_d, wire = committed[0]
-        assert wire.ndim == 1 and packed_d.ndim == 2
+        assert committed and all(len(p) == 2 for p in committed)
+        packed_d, wire = committed[0]
+        fleet = core._fleet
+        assert wire.ndim == 1 and packed_d.shape == (
+            MIN_EVENTS + ack_lane_rows(fleet.ack_capacity, S + 2), S + 2)
+        assert np.asarray(packed_d).dtype == np.uint32
+        # the lane crossed inside it, padding where nothing was acked
+        lane = np.asarray(packed_d)[MIN_EVENTS:].reshape(-1)
+        assert (lane[:fleet.ack_capacity].view(np.int32) == -1).all()
         await core.stop()
         assert bufs.reuse_waits == 0
+
+    asyncio.run(main())
+
+
+class _OneSlot(WireBuffers):
+    """A single staging slot: every tick is handed the buffer of the tick
+    before it, whose step's output is held not-ready."""
+
+    def __init__(self):
+        super().__init__(depth=1)
+        self.handed: list[int] = []
+
+    def acquire(self, *a):
+        slot, packed, acks = super().acquire(*a)
+        self.handed.append(id(packed))
+        return slot, packed, acks
+
+    def commit(self, slot, *arrays):
+        # read not-ready until the gate has really waited for them (the
+        # CPU backend would mostly have finished)
+        super().commit(slot, *(_Lagging(a) for a in arrays))
+
+
+def test_a_slot_handed_out_before_its_step_is_ready_blocks_and_loses_nothing():
+    """One buffer a slot, the ack lane inside it: a slot that comes round
+    again while the step that read it is still running BLOCKS on that
+    step's output (`reuse_waits` rises) before the buffer is reset, and
+    the run's patch stream is the serial reference's byte for byte — no
+    tick's events lost to a buffer refilled under the step (the loss the
+    WireBuffers docstring describes, found with only the put gating)."""
+
+    async def main():
+        bufs = _OneSlot()
+        held, ticks = await _run_schedule("double", 27, bufs=bufs)
+        serial, serial_ticks = await _run_schedule("serial", 27)
+        assert ticks == serial_ticks and held == serial and len(held) > 0
+        # ONE buffer a slot (no second per-slot list), handed out again
+        # wherever the tick's shape is the one before's ...
+        assert len(bufs._packed) == 1 and not hasattr(bufs, "_acks")
+        assert len(set(bufs.handed)) < len(bufs.handed) == ticks
+        # ... and every hand-out after the first waited for the put and
+        # for the consuming step's output
+        assert bufs.reuse_waits == 2 * (ticks - 1)
 
     asyncio.run(main())

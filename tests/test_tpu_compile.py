@@ -24,6 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from kcp_tpu.models.reconcile_model import (
     ReconcileState,
+    ack_lane_rows,
     reconcile_step_fleet,
     reconcile_step_packed,
 )
@@ -126,16 +127,22 @@ def test_donated_packed_step_fits_one_v5e(chip_state, chip_wire):
 def _fleet_step():
     """The serving default, jitted as syncer/core.py FleetBatch jits it."""
     return jax.jit(reconcile_step_fleet, donate_argnums=(0, 1),
-                   static_argnames=("patch_capacity", "seg_capacity",
-                                    "use_pallas", "mesh"))
+                   static_argnames=("ack_capacity", "patch_capacity",
+                                    "seg_capacity", "use_pallas", "mesh"))
 
 
-def test_fleet_step_compiles_for_one_chip(chip_state, chip_wire, one_chip):
-    packed, acks = chip_wire
+def _fleet_wire(sharding):
+    """The one array a fleet tick puts: D event rows, the ack lane in its
+    tail rows (models/reconcile_model.py WireBuffers)."""
+    return jax.ShapeDtypeStruct((D + ack_lane_rows(ACKS, S + 2), S + 2),
+                                jnp.uint32, sharding=sharding)
+
+
+def test_fleet_step_compiles_for_one_chip(chip_state, one_chip):
     seg = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
     compiled = _fleet_step().lower(
-        chip_state, seg, packed, acks, patch_capacity=K,
-        seg_capacity=SEGS).compile()
+        chip_state, seg, _fleet_wire(one_chip), ack_capacity=ACKS,
+        patch_capacity=K, seg_capacity=SEGS).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
     assert "all-reduce" not in compiled.as_text()
@@ -149,11 +156,9 @@ def test_fleet_step_shards_over_four_chips(mesh4):
                 sh["placement_rows"], sh["labels"], sh["selectors"])
     repl = NamedSharding(mesh4, P())
     seg = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=sh["flags"])
-    packed = jax.ShapeDtypeStruct((D, S + 2), jnp.uint32, sharding=repl)
-    acks = jax.ShapeDtypeStruct((ACKS,), jnp.int32, sharding=repl)
     compiled = _fleet_step().lower(
-        st, seg, packed, acks, patch_capacity=K, seg_capacity=SEGS,
-        mesh=mesh4).compile()
+        st, seg, _fleet_wire(repl), ack_capacity=ACKS, patch_capacity=K,
+        seg_capacity=SEGS, mesh=mesh4).compile()
     # the stats and per-segment counters reduce across the row shards
     assert "all-reduce" in compiled.as_text()
     mem = compiled.memory_analysis()
