@@ -1,6 +1,7 @@
 """kcp_tpu.obs — fleet-wide distributed tracing (see obs/trace.py)."""
 
 from .trace import (
+    NOOP,
     PHASES,
     TRACEPARENT,
     TRACER,
@@ -21,7 +22,7 @@ from .trace import (
 )
 
 __all__ = [
-    "PHASES", "TRACEPARENT", "TRACER", "TraceContext", "annotate",
+    "NOOP", "PHASES", "TRACEPARENT", "TRACER", "TraceContext", "annotate",
     "conv_begin",
     "ctx_from_wal", "current", "link_obj", "obj_link", "phase",
     "record_span", "reset_current", "set_current", "span", "use",

@@ -146,6 +146,9 @@ class _Noop:
 
 
 _NOOP = _Noop()
+#: the section of a site that must not be named (a handler whose store
+#: verbs leave the loop's thread): same ``with`` / ``begin`` / ``end``
+NOOP = _NOOP
 
 
 class Tracer:

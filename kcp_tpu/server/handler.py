@@ -36,6 +36,10 @@ from .httpd import Request, Response, StreamResponse
 DEFAULT_CLUSTER = "admin"
 CLUSTER_HEADER = "x-kubernetes-cluster"
 
+# the read verbs: one counter, one histogram and one host section
+# (`kcp.read.<verb>`) each
+_READ_VERBS = ("get", "list", "page", "table")
+
 
 _QUEUE_EVICTED = ("watch queue overflowed (KCP_WATCH_QUEUE): slow watcher "
                   "evicted; re-list and resume")
@@ -173,11 +177,43 @@ class RestHandler:
             "write_request_body_bytes_total",
             "request-body bytes of the write requests handled (a delete "
             "carries none)")
+        # the read verbs, one family a verb (`get` one object, `list` a
+        # whole scope plain or selected, `page` one limit/continue
+        # chunk, `table` a kubectl-get rendering): requests, the
+        # request's entry (`req.t0`) -> its response ready for the
+        # transport, and over all four the body bytes answered (the
+        # items a list returned are the store's `store_list_returned_
+        # total`). Their loop time is the `kcp.read.<verb>` sections.
+        self._read_requests = {
+            v: REGISTRY.counter(
+                f"read_requests_total_{v}",
+                f"read requests served by the `{v}` path (errors included)")
+            for v in _READ_VERBS}
+        self._read_seconds = {
+            v: REGISTRY.histogram(
+                f"read_request_seconds_{v}",
+                f"one `{v}` read from the handler's entry to its response "
+                f"ready for the transport")
+            for v in _READ_VERBS}
+        self._read_bytes = REGISTRY.counter(
+            "read_response_bytes_total",
+            "body bytes of the read responses (get, list, page, table)")
+        self._list_cache_lookups = REGISTRY.counter(
+            "list_cache_lookups_total",
+            "whole-list reads that asked the RV-keyed list-body cache")
+        self._list_cache_hits = REGISTRY.counter(
+            "list_cache_hits_total",
+            "whole-list reads the RV-keyed list-body cache answered")
         # RV-keyed list-body cache: the store RV increments on every
         # mutation, so (query shape, rv) fully determines a list
         # response's bytes — informer relists and polling dashboards
         # repeat identical list queries against an unchanged store, and
-        # those hits skip even the byte-splice. Small FIFO (bodies can
+        # those hits skip even the byte-splice. The key's RV is the
+        # STORE's, not the scope's: any write anywhere (a tenant's spec,
+        # a syncer's status upsync) ages every entry, so under steady
+        # writes it answers only reads that fall between two commits
+        # (`list_cache_hits_total` over `list_cache_lookups_total`).
+        # Small FIFO (bodies can
         # be tens of MB at 100k objects); bypassed while a KCP_FAULTS
         # schedule is active so encode.cache drops always reach the
         # per-record cache underneath.
@@ -705,58 +741,25 @@ class RestHandler:
                 "the status subresource supports get and update only")
 
         if req.method == "GET":
-            from ..apis.printers import render_table, wants_table
+            from ..apis.printers import wants_table
 
             self._check_replica_lag()
-            await self._consistent_read_gate(
-                req, watch=(name is None
-                            and req.param("watch") in ("true", "1")))
+            is_watch = name is None and req.param("watch") in ("true", "1")
+            await self._consistent_read_gate(req, watch=is_watch)
+            if is_watch:
+                return self._watch(req, cluster, res, namespace or None)
             as_table = wants_table(req.headers.get("accept", ""))
-            if name is None:
-                if req.param("watch") in ("true", "1"):
-                    return self._watch(req, cluster, res, namespace or None)
-                selector = parse_selector(req.param("labelSelector"))
-                limit_s = req.param("limit")
-                cont = req.param("continue")
-                if ((limit_s or cont) and not as_table
-                        and hasattr(self.store, "list_page")):
-                    try:
-                        limit = int(limit_s) if limit_s else 0
-                    except ValueError:
-                        raise errors.BadRequestError(
-                            f"malformed limit {limit_s!r}") from None
-                    if limit < 0:
-                        raise errors.BadRequestError("limit must be >= 0")
-                    return await self._list_page(
-                        req, cluster, res, namespace, selector, info, gv,
-                        limit, cont or None)
-                if self._encode and not as_table:
-                    return await self._list_encoded(
-                        req, cluster, res, namespace, selector, info, gv)
-                items, rv = await self._st(
-                    self.store.list, res, cluster, namespace or None, selector)
-                if as_table:  # kubectl get: server-side printer columns
-                    return Response.of_json(render_table(res, items, rv))
-                t0 = time.perf_counter()
-                resp = Response.of_json({
-                    "kind": info.list_kind, "apiVersion": gv,
-                    "metadata": {"resourceVersion": str(rv)},
-                    "items": items,
-                })
-                self._enc_seconds.observe(time.perf_counter() - t0)
-                return resp
-            target = await self._read_cluster(cluster, res, name, namespace)
-            if self._encode and not as_table:
-                raw = self._get_encoded(res, target, name, namespace)
-                if raw is not None:
-                    return Response(body=raw)
-            obj = await self._st(self.store.get, res, target, name, namespace)
-            # no table transform for the status subresource (matches the
-            # real apiserver: table rendering applies to objects, not
-            # subresources)
-            if as_table and subresource is None:
-                return Response.of_json(render_table(res, [obj]))
-            return Response.of_json(self._stamp(obj, info, gv))
+            if as_table and (name is None or subresource is None):
+                verb = "table"
+            elif name is not None:
+                verb = "get"
+            elif ((req.param("limit") or req.param("continue"))
+                    and hasattr(self.store, "list_page")):
+                verb = "page"
+            else:
+                verb = "list"
+            return await self._read(verb, req, cluster, info, gv, namespace,
+                                    name, subresource)
 
         if req.method == "POST" and name is None:
             obj = self._body_object(req)
@@ -800,6 +803,91 @@ class RestHandler:
                     200, "Deleted", f"{res} {name} deleted")), rv))
 
         raise errors.BadRequestError(f"unsupported method {req.method} for {req.path}")
+
+    async def _read(self, verb: str, req: Request, cluster: str,
+                    info: ResourceInfo, gv: str, namespace: str,
+                    name: str | None, subresource: str | None) -> Response:
+        """The shared frame of the read verbs: the request counted, its
+        handling under the verb's host section, then the answer's bytes
+        counted and ``read_request_seconds_<verb>`` closed
+        from the request's entry stamp (one clock read). An in-process
+        store's verbs run inline, so the section closes in the pass that
+        opened it; a remote store's hop to the I/O pool, and a section
+        may not stay open across an ``await`` that yields: a storage
+        frontend's reads are counted and timed, not named."""
+        self._read_requests[verb].inc()
+        sec = (obs.annotate(f"kcp.read.{verb}")
+               if self._store_pool is None else obs.NOOP)
+        with sec:
+            if verb == "get":
+                resp = await self._get(cluster, info, gv, namespace, name)
+            elif verb == "table":
+                resp = await self._table(
+                    req, cluster, info.gvr.storage_name, namespace, name)
+            else:
+                resp = await self._list(verb, req, cluster, info, gv,
+                                        namespace)
+        self._read_bytes.inc(resp.body_len())
+        self._read_seconds[verb].observe(time.monotonic() - req.t0)
+        return resp
+
+    async def _get(self, cluster: str, info: ResourceInfo, gv: str,
+                   namespace: str, name: str) -> Response:
+        res = info.gvr.storage_name
+        target = await self._read_cluster(cluster, res, name, namespace)
+        if self._encode:
+            raw = self._get_encoded(res, target, name, namespace)
+            if raw is not None:
+                return Response(body=raw)
+        obj = await self._st(self.store.get, res, target, name, namespace)
+        return Response.of_json(self._stamp(obj, info, gv))
+
+    async def _table(self, req: Request, cluster: str, res: str,
+                     namespace: str, name: str | None) -> Response:
+        """``kubectl get``: server-side printer columns over the dict
+        path (one object, or a whole scope; a Table is never chunked).
+        The status subresource has no table transform (as the real
+        apiserver: rendering applies to objects), so it never gets here."""
+        from ..apis.printers import render_table
+
+        if name is not None:
+            target = await self._read_cluster(cluster, res, name, namespace)
+            obj = await self._st(self.store.get, res, target, name, namespace)
+            return Response.of_json(render_table(res, [obj]))
+        items, rv = await self._st(
+            self.store.list, res, cluster, namespace or None,
+            parse_selector(req.param("labelSelector")))
+        return Response.of_json(render_table(res, items, rv))
+
+    async def _list(self, verb: str, req: Request, cluster: str,
+                    info: ResourceInfo, gv: str, namespace: str) -> Response:
+        res = info.gvr.storage_name
+        selector = parse_selector(req.param("labelSelector"))
+        if verb == "page":
+            limit_s = req.param("limit")
+            try:
+                limit = int(limit_s) if limit_s else 0
+            except ValueError:
+                raise errors.BadRequestError(
+                    f"malformed limit {limit_s!r}") from None
+            if limit < 0:
+                raise errors.BadRequestError("limit must be >= 0")
+            return await self._list_page(
+                req, cluster, res, namespace, selector, info, gv,
+                limit, req.param("continue") or None)
+        if self._encode:
+            return await self._list_encoded(
+                req, cluster, res, namespace, selector, info, gv)
+        items, rv = await self._st(
+            self.store.list, res, cluster, namespace or None, selector)
+        t0 = time.perf_counter()
+        resp = Response.of_json({
+            "kind": info.list_kind, "apiVersion": gv,
+            "metadata": {"resourceVersion": str(rv)},
+            "items": items,
+        })
+        self._enc_seconds.observe(time.perf_counter() - t0)
+        return resp
 
     async def _write(self, req: Request, verb: str, res: str, target: str,
                      namespace: str, obj: dict | None, fn, *args):
@@ -886,8 +974,10 @@ class RestHandler:
                      and not _san.enabled())
         ck = (res, cluster, namespace, req.param("labelSelector") or "", gv)
         if cacheable:
+            self._list_cache_lookups.inc()
             ent = self._list_cache.get(ck)
             if ent is not None and ent[0] == self.store.resource_version:
+                self._list_cache_hits.inc()
                 REGISTRY.counter("encode_cache_hits_total").inc()
                 REGISTRY.counter(
                     "encode_cache_bytes_shared_total").inc(ent[2])
@@ -1437,6 +1527,15 @@ class RestHandler:
         # (apiserver uses ~1/min; our watch windows are smaller)
         bookmark_every = self._bookmark_every
 
+        # an in-process store's watch opens and closes inline on the loop
+        # (registration, a since_rv replay; the unsubscribe), so each is a
+        # host section; a remote store's hop to the I/O pool and are not
+        inline = self._store_pool is None
+
+        def close_watch(watch) -> None:
+            with obs.annotate("kcp.watch.close") if inline else obs.NOOP:
+                watch.close()
+
         async def produce(stream: StreamResponse) -> None:
             init_items = init_rv = None
             try:
@@ -1454,6 +1553,10 @@ class RestHandler:
                         return w, items, rv
                     watch, init_items, init_rv = await self._st(
                         _open_watch_list)
+                elif inline:
+                    with obs.annotate("kcp.watch.open"):
+                        watch = self.store.watch(
+                            res, cluster, namespace, selector, since_rv)
                 else:
                     watch = await self._st(
                         self.store.watch, res, cluster, namespace,
@@ -1650,7 +1753,8 @@ class RestHandler:
                     # stream is out: what the watch buffered meanwhile
                     # (a since_rv replay, writes racing the snapshot) is
                     # handed over first, in order, by the attach itself
-                    watch.set_sink(push, wake.set)
+                    with obs.annotate("kcp.watch.open"):
+                        watch.set_sink(push, wake.set)
                     while True:
                         if self.draining.is_set() or self.watch_fence.is_set():
                             write_rest()
@@ -1699,7 +1803,7 @@ class RestHandler:
                 try:
                     await serve_pushed()
                 finally:
-                    watch.close()
+                    close_watch(watch)
                 return
             drain_task: asyncio.Task | None = None
             fence_task: asyncio.Task | None = None
@@ -1815,7 +1919,7 @@ class RestHandler:
                     if t is not None:
                         t.add_done_callback(
                             lambda t: t.cancelled() or t.exception())
-                watch.close()
+                close_watch(watch)
 
         return StreamResponse(produce)
 

@@ -755,6 +755,13 @@ class LogicalStore:
         # concatenates the rest — no global sort, no per-item probe.
         self._span_cache: dict[tuple[str, str, str], tuple[int, bytes]] = {}
         self._bucket_ver: dict[tuple[str, str, str], int] = {}
+        self._plan_rebuilds = REGISTRY.counter(
+            "store_fanout_plan_rebuilds_total",
+            "fan-out plans rebuilt: a flush met a resource whose set of "
+            "watches had changed since its plan was made")
+        self._plan_watches = REGISTRY.counter(
+            "store_fanout_plan_watches_total",
+            "watches walked by fan-out plan rebuilds")
         self._enc_hits = REGISTRY.counter(
             "encode_cache_hits_total",
             "serializations served from the encode-once byte cache")
@@ -2111,7 +2118,12 @@ class LogicalStore:
         if plan is not None and plan.ver == ver:
             return plan
         plan = _FanoutPlan(ver)
-        for w in self._watches_by_res.get(res, ()):
+        ws = self._watches_by_res.get(res, ())
+        # every open and every close of a watch of the resource ages the
+        # plan: the next flush that carries the resource walks them all
+        self._plan_rebuilds.inc()
+        self._plan_watches.inc(len(ws))
+        for w in ws:
             if w._closed:
                 continue
             if w.cluster != WILDCARD:
