@@ -1,6 +1,7 @@
 """The serving process's own runtime, measured from inside: what the
 event loop's one thread did with its time, how late it runs its
-callbacks, and how long the cyclic collector stops every thread.
+callbacks, how long the cyclic collector stops every thread, and when
+that collector runs at all.
 
 One interpreter runs httpd, store, watch fan-out, informers, appliers
 and the tick loop, so "queueing on the one interpreter" is a first-order
@@ -39,7 +40,18 @@ it); nothing here runs at import.
 - ``py_gc_pause_seconds``: a ``gc.callbacks`` hook, start to stop of
   every collection; each one is also a ``kcp.gc`` section (generation
   as a stat), so an idle gap of the device, or a long pass, under a
-  full collection reads as that;
+  full collection reads as that. The same hook counts, one add a
+  collection: ``py_gc_collections_total_gen0`` / ``_gen1`` / ``_gen2``
+  (by the oldest generation walked; ``_gen2`` is a full collection),
+  ``py_gc_collected_objects_total`` (the cyclic garbage found) and
+  ``py_gc_uncollectable_total``;
+- the collector's policy (:data:`GC_THRESHOLDS`): while a server of
+  the process lives the young generation is larger than the largest
+  burst one request allocates, so transient trees die by reference
+  count before a young collection can promote them towards a full
+  one. Process-wide — set by the first :meth:`RuntimeProbes.start`,
+  the thresholds found there restored by the last ``stop`` — with no
+  flag, and nothing is disabled;
 - ``jax_backend_compile_seconds``: a ``jax.monitoring`` listener for
   the backend's compile events, with their seconds: every program this
   process asked the backend for, whoever asked — compiled by XLA, or
@@ -69,6 +81,37 @@ LONG_PASS_S = 0.05
 # the LAST so many long passes, not the longest since start: set-up's
 # compiles would fill the latter before anyone looks
 RING = 64
+# the collector's policy while a server of the process lives (set by the
+# first RuntimeProbes.start, the thresholds found there restored by the
+# last stop). CPython starts a young collection every 700 NET container
+# allocations, and a full one when the objects promoted out of
+# generation 1 since the last exceed a quarter of those that survived
+# it; a promotion is never taken back when the object dies by reference
+# count a millisecond later. One page of a paged walk, one LIST, one
+# request body, one tick's rows each allocate thousands of containers
+# in one stretch, so at 700 the collector ran INSIDE them, found all of
+# it alive and promoted it, and garbage that was never long-lived
+# bought three to twelve walks of the whole heap (0.2-0.7 s each,
+# every thread waiting) per 51 s. A young generation larger than the
+# largest burst of one request is freed by reference count before it
+# is ever looked at. Generations 1 and 2 keep CPython's 10 and 10.
+# The sweep on the chip that chose the value (PERF.md 6, PR 55; share
+# of a 51 s window inside the collector in the flood | the read cell |
+# steady, and its pauses of 50 ms or more):
+#    20,000: 1.38 | 1.51 | 0.56 %; every generation-1 pass 50-67 ms
+#            (four, two, two a window), and the read cell keeps a full
+#            collection of 0.40 s (a page of the `*` walk still outgrows
+#            the young generation)
+#   100,000: 1.13 | 0.34 | 0.30 %; none in the read cell and steady,
+#            one generation-1 pass of 0.25 s a window in the flood
+#   500,000: 2.06 | 0.30 | 0.82 %; a generation-1 pass walks up to
+#            five million objects: 0.39-0.42 s, and one young pass 0.15 s
+# (at CPython's 700 the same cells read 7.5 | 11.1 | 2.1-2.5 % with
+# six | ten | three full collections of 0.3-0.6 s). A generation-1
+# pass is ten young generations wide at any value, so none of the
+# three has every pause under 50 ms in the flood; 100,000 has the
+# lowest share there.
+GC_THRESHOLDS = (100_000, 10, 10)
 
 _TOTALS = {
     "busy_seconds": REGISTRY.counter(
@@ -275,6 +318,7 @@ class RuntimeProbes:
     # the collector is the process's, not a server's: ServerThread tests
     # run several servers in one process, and each pause is observed once
     _gc_users = 0
+    _gc_found: tuple[int, int, int] | None = None
     _gc_t0 = 0.0
     _gc_ann = None
     # jax.monitoring keeps a listener for the life of the process: one
@@ -296,6 +340,8 @@ class RuntimeProbes:
         cls = RuntimeProbes
         if cls._gc_users == 0:
             gc.callbacks.append(_on_gc)
+            cls._gc_found = gc.get_threshold()
+            gc.set_threshold(*GC_THRESHOLDS)
         cls._gc_users += 1
         cls._hear_compiles()
         return self
@@ -318,8 +364,12 @@ class RuntimeProbes:
                 self.ledger = None
             cls = RuntimeProbes
             cls._gc_users -= 1
-            if cls._gc_users == 0 and _on_gc in gc.callbacks:
-                gc.callbacks.remove(_on_gc)
+            if cls._gc_users == 0:
+                if _on_gc in gc.callbacks:
+                    gc.callbacks.remove(_on_gc)
+                if cls._gc_found is not None:
+                    gc.set_threshold(*cls._gc_found)
+                    cls._gc_found = None
 
     def _arm(self) -> None:
         self._due = time.monotonic() + LAG_INTERVAL_S
@@ -338,6 +388,24 @@ _GC_PAUSE = REGISTRY.histogram(
     "py_gc_pause_seconds",
     "one run of the interpreter's cyclic collector, start to stop "
     "(every thread of the process waits for it)")
+_GC_RUNS = [
+    REGISTRY.counter(
+        f"py_gc_collections_total_gen{g}",
+        f"runs of the cyclic collector whose oldest generation was {g} "
+        f"({what})")
+    for g, what in enumerate((
+        "the young generation: every GC_THRESHOLDS[0] net container "
+        "allocations",
+        "the young generation and the one it promotes into",
+        "a full collection: the whole heap is walked"))]
+_GC_COLLECTED = REGISTRY.counter(
+    "py_gc_collected_objects_total",
+    "objects the cyclic collector found unreachable and freed: the "
+    "garbage reference counting could not free, which is how much the "
+    "program needs a collector at all")
+_GC_UNCOLLECTABLE = REGISTRY.counter(
+    "py_gc_uncollectable_total",
+    "objects the cyclic collector found unreachable and could not free")
 
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -363,3 +431,6 @@ def _on_gc(phase: str, info: dict) -> None:
         _GC_PAUSE.observe(time.monotonic() - cls._gc_t0)
         cls._gc_ann.__exit__(None, None, None)
         cls._gc_ann = None
+        _GC_RUNS[info["generation"]].inc()
+        _GC_COLLECTED.inc(info.get("collected", 0))
+        _GC_UNCOLLECTABLE.inc(info.get("uncollectable", 0))

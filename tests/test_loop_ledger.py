@@ -461,6 +461,66 @@ def test_the_beat_publishes_and_a_stopped_probe_publishes_its_last_rise(
         led.cpu_seconds)
 
 
+def test_collector_policy_lives_with_the_first_start_and_the_last_stop(
+        monkeypatch):
+    import gc
+
+    # as in a process no server lives in, whatever ran before this file
+    monkeypatch.setattr(RuntimeProbes, "_gc_users", 0)
+    monkeypatch.setattr(RuntimeProbes, "_gc_found", None)
+    policy, found = runtime.GC_THRESHOLDS, gc.get_threshold()
+    odd = (found[0] + 1, found[1] + 1, found[2] + 1)
+    gc.set_threshold(*odd)  # what the process had, not CPython's own
+
+    async def main(loop):
+        a, b = RuntimeProbes(loop).start(), RuntimeProbes(loop).start()
+        assert gc.get_threshold() == policy
+        a.stop()
+        assert gc.get_threshold() == policy  # a server still lives
+        a.stop()  # idempotent: no second count down
+        assert gc.get_threshold() == policy
+        b.stop()
+        assert gc.get_threshold() == odd
+        b.stop()
+        # a later server restores what it finds THEN
+        gc.set_threshold(*found)
+        RuntimeProbes(loop).start().stop()
+        return gc.get_threshold()
+
+    try:
+        assert drive(main) == found
+    finally:
+        gc.set_threshold(*found)
+    assert policy[0] > 3000 and policy[1:] == (10, 10)
+
+
+def test_a_burst_is_freed_before_the_collector_looks_and_cycles_still_go():
+    import gc
+
+    class Node:
+        pass
+
+    async def main(loop):
+        probes = RuntimeProbes(loop).start()
+        try:
+            gc.collect()  # the young count starts from nothing
+            runs = [g["collections"] for g in gc.get_stats()]
+            # one page of a paged walk: 3,000 fresh tuples, then dropped
+            page = [(i, str(i)) for i in range(3000)]
+            assert len(page) == 3000
+            del page
+            assert [g["collections"] for g in gc.get_stats()] == runs
+            # nothing was disabled: a cycle made under the policy goes
+            a, b = Node(), Node()
+            a.other, b.other = b, a
+            del a, b
+            assert gc.isenabled() and gc.collect() >= 2
+        finally:
+            probes.stop()
+
+    drive(main)
+
+
 def test_a_gc_callback_under_a_half_imported_jax_does_not_raise(monkeypatch):
     """``sys.modules`` has ``jax`` before ``jax.profiler`` exists; a
     collection can fire there (PERF.md §7's hazard)."""

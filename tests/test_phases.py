@@ -587,6 +587,63 @@ def test_gc_hook_is_installed_once_and_removed():
     asyncio.run(main())
 
 
+@pytest.mark.parametrize("generation", [0, 1, 2])
+def test_gc_hook_counts_collections_by_generation(generation):
+    from kcp_tpu.obs.runtime import _on_gc
+
+    names = [f"py_gc_collections_total_gen{g}" for g in range(3)] + [
+        "py_gc_collected_objects_total", "py_gc_uncollectable_total"]
+    pauses = REGISTRY.histogram("py_gc_pause_seconds")
+    before, n0 = snap(), pauses.n
+    # a stop with no start behind it (the hook came in mid-collection)
+    _on_gc("stop", {"generation": generation, "collected": 5})
+    assert [snap()[n] for n in names] == [before[n] for n in names]
+    _on_gc("start", {"generation": generation})
+    _on_gc("stop", {"generation": generation, "collected": 17,
+                    "uncollectable": 2})
+    after = snap()
+    rose = [after[n] - before[n] for n in names]
+    assert rose == [float(g == generation) for g in range(3)] + [17.0, 2.0]
+    assert pauses.n == n0 + 1
+
+
+def test_full_collections_reader_reads_the_rise_or_nothing(capsys):
+    import importlib
+
+    mod = importlib.import_module(
+        "benchmarks.layer_metrics.gc_full_collections_in_window")
+    rise = {"py_gc_collections_total_gen0": 41.0,
+            "py_gc_collections_total_gen1": 4.0,
+            "py_gc_collections_total_gen2": 1.0,
+            "py_gc_collected_objects_total": 1234.0,
+            "py_gc_uncollectable_total": 0.0}
+    assert mod.read({"registry": rise}) == 1.0
+    out = capsys.readouterr().out
+    assert f"thresholds {gc.get_threshold()}" in out and "peak RSS" in out
+    assert "41 young, 4 of generation 1, 1 full; 1234 objects" in out
+    # a window without a full collection reads 0, not nothing
+    assert mod.read({"registry": dict(
+        rise, py_gc_collections_total_gen2=0.0)}) == 0.0
+    # the parent: no counter, nothing to read, and the process's own
+    # line all the same (its thresholds and peak RSS are the comparison)
+    capsys.readouterr()
+    assert mod.read({"registry": {"py_gc_pause_seconds": 3.0}}) is None
+    assert "peak RSS" in capsys.readouterr().out
+    kb, source = mod.peak_rss_kb()
+    assert kb > 0 and source in ("VmHWM", "ru_maxrss")
+    assert 0 < mod.resident_now_kb() <= kb
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    entry, = (m for m in manifest["per_layer"]
+              if m["name"] == "gc_full_collections_in_window")
+    assert entry == {
+        "name": "gc_full_collections_in_window", "unit": "count",
+        "better": "lower", "source": "program_counter",
+        "layer": "Python runtime of the server process",
+        "moves": "converge_p50_ms",
+        "workloads": [w["name"] for w in manifest["workloads"]]}
+
+
 # ---------------------------------------------------------------------------
 # splitter
 # ---------------------------------------------------------------------------
