@@ -2,6 +2,7 @@
 
 from .trace import (
     NOOP,
+    PERF_TO_MONO,
     PHASES,
     TRACEPARENT,
     TRACER,
@@ -10,6 +11,9 @@ from .trace import (
     conv_begin,
     ctx_from_wal,
     current,
+    edge_append,
+    edge_kept,
+    edges,
     link_obj,
     obj_link,
     phase,
@@ -22,9 +26,10 @@ from .trace import (
 )
 
 __all__ = [
-    "NOOP", "PHASES", "TRACEPARENT", "TRACER", "TraceContext", "annotate",
-    "conv_begin",
-    "ctx_from_wal", "current", "link_obj", "obj_link", "phase",
+    "NOOP", "PERF_TO_MONO", "PHASES", "TRACEPARENT", "TRACER",
+    "TraceContext", "annotate", "conv_begin",
+    "ctx_from_wal", "current", "edge_append", "edge_kept", "edges",
+    "link_obj", "obj_link", "phase",
     "record_span", "reset_current", "set_current", "span", "use",
     "write_ctx",
 ]

@@ -33,6 +33,18 @@ it); nothing here runs at import.
   or section takes a lock. All of it is work on a loop that queues: at
   91 % busy half a percent of loop work was 8 % of the median
   (``PERF.md`` §6, PR 42), which is why a pass reads one clock;
+- the passes by HANDLE (:class:`HandleTable`), only while a profiler
+  slice is open: the ledger registers ``sys.monitoring`` local events
+  on ``asyncio.events.Handle._run``'s code object and nothing else, so
+  every callback the loop runs — a transport's ``_read_ready``, the
+  self-pipe's ``_read_from_self``, a step of a task, a timer — is named
+  by its kind and timed: wall seconds, and the seconds of it under no
+  ``kcp.*`` section (``server_loop_handle_seconds_<kind>``,
+  ``server_loop_handle_unnamed_seconds_<kind>``, ``GET /debug/loop``).
+  This is what tells the loop's unnamed time apart: a sampler THREAD
+  (``/debug/profile``) sees the loop's thread chiefly where it lets go
+  of the GIL. With no slice open nothing is registered and the tool id
+  is free;
 - ``server_loop_lag_seconds``: a timer that re-arms itself every
   :data:`LAG_INTERVAL_S` on the serving loop and observes how late it
   fired — the time a ready callback waits behind whatever the loop is
@@ -63,11 +75,15 @@ it); nothing here runs at import.
 from __future__ import annotations
 
 import asyncio
+import functools
 import gc
+import re
 import sys
+import threading
 import time
 from collections import deque
 from threading import get_ident
+from time import perf_counter as _perf_counter
 
 from .. import obs
 from ..utils.trace import REGISTRY
@@ -176,7 +192,12 @@ class LoopLedger:
         self._tid = 0
         # a profiler session is open (asked once a beat, not a section)
         self.profiling = False
-        self._t = time.monotonic()
+        # time by handle, while a slice is open (HandleTable); else None
+        self.handles: HandleTable | None = None
+        #: time.monotonic() at the start of the pass that is running (the
+        #: last return of ``select``): what a request is stamped with as
+        #: the instant its first byte could have been read
+        self.pass_start = time.monotonic()
         self._c = 0.0
 
     # ---------------------------------------------------------- install
@@ -210,6 +231,7 @@ class LoopLedger:
         self.users -= 1
         self.publish()
         if self.users == 0:
+            self._close_handles()
             del self._selector.select
             if _LEDGERS.get(self._tid) is self:
                 del _LEDGERS[self._tid]
@@ -222,7 +244,7 @@ class LoopLedger:
 
     def select(self, timeout=None):
         now = time.monotonic()
-        busy = now - self._t
+        busy = now - self.pass_start
         self.busy_seconds += busy
         self.passes += 1
         if busy >= LONG_PASS_S:
@@ -237,7 +259,7 @@ class LoopLedger:
             # ready work is behind this select, so it comes straight
             # back (2-5 us): counted as the next pass's first
             # microseconds, at one clock read a pass
-            self._t = now
+            self.pass_start = now
             return self._select(0)
         # a select that may wait: idle is measured, and the thread's
         # clock (a system call) read on both sides of it, so CPU time
@@ -251,7 +273,7 @@ class LoopLedger:
         else:
             events = self._select(timeout)
         self._c = time.thread_time()
-        t = self._t = time.monotonic()
+        t = self.pass_start = time.monotonic()
         self.idle_seconds += t - now
         return events
 
@@ -264,14 +286,46 @@ class LoopLedger:
             by_name[sec.name] = by_name.get(sec.name, 0.0) + seconds
         top = sorted(by_name.items(), key=lambda kv: -kv[1])[:3]
         self.ring.append({
-            "start": self._t, "wall_s": wall,
+            "start": self.pass_start, "wall_s": wall,
             "sections": [[n, s] for n, s in top]})
 
     # ---------------------------------------------------------- publish
 
+    def named_seconds(self) -> float:
+        """Σ of the sections' self seconds as they stand."""
+        return sum([s.seconds for s in self.sections.values()
+                    if s is not _NOOP])
+
+    def busy_now(self) -> float:
+        """Busy seconds up to this instant, the running pass included
+        (for a caller ON the loop, which is inside a pass)."""
+        return self.busy_seconds + (time.monotonic() - self.pass_start)
+
+    def _open_handles(self) -> None:
+        if (self.handles is None and self._tid == get_ident()
+                and _HandleHook.acquire()):
+            self.handles = HandleTable(self)
+
+    def _close_handles(self) -> None:
+        table = self.handles
+        if table is not None:
+            table.publish()
+            self.handles = None
+            _HandleHook.release()
+
     def publish(self) -> None:
         ta = _trace._trace_annotation or _trace.profiler_annotation()
-        self.profiling = ta is not None and ta.is_enabled()
+        profiling = ta is not None and ta.is_enabled()
+        if profiling != self.profiling:
+            # a profiler slice opened or closed since the last beat: the
+            # handle table lives exactly as long
+            self.profiling = profiling
+            if profiling:
+                self._open_handles()
+            else:
+                self._close_handles()
+        if self.handles is not None:
+            self.handles.publish()
         if self._tid == get_ident():
             # CPU time up to here: a loop that never waits (a closed
             # loop at saturation) would otherwise account none of it
@@ -304,7 +358,216 @@ class LoopLedger:
                                  for s in self.sections.values()
                                  if s is not _NOOP},
                    long_passes_recent=list(self.ring))
+        if self.handles is not None:
+            out["handles"] = self.handles.report()
         return out
+
+
+# ---------------------------------------------------------------------------
+# the loop's passes by handle (while a profiler slice is open)
+# ---------------------------------------------------------------------------
+
+#: every callback an asyncio loop runs goes through this one function
+_RUN_CODE = asyncio.events.Handle._run.__code__
+#: the table names at most this many kinds of handle; the rest are
+#: ``other`` (the counters are a family of the registry: bounded)
+MAX_KINDS = 32
+_TOOL_NAME = "kcp-loop-handles"
+_HANDLE_BUSY = REGISTRY.counter(
+    "server_loop_handle_busy_seconds_total",
+    "busy seconds of the serving loop while its handle table was open "
+    "(a profiler slice): what the table's wall seconds are a share of")
+
+
+class _Kind:
+    """One kind of handle: its seconds, and what the beat has published."""
+
+    __slots__ = ("wall", "unnamed", "runs", "_done", "_counters")
+
+    def __init__(self) -> None:
+        self.wall = self.unnamed = 0.0
+        self.runs = 0
+        self._done = [0.0, 0.0]
+        self._counters: tuple | None = None
+
+    def publish(self, kind: str) -> None:
+        if self._counters is None:
+            name = re.sub(r"\W", "_", kind)
+            self._counters = (
+                REGISTRY.counter(
+                    f"server_loop_handle_seconds_{name}",
+                    "wall seconds of one kind of asyncio handle on the "
+                    "serving loop, while a profiler slice is open"),
+                REGISTRY.counter(
+                    f"server_loop_handle_unnamed_seconds_{name}",
+                    "the seconds of one kind of asyncio handle on the "
+                    "serving loop under no obs.annotate section, while a "
+                    "profiler slice is open"))
+        for i, value in enumerate((self.wall, self.unnamed)):
+            if value != self._done[i]:
+                self._counters[i].inc(value - self._done[i])
+                self._done[i] = value
+
+
+class HandleTable:
+    """What one loop's handles did with the loop's busy time, kind by
+    kind, for as long as a profiler slice is open (the ledger's beat
+    opens and closes it with ``LoopLedger.profiling``).
+
+    A kind is a step of a task by its coroutine (``task:HttpServer.
+    _serve``), a bound method by class and name (``_SelectorSocket
+    Transport._read_ready``, ``BaseSelectorEventLoop._read_from_self``,
+    a timer's ``RuntimeProbes._beat``), anything else by its
+    ``__qualname__``. Per kind: wall seconds from ``Handle._run``'s
+    start to its return, and the part of them under no ``kcp.*``
+    section — wall less the rise of the sections' self seconds across
+    the handle, summed only where the ledger's boundary stamp moved
+    (most handles open no section). Single writer: the loop's thread."""
+
+    def __init__(self, ledger: LoopLedger) -> None:
+        self._led = ledger
+        self.kinds: dict[str, _Kind] = {}
+        self._names: dict = {}  # a callback's code or (type, name) -> kind
+        self._cur: _Kind | None = None
+        self._t0 = 0.0
+        # Σ self seconds of the ledger's sections, at the boundary stamp
+        # it was summed at (_named_rise)
+        self._named = 0.0
+        self._named_mark = -1.0
+        self._busy0 = self._busy_done = ledger.busy_now()
+
+    def _kind_of(self, cb) -> str:
+        while isinstance(cb, functools.partial):
+            cb = cb.func
+        owner = getattr(cb, "__self__", None)
+        get_coro = getattr(owner, "get_coro", None)
+        if get_coro is not None:  # a task's step or wake-up
+            coro = get_coro()
+            key = getattr(coro, "cr_code", None) or type(coro)
+            kind = self._names.get(key)
+            if kind is None:
+                kind = self._names[key] = "task:" + (
+                    getattr(coro, "__qualname__", "") or type(coro).__name__)
+            return kind
+        code = getattr(getattr(cb, "__func__", cb), "__code__", None)
+        name = getattr(cb, "__name__", None) or type(cb).__name__
+        if owner is not None:
+            key = (type(owner), code or name)
+        else:
+            key = code or (type(cb), name)
+        kind = self._names.get(key)
+        if kind is None:
+            kind = self._names[key] = (
+                f"{type(owner).__name__}.{name}" if owner is not None
+                else getattr(cb, "__qualname__", name))
+        return kind
+
+    def _named_rise(self) -> float:
+        """The rise of the sections' Σ self seconds since the last call,
+        summed only where the ledger's boundary stamp has moved."""
+        mark = self._led.mark
+        if mark == self._named_mark:
+            return 0.0
+        total = self._led.named_seconds()
+        rise, self._named, self._named_mark = total - self._named, total, mark
+        return rise
+
+    def start(self, handle) -> None:
+        kind = self._kind_of(handle._callback)
+        slot = self.kinds.get(kind)
+        if slot is None:
+            if len(self.kinds) >= MAX_KINDS:
+                kind = "other"
+            slot = self.kinds.get(kind)
+            if slot is None:
+                slot = self.kinds[kind] = _Kind()
+        self._cur = slot
+        self._named_rise()  # what ran between two handles is not this one's
+        self._t0 = _perf_counter()
+
+    def finish(self) -> None:
+        now = _perf_counter()
+        slot = self._cur
+        if slot is None:  # the handle that opened the table
+            return
+        self._cur = None
+        wall = now - self._t0
+        slot.wall += wall
+        slot.unnamed += max(0.0, wall - self._named_rise())
+        slot.runs += 1
+
+    def publish(self) -> None:
+        for kind, slot in list(self.kinds.items()):
+            slot.publish(kind)
+        busy = self._led.busy_now()
+        _HANDLE_BUSY.inc(busy - self._busy_done)
+        self._busy_done = busy
+
+    def report(self) -> dict:
+        return {"busy_s": self._led.busy_now() - self._busy0,
+                "kinds": {k: {"wall_s": s.wall, "unnamed_s": s.unnamed,
+                              "runs": s.runs}
+                          for k, s in self.kinds.items()}}
+
+
+def _on_handle_start(code, offset) -> None:
+    led = _LEDGERS.get(get_ident())
+    if led is not None and led.handles is not None:
+        led.handles.start(sys._getframe(1).f_locals["self"])
+
+
+def _on_handle_return(code, offset, retval) -> None:
+    led = _LEDGERS.get(get_ident())
+    if led is not None and led.handles is not None:
+        led.handles.finish()
+
+
+class _HandleHook:
+    """``sys.monitoring``'s registration for :class:`HandleTable`: LOCAL
+    events (start and return) on ``Handle._run``'s code object alone,
+    held while at least one ledger of the process has its table open.
+    A tool id is the interpreter's, so the ledgers share one; with no
+    table open no tool id is in use and the code object carries no
+    event. An interpreter without ``sys.monitoring`` (before 3.12), or
+    with every tool id taken, has no table."""
+
+    tool: int | None = None
+    users = 0
+    _lock = threading.Lock()
+
+    @classmethod
+    def acquire(cls) -> bool:
+        mon = getattr(sys, "monitoring", None)
+        if mon is None:
+            return False
+        with cls._lock:
+            if cls.users == 0:
+                free = [t for t in (mon.PROFILER_ID, 3, 4, mon.OPTIMIZER_ID)
+                        if mon.get_tool(t) is None]
+                if not free:
+                    return False
+                tool = cls.tool = free[0]
+                ev = mon.events
+                mon.use_tool_id(tool, _TOOL_NAME)
+                mon.register_callback(tool, ev.PY_START, _on_handle_start)
+                mon.register_callback(tool, ev.PY_RETURN, _on_handle_return)
+                mon.set_local_events(tool, _RUN_CODE,
+                                     ev.PY_START | ev.PY_RETURN)
+            cls.users += 1
+        return True
+
+    @classmethod
+    def release(cls) -> None:
+        mon = sys.monitoring
+        with cls._lock:
+            cls.users -= 1
+            if cls.users == 0:
+                tool, cls.tool = cls.tool, None
+                ev = mon.events
+                mon.set_local_events(tool, _RUN_CODE, 0)
+                mon.register_callback(tool, ev.PY_START, None)
+                mon.register_callback(tool, ev.PY_RETURN, None)
+                mon.free_tool_id(tool)
 
 
 def long_passes() -> list[dict]:

@@ -32,6 +32,14 @@ layer for the kcp-tpu fleet, Dapper-style:
   stamps (all ``time.monotonic()``), so their sum telescopes to the
   end-to-end time by construction
   (``tests/test_tracing.py::test_convergence_phases_sum_reconcile_in_process``);
+- the two sockets: a write that came over HTTP starts its timeline
+  one phase earlier, ``ingress`` (the start of the loop pass that read
+  the request's first byte → the handler's entry), and for ONE OBJECT
+  IN EIGHT, chosen by its name, a bounded in-process **edge log**
+  (:func:`edges`) keeps where each write request and each delivered
+  watch frame of that object met the transport, on the clock every
+  process of the machine shares — what a load generator on the same
+  host joins its own send / ack / seen stamps against;
 - the same boundaries as host SECTIONS: :func:`annotate` names the
   synchronous ``kcp.*`` sections of the tick, the store, the applier,
   the HTTP path and the watch relay. On a serving loop's thread each
@@ -71,7 +79,11 @@ from ..utils.trace import REGISTRY
 TRACEPARENT = "traceparent"
 
 #: the convergence phases, in timeline order; adjacent phases share
-#: their boundary stamp. ``write`` (entry of the serving handler — or of
+#: their boundary stamp. ``ingress`` (a write that came over HTTP only:
+#: the start of the loop pass that read the request's first byte →
+#: entry of the serving handler — the pass's earlier callbacks, the
+#: ``recv``, the reader's wake-up and the parse), ``write`` (entry of
+#: the serving handler — or of
 #: the store, for an in-process writer — → the commit stamp on the
 #: write's event), ``propagate`` (commit → the syncer engine staged the
 #: key: commit window, watch fan-out, informer), ``stage`` (staged →
@@ -87,8 +99,8 @@ TRACEPARENT = "traceparent"
 #: a write whose first status is already up, as a rolling controller
 #: makes them — the downstream status event re-staged the row → that
 #: status committed upstream; no span, a histogram only).
-PHASES = ("write", "propagate", "stage", "tick", "patch", "downstream",
-          "upstatus", "observe", "restatus")
+PHASES = ("ingress", "write", "propagate", "stage", "tick", "patch",
+          "downstream", "upstatus", "observe", "restatus")
 
 #: the phase histograms, fetched once: an observation is a dict probe,
 #: a bisect and the histogram's own leaf lock — never the registry's
@@ -101,6 +113,11 @@ _PHASE_H = {
 # phase stamps are time.monotonic(); a span's t0 is wall-clock (spans
 # from several processes are merged by t0). One offset per process.
 _MONO_TO_WALL = time.time() - time.monotonic()
+#: a section's stamps are time.perf_counter(); added to one, this gives
+#: the same instant on time.monotonic()'s clock (0.0 where the two are
+#: one clock, as on Linux): how a site that closes a section hands the
+#: section's own end stamp on as a phase or edge stamp
+PERF_TO_MONO = time.monotonic() - _perf_counter()
 
 _current: contextvars.ContextVar["TraceContext | None"] = \
     contextvars.ContextVar("kcp_trace_ctx", default=None)
@@ -401,6 +418,39 @@ def phase(name: str, ctx: TraceContext | None, t0: float, t1: float,
         sub = TRACER.child(ctx)
         TRACER.record("conv." + name, sub, ctx.span_id,
                       t0 + _MONO_TO_WALL, dur, attrs or None)
+
+
+# ---------------------------------------------------------------------------
+# the edge log: what happened at the two sockets, for one object in eight
+# ---------------------------------------------------------------------------
+
+#: a key is kept when ``hash(name) & EDGE_MASK == 0``: chosen by the
+#: object's NAME, so the request that wrote an object and the frames that
+#: carried its events are kept or dropped together within a process (a
+#: coin per request cannot give that). ``str`` hashes differ between
+#: processes: whoever joins the log against stamps from outside asks
+#: :func:`edge_kept` in THIS process.
+EDGE_MASK = 7
+#: ``("req", cluster, name, rx, t0, t_out)`` once a write request of a
+#: kept key, where its response has been handed to the transport (``rx``
+#: the start of the loop pass that read its first byte, 0.0 = not known;
+#: ``t0`` the handler's entry); ``("frame", cluster, name, tm, t_handed)``
+#: once a delivered watch event of a kept key (``tm`` its commit stamp).
+#: Every stamp is time.monotonic(), the one CLOCK_MONOTONIC every
+#: process of the machine reads. Bounded, always on; appends are GIL-
+#: atomic, :func:`edges` copies.
+_EDGES: deque[tuple] = deque(maxlen=32768)
+edge_append = _EDGES.append
+
+
+def edge_kept(name: str) -> bool:
+    """Whether the edge log of THIS process keeps the object ``name``."""
+    return hash(name) & EDGE_MASK == 0
+
+
+def edges() -> list[tuple]:
+    """A copy of the edge log, oldest record first."""
+    return list(_EDGES)
 
 
 _trace_annotation = None  # jax.profiler.TraceAnnotation, once jax is imported

@@ -40,6 +40,16 @@ CLUSTER_HEADER = "x-kubernetes-cluster"
 # (`kcp.read.<verb>`) each
 _READ_VERBS = ("get", "list", "page", "table")
 
+# every request, reads too: the listener fed the request's first bytes
+# to the connection's reader (`Request.fed`) -> the handler's entry
+# (`Request.t0`) — the reader's wake-up, `readuntil`, `readexactly`,
+# the parse. The `ingress` phase less this is what the bytes waited
+# behind the pass's earlier callbacks and the `recv`.
+_INGRESS_WAKE = REGISTRY.histogram(
+    "http_ingress_wake_seconds",
+    "one request from its first bytes fed to the connection's reader to "
+    "the serving handler's entry: the reader's wake-up and the parse")
+
 
 _QUEUE_EVICTED = ("watch queue overflowed (KCP_WATCH_QUEUE): slow watcher "
                   "evicted; re-list and resume")
@@ -352,13 +362,20 @@ class RestHandler:
         """Serve one request under a trace context (kcp_tpu/obs/): the
         incoming ``traceparent`` is honored, otherwise a root is minted
         (head-sampled); the span records only when sampled — except
-        SLO-breaching requests (> KCP_TRACE_SLO_MS), which force-record
+        SLO-breaching requests (> KCP_TRACE_SLO_MS, counted from the
+        pass that read the request's first byte where the listener
+        stamped it), which force-record
         so a latency regression always comes with its own explanation.
         Under ``KCP_TRACE=0`` this wrapper is one attribute read."""
-        req.t0 = time.monotonic()
+        t_in = req.t0 = time.monotonic()
+        if req.fed:
+            _INGRESS_WAKE.observe(t_in - req.fed)
         tracer = obs.TRACER
         if not tracer.enabled:
             return await self._handle(req)
+        # what the request waited before this entry counts towards the
+        # SLO: a breach that happened before the handler is a breach
+        ingress = t_in - req.rx if req.rx else 0.0
         tp = req.headers.get(obs.TRACEPARENT)
         if tp is None and not tracer.head_sampled():
             # the overwhelmingly common case — untraced arrival, coin
@@ -367,8 +384,8 @@ class RestHandler:
             t0 = time.time()
             resp = await self._handle(req)
             dur = time.time() - t0
-            if dur >= tracer.slo_s:
-                self._slo_span(None, req, resp, t0, dur)
+            if dur + ingress >= tracer.slo_s:
+                self._slo_span(None, req, resp, t0, dur, ingress)
             return resp
         ctx = tracer.from_headers(req.headers) if tp else \
             tracer.mint(sampled=True)
@@ -378,8 +395,8 @@ class RestHandler:
             t0 = time.time()
             resp = await self._handle(req)
             dur = time.time() - t0
-            if dur >= tracer.slo_s:
-                self._slo_span(ctx, req, resp, t0, dur)
+            if dur + ingress >= tracer.slo_s:
+                self._slo_span(ctx, req, resp, t0, dur, ingress)
             return resp
         sub = tracer.child(ctx)
         token = obs.set_current(sub)
@@ -394,25 +411,33 @@ class RestHandler:
             dur = time.time() - t0
             attrs = {"method": req.method, "path": req.path,
                      "status": status}
-            if dur >= tracer.slo_s:
+            if dur + ingress >= tracer.slo_s:
                 attrs["slo_breach"] = True
+                if req.rx:
+                    attrs["ingress_s"] = round(ingress, 6)
             obs.record_span("server.request", sub, ctx.span_id, t0,
                             dur, attrs)
 
     @staticmethod
-    def _slo_span(ctx, req: Request, resp, t0: float, dur: float) -> None:
+    def _slo_span(ctx, req: Request, resp, t0: float, dur: float,
+                  ingress: float) -> None:
         """Force-record the serving span of an SLO-breaching request
         that head sampling skipped — a latency regression always ships
-        with its own explanation."""
+        with its own explanation. The breach is measured from the pass
+        that read the request's first byte where that is known
+        (``req.rx``): ``ingress_s`` is the part of it before the
+        handler's entry, which the span's own ``dur`` does not hold."""
         tracer = obs.TRACER
         base = ctx or tracer.mint(sampled=False)
         if base is None:
             return
+        attrs = {"method": req.method, "path": req.path,
+                 "status": getattr(resp, "status", 200), "slo_breach": True}
+        if req.rx:
+            attrs["ingress_s"] = round(ingress, 6)
         obs.record_span(
             "server.request", tracer.child(base), base.span_id, t0, dur,
-            {"method": req.method, "path": req.path,
-             "status": getattr(resp, "status", 200), "slo_breach": True},
-            force=True)
+            attrs, force=True)
 
     async def _handle(self, req: Request) -> Response | StreamResponse:
         if self.draining.is_set():
@@ -510,7 +535,8 @@ class RestHandler:
         if head == "debug" and segs[1:] == ["loop"]:
             # "what held the loop": the serving loop's ledger as it
             # stands and its last long passes, each with its three
-            # largest sections (obs/runtime.py); stamps are
+            # largest sections, and while a profiler slice is open its
+            # time by asyncio handle (obs/runtime.py); stamps are
             # time.monotonic()'s. Server-global like /debug/profile.
             if not await self._server_scope_allowed(req):
                 return self._forbidden(req, "read /debug/loop")
@@ -764,6 +790,8 @@ class RestHandler:
         if req.method == "POST" and name is None:
             obj = self._body_object(req)
             target = resolve_write_cluster(cluster, obj, errors.BadRequestError)
+            self._mark_edge(req, target,
+                            (obj.get("metadata") or {}).get("name"))
             # the stored snapshot, not a private copy of it: it is only
             # encoded here (stamped on a shallow copy of its top level)
             created, t_done = await self._write(
@@ -780,6 +808,7 @@ class RestHandler:
                 raise errors.BadRequestError(
                     f"name in URL ({name}) does not match name in object ({body_name})")
             target = resolve_write_cluster(cluster, obj, errors.BadRequestError)
+            self._mark_edge(req, target, name)
             updated, t_done = await self._write(
                 req, "update", res, target, namespace, obj,
                 self.store.update_snapshot, res, target, obj, namespace,
@@ -790,6 +819,7 @@ class RestHandler:
 
         if req.method == "DELETE" and name is not None:
             target = await self._read_cluster(cluster, res, name, namespace)
+            self._mark_edge(req, target, name)
             _none, t_done = await self._write(
                 req, "delete", res, target, namespace, None,
                 self.store.delete, res, target, name, namespace)
@@ -889,6 +919,14 @@ class RestHandler:
         self._enc_seconds.observe(time.perf_counter() - t0)
         return resp
 
+    @staticmethod
+    def _mark_edge(req: Request, cluster: str, name) -> None:
+        """Note the object a write request writes, where the edge log
+        keeps its name (one object in eight): the connection loop then
+        logs the request's way in and out (``httpd._serve``)."""
+        if name and obs.edge_kept(name):
+            req.edge = (cluster, name)
+
     async def _write(self, req: Request, verb: str, res: str, target: str,
                      namespace: str, obj: dict | None, fn, *args):
         """The shared body of the write verbs: admission, the store call,
@@ -896,6 +934,9 @@ class RestHandler:
         store call's result and the ``time.monotonic()`` of its return,
         from which :meth:`_acked` closes ``request_finish_seconds`` once
         the caller has encoded the response."""
+        if req.rx:
+            # the way in, ending on the stamp `write` starts from
+            obs.phase("ingress", obs.write_ctx(), req.rx, req.t0)
         t0 = time.monotonic()
         self._body_bytes.inc(len(req.body))
         # admission inline (reads never touch it): admit_nowait only
@@ -1620,11 +1661,17 @@ class RestHandler:
             def stamp_observed(batch) -> None:
                 # `observe`: commit of each event -> its frame handed to
                 # this stream's transport, for every delivered event
+                # (and, for a key the edge log keeps, the way out's
+                # record: the join against the client's own `seen`)
                 now = time.monotonic()
+                kept = obs.edge_kept
                 for e in batch:
                     tm = e.__dict__.get("_tm")
                     if tm is not None:
                         obs.phase("observe", None, tm, now)
+                    if kept(e.name):
+                        obs.edge_append(
+                            ("frame", e.cluster, e.name, tm or 0.0, now))
 
             def encode_lines(batch) -> list[bytes]:
                 # encode-once: every stream serving this store splices

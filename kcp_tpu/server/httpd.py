@@ -15,10 +15,14 @@ import json
 import logging
 import os
 from dataclasses import dataclass
+from threading import get_ident as _get_ident
+from time import monotonic as _monotonic
+from time import perf_counter as _perf_counter
 from typing import Awaitable, Callable
 from urllib.parse import parse_qs, unquote, urlsplit
 
 from .. import obs
+from ..obs.trace import _LEDGERS
 from ..utils.trace import REGISTRY, SIZE_BUCKETS
 
 log = logging.getLogger(__name__)
@@ -44,6 +48,15 @@ _JOIN_AVOIDED = REGISTRY.counter(
     "wire_join_avoided_total",
     "response-body bytes written without the whole-body b''.join copy "
     "(scatter path only)")
+
+#: the way in and out of one request, from the loop pass that read its
+#: first byte (``Request.rx``) to the instant its response was handed to
+#: the transport: the handler's own histograms start at ``Request.t0``
+_SERVED = REGISTRY.histogram(
+    "request_served_seconds",
+    "one request from the start of the loop pass that read its first "
+    "byte to its response handed to the transport (every non-stream "
+    "response whose first byte's pass is known)")
 
 MAX_HEADER_BYTES = 64 * 1024
 # listener accept backlog: a 10k-watcher reconnect storm lands thousands
@@ -133,6 +146,17 @@ class Request:
     # time.monotonic() at the serving handler's entry: where the `write`
     # phase of a convergence starts (obs/trace.py PHASES)
     t0: float = 0.0
+    # the way in, stamped by the listener's protocol (_StampedProtocol),
+    # both time.monotonic(), 0.0 = not known (a pipelined request whose
+    # bytes came with the one before it): ``rx`` the start of the loop
+    # pass that read the request's first byte — where the `ingress`
+    # phase starts — and ``fed`` the instant those bytes were fed to
+    # the connection's reader
+    rx: float = 0.0
+    fed: float = 0.0
+    # (cluster, name) of the object a write request wrote, set by the
+    # handler for a key the edge log keeps (obs.edge_kept), else None
+    edge: tuple[str, str] | None = None
 
     def param(self, name: str, default: str | None = None) -> str | None:
         vals = self.query.get(name)
@@ -328,8 +352,30 @@ def _reason(status: int) -> str:
 Handler = Callable[[Request], Awaitable["Response | StreamResponse"]]
 
 
+class _StampedProtocol(asyncio.StreamReaderProtocol):
+    """The listener's protocol: ``asyncio``'s own, plus the two stamps of
+    a request's way in. While the connection has no request open
+    (``rx`` is 0.0: ``_read_request`` clears it as it hands a request
+    over), the first ``data_received`` stamps ``rx`` — the start of the
+    loop pass that is running, which the loop's ledger already holds
+    (no clock read; on a loop without a ledger, the one read below) —
+    and ``fed``, ``time.monotonic()`` here: one clock read a request."""
+
+    rx = 0.0
+    fed = 0.0
+
+    def data_received(self, data: bytes) -> None:
+        if not self.rx:
+            now = self.fed = _monotonic()
+            led = _LEDGERS.get(_get_ident())
+            self.rx = led.pass_start if led is not None else now
+        super().data_received(data)
+
+
 class HttpServer:
-    """asyncio.start_server wrapper dispatching to a single handler."""
+    """An asyncio stream server (what ``asyncio.start_server`` builds,
+    with :class:`_StampedProtocol` in the factory) dispatching to a
+    single handler."""
 
     def __init__(self, handler: Handler, host: str = "127.0.0.1", port: int = 0,
                  ssl_context=None):
@@ -348,8 +394,15 @@ class HttpServer:
         self._busy = 0  # requests currently between parse and response
 
     async def start(self) -> None:
-        self._server = await asyncio.start_server(
-            self._serve, self.host, self.port, ssl=self.ssl_context,
+        # what asyncio.start_server does, with our protocol in the factory
+        loop = asyncio.get_running_loop()
+
+        def factory() -> _StampedProtocol:
+            return _StampedProtocol(asyncio.StreamReader(loop=loop),
+                                    self._serve, loop=loop)
+
+        self._server = await loop.create_server(
+            factory, self.host, self.port, ssl=self.ssl_context,
             backlog=LISTEN_BACKLOG)
         self.port = self._server.sockets[0].getsockname()[1]
         log.info("http%s server listening on %s:%d",
@@ -423,11 +476,12 @@ class HttpServer:
         if task is not None:
             self._conns.add(task)
             task.add_done_callback(self._conns.discard)
+        proto = writer.transport.get_protocol()
         try:
             while True:
                 self._idle.add(writer)
                 try:
-                    req = await self._read_request(reader)
+                    req = await self._read_request(reader, proto)
                 except RequestTooLarge as e:
                     # 413 instead of buffering: the body was never read,
                     # so answer and close rather than resynchronize
@@ -468,8 +522,20 @@ class HttpServer:
                         # requests on a server that is going away
                         keep = (req.headers.get("connection", "keep-alive")
                                 != "close") and not self._draining
-                        with obs.annotate("kcp.http.respond"):
+                        sec = obs.annotate("kcp.http.respond")
+                        sec.begin(_perf_counter())
+                        try:
                             self._respond(writer, resp, keep)
+                        finally:
+                            now = _perf_counter()
+                            sec.end(now)
+                        # the way out, at the section's own end stamp
+                        t_out = now + obs.PERF_TO_MONO
+                        if req.rx:
+                            _SERVED.observe(t_out - req.rx)
+                        if req.edge is not None:
+                            obs.edge_append(("req", *req.edge, req.rx,
+                                             req.t0, t_out))
                         await writer.drain()
                 finally:
                     self._busy -= 1
@@ -540,7 +606,12 @@ class HttpServer:
         else:
             writer.write(head.encode() + resp.body)
 
-    async def _read_request(self, reader: asyncio.StreamReader) -> Request | None:
+    async def _read_request(self, reader: asyncio.StreamReader,
+                            proto=None) -> Request | None:
+        """One parsed request, or None at the connection's end. ``proto``
+        is the connection's protocol where it stamps the way in
+        (:class:`_StampedProtocol`): its stamps go onto the request and
+        its slot is cleared, so the next first byte stamps anew."""
         try:
             head = await reader.readuntil(b"\r\n\r\n")
         except (asyncio.IncompleteReadError, ConnectionError):
@@ -565,7 +636,7 @@ class HttpServer:
                 raise RequestTooLarge(clen)
             body = await reader.readexactly(clen)
         parts = urlsplit(target)
-        return Request(
+        req = Request(
             method=method.upper(),
             path=unquote(parts.path),
             query=parse_qs(parts.query),
@@ -573,3 +644,8 @@ class HttpServer:
             body=body,
             target=target,
         )
+        rx = getattr(proto, "rx", 0.0)
+        if rx:
+            req.rx, req.fed = rx, proto.fed
+            proto.rx = 0.0
+        return req
